@@ -59,6 +59,9 @@ def main(argv=None) -> int:
         if args.cordapp:
             config.cordapps = config.cordapps + args.cordapp
 
+    if config.verifier_type == "Tpu":
+        from ..utils.compile_cache import enable_compile_cache
+        enable_compile_cache()
     if not args.quiet:
         print(BANNER)
     node = Node(config).start()

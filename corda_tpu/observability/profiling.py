@@ -141,8 +141,9 @@ class KernelProfiler:
         # compile count stamped by mark_warm(); compiles_since_warm() is the
         # steady-state regression signal (a hot jit cache must stop growing)
         self._warm_compiles = 0
-        # fallback compile detection for callables without _cache_size:
-        # kernel name -> set of seen arg-shape signatures
+        # compile detection for plain callables (test stubs): kernel name
+        # -> set of seen arg-shape signatures. Every production kernel is a
+        # jax.jit, whose _cache_size the installed JAX (0.9.0) provides.
         self._seen_sigs: dict[str, set] = {}
         # id(device value) -> kernel name, for finish-time attribution
         self._pending: OrderedDict = OrderedDict()

@@ -9,8 +9,8 @@ one Artemis queue, Verifier.kt:58-76) with SPMD over a `Mesh`:
   builds its local subtree, local roots `all_gather`ed over ICI and the
   (tiny) top of the tree computed replicated (the sp axis + collective).
 
-Everything here is also the multi-chip dry-run path exercised by
-``__graft_entry__.dryrun_multichip`` on a virtual CPU mesh.
+``python chip_smoke.py --chips 4`` runs the Ed25519 dp path and the Merkle
+all_gather path on four real chips; tests run them on virtual CPU devices.
 """
 from __future__ import annotations
 

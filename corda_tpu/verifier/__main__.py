@@ -19,6 +19,7 @@ device-verified work happened in this process, then exits cleanly.
 from __future__ import annotations
 
 import argparse
+import glob
 import importlib
 import json
 import os
@@ -34,6 +35,43 @@ def _literal_resolve(name: str):
         return host, int(port)
     except ValueError:
         return None
+
+
+#: TPU_CHIPS_PER_PROCESS_BOUNDS for a process that owns this many chips.
+_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
+
+
+def _local_chip_count() -> int:
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
+
+
+def confine_to_shard(shard_index: int, num_shards: int) -> tuple:
+    """One process per chip: a TPU runtime that opens every local chip
+    leaves nothing for the next worker, so a fleet worker tells the runtime
+    — through its own visible-chips settings, BEFORE the backend first
+    initialises — to open only this shard's chips. Returns the chip
+    indices it confined itself to; () when there is nothing to confine (no
+    local TPU, the operator already set the visibility, or a shard size
+    the runtime has no bounds for) and the shard is cut from
+    ``jax.devices()`` as before."""
+    if os.environ.get("TPU_VISIBLE_CHIPS") or \
+            os.environ.get("TPU_VISIBLE_DEVICES"):
+        return ()
+    chips = _local_chip_count()
+    if not 0 <= shard_index < num_shards <= chips:
+        return ()
+    # the same contiguous split as parallel.shard_devices
+    base, extra = divmod(chips, num_shards)
+    start = shard_index * base + min(shard_index, extra)
+    mine = tuple(range(start, start + base + (1 if shard_index < extra
+                                              else 0)))
+    if len(mine) not in _CHIP_BOUNDS:
+        return ()
+    os.environ["TPU_VISIBLE_CHIPS"] = ",".join(map(str, mine))
+    os.environ["TPU_CHIPS_PER_PROCESS_BOUNDS"] = _CHIP_BOUNDS[len(mine)]
+    os.environ["TPU_PROCESS_BOUNDS"] = "1,1,1"
+    return mine
 
 
 def main(argv=None) -> int:
@@ -73,18 +111,18 @@ def main(argv=None) -> int:
                              "corda_tpu.testing.dummy)")
     args = parser.parse_args(argv)
 
+    confined: tuple = ()
+    if args.num_shards is not None and not args.no_device:
+        confined = confine_to_shard(args.shard_index, args.num_shards)
+
     for module in (args.cordapp if args.cordapp is not None
                    else ["corda_tpu.finance", "corda_tpu.testing.dummy"]):
         importlib.import_module(module)
 
-    # persistent compile cache: repeated worker launches must not re-pay the
-    # kernel compiles (jax.config.update is the reliable path — the env-var
-    # spelling is not honored by all versions)
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if cache_dir:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    if not args.no_device:
+        # repeated worker launches must not re-pay the kernel compiles
+        from ..utils.compile_cache import enable_compile_cache
+        enable_compile_cache()
 
     from ..network.tcp import TcpMessagingService
     from .batcher import SignatureBatcher
@@ -111,9 +149,15 @@ def main(argv=None) -> int:
         # fleet mode: this worker owns one contiguous shard of the visible
         # devices — a private mesh when the shard has several chips, a
         # plain device pin (no shard_map overhead) when it has one
-        from ..parallel import shard_devices
-        shard = shard_devices(args.num_shards)[args.shard_index]
-        device_shard = tuple(d.id for d in shard)
+        if confined:
+            # the runtime shows this process only its own chips
+            import jax
+            shard = jax.devices()
+            device_shard = confined
+        else:
+            from ..parallel import shard_devices
+            shard = shard_devices(args.num_shards)[args.shard_index]
+            device_shard = tuple(d.id for d in shard)
         if len(shard) > 1:
             from ..parallel import make_mesh
             batcher_kwargs["mesh"] = make_mesh(devices=shard)

@@ -237,17 +237,16 @@ class SignatureBatcher:
     """Accepts individual signature checks, returns Future[bool] verdicts,
     dispatches device-batched kernels per scheme from a background thread.
 
-    Batch-size policy (VERDICT r2 #1): the cap defaults to the kernels'
-    measured throughput sweet spot (32k; BASELINE.md "the fixed ~140 ms
-    dispatch floor amortizes past batch ~8k") and the drain adapts to load —
-    kernels pad to power-of-two buckets so variable batch sizes compile once
-    per bucket, not per length. Batches *below* ``host_crossover`` route to
-    the host verify path instead: with a ~140 ms device dispatch floor and
-    ~2k verifies/s on one host core, a batch under ~200 items finishes on
-    host before the device kernel would even launch — this is what makes
-    p50 @ batch=1 milliseconds instead of the dispatch floor. Below the
-    crossover the dispatcher also skips the linger wait, so a lone submit
-    is not taxed ``max_latency_s`` for a batch that was never coming."""
+    Batch-size policy (VERDICT r2 #1): the cap defaults to 32k and the
+    drain adapts to load — kernels pad to power-of-two buckets so variable
+    batch sizes compile once per bucket, not per length. Batches *below*
+    ``host_crossover`` route to the host verify path instead: a small batch
+    finishes on one host core before a device round trip would. The 192
+    default was fitted to a dispatch floor that an attached chip does not
+    have (chip_smoke.py prints the measured round trip; retuning is
+    ROADMAP A2). Below the crossover the dispatcher also skips the linger
+    wait, so a lone submit is not taxed ``max_latency_s`` for a batch that
+    was never coming."""
 
     #: Prep-pool width: one worker per device scheme, so a mixed drain preps
     #: ed25519 + k1 + r1 concurrently. The heavy prep (sm_*_prep, hashing,

@@ -1,5 +1,5 @@
 """Bench regression gate (tools/benchguard.py): floors fit from the
-trajectory must fail a synthetic regression, pass the repo's real
+trajectory must fail a synthetic regression, pass a recorded
 BENCH_r*.json history, and degrade to a schema check on smoke artifacts.
 """
 import json
@@ -91,23 +91,37 @@ def test_smoke_and_zero_rounds_do_not_drag_floors():
     assert guards["value"]["best"] == 100.0
 
 
-def test_real_trajectory_passes_self_replay():
+@pytest.fixture
+def trajectory_paths(tmp_path):
+    """A three-round trajectory in the driver's artifact wrapping, written
+    where default_trajectory_paths(root) finds it — the checkout keeps no
+    BENCH_r*.json of its own."""
+    paths = []
+    for i, value in enumerate((100.0, 118.0, 112.0), start=1):
+        p = tmp_path / f"BENCH_r{i:02d}.json"
+        p.write_text(json.dumps(
+            {"n": i, "rc": 0, "parsed": _artifact(value=value)}))
+        paths.append(str(p))
+    assert benchguard.default_trajectory_paths(str(tmp_path)) == paths
+    return paths
+
+
+def test_real_trajectory_passes_self_replay(trajectory_paths):
     """Every recorded round must clear the guards fit from the rounds
-    before it — the tolerances are calibrated to the repo's real noise."""
-    paths = benchguard.default_trajectory_paths()
-    if not paths:
-        pytest.skip("no BENCH_r*.json artifacts in this checkout")
-    trajectory = benchguard.load_trajectory(paths)
+    before it."""
+    trajectory = benchguard.load_trajectory(trajectory_paths)
+    assert len(trajectory) == 3
     for i, run in enumerate(trajectory):
         guards = benchguard.fit_guards(trajectory[:i])
         value_problems = [p for p in benchguard.check(run, guards)
                           if "<" in p or ">" in p]
-        assert value_problems == [], f"round {paths[i]}: {value_problems}"
+        assert value_problems == [], \
+            f"round {trajectory_paths[i]}: {value_problems}"
 
 
-def test_cli_replays_trajectory(capsys):
-    if not benchguard.default_trajectory_paths():
-        pytest.skip("no BENCH_r*.json artifacts in this checkout")
+def test_cli_replays_trajectory(capsys, monkeypatch, trajectory_paths):
+    monkeypatch.setattr(benchguard, "default_trajectory_paths",
+                        lambda root=None: trajectory_paths)
     assert benchguard.main([]) == 0
     assert "ok" in capsys.readouterr().out
 
