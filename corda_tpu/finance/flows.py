@@ -32,8 +32,8 @@ class CashIssueFlow(FlowLogic):
         Cash.generate_issue(builder, self.amount,
                             PartyAndReference(me, self.issuer_ref),
                             self.recipient.owning_key, self.notary)
-        builder.sign_with(self.service_hub.key_management.key_pair(me.owning_key))
-        stx = builder.to_signed_transaction(check_sufficient_signatures=False)
+        stx = self.service_hub.sign_initial_transaction(
+            builder.to_wire_transaction(), me.owning_key)
         final = yield from self.sub_flow(FinalityFlow(stx, [self.recipient]))
         return final
 
@@ -71,8 +71,8 @@ class CashPaymentFlow(FlowLogic):
             Cash.generate_spend(builder, self.amount,
                                 self.recipient.owning_key, coins,
                                 change_owner=me.owning_key)
-            builder.sign_with(hub.key_management.key_pair(me.owning_key))
-            return builder.to_signed_transaction(check_sufficient_signatures=False)
+            return hub.sign_initial_transaction(
+                builder.to_wire_transaction(), me.owning_key)
         except InsufficientBalanceException as e:
             hub.vault.soft_lock_release(lock_id)
             raise FlowException(str(e)) from e
@@ -123,5 +123,5 @@ class CashExitFlow(FlowLogic):
         builder.add_command(Exit(exit_amount), me.owning_key)
         # conservation is enforced by the Move clause (inputs = outputs + exit)
         builder.add_command(Move(), me.owning_key)
-        builder.sign_with(hub.key_management.key_pair(me.owning_key))
-        return builder.to_signed_transaction(check_sufficient_signatures=False)
+        return hub.sign_initial_transaction(builder.to_wire_transaction(),
+                                            me.owning_key)

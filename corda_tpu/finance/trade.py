@@ -135,8 +135,8 @@ class BuyerFlow(FlowLogic):
         # cash leg
         Cash.generate_spend(builder, info.price, info.seller_owner_key, coins,
                             change_owner=me.owning_key)
-        builder.sign_with(hub.key_management.key_pair(me.owning_key))
-        return builder.to_signed_transaction(check_sufficient_signatures=False)
+        return hub.sign_initial_transaction(builder.to_wire_transaction(),
+                                            me.owning_key)
 
 
 def _refuse():

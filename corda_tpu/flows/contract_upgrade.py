@@ -164,7 +164,7 @@ class ContractUpgradeAcceptor(FlowLogic):
                         if leaf in hub.key_management.keys), None)
         if our_key is None:
             raise ContractUpgradeException("Our signature is not required")
-        yield Send(self.peer, hub.key_management.sign(stx.id.bytes, our_key))
+        yield Send(self.peer, hub.sign(stx.id.bytes, our_key))
         return None
 
 

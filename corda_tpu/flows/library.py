@@ -555,6 +555,6 @@ class SignTransactionFlow(FlowLogic):
         if our_key is None:
             raise FlowException("Transaction does not require our signature")
         leaf = next(k for k in our_key.keys if k in hub.key_management.keys)
-        sig = hub.key_management.sign(stx.id.bytes, leaf)
+        sig = hub.sign(stx.id.bytes, leaf)
         yield Send(self.peer, sig)
         return None

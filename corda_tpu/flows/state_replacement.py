@@ -140,7 +140,7 @@ class NotaryChangeAcceptor(FlowLogic):
         if our_key is None:
             raise StateReplacementException(
                 "Proposal does not require our signature")
-        sig = hub.key_management.sign(stx.id.bytes, our_key)
+        sig = hub.sign(stx.id.bytes, our_key)
         yield Send(self.peer, sig)
         return None
 

@@ -9,6 +9,7 @@ reorder) in tests.
 """
 from __future__ import annotations
 
+import time
 from collections import deque
 from dataclasses import dataclass
 from typing import Callable
@@ -127,7 +128,10 @@ class InMemoryMessaging(MessagingService):
 
     def send(self, topic_session: TopicSession, payload: bytes,
              recipient: str, trace: tuple | None = None) -> None:
-        msg = Message(topic_session, payload, sender=self._name, trace=trace)
+        # a send IS the arrival in the receiver's queue here: a traced
+        # message is ready from now, whenever the bus is pumped
+        msg = Message(topic_session, payload, sender=self._name, trace=trace,
+                      ready_s=time.time() if trace is not None else None)
         self._network._enqueue(self._name, recipient, msg)
 
     def add_message_handler(self, topic_session: TopicSession, callback

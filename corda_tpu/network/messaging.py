@@ -48,6 +48,10 @@ class Message:
     # (trace_id, span_id) of the sending flow's span, when the transport
     # propagates traces (observability.tracing) — None otherwise
     trace: tuple | None = None
+    # wall-clock instant (the RECEIVER's clock) at which the transport put
+    # a traced message into the receiver's queue: where the flow it wakes
+    # became runnable (wait.runnable starts here). None on untraced sends.
+    ready_s: float | None = None
 
 
 @dataclass(frozen=True)

@@ -29,6 +29,7 @@ from __future__ import annotations
 import asyncio
 import logging
 import threading
+import time
 from typing import Callable
 
 from ..core.serialization import deserialize, serialize
@@ -173,7 +174,9 @@ class TcpMessagingService(MessagingService):
                 trace = tuple(rest[0]) if rest and rest[0] else None
                 msg = Message(TopicSession(topic, session_id), payload,
                               sender=cert_cn if cert_cn is not None
-                              else sender, trace=trace)
+                              else sender, trace=trace,
+                              # queued for the node's executor from here
+                              ready_s=time.time() if trace else None)
                 self.executor.execute(lambda m=msg: self._deliver(m))
         except (asyncio.IncompleteReadError, ConnectionResetError,
                 MessageSizeExceededError):

@@ -10,6 +10,7 @@ from __future__ import annotations
 import json
 import os
 import threading
+import time as _time
 from dataclasses import dataclass
 
 from ..core.contracts.structures import Attachment
@@ -393,8 +394,6 @@ class ServiceHub:
 
     # -- ledger recording (ServiceHub.recordTransactions) --------------------
     def record_transactions(self, *stxs) -> None:
-        import time as _time
-
         from ..observability import get_tracer
         # vault updates land before ledger-commit waiters wake, so a resumed
         # flow observes a consistent vault (HibernateObserver ordering analog)
@@ -428,7 +427,20 @@ class ServiceHub:
     def sign(self, content: bytes, key: PublicKey | None = None
              ) -> DigitalSignatureWithKey:
         key = key or self.my_info.legal_identity.owning_key
-        return self.key_management.sign(content, key)
+        smm = self.smm
+        fsm = smm.current_fsm if smm is not None else None
+        step = fsm.step_span if fsm is not None else None
+        if step is None:      # no flow step is being traced
+            return self.key_management.sign(content, key)
+        # a cost carried on the running flow.step, not a span of its own
+        t0 = _time.perf_counter()
+        try:
+            return self.key_management.sign(content, key)
+        finally:
+            tags = step.tags
+            tags["sign_s"] = tags.get("sign_s", 0.0) \
+                + _time.perf_counter() - t0
+            tags["n_sign"] = tags.get("n_sign", 0) + 1
 
     def sign_initial_transaction(self, wtx, key: PublicKey | None = None):
         from ..core.transactions.signed import SignedTransaction

@@ -114,6 +114,11 @@ class FlowStateMachine:
         # wall-clock stamp of the current external park (Verify /
         # AwaitFuture) — the wait-state span's start once the flow resumes
         self.park_t0 = None
+        # the flow.step span of the _advance now running this flow (None
+        # between steps and whenever tracing is off): session.send spans
+        # parent to it, and the hub's sign / _checkpoint add their cost
+        # to its tags instead of recording spans of their own
+        self.step_span = None
 
     @property
     def current_group(self) -> tuple[int, str]:
@@ -154,6 +159,10 @@ class StateMachineManager:
         # MockNetwork polls it from run_network().
         self._external: "queue.Queue" = queue.Queue()
         self._awaiting_external = 0
+        # wall-clock instant at which the completion drain_external is now
+        # running was POSTED (None with tracing off): where a park wait
+        # ends and the flow's wait for this thread (wait.runnable) begins
+        self._ready_s: float | None = None
         self.scheduler_poke = None
         # Flow timers (Sleep + Receive timeouts — ClockUtils parity): the
         # clock is injectable (seconds; tests install a TestClock) and
@@ -174,22 +183,50 @@ class StateMachineManager:
         return self._awaiting_external
 
     def _record_wait(self, fsm: FlowStateMachine, name: str, kind: str,
-                     t0, **tags) -> None:
+                     t0, end_s: float | None = None, **tags) -> None:
         """Retroactive wait-state span: the time a flow spent parked at a
         commit-path queue, recorded under the flow's root span once the
         wait resolves. ``wait_kind`` makes "time not doing work" first-
         class in the trace tree — observability/critpath.py attributes it
-        to a blame component instead of leaving an unexplained gap."""
+        to a blame component instead of leaving an unexplained gap. The
+        wait ends at ``end_s``, the instant the awaited thing was READY
+        (now, where the caller has no such stamp): what follows until the
+        node's thread takes the flow up is ``wait.runnable``."""
         if t0 is None or fsm.trace_ctx is None:
             return
-        dur = _time.time() - t0
+        dur = (_time.time() if end_s is None else end_s) - t0
         if dur > 0.0:
             get_tracer().record(name, parent=fsm.trace_ctx, start_s=t0,
                                 duration_s=dur, wait_kind=kind, **tags)
 
+    def _record_park(self, fsm: FlowStateMachine, name: str, kind: str,
+                     t0, **tags) -> None:
+        """A park on a future, resolved (called from its continuation in
+        drain_external): the wait up to the completion's post, then the
+        wait from there for this thread."""
+        self._record_wait(fsm, name, kind, t0, self._ready_s, **tags)
+        self._record_runnable(fsm.trace_ctx, self._ready_s, "external")
+
+    def _record_runnable(self, ctx, ready_s: float | None, source: str,
+                         taken_s: float | None = None) -> None:
+        """The wait for the node's thread: from the instant the thing a
+        flow awaited was ready (a completion posted, a message queued at
+        this node, a timer's deadline) to the instant this thread took it
+        up. ``ctx`` is the woken flow's context (the message's trace for
+        a flow not yet born)."""
+        if ready_s is None or ctx is None:
+            return
+        dur = (_time.time() if taken_s is None else taken_s) - ready_s
+        if dur > 0.0:
+            get_tracer().record("wait.runnable", parent=ctx, start_s=ready_s,
+                                duration_s=dur,
+                                wait_kind="scheduler.runnable", source=source)
+
     def _post_external(self, fn) -> None:
-        """Thread-safe: queue a completion for the node thread."""
-        self._external.put(fn)
+        """Thread-safe: queue a completion for the node thread, stamped
+        (tracing on) with the instant it became ready to run."""
+        self._external.put(
+            (fn, _time.time() if get_tracer().enabled else None))
         poke = self.scheduler_poke
         if poke is not None:
             poke()
@@ -201,11 +238,14 @@ class StateMachineManager:
         ran = False
         while True:
             try:
-                fn = self._external.get_nowait()
+                fn, self._ready_s = self._external.get_nowait()
             except queue.Empty:
                 return ran
             ran = True
-            fn()
+            try:
+                fn()
+            finally:
+                self._ready_s = None
 
     def record_tx_mapping(self, run_id: str, tx_id) -> None:
         mapping = (run_id, tx_id)
@@ -294,10 +334,29 @@ class StateMachineManager:
         the previous response and receives the next FlowIORequest."""
         previous = self.current_fsm
         self.current_fsm = fsm   # attribute hub.record_transactions to us
+        step = None
+        if fsm.trace_span is not None and fsm.generator is not None:
+            # one span per scheduler step, handing the generator its value
+            # to the park, completion or failure; only a flow whose
+            # flow.run is open here (tracing on) has one
+            step = fsm.step_span = get_tracer().span(
+                "flow.step", parent=fsm.trace_ctx,
+                flow_type=flow_name(type(fsm.flow)))
         try:
             self._advance_inner(fsm, first, resume_value, resume_error)
         finally:
             self.current_fsm = previous
+            if step is not None:
+                fsm.step_span = None
+                fut = fsm.result_future
+                if not fsm.done:
+                    step.tags["exit"] = type(fsm.parked_on).__name__
+                elif fut.done() and not fut.cancelled() \
+                        and fut.exception() is None:
+                    step.tags["exit"] = "done"
+                else:
+                    step.tags["exit"] = "failed"
+                step.finish()
 
     def _advance_inner(self, fsm: FlowStateMachine, first: bool = False,
                        resume_value: Any = None,
@@ -449,11 +508,18 @@ class StateMachineManager:
             return 0
         self._timers = [t for t in self._timers if t[0] > now]
         fired = 0
-        for _, run_id, request in due:
+        for deadline, run_id, request in due:
             fsm = self.flows.get(run_id)
             if fsm is None or fsm.done or fsm.parked_on is not request:
                 continue
             fired += 1
+            if fsm.trace_span is not None:
+                # ready at the deadline; how late this thread is, measured
+                # on the timers' clock, laid back from the wall clock's now
+                taken = _time.time()
+                self._record_runnable(fsm.trace_ctx,
+                                      taken - max(0.0, now - deadline),
+                                      "timer", taken)
             if isinstance(request, Sleep):
                 fsm.response_log.append(("value", None))
                 self._resume(fsm, value=None)
@@ -513,7 +579,7 @@ class StateMachineManager:
             # future completion (double-invoked callback, flow already
             # resumed by another path) must not resume at the wrong yield.
             return
-        self._record_wait(fsm, "wait.verify_park", "verify.park",
+        self._record_park(fsm, "wait.verify_park", "verify.park",
                           fsm.park_t0)
         err = fut.exception()
         if err is None:
@@ -579,7 +645,7 @@ class StateMachineManager:
             return
         if fsm.parked_on is not request:
             return
-        self._record_wait(fsm, "wait.verify_gather", "verify.gather",
+        self._record_park(fsm, "wait.verify_gather", "verify.gather",
                           state["t0"], wave=state["n"])
         if state["errors"]:
             first = state["errors"][min(state["errors"])]
@@ -618,7 +684,7 @@ class StateMachineManager:
             return
         if fsm.parked_on is not request:
             return
-        self._record_wait(fsm, "wait.await_future",
+        self._record_park(fsm, "wait.await_future",
                           getattr(request, "purpose", "future"),
                           fsm.park_t0)
         err = fut.exception()
@@ -717,20 +783,31 @@ class StateMachineManager:
             raise FlowException(f"Session with {party.name} is {sess.state}")
         self._post(party, SessionData(sess.peer_session_id, payload))
 
-    def _post(self, party, message) -> None:
+    def _post(self, party, message, fsm: FlowStateMachine | None = None
+              ) -> None:
+        """Serialize and send one session message on behalf of ``fsm`` (the
+        flow being stepped, unless the caller names one). Traced, it is a
+        ``session.send`` span over serialize + send, child of the running
+        ``flow.step`` where there is one."""
         svc = self.hub.network_service
-        fsm = self.current_fsm
+        if fsm is None:
+            fsm = self.current_fsm
         if getattr(svc, "supports_trace", False) and fsm is not None \
                 and fsm.trace_ctx is not None:
             ctx = fsm.trace_ctx
             # ctx is a SpanContext once _register ran under a live tracer,
             # but may still be the raw wire tuple of an initiating message
             ids = ctx if isinstance(ctx, tuple) else (ctx.trace_id, ctx.span_id)
-            get_tracer().record(
-                "session.send", parent=ctx, peer=str(party.name),
-                kind=type(message).__name__)
-            svc.send(TopicSession(TOPIC_P2P), serialize(message),
-                     str(party.name), trace=ids)
+            tracer = get_tracer()
+            t0 = _time.time() if tracer.enabled else 0.0
+            data = serialize(message)
+            svc.send(TopicSession(TOPIC_P2P), data, str(party.name),
+                     trace=ids)
+            if tracer.enabled:
+                tracer.record(
+                    "session.send", parent=fsm.step_span or ctx, start_s=t0,
+                    duration_s=_time.time() - t0, peer=str(party.name),
+                    kind=type(message).__name__, bytes=len(data))
             return
         svc.send(TopicSession(TOPIC_P2P), serialize(message), str(party.name))
 
@@ -752,32 +829,59 @@ class StateMachineManager:
 
     # -- inbound dispatch (onSessionMessage, StateMachineManager.kt:307+) ----
     def _on_message(self, msg) -> None:
-        sm = deserialize(msg.data)
+        """One inbound session message: deserialize and book it into its
+        session, THEN run what it wakes. Traced, that is three spans end
+        to end on this thread: ``wait.runnable`` (queued at this node ->
+        taken up here), ``session.receive`` (deserialize + bookkeeping),
+        and the woken flow's ``flow.step``; the first two are closed
+        before the step starts, so siblings never overlap."""
         trace = getattr(msg, "trace", None)
-        if trace is not None:
-            get_tracer().record("session.receive", parent=trace,
-                                sender=str(getattr(msg, "sender", None)),
-                                kind=type(sm).__name__)
+        tracer = get_tracer()
+        taken = _time.time() if trace is not None and tracer.enabled else None
+        sm = deserialize(msg.data)
+        fsm, wake = self._receive(sm, trace)
+        if taken is not None:
+            # under the woken flow's run; under the sender's context for a
+            # flow not yet born (its flow.run opens in the wake-up)
+            ctx = fsm.trace_ctx if fsm is not None \
+                and fsm.trace_span is not None else trace
+            self._record_runnable(ctx, getattr(msg, "ready_s", None),
+                                  "message", taken)
+            tracer.record("session.receive", parent=ctx, start_s=taken,
+                          duration_s=_time.time() - taken,
+                          sender=str(getattr(msg, "sender", None)),
+                          kind=type(sm).__name__, bytes=len(msg.data))
+        if wake is not None:
+            wake()
+
+    def _receive(self, sm, trace):
+        """Session bookkeeping for one message, stepping nothing: returns
+        (the flow concerned or None, what to run next or None)."""
         if isinstance(sm, SessionInit):
-            self._on_session_init(sm, trace=trace)
-            return
+            return None, self._on_session_init(sm, trace=trace)
         if isinstance(sm, SessionConfirm):
             entry = self._session_index.get(sm.initiator_session_id)
             if entry is None:
-                return
+                return None, None
             fsm, sess = entry
             sess.peer_session_id = sm.initiated_session_id
             sess.state = "open"
-            for payload in getattr(sess, "pending_out", []):
-                self._post(sess.peer, SessionData(sess.peer_session_id, payload))
-            if hasattr(sess, "pending_out"):
-                sess.pending_out = []
-            return
+            pending = getattr(sess, "pending_out", None)
+            if not pending:
+                return fsm, None
+            sess.pending_out = []
+
+            def flush():
+                for payload in pending:
+                    self._post(sess.peer,
+                               SessionData(sess.peer_session_id, payload),
+                               fsm=fsm)
+            return fsm, flush
         entry = self._session_index.get(sm.recipient_session_id
                                         if not isinstance(sm, SessionReject)
                                         else sm.initiator_session_id)
         if entry is None:
-            return
+            return None, None
         fsm, sess = entry
         if isinstance(sm, SessionReject):
             sess.state = "errored"
@@ -789,7 +893,7 @@ class StateMachineManager:
         elif isinstance(sm, ErrorSessionEnd):
             sess.state = "errored"
             sess.error = FlowException(sm.error_message)
-        self._maybe_deliver(fsm, sess)
+        return fsm, lambda: self._maybe_deliver(fsm, sess)
 
     def _maybe_deliver(self, fsm: FlowStateMachine, sess: FlowSession) -> None:
         req = fsm.parked_on
@@ -834,7 +938,9 @@ class StateMachineManager:
             self._session_index.pop(sess.our_session_id, None)
 
     def _on_session_init(self, init: SessionInit,
-                         trace: tuple | None = None) -> None:
+                         trace: tuple | None = None):
+        """Build the responder flow for an inbound SessionInit; returns
+        the call that registers and first steps it (None when refused)."""
         factory = (self.flow_factories.get(init.flow_name)
                    or get_initiated_flow_factory(init.flow_name))
         peer = self.hub.well_known_party(init.initiator_party)
@@ -844,13 +950,12 @@ class StateMachineManager:
                       f"Unknown party {init.initiator_party}")
             if peer is not None:
                 self._post(peer, SessionReject(init.initiator_session_id, reason))
-            return
+            return None
         flow = factory(peer)
         fsm = FlowStateMachine(uuid.uuid4().hex, flow, self)
         # the responder flow's span joins the initiator's trace — the wire
         # carried (trace_id, span_id), so the whole P2P exchange is one trace
         fsm.trace_ctx = trace
-        self._register(fsm)
         sess = FlowSession(peer=peer,
                            peer_session_id=init.initiator_session_id,
                            state="open")
@@ -859,11 +964,17 @@ class StateMachineManager:
         self._session_index[sess.our_session_id] = (fsm, sess)
         if init.first_payload is not None:
             sess.received.append(init.first_payload)
-        self._post(peer, SessionConfirm(init.initiator_session_id,
-                                        sess.our_session_id))
-        self._notify("add", fsm)
-        self._start_generator(fsm)
-        self._advance(fsm, first=True)
+
+        def launch():
+            # the flow is born here, after the message's session.receive
+            # closed: its flow.run opens, the confirm goes out under it
+            self._register(fsm)
+            self._post(peer, SessionConfirm(init.initiator_session_id,
+                                            sess.our_session_id), fsm=fsm)
+            self._notify("add", fsm)
+            self._start_generator(fsm)
+            self._advance(fsm, first=True)
+        return launch
 
     # -- ledger-commit wakeups ----------------------------------------------
     def _on_tx_committed(self, stx) -> None:
@@ -939,7 +1050,10 @@ class StateMachineManager:
     # -- checkpointing -------------------------------------------------------
     def _checkpoint(self, fsm: FlowStateMachine) -> None:
         """Atomic checkpoint at suspension (updateCheckpoint,
-        StateMachineManager.kt:526-543)."""
+        StateMachineManager.kt:526-543). Its cost rides on the running
+        flow.step as ``checkpoint_s`` (tracing on)."""
+        step = fsm.step_span
+        t0 = _time.perf_counter() if step is not None else 0.0
         fields = {k: v for k, v in vars(fsm.flow).items()
                   if k not in ("state_machine", "service_hub")}
         sessions = [SessionSnapshot(
@@ -955,6 +1069,9 @@ class StateMachineManager:
                         response_log=list(fsm.response_log),
                         sessions=sessions)
         self.checkpoints.add_checkpoint(cp)
+        if step is not None:
+            step.tags["checkpoint_s"] = step.tags.get("checkpoint_s", 0.0) \
+                + _time.perf_counter() - t0
 
     def _restore(self, cp: Checkpoint) -> None:
         """Rebuild a flow from its checkpoint and replay it to its suspension

@@ -50,6 +50,7 @@ COMPONENTS = ("flow.compute", "scheduler.wait", "verify",
 #: commit-path queueing point (docs/OBSERVABILITY.md, tail forensics).
 WAIT_KINDS = {
     "scheduler.admission": "scheduler.wait",   # FlowScheduler._waiting
+    "scheduler.runnable": "scheduler.wait",    # ready -> node thread took it
     "verify.park": "verify",                   # Verify future park
     "verify.gather": "verify",                 # VerifyMany wave gather
     "verifier.admission": "verify",            # bulk cap block (_enqueue)
@@ -65,6 +66,7 @@ WAIT_KINDS = {
 #: wait_kind tag for ``wait.*`` spans.
 _NAME_RULES = (
     ("wait.scheduler_admission", "scheduler.wait"),
+    ("wait.runnable", "scheduler.wait"),
     ("wait.verifier_admission", "verify"),
     ("wait.verify", "verify"),
     ("wait.cross_shard_prepare", "cross_shard"),

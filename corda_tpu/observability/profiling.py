@@ -190,7 +190,8 @@ class KernelProfiler:
             self.compile_hist.update(dt)
             tracer = get_tracer()
             if tracer.enabled:
-                tracer.record("kernel.compile", duration_s=dt, kernel=name,
+                tracer.record("kernel.compile", start_s=time.time() - dt,
+                              duration_s=dt, kernel=name,
                               batch_capacity=capacity)
         else:
             self.dispatch_hist.update(dt)

@@ -22,14 +22,31 @@ Zero-dependency, thread-safe, stdlib-only.
 """
 from __future__ import annotations
 
+import itertools
 import os
 import time
+from threading import current_thread
 
 from .ring import SpanRing
 
+# Ids: a random per-process prefix plus a counter. One os.urandom per
+# process, not one or two per span (a syscall each); ``next`` on a
+# ``count`` is atomic under the interpreter lock, and a forked child draws
+# a fresh prefix so its ids cannot collide with its parent's.
+_id_prefix = os.urandom(4).hex()
+_id_counter = itertools.count(1)
+
+
+def _reseed_ids() -> None:
+    global _id_prefix
+    _id_prefix = os.urandom(4).hex()
+
+
+os.register_at_fork(after_in_child=_reseed_ids)
+
 
 def _new_id() -> str:
-    return os.urandom(8).hex()
+    return f"{_id_prefix}{next(_id_counter) & 0xFFFFFFFF:08x}"
 
 
 class SpanContext:
@@ -73,7 +90,7 @@ class Span:
     an unfinished span is never visible in the ring."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "start_s",
-                 "duration_s", "tags", "_ring", "_t0", "_done")
+                 "duration_s", "tags", "thread", "_ring", "_t0", "_done")
 
     def __init__(self, ring: SpanRing, name: str, trace_id: str,
                  parent_id: str | None, tags: dict):
@@ -83,6 +100,10 @@ class Span:
         self.span_id = _new_id()
         self.parent_id = parent_id
         self.tags = tags
+        # the thread that OPENED the span: spans of one thread never
+        # overlap unless nested, which is what lets a reader say how busy
+        # the node's thread was
+        self.thread = current_thread().name
         self.start_s = time.time()
         self._t0 = time.perf_counter()
         self.duration_s = 0.0
@@ -106,7 +127,7 @@ class Span:
         return {"name": self.name, "trace_id": self.trace_id,
                 "span_id": self.span_id, "parent_id": self.parent_id,
                 "start_s": self.start_s, "duration_s": self.duration_s,
-                "tags": dict(self.tags)}
+                "thread": self.thread, "tags": self.tags}
 
     def __enter__(self) -> "Span":
         return self
@@ -201,7 +222,8 @@ class Tracer:
             "name": name, "trace_id": trace_id, "span_id": span_id,
             "parent_id": parent_id,
             "start_s": time.time() if start_s is None else start_s,
-            "duration_s": duration_s, "tags": dict(tags)})
+            "duration_s": duration_s, "thread": current_thread().name,
+            "tags": tags})
         return SpanContext(trace_id, span_id)
 
     def ingest(self, span_dict) -> None:
@@ -218,6 +240,7 @@ class Tracer:
         d.setdefault("parent_id", None)
         d.setdefault("start_s", 0.0)
         d.setdefault("duration_s", 0.0)
+        d.setdefault("thread", None)    # an older worker's span has none
         if not isinstance(d.get("tags"), dict):
             d["tags"] = {}
         self.ring.record(d)
@@ -245,7 +268,7 @@ def make_span_dict(name: str, parent, start_s: float, duration_s: float,
         trace_id = _new_id()
     return {"name": name, "trace_id": trace_id, "span_id": _new_id(),
             "parent_id": parent_id, "start_s": start_s,
-            "duration_s": duration_s,
+            "duration_s": duration_s, "thread": current_thread().name,
             "tags": {k: v for k, v in tags.items() if v is not None}}
 
 

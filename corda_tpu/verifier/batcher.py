@@ -784,11 +784,13 @@ class SignatureBatcher:
         try:
             self.metrics.histogram("verifier_batch_size").update(len(items))
             tracer = get_tracer()
-            bctx = self._trace_flush(tracer, bucket, items, reason) \
+            host_route = bucket == "host" or len(items) < self.host_crossover
+            bctx = self._trace_flush(tracer, bucket, items, reason,
+                                     "host" if host_route else "device") \
                 if tracer.enabled else None
             jlog(_log, "batcher.flush", ctx=bctx, bucket=bucket,
                  batch_size=len(items), flush_reason=reason)
-            if bucket == "host" or len(items) < self.host_crossover:
+            if host_route:
                 if bucket != "host":
                     self.metrics.meter("SigBatcher.HostRouted").mark(
                         len(items))
@@ -833,9 +835,11 @@ class SignatureBatcher:
     #: batch must not turn one flush into 32k ring inserts.
     MAX_WAIT_SPANS = 64
 
-    def _trace_flush(self, tracer, bucket, items, reason):
+    def _trace_flush(self, tracer, bucket, items, reason, route):
         """Record the flush span (+ capped per-item enqueue-wait spans) and
         return its context — the parent for dispatch/wait/resolve spans.
+        ``route`` is the one the flush is about to take (an open breaker can
+        still turn a device flush to the host: batcher.dispatch says so).
         A mixed batch carries many traces; the flush span joins the FIRST
         traced submitter's trace and tags how many others rode along."""
         now = _time.time()
@@ -854,7 +858,8 @@ class SignatureBatcher:
                               bucket=bucket)
         return tracer.record("batcher.flush", parent=first_ctx, start_s=now,
                              bucket=bucket, batch_size=len(items),
-                             flush_reason=reason, n_traced=traced)
+                             flush_reason=reason, n_traced=traced,
+                             route=route)
 
     #: Max device batches in flight PER SCHEME: the one just launched plus
     #: two awaiting their results. A/B on v5e (3 runs each, 32k batches):
