@@ -613,6 +613,10 @@ class StateMachineManager:
         kwargs = {}
         if getattr(svc, "supports_trace_ctx", False) and fsm.trace_ctx is not None:
             kwargs["trace_ctx"] = fsm.trace_ctx
+        if getattr(svc, "supports_wave_rows", False):
+            # the wave's members reach the verifier together and are routed
+            # as one depth: the service is told the size it can observe
+            kwargs["wave_rows"] = sum(len(stx.sigs) for stx in stxs)
         futs = [svc.verify_signed(
                     stx, self.hub, check_sufficient_signatures=
                     request.check_sufficient_signatures, **kwargs)

@@ -121,6 +121,18 @@ class _Group:
         self.lock = threading.Lock()
 
 
+class HeldGroup:
+    """What ``hold_group`` returns and ``collect_group`` takes: the group's
+    future, its rows by queue, and the size of the wave it came in."""
+
+    __slots__ = ("future", "routed", "wave_rows")
+
+    def __init__(self, future: Future, routed: dict, wave_rows: int):
+        self.future = future
+        self.routed = routed
+        self.wave_rows = wave_rows
+
+
 @dataclass
 class _Pending:
     key: PublicKey
@@ -246,7 +258,22 @@ class SignatureBatcher:
     have (chip_smoke.py prints the measured round trip; retuning is
     ROADMAP A2). Below the crossover the dispatcher also skips the linger
     wait, so a lone submit is not taxed ``max_latency_s`` for a batch that
-    was never coming."""
+    was never coming.
+
+    The inline route: a caller whose own worker thread blocks on the
+    verdicts anyway (the service's pool) submits with ``hold_group`` and
+    gets them with ``collect_group``. The rows join the queue as any
+    submission's do, but the planner is not woken while it would only
+    host-route them at once (the queue is ``host``, or its depth, these
+    rows and every other flow's included, is under ``host_crossover``); the
+    worker takes its rows back out and runs the host loop itself if that
+    rule, ``_host_at_once``, still holds when it gets the lock. The group
+    then visits neither the planner thread nor the prep pool: one thread
+    hand-off where the queue costs three, each a wait for the interpreter
+    lock. At or over the crossover the rows stay in the queue and the
+    planner is woken as before, so a row reaches the device exactly when it
+    did; ``host_crossover`` is the one number that decides, and
+    ``SigBatcher.HostInline`` counts the rows that took the route."""
 
     #: Prep-pool width: one worker per device scheme, so a mixed drain preps
     #: ed25519 + k1 + r1 concurrently. The heavy prep (sm_*_prep, hashing,
@@ -530,8 +557,93 @@ class SignatureBatcher:
             p.ctx = ctx
             p.t_enq = now
 
+    def hold_group(self, checks, ctx=None,
+                   wave_rows: int | None = None) -> "HeldGroup":
+        """``submit_group`` (interactive class) for a caller whose own
+        worker thread is about to block on the verdicts anyway: the rows
+        join their queues exactly as ``submit_group``'s do, in submission
+        order and on the calling thread, but the planner is not woken while
+        it would only host-route them at once (``_host_at_once``). The
+        worker then calls ``collect_group``, which takes the rows back out
+        and verifies them on its own thread if that rule still holds.
+        Whoever holds the lock first, planner or worker, judges the same
+        queue by the same rule, so a row goes to the device exactly when it
+        did; under the crossover the group crosses one thread, not three.
+
+        ``wave_rows`` is the signature count of the ``VerifyMany`` wave the
+        group belongs to: a member is judged by the wave's size, so a wave
+        at or over the crossover is the planner's as a whole, however the
+        threads interleave while it is being submitted. A closed batcher
+        raises as ``_enqueue`` does. Every held group MUST be collected
+        (until then only another submission or ``close`` moves its rows)."""
+        group = _Group(len(checks))
+        pendings = [_Pending(key, sig, content, group=group, index=i)
+                    for i, (key, sig, content) in enumerate(checks)]
+        self._stamp_trace(pendings, ctx)
+        routed = self._enqueue(pendings, INTERACTIVE, held_wave=wave_rows or 0)
+        if not pendings:
+            group.future.set_result([])
+        return HeldGroup(group.future, routed, wave_rows or 0)
+
+    def collect_group(self, held: "HeldGroup") -> list[bool]:
+        """The verdicts of a ``hold_group`` submission, on the thread that
+        wants them. Under the lock, each bucket's rows that are still queued
+        and that the planner would host-route at once are taken out of the
+        queue and verified HERE; the rest (the planner got there first, or
+        the depth is at or over the crossover: other flows' rows count, they
+        share the queue) stay the planner's, and this thread waits for them
+        as ``submit_group(...).result()`` would.
+
+        Counters, histograms and spans are those of a queued host flush
+        (``batcher.flush`` tagged ``inline=True``), plus
+        ``SigBatcher.HostInline``. The host in-flight window
+        (``MAX_IN_FLIGHT + 1``) is not consulted: the callers' own pool
+        (four workers in the service) bounds the concurrent inline
+        flushes."""
+        mine: dict[str, list[_Pending]] = {}
+        with self._lock:
+            for bucket, ps in held.routed.items():
+                if not self._host_at_once(bucket, held.wave_rows):
+                    continue
+                # a group's rows are one contiguous run (one extend under
+                # the lock; the planner cuts prefixes, collectors whole runs)
+                lst = self._queues[bucket].interactive
+                at = next((i for i, p in enumerate(lst) if p is ps[0]), None)
+                if at is not None and lst[at + len(ps) - 1] is ps[-1]:
+                    del lst[at:at + len(ps)]
+                    mine[bucket] = ps
+            if len(mine) < len(held.routed):
+                self._lock.notify_all()     # what stays is the planner's
+        tracer = get_tracer()
+        for bucket, ps in mine.items():
+            self.metrics.histogram("verifier_batch_size").update(len(ps))
+            reason = "host" if bucket == "host" else "small_batch"
+            bctx = self._trace_flush(tracer, bucket, ps, reason, "host",
+                                     inline=True) \
+                if tracer.enabled else None
+            jlog(_log, "batcher.flush", ctx=bctx, bucket=bucket,
+                 batch_size=len(ps), flush_reason=reason)
+            self._flush_host(tracer, bucket, ps, bctx)
+            self.metrics.meter("SigBatcher.HostInline").mark(len(ps))
+        return held.future.result()
+
+    def _host_at_once(self, name: str, wave_rows: int = 0) -> bool:
+        """THE routing rule (CALLER HOLDS THE LOCK): a queue's rows go to
+        the host loop without waiting when it is the ``host`` queue (device
+        off, or ``route_interactive_host`` on) or holds fewer rows than
+        ``host_crossover``; at or over it they are the device's. The
+        planner applies it to every non-empty queue; ``hold_group`` and
+        ``collect_group`` apply it with the wave's size beside the depth."""
+        return name == "host" or max(len(self._queues[name]),
+                                     wave_rows) < self.host_crossover
+
     def _enqueue(self, pendings: list[_Pending],
-                 latency_class: str = BULK) -> None:
+                 latency_class: str = BULK,
+                 held_wave: int | None = None
+                 ) -> dict[str, list[_Pending]]:
+        """Put the rows on their queues and wake the planner. ``held_wave``
+        is not None for ``hold_group``: the planner then sleeps on while
+        every queue touched is one it would host-route at once."""
         # bucket lookups happen OUTSIDE the condition lock: a 32k-item
         # submission must not hold the dispatcher up for the whole scan
         force_host = (self._force_host_interactive
@@ -575,7 +687,10 @@ class SignatureBatcher:
             for bucket, ps in routed.items():
                 self._queues[bucket].add(latency_class, ps, now)
             self.metrics.counter("SigBatcher.InFlight").inc(len(pendings))
-            self._lock.notify_all()
+            if held_wave is None or not all(
+                    self._host_at_once(b, held_wave) for b in routed):
+                self._lock.notify_all()
+        return routed
 
     def close(self) -> None:
         with self._lock:
@@ -631,7 +746,7 @@ class SignatureBatcher:
                 continue
             window = self.MAX_IN_FLIGHT if name != "host" \
                 else self.MAX_IN_FLIGHT + 1
-            if name == "host" or len(q) < self.host_crossover:
+            if self._host_at_once(name):
                 # host route (below the crossover both classes merge — the
                 # host loop has no shape or occupancy stake, and waiting
                 # would add pure latency: the p50@1 case)
@@ -791,17 +906,7 @@ class SignatureBatcher:
             jlog(_log, "batcher.flush", ctx=bctx, bucket=bucket,
                  batch_size=len(items), flush_reason=reason)
             if host_route:
-                if bucket != "host":
-                    self.metrics.meter("SigBatcher.HostRouted").mark(
-                        len(items))
-                t0 = _time.perf_counter()
-                with tracer.span("batcher.dispatch", parent=bctx,
-                                 bucket=bucket, batch_size=len(items),
-                                 route="host"):
-                    verdicts = self._run_host(items)
-                self.metrics.histogram("verifier_dispatch_seconds").update(
-                    _time.perf_counter() - t0, trace_id=_tid(bctx))
-                self._resolve("host", items, verdicts, bctx)
+                self._flush_host(tracer, bucket, items, bctx)
                 return None
             breaker = self._breakers[bucket]
             if not breaker.allow():
@@ -831,11 +936,26 @@ class SignatureBatcher:
                 self._prep_active -= 1
                 gauge.set(self._prep_active)
 
+    def _flush_host(self, tracer, bucket: str, items: list[_Pending],
+                    bctx) -> None:
+        """The host route of one flush, on whichever thread flushes it (a
+        prep-pool worker, or the caller of ``collect_group``)."""
+        if bucket != "host":
+            self.metrics.meter("SigBatcher.HostRouted").mark(len(items))
+        t0 = _time.perf_counter()
+        with tracer.span("batcher.dispatch", parent=bctx, bucket=bucket,
+                         batch_size=len(items), route="host"):
+            verdicts = self._run_host(items)
+        self.metrics.histogram("verifier_dispatch_seconds").update(
+            _time.perf_counter() - t0, trace_id=_tid(bctx))
+        self._resolve("host", items, verdicts, bctx)
+
     #: Per-flush cap on retroactive enqueue-wait spans: a fully-traced 32k
     #: batch must not turn one flush into 32k ring inserts.
     MAX_WAIT_SPANS = 64
 
-    def _trace_flush(self, tracer, bucket, items, reason, route):
+    def _trace_flush(self, tracer, bucket, items, reason, route,
+                     **tags):
         """Record the flush span (+ capped per-item enqueue-wait spans) and
         return its context — the parent for dispatch/wait/resolve spans.
         ``route`` is the one the flush is about to take (an open breaker can
@@ -859,7 +979,7 @@ class SignatureBatcher:
         return tracer.record("batcher.flush", parent=first_ctx, start_s=now,
                              bucket=bucket, batch_size=len(items),
                              flush_reason=reason, n_traced=traced,
-                             route=route)
+                             route=route, **tags)
 
     #: Max device batches in flight PER SCHEME: the one just launched plus
     #: two awaiting their results. A/B on v5e (3 runs each, 32k batches):

@@ -100,9 +100,15 @@ class TpuTransactionVerifierService(TransactionVerifierService):
     (SignedTransaction.kt:174-178).
     """
 
-    #: safe to block a flow on: the batcher + pool resolve on their own
-    #: threads, never via the node's serial executor (hub.verify_transaction)
+    #: safe to block a flow on: a verify resolves on this service's pool
+    #: (and, over the crossover, on the batcher's threads), never via the
+    #: node's serial executor (hub.verify_transaction). The node's thread
+    #: only submits and parks.
     resolves_off_node_thread = True
+
+    #: verify_signed takes ``wave_rows`` (the SMM's VerifyMany passes the
+    #: wave's signature count to a service that says so)
+    supports_wave_rows = True
 
     def __init__(self, workers: int = 4, batcher: SignatureBatcher | None = None,
                  metrics: MetricRegistry | None = None, mesh=None):
@@ -117,12 +123,25 @@ class TpuTransactionVerifierService(TransactionVerifierService):
     # -- full TPU path (verify(ltx) is inherited) ----------------------------
     def verify_signed(self, stx, services,
                       check_sufficient_signatures: bool = True,
-                      trace_ctx=None) -> Future:
+                      trace_ctx=None, wave_rows: int | None = None) -> Future:
         """Async full verify of a SignedTransaction; the per-signature EC math
         rides the shared device batcher (cross-transaction batching). With
         tracing enabled the whole pipeline — submit, batch flush, device
         dispatch, resolve — lands in one trace rooted here (or in the
-        caller's, when ``trace_ctx`` carries the flow's context)."""
+        caller's, when ``trace_ctx`` carries the flow's context).
+
+        Which threads it crosses: the caller (the node's thread) puts the
+        rows on the batcher's queue without waking its planner
+        (``SignatureBatcher.hold_group``) and hands ``work`` to the pool. The
+        ``tpu-verifier`` worker that takes it collects the verdicts
+        (``collect_group``): a group the planner would host-route at once,
+        which under ``host_crossover`` is every transaction that arrives
+        alone, is verified on that worker, one thread hand-off in all.
+        Otherwise the rows stay queued for the planner (prep pool, device)
+        and the worker blocks on them; the groups of flows suspended
+        together share the queue, so they still coalesce into one device
+        batch. ``wave_rows`` is the signature count of the ``VerifyMany``
+        wave ``stx`` belongs to: the batcher judges a member by it."""
         tracer = get_tracer()
         root = tracer.span("tx.verify", parent=trace_ctx,
                            tx_id=stx.id.bytes.hex()[:16],
@@ -136,13 +155,14 @@ class TpuTransactionVerifierService(TransactionVerifierService):
             # with one lock acquire per flush). Interactive class: a single
             # tx's few signatures are latency-bound — they flush on the
             # short deadline instead of lingering behind a bulk megabatch.
-            group_future = self.batcher.submit_group(
+            held = self.batcher.hold_group(
                 [(sig.by, sig.bytes, stx.id.bytes) for sig in stx.sigs],
-                ctx=ctx, latency_class="interactive")
+                ctx=ctx, wave_rows=wave_rows)
 
             def work():
                 try:
-                    for sig, ok in zip(stx.sigs, group_future.result()):
+                    for sig, ok in zip(stx.sigs,
+                                       self.batcher.collect_group(held)):
                         if not ok:
                             raise SignatureException(
                                 f"Signature by {sig.by.to_string_short()} "

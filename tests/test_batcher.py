@@ -209,3 +209,201 @@ def test_cancelled_future_does_not_wedge_the_dispatcher():
                    .result(timeout=120))
     finally:
         b.close()
+
+
+# ---------------------------------------------------------------------------
+# The inline route (hold_group / collect_group): a group the planner would
+# host-route at once is verified on the thread that collects it
+# ---------------------------------------------------------------------------
+
+ED_KP = generate_keypair(EDDSA_ED25519_SHA512, entropy=b"\x75" * 32)
+ED_OTHER_KP = generate_keypair(EDDSA_ED25519_SHA512, entropy=b"\x76" * 32)
+ED_SIG = Crypto.sign_with_key(ED_KP, CONTENT).bytes
+
+_INLINE_ROWS = {
+    "valid": (ED_KP.public, ED_SIG, CONTENT),
+    "corrupted_signature": (ED_KP.public,
+                            ED_SIG[:-1] + bytes([ED_SIG[-1] ^ 1]), CONTENT),
+    "wrong_key": (ED_OTHER_KP.public, ED_SIG, CONTENT),
+    "wrong_message": (ED_KP.public, ED_SIG, CONTENT + b"!"),
+}
+
+
+def _spy_threads(obj, name):
+    """Wrap ``obj.<name>``; returns the list of thread names it ran on."""
+    seen, orig = [], getattr(obj, name)
+
+    def spy(*a, **k):
+        seen.append(threading.current_thread().name)
+        return orig(*a, **k)
+
+    setattr(obj, name, spy)
+    return seen
+
+
+def _count(batcher, name):
+    return batcher.metrics.snapshot().get(name, {}).get("count", 0)
+
+
+def _stub_device(batcher):
+    """Host verdicts in place of the kernels; returns the batch sizes."""
+    batches = []
+
+    def device(bucket, items, reason="full", bctx=None):
+        batches.append(len(items))
+        batcher._mark_device(items)
+        batcher._resolve(bucket, items, batcher._run_host(items), bctx)
+
+    batcher._dispatch_device = device
+    return batches
+
+
+@pytest.mark.parametrize("kind", sorted(_INLINE_ROWS))
+def test_inline_verdicts_equal_the_queued_path(kind):
+    """Same rows, both routes, same verdicts: the inline route runs the
+    queue's own host loop (Crypto.is_valid), only on another thread."""
+    checks = [_INLINE_ROWS["valid"], _INLINE_ROWS[kind],
+              (KP.public, SIG, CONTENT)]
+    b = SignatureBatcher()
+    try:
+        inline = b.collect_group(b.hold_group(checks))
+        assert _count(b, "SigBatcher.HostInline") == 3
+        queued = b.submit_group(checks, latency_class="interactive") \
+            .result(timeout=30)
+        assert inline == queued == [True, kind == "valid", True]
+    finally:
+        b.close()
+
+
+def test_inline_group_runs_on_the_calling_thread_and_counts_its_rows():
+    b = SignatureBatcher()
+    seen = _spy_threads(b, "_run_host")
+    b._submit_flush = lambda *a, **k: pytest.fail("planner cut a plan")
+    checks = [_INLINE_ROWS["valid"], (KP.public, SIG, CONTENT),
+              _INLINE_ROWS["wrong_key"]]
+    try:
+        held = b.hold_group(checks)
+        assert b.queue_depths()["ed25519"] == 2
+        assert b.collect_group(held) == [True, True, False]
+        assert not any(b.queue_depths().values())
+        # one host loop a scheme bucket, both on this thread
+        assert seen == [threading.current_thread().name] * 2
+        assert b._prep_pool is None
+        assert _count(b, "SigBatcher.HostRouted") == 3
+        assert _count(b, "SigBatcher.Checked") == 3
+        assert _count(b, "SigBatcher.HostInline") == 3
+        snap = b.metrics.snapshot()
+        assert snap["SigBatcher.InFlight"]["value"] == 0
+        assert snap["verifier_batch_size"]["count"] == 2
+        assert snap["verifier_dispatch_seconds"]["count"] == 2
+        assert snap["verifier_finish_seconds"]["count"] == 2
+        assert b.collect_group(b.hold_group([])) == []
+    finally:
+        b.close()
+
+
+@pytest.mark.parametrize("others,rows,wave_rows,inline", [
+    (2, 1, None, True),      # 2 + 1 under the crossover of 4
+    (2, 2, None, False),     # 2 + 2 at it: the planner's, all four
+    (0, 4, None, False),     # the group alone at it
+    (0, 1, 3, True),         # judged by its wave: 3 under
+    (0, 1, 4, False),        # ... 4 at it
+    (3, 1, 2, False),        # ... and the depth counts beside the wave
+])
+def test_inline_rule_is_the_planners(others, rows, wave_rows, inline):
+    """The queue's depth (this group's rows AND the other flows') or the
+    wave's size against host_crossover, read under the batcher's lock. The
+    test holds that (re-entrant) lock while it submits, so the depth the
+    collector and the planner find is the one it set up, whoever runs
+    first. interactive_batch=4 makes a depth of 4 ready at its cap."""
+    b = SignatureBatcher(host_crossover=4, interactive_batch=4)
+    batches = _stub_device(b)
+    row = _INLINE_ROWS["valid"]
+    try:
+        with b._lock:
+            other = [b.hold_group([row]) for _ in range(others)]
+            mine = b.hold_group([row] * rows, wave_rows=wave_rows)
+            assert b.queue_depths()["ed25519"] == others + rows
+        assert b.collect_group(mine) == [True] * rows
+        assert _count(b, "SigBatcher.HostInline") == (rows if inline else 0)
+        if others + rows >= 4:      # one device batch of every flow's rows
+            assert [o.future.result(timeout=30) for o in other] \
+                == [[True]] * others
+            assert batches == [others + rows]
+            assert _count(b, "SigBatcher.DeviceChecked") == others + rows
+        assert [b.collect_group(o) for o in other] == [[True]] * others
+        assert b.metrics.snapshot()["SigBatcher.InFlight"]["value"] == 0
+        assert not any(b.queue_depths().values())
+    finally:
+        b.close()
+
+
+def test_concurrent_lone_groups_at_the_crossover_share_one_device_batch():
+    """Eight one-signature groups held together against a crossover of 4:
+    none is collected inline, the planner cuts ONE device batch of 8, as it
+    did when every group went through submit_group."""
+    b = SignatureBatcher(host_crossover=4, interactive_batch=8)
+    batches = _stub_device(b)
+    row = _INLINE_ROWS["valid"]
+    try:
+        with b._lock:
+            held = [b.hold_group([row]) for _ in range(8)]
+        assert [b.collect_group(h) for h in held] == [[True]] * 8
+        assert batches == [8]
+        assert _count(b, "SigBatcher.DeviceChecked") == 8
+        assert _count(b, "SigBatcher.HostInline") == 0
+        assert _count(b, "SigBatcher.HostRouted") == 0
+    finally:
+        b.close()
+
+
+def test_a_group_the_planner_took_first_is_waited_for():
+    """Another submission woke the planner, which drained the held rows
+    with it: the collector finds nothing to take and reads the future."""
+    b = SignatureBatcher()
+    row = _INLINE_ROWS["valid"]
+    try:
+        held = b.hold_group([row, _INLINE_ROWS["wrong_key"]])
+        assert b.submit(*row).result(timeout=30) is True
+        assert b.collect_group(held) == [True, False]
+        assert _count(b, "SigBatcher.HostInline") == 0
+        assert _count(b, "SigBatcher.HostRouted") == 3
+    finally:
+        b.close()
+
+
+@pytest.mark.parametrize("how", ["use_device_false",
+                                 "route_interactive_host"])
+def test_host_bucket_takes_the_inline_route_whatever_the_crossover(how):
+    b = SignatureBatcher(use_device=how != "use_device_false",
+                         host_crossover=0)
+    b._submit_flush = lambda *a, **k: pytest.fail("planner cut a plan")
+    b.route_interactive_host(how == "route_interactive_host")
+    try:
+        held = b.hold_group([_INLINE_ROWS["valid"]] * 5)
+        assert b.collect_group(held) == [True] * 5
+        assert _count(b, "SigBatcher.HostInline") == 5
+        # the host bucket's rows were never "routed" away from a device
+        assert _count(b, "SigBatcher.HostRouted") == 0
+        assert _count(b, "SigBatcher.Checked") == 5
+    finally:
+        b.close()
+
+
+def test_hold_on_a_closed_batcher_raises_as_enqueue_does():
+    b = SignatureBatcher()
+    b.close()
+    with pytest.raises(RuntimeError, match="closed"):
+        b.hold_group([_INLINE_ROWS["valid"]])
+    with pytest.raises(RuntimeError, match="closed"):
+        b.submit_group([_INLINE_ROWS["valid"]])
+    assert b.metrics.snapshot().get(
+        "SigBatcher.InFlight", {"value": 0})["value"] == 0
+
+
+def test_close_drains_a_group_that_was_held_and_not_yet_collected():
+    b = SignatureBatcher()
+    held = b.hold_group([_INLINE_ROWS["valid"]])
+    b.close()
+    assert b.collect_group(held) == [True]
+    assert b.metrics.snapshot()["SigBatcher.InFlight"]["value"] == 0

@@ -4,6 +4,8 @@ Reference analogs: InMemoryTransactionVerifierService behavior, the
 OutOfProcess service's metrics wiring (OutOfProcessTransactionVerifierService.kt:33-45),
 and VerifierTests.kt's "all transactions verify / invalid one fails" cases.
 """
+import threading
+
 import pytest
 
 from corda_tpu.core.contracts import (Command, StateRef, TransactionState)
@@ -107,12 +109,12 @@ def test_tpu_service_full_path(services):
 
 
 def test_verify_signed_submits_one_group_per_tx(services):
-    """Acceptance pin: the TPU service path rides submit_group — ONE future
-    per transaction's signature set, never per-signature submit_many
-    futures (~25µs of Future allocation each)."""
+    """Acceptance pin: the TPU service path asks the batcher for ONE group
+    (one future) per transaction's signature set, never per-signature
+    submit_many futures (~25µs of Future allocation each)."""
     svc = TpuTransactionVerifierService()
     calls = []
-    orig = svc.batcher.submit_group
+    orig = svc.batcher.hold_group
 
     def spy(checks, ctx=None, **kw):
         calls.append(len(checks))
@@ -121,7 +123,7 @@ def test_verify_signed_submits_one_group_per_tx(services):
     def reject(*a, **k):
         raise AssertionError("verify_signed must not use submit_many")
 
-    svc.batcher.submit_group = spy
+    svc.batcher.hold_group = spy
     svc.batcher.submit_many = reject
     try:
         stx = make_issue_stx(services)
@@ -131,17 +133,98 @@ def test_verify_signed_submits_one_group_per_tx(services):
         svc.shutdown()
 
 
-def test_verify_signed_on_closed_batcher_returns_failed_future(services):
-    """Span-leak fix: if the batcher rejects the submission (closed), the
+def _spy_threads(obj, name):
+    """Wrap ``obj.<name>``; returns the list of thread names it ran on."""
+    seen, orig = [], getattr(obj, name)
+
+    def spy(*a, **k):
+        seen.append(threading.current_thread().name)
+        return orig(*a, **k)
+
+    setattr(obj, name, spy)
+    return seen
+
+
+def _corrupted(stx):
+    sig = stx.sigs[0]
+    return SignedTransaction(stx.tx_bits, (sig.__class__(
+        sig.bytes[:-1] + bytes([sig.bytes[-1] ^ 1]), sig.by),))
+
+
+def test_host_routed_verify_crosses_one_thread(services):
+    """A lone transaction under the crossover is verified, signatures and
+    contract rules, on the ``tpu-verifier`` worker that serves it: neither
+    the planner nor the prep pool sees the group, and the caller's thread
+    runs none of it."""
+    svc = TpuTransactionVerifierService()
+    b = svc.batcher
+    seen = _spy_threads(b, "_run_host")
+    held_on = _spy_threads(b, "hold_group")
+    b._submit_flush = lambda *a, **k: pytest.fail("planner cut a plan")
+    try:
+        stx = make_issue_stx(services)
+        assert svc.verify_signed(stx, services).result(timeout=30) is None
+        assert held_on == [threading.current_thread().name]
+        with pytest.raises(SignatureException):
+            svc.verify_signed(_corrupted(stx), services).result(timeout=30)
+        assert len(seen) == 2
+        assert all(name.startswith("tpu-verifier") for name in seen)
+        assert b._prep_pool is None
+        snap = svc.metrics.snapshot()
+        for meter in ("HostRouted", "Checked", "HostInline"):
+            assert snap[f"SigBatcher.{meter}"]["count"] == 2
+        assert snap["SigBatcher.InFlight"]["value"] == 0
+        assert snap["Verification.Success"]["count"] == 1
+        assert snap["Verification.Failure"]["count"] == 1
+    finally:
+        svc.shutdown()
+
+
+def test_worker_waits_for_the_queue_when_the_depth_is_the_devices(services):
+    """At or over the crossover the rows stay queued for the planner (here
+    a crossover of 1 and a stubbed device): the worker takes nothing back
+    and reads the planner's verdicts, valid and corrupted, as before."""
+    b = SignatureBatcher(host_crossover=1)
+    batches = []
+
+    def device(bucket, items, reason="full", bctx=None):
+        batches.append(len(items))
+        b._mark_device(items)
+        b._resolve(bucket, items, b._run_host(items), bctx)
+
+    b._dispatch_device = device
+    svc = TpuTransactionVerifierService(batcher=b)
+    try:
+        stx = make_issue_stx(services)
+        assert svc.verify_signed(stx, services).result(timeout=30) is None
+        with pytest.raises(SignatureException):
+            svc.verify_signed(_corrupted(stx), services).result(timeout=30)
+        assert batches == [1, 1]
+        snap = b.metrics.snapshot()
+        assert snap["SigBatcher.DeviceChecked"]["count"] == 2
+        assert "SigBatcher.HostInline" not in snap
+        assert snap["SigBatcher.InFlight"]["value"] == 0
+    finally:
+        svc.shutdown()
+
+
+@pytest.mark.parametrize("rows_kw", [{}, {"wave_rows": 191},
+                                     {"wave_rows": 192}],
+                         ids=["lone", "wave_under", "wave_at_crossover"])
+def test_verify_signed_on_closed_batcher_returns_failed_future(services,
+                                                               rows_kw):
+    """Span-leak fix: if the batcher rejects the group (closed), the
     caller must get a FAILED FUTURE — verify_signed's contract is async —
-    and the root tx.verify span must still be finished, not leaked."""
+    and the root tx.verify span must still be finished, not leaked. The
+    rows are refused on the caller's thread, whatever their wave's size:
+    the future comes back already failed."""
     from corda_tpu.observability import disable_tracing, enable_tracing
     tracer = enable_tracing()
     svc = TpuTransactionVerifierService()
     try:
         stx = make_issue_stx(services)
         svc.batcher.close()
-        fut = svc.verify_signed(stx, services)
+        fut = svc.verify_signed(stx, services, **rows_kw)
         assert fut.done()
         with pytest.raises(RuntimeError, match="closed"):
             fut.result(timeout=5)
@@ -151,6 +234,56 @@ def test_verify_signed_on_closed_batcher_returns_failed_future(services):
     finally:
         disable_tracing()
         svc.shutdown()
+
+
+def test_inline_flush_spans_hang_under_the_callers_tx_verify(services):
+    from corda_tpu.observability import disable_tracing, enable_tracing
+    tracer = enable_tracing()
+    svc = TpuTransactionVerifierService()
+    try:
+        stx = make_issue_stx(services)
+        assert svc.verify_signed(stx, services).result(timeout=30) is None
+    finally:
+        disable_tracing()
+        svc.shutdown()
+    by_name = {s["name"]: s for s in tracer.spans()}
+    root, flush = by_name["tx.verify"], by_name["batcher.flush"]
+    assert flush["tags"]["route"] == "host"
+    assert flush["tags"]["inline"] is True
+    assert flush["tags"]["flush_reason"] == "small_batch"
+    assert flush["parent_id"] == root["span_id"]
+    assert by_name["batcher.enqueue_wait"]["parent_id"] == root["span_id"]
+    for name in ("batcher.dispatch", "batcher.resolve"):
+        assert by_name[name]["parent_id"] == flush["span_id"]
+    assert by_name["batcher.dispatch"]["tags"]["route"] == "host"
+    threads = {by_name[n]["thread"] for n in (
+        "batcher.flush", "batcher.dispatch", "batcher.resolve",
+        "verifier.run", "verifier.resolve")}
+    assert len(threads) == 1 and threads.pop().startswith("tpu-verifier")
+    assert all(s["trace_id"] == root["trace_id"] for s in tracer.spans())
+
+
+def test_inline_route_records_nothing_with_tracing_off(services):
+    from corda_tpu.observability import get_tracer
+    svc = TpuTransactionVerifierService()
+    svc.batcher._trace_flush = lambda *a, **k: pytest.fail(
+        "a flush span with the no-op tracer")
+    stamped = []
+    stamp = svc.batcher._stamp_trace
+
+    def spy_stamp(pendings, ctx):
+        stamped.append(ctx)
+        return stamp(pendings, ctx)
+
+    svc.batcher._stamp_trace = spy_stamp
+    try:
+        stx = make_issue_stx(services)
+        assert svc.verify_signed(stx, services).result(timeout=30) is None
+        assert svc.metrics.snapshot()["SigBatcher.HostInline"]["count"] == 1
+    finally:
+        svc.shutdown()
+    assert stamped == [None]
+    assert get_tracer().spans() == []
 
 
 def test_make_verifier_service_seam():

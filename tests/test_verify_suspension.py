@@ -281,3 +281,175 @@ def test_rebuild_error_uses_whitelist_not_dynamic_import():
                    ["builtins:exec", "1+1"], ["no.such.module:X", "y"]):
         rebuilt = _rebuild_error(gadget)
         assert type(rebuilt) is FlowException, gadget
+
+
+# ---------------------------------------------------------------------------
+# VerifyMany against the Tpu service: the WAVE's size picks the route
+# ---------------------------------------------------------------------------
+
+class WaveFlow(FlowLogic):
+    def __init__(self, stxs):
+        self.stxs = stxs
+
+    def call(self):
+        from corda_tpu.flows.api import VerifyMany
+        try:
+            yield VerifyMany(tuple(self.stxs))
+        except SignatureException as e:
+            return f"caught:{e}"
+        return "verified"
+
+
+def _corrupt(stx):
+    sig = stx.sigs[0]
+    return stx.__class__(stx.tx_bits, (sig.__class__(
+        sig.bytes[:-1] + bytes([sig.bytes[-1] ^ 1]), sig.by),))
+
+
+def _wave(svcs, bad=()):
+    stxs = [make_issue_stx(svcs, i) for i in range(5)]
+    return [_corrupt(s) if i in bad else s for i, s in enumerate(stxs)]
+
+
+def _spy_routes(batcher):
+    """Thread names where the rows are queued, collected and host-verified,
+    and the sizes of the batches a stubbed device was handed (host
+    verdicts, no kernel)."""
+    import threading
+    seen = {"hold": [], "collect": [], "host_loop": [], "device_batches": []}
+
+    def record(key, name):
+        orig = getattr(batcher, name)
+
+        def spy(*a, **k):
+            seen[key].append(threading.current_thread().name)
+            return orig(*a, **k)
+        setattr(batcher, name, spy)
+
+    record("hold", "hold_group")
+    record("collect", "collect_group")
+    record("host_loop", "_run_host")
+
+    def device(bucket, items, reason="full", bctx=None):
+        seen["device_batches"].append(len(items))
+        batcher._mark_device(items)
+        batcher._resolve(bucket, items, batcher._run_host(items), bctx)
+
+    batcher._dispatch_device = device
+    return seen
+
+
+@pytest.mark.parametrize("bad,outcome", [((), "verified"), ((3, 1), 1)],
+                         ids=["all_valid", "first_failure_in_order"])
+def test_wave_at_the_crossover_is_enqueued_whole_from_the_node_thread(
+        bad, outcome):
+    import threading
+    network, node = make_network_node()
+    svcs = seed_services(node)
+    # 5 one-signature members against a crossover of 5; interactive_batch 5
+    # makes the whole wave ready at its cap: no deadline is waited for
+    batcher = SignatureBatcher(host_crossover=5, interactive_batch=5)
+    node.services.verifier_service = TpuTransactionVerifierService(
+        batcher=batcher)
+    seen = _spy_routes(batcher)
+    stxs = _wave(svcs, bad)
+    try:
+        with batcher._lock:     # re-entrant: the planner waits for the wave
+            fsm = node.start_flow(WaveFlow(stxs))
+            assert len(batcher._queues["ed25519"]) == 5
+        assert seen["hold"] == [threading.current_thread().name] * 5
+        network.run_network()
+        got = fsm.result_future.result(timeout=60)
+        assert got == "verified" if outcome == "verified" else \
+            stxs[outcome].id.prefix_chars() in got
+        assert seen["device_batches"] == [5]
+        # the stub's one host loop, on a batcher thread: no worker ran one
+        assert len(seen["host_loop"]) == 1
+        assert not seen["host_loop"][0].startswith("tpu-verifier")
+        snap = batcher.metrics.snapshot()
+        assert snap["SigBatcher.DeviceChecked"]["count"] == 5
+        assert "SigBatcher.HostInline" not in snap
+        assert "SigBatcher.HostRouted" not in snap
+    finally:
+        node.services.verifier_service.shutdown()
+
+
+@pytest.mark.parametrize("bad,outcome", [((), "verified"), ((4, 2), 2)],
+                         ids=["all_valid", "first_failure_in_order"])
+def test_wave_under_the_crossover_goes_inline_member_by_member(bad, outcome):
+    network, node = make_network_node()
+    svcs = seed_services(node)
+    batcher = SignatureBatcher(host_crossover=6)
+    node.services.verifier_service = TpuTransactionVerifierService(
+        batcher=batcher)
+    seen = _spy_routes(batcher)
+    stxs = _wave(svcs, bad)
+    try:
+        fsm = node.start_flow(WaveFlow(stxs))
+        assert node.smm.awaiting_external == 1
+        network.run_network()
+        got = fsm.result_future.result(timeout=60)
+        assert got == "verified" if outcome == "verified" else \
+            stxs[outcome].id.prefix_chars() in got
+        assert len(seen["hold"]) == 5 and len(seen["host_loop"]) == 5
+        assert all(n.startswith("tpu-verifier")
+                   for n in seen["collect"] + seen["host_loop"])
+        assert seen["device_batches"] == []
+        snap = batcher.metrics.snapshot()
+        for meter in ("HostInline", "HostRouted", "Checked"):
+            assert snap[f"SigBatcher.{meter}"]["count"] == 5
+        assert snap["SigBatcher.InFlight"]["value"] == 0
+    finally:
+        node.services.verifier_service.shutdown()
+
+
+def test_wave_rows_reach_only_a_service_that_takes_them():
+    """ManualVerifierService's verify_signed has no wave_rows: VerifyMany
+    must keep calling such a service as it did."""
+    network, node = make_network_node()
+    svcs = seed_services(node)
+    manual = ManualVerifierService()
+    node.services.verifier_service = manual
+    fsm = node.start_flow(WaveFlow(_wave(svcs)))
+    assert len(manual.futures) == 5
+    for fut in manual.futures:
+        fut.set_result(None)
+    network.run_network()
+    assert fsm.result_future.result(timeout=30) == "verified"
+
+
+@pytest.mark.parametrize("flows,crossover,device_batches", [
+    (8, 4, [8]),     # together at or over the crossover: one device batch
+    (3, 4, []),      # together under it: each on the worker that serves it
+], ids=["at_or_over", "under"])
+def test_lone_verifies_suspended_together_are_judged_as_one_depth(
+        flows, crossover, device_batches):
+    """One-signature ``Verify`` flows suspended together share the
+    batcher's queue, and the crossover is held against THAT depth, not
+    against each transaction's own signature count (the lock is held while
+    they start, so the depth is the same for whoever judges it first)."""
+    network, node = make_network_node()
+    svcs = seed_services(node)
+    batcher = SignatureBatcher(host_crossover=crossover,
+                               interactive_batch=flows)
+    node.services.verifier_service = TpuTransactionVerifierService(
+        batcher=batcher)
+    seen = _spy_routes(batcher)
+    try:
+        with batcher._lock:
+            fsms = [node.start_flow(VerifyFlow(make_issue_stx(svcs, i)))
+                    for i in range(flows)]
+            assert len(batcher._queues["ed25519"]) == flows
+        network.run_network()
+        assert [f.result_future.result(timeout=60) for f in fsms] \
+            == ["verified"] * flows
+        assert seen["device_batches"] == device_batches
+        snap = batcher.metrics.snapshot()
+        inline = 0 if device_batches else flows
+        assert snap.get("SigBatcher.DeviceChecked", {"count": 0})["count"] \
+            == flows - inline
+        assert snap.get("SigBatcher.HostInline", {"count": 0})["count"] \
+            == inline
+        assert snap["SigBatcher.InFlight"]["value"] == 0
+    finally:
+        node.services.verifier_service.shutdown()
