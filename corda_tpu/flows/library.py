@@ -11,12 +11,14 @@ Reference parity (core/src/main/kotlin/net/corda/core/flows/):
 """
 from __future__ import annotations
 
+import time as _time
 from dataclasses import dataclass
 from typing import Any
 
 from ..core.crypto.signatures import DigitalSignatureWithKey
 from ..core.serialization import register_type
 from ..core.transactions.signed import SignedTransaction
+from ..observability import get_tracer
 from .api import (AwaitFuture, FlowException, FlowLogic, Receive, Send,
                   SendAndReceive, Verify, VerifyMany, initiating_flow)
 
@@ -256,12 +258,32 @@ class ResolveTransactionsFlow(FlowLogic):
     """Wave-based dependency download + verify+record
     (ResolveTransactionsFlow.kt:31-134, vectorized): instead of walking the
     graph link-by-link, each round fetches the ENTIRE unseen frontier as
-    one batched request (paged at FETCH_PAGE ids), so a depth-D chain costs
-    D round trips, not D×(chain width). Verification then runs in
-    topological WAVES — every member of a wave has its dependencies already
+    one batched request (paged at FETCH_PAGE ids), so a depth-D graph costs
+    D round trips, not D x (graph width). Verification then runs in
+    topological WAVES: every member of a wave has its dependencies already
     recorded, so the whole wave is submitted to the verifier service at
-    once (VerifyMany) and its signatures coalesce into shared device
-    batches. Hard cap of 5000 transactions per walk."""
+    once (VerifyMany). Hard cap of 5000 transactions per walk.
+
+    What that buys depends on the graph's WIDTH. On a wide graph a wave's
+    signatures reach the batcher together. On a CHAIN (one coin paid on and
+    on, its change spent by the next payment) the frontier is one id and
+    every level is one transaction: D round trips, D fetch sessions, D
+    ``VerifyMany`` waves of one, each a lone host-routed verify under
+    ``host_crossover`` and a park of its own, and not one row for the
+    device however deep the chain (measured: PERF.md,
+    ``crosscash-deepchain.latejoin``). The walk's cost is linear in D.
+
+    Traced, a walk that fetched anything leaves ``resolve.walk`` (tags
+    ``fetched``, ``hops``, ``waves``, ``peer``) with the children
+    ``resolve.fetch`` (the download loop), ``resolve.order``,
+    ``resolve.verify`` and ``resolve.record`` (each the SUM over the waves,
+    laid from its first wave's start, each tagged ``hops`` too). They join
+    the flow's trace without a parent span: the walk spans many of the
+    flow's steps and waits, and a critical-path walk that charges every
+    millisecond of a ``flow.run`` to one span has to go on charging those.
+    Counted always:
+    ``Resolve.Walks`` / ``Hops`` / ``Fetched`` / ``Recorded`` / ``Refused``
+    and the ``resolve_depth`` histogram (hops per walk)."""
 
     def __init__(self, peer, tx_ids=None, stx: SignedTransaction | None = None):
         self.peer = peer
@@ -277,61 +299,143 @@ class ResolveTransactionsFlow(FlowLogic):
         seen = set(frontier)
         queue = [tx_id for tx_id in frontier
                  if hub.storage.get_transaction(tx_id) is None]
-        while queue:
-            if len(fetched) + len(queue) > MAX_RESOLVE_TRANSACTIONS:
-                raise FlowException(
-                    f"Transaction resolution exceeds the {MAX_RESOLVE_TRANSACTIONS} limit")
-            # one wave = the whole current frontier; page only to bound the
-            # size of a single wire message
-            wave, queue = queue, []
-            stxs = []
-            for i in range(0, len(wave), FETCH_PAGE):
-                page = yield from self.sub_flow(
-                    FetchTransactionsFlow(self.peer, wave[i:i + FETCH_PAGE]))
-                stxs.extend(page)
-            for stx in stxs:
-                fetched[stx.id] = stx
-                for ref in stx.inputs:
-                    dep = ref.txhash
-                    if dep not in seen:
-                        seen.add(dep)
-                        if hub.storage.get_transaction(dep) is None:
-                            queue.append(dep)
-        # attachments referenced anywhere in the resolved set must be local
-        # before verification can open them (FetchAttachmentsFlow leg of
-        # ResolveTransactionsFlow.kt)
-        att_ids = {a for stx in fetched.values() for a in stx.tx.attachments}
-        if self.stx is not None:
-            att_ids |= set(self.stx.tx.attachments)
-        missing = [a for a in att_ids if not hub.attachments.has_attachment(a)]
-        if missing:
-            yield from self.sub_flow(FetchAttachmentsFlow(self.peer, missing))
-        # verify in topological waves: all of wave N's dependencies were
-        # recorded by waves < N, and within a wave the transactions are
-        # independent — so the whole wave verifies concurrently
-        ordered = []
-        for wave in _topological_waves(fetched):
-            yield VerifyMany(tuple(wave), check_sufficient_signatures=False)
-            for stx in wave:
-                hub.record_transactions(stx)
-                ordered.append(stx)
+        walk = _WalkRecord(self)
+        try:
+            while queue:
+                if len(fetched) + len(queue) > MAX_RESOLVE_TRANSACTIONS:
+                    raise FlowException(
+                        "Transaction resolution exceeds the "
+                        f"{MAX_RESOLVE_TRANSACTIONS} limit")
+                # one wave = the whole current frontier; page only to bound
+                # the size of a single wire message
+                wave, queue = queue, []
+                walk.hops += 1
+                stxs = []
+                for i in range(0, len(wave), FETCH_PAGE):
+                    page = yield from self.sub_flow(
+                        FetchTransactionsFlow(self.peer, wave[i:i + FETCH_PAGE]))
+                    stxs.extend(page)
+                for stx in stxs:
+                    fetched[stx.id] = stx
+                    for ref in stx.inputs:
+                        dep = ref.txhash
+                        if dep not in seen:
+                            seen.add(dep)
+                            if hub.storage.get_transaction(dep) is None:
+                                queue.append(dep)
+            walk.fetched = len(fetched)
+            # attachments referenced anywhere in the resolved set must be
+            # local before verification can open them (FetchAttachmentsFlow
+            # leg of ResolveTransactionsFlow.kt)
+            att_ids = {a for stx in fetched.values()
+                       for a in stx.tx.attachments}
+            if self.stx is not None:
+                att_ids |= set(self.stx.tx.attachments)
+            missing = [a for a in att_ids
+                       if not hub.attachments.has_attachment(a)]
+            if missing:
+                yield from self.sub_flow(
+                    FetchAttachmentsFlow(self.peer, missing))
+            walk.phase("fetch")
+            waves = _topological_waves(fetched)
+            walk.waves = len(waves)
+            walk.phase("order")
+            # verify in topological waves: all of wave N's dependencies were
+            # recorded by waves < N, and within a wave the transactions are
+            # independent, so the whole wave verifies concurrently
+            ordered = []
+            for wave in waves:
+                yield VerifyMany(tuple(wave),
+                                 check_sufficient_signatures=False)
+                walk.phase("verify")
+                hub.record_transactions(*wave)
+                ordered.extend(wave)
+                walk.recorded += len(wave)
+                walk.phase("record")
+        except Exception:
+            walk.close(refused=True)
+            raise
+        walk.close(refused=False)
         return [stx.id for stx in ordered]
 
 
+class _WalkRecord:
+    """One resolution walk's counts and, traced, its phases' times: the
+    meters and spans named in ResolveTransactionsFlow's docstring."""
+
+    def __init__(self, flow):
+        self.flow = flow
+        self.hops = self.fetched = self.waves = self.recorded = 0
+        self.tracer = get_tracer()
+        self.phases: dict = {}         # name -> [first start, summed seconds]
+        self.t0 = self.mark = _time.time() if self.tracer.enabled else None
+
+    def phase(self, name: str) -> None:
+        """What has passed since the last mark belongs to ``name``."""
+        if self.t0 is None:
+            return
+        now = _time.time()
+        first = self.phases.setdefault(name, [self.mark, 0.0])
+        first[1] += now - self.mark
+        self.mark = now
+
+    def close(self, refused: bool) -> None:
+        if not self.hops:       # nothing to fetch: no walk to count
+            return
+        monitoring = getattr(self.flow.service_hub, "monitoring", None)
+        if monitoring is not None:
+            monitoring.meter("Resolve.Walks").mark()
+            monitoring.meter("Resolve.Hops").mark(self.hops)
+            monitoring.meter("Resolve.Fetched").mark(self.fetched)
+            monitoring.meter("Resolve.Recorded").mark(self.recorded)
+            if refused:
+                monitoring.meter("Resolve.Refused").mark()
+            monitoring.histogram("resolve_depth").update(self.hops)
+        ctx = getattr(self.flow.state_machine, "trace_ctx", None)
+        if self.t0 is None or ctx is None:
+            return
+        trace_id = ctx[0] if isinstance(ctx, tuple) else ctx.trace_id
+        walk = self.tracer.record(
+            "resolve.walk", parent=(trace_id, None), start_s=self.t0,
+            duration_s=_time.time() - self.t0, fetched=self.fetched,
+            hops=self.hops, waves=self.waves, recorded=self.recorded,
+            refused=refused, peer=str(self.flow.peer.name))
+        for name, (start, seconds) in self.phases.items():
+            self.tracer.record(f"resolve.{name}", parent=walk,
+                               start_s=start, duration_s=seconds,
+                               hops=self.hops)
+
+
 def _topological_waves(txs: dict) -> list:
-    """Kahn's algorithm by levels: wave k = every tx whose dependencies all
-    live in waves < k (dependency-free members first). Flattening the waves
-    yields a valid topological order."""
-    pending = dict(txs)
+    """Kahn's algorithm by levels, in time linear in transactions + edges:
+    wave k = every tx whose dependencies all live in waves < k
+    (dependency-free members first, in ``txs``' own order). Flattening the
+    waves yields a valid topological order. Dependencies outside ``txs``
+    (already in storage) count for nothing."""
+    waiting_on = {}                 # tx id -> dependencies not yet in a wave
+    dependants: dict = {}           # tx id -> the txs that spend from it
+    wave = []
+    for tx_id, stx in txs.items():
+        deps = {ref.txhash for ref in stx.inputs if ref.txhash in txs}
+        if not deps:
+            wave.append(stx)
+            continue
+        waiting_on[tx_id] = len(deps)
+        for dep in deps:
+            dependants.setdefault(dep, []).append(stx)
     waves = []
-    while pending:
-        wave = [stx for tx_id, stx in pending.items()
-                if all(ref.txhash not in pending for ref in stx.inputs)]
-        if not wave:
-            raise FlowException("Transaction dependency cycle detected")
-        for stx in wave:
-            del pending[stx.id]
+    while wave:
         waves.append(wave)
+        following = []
+        for done in wave:
+            for stx in dependants.get(done.id, ()):
+                waiting_on[stx.id] -= 1
+                if not waiting_on[stx.id]:
+                    del waiting_on[stx.id]
+                    following.append(stx)
+        wave = following
+    if waiting_on:
+        raise FlowException("Transaction dependency cycle detected")
     return waves
 
 
@@ -413,7 +517,6 @@ class FinalityFlow(FlowLogic):
         self.extra_recipients = tuple(extra_recipients)
 
     def call(self):
-        import time as _time
         hub = self.service_hub
         stx = self.stx
         needs_notary = stx.notary is not None and (

@@ -7,8 +7,11 @@ flow class + flow fields + the ordered responses consumed at each yield + the
 session table. Resume = re-execute `call()` feeding the log (corda_tpu.flows
 module docstring).
 
-`FileCheckpointStorage` adds crash-durable atomic persistence (one file per
-checkpoint, write-tmp-then-rename — the node_checkpoints table analog).
+`FileCheckpointStorage` and `KvCheckpointStorage` add crash-durable atomic
+persistence. A suspension writes a DELTA (`Checkpoint.log_from`): the durable
+stores rewrite one small head per suspension and seal the log behind it into
+segments that are written once, so a flow that suspends D times writes O(D)
+bytes in all, not O(D^2).
 """
 from __future__ import annotations
 
@@ -38,6 +41,10 @@ class Checkpoint:
     flow_fields: dict         # flow __dict__ minus injected attrs
     response_log: list        # ordered responses consumed at yields
     sessions: list = field(default_factory=list)  # SessionSnapshot list
+    #: what a suspension writes is a DELTA: ``response_log`` holds the
+    #: entries from this index on, and what the storage holds below it
+    #: stands. 0 (always, in what a storage hands back) is the whole log.
+    log_from: int = 0
 
     @property
     def id(self) -> str:
@@ -45,13 +52,29 @@ class Checkpoint:
 
 
 class CheckpointStorage:
-    """In-memory checkpoint store (reference CheckpointStorage SPI)."""
+    """In-memory checkpoint store (reference CheckpointStorage SPI).
+
+    ``add_checkpoint`` takes a delta (``Checkpoint.log_from``): the flow's
+    fields and session table replace what is held, the log entries are
+    appended, so a suspension costs what changed since the last one and not
+    the flow's whole history. ``get_all_checkpoints`` hands back whole logs."""
 
     def __init__(self):
         self._checkpoints: dict[str, Checkpoint] = {}
 
     def add_checkpoint(self, cp: Checkpoint) -> None:
-        self._checkpoints[cp.id] = cp
+        if cp.log_from == 0:
+            self._checkpoints[cp.id] = cp
+            return
+        held = self._checkpoints.get(cp.id)
+        n_held = len(held.response_log) if held is not None else 0
+        if cp.log_from > n_held:
+            raise ValueError(
+                f"checkpoint {cp.id} starts at log entry {cp.log_from}, the "
+                f"storage holds {n_held}")
+        del held.response_log[cp.log_from:]
+        held.response_log.extend(cp.response_log)
+        held.flow_fields, held.sessions = cp.flow_fields, cp.sessions
 
     def remove_checkpoint(self, cp_or_id) -> None:
         cp_id = cp_or_id if isinstance(cp_or_id, str) else cp_or_id.id
@@ -61,76 +84,133 @@ class CheckpointStorage:
         return list(self._checkpoints.values())
 
 
-class FileCheckpointStorage(CheckpointStorage):
-    """Durable variant: canonical-codec blobs, atomic replace per checkpoint."""
+#: a durable checkpoint's unsealed log tail is sealed into a segment of its
+#: own once it holds this many entries, or once the head blob (fields,
+#: sessions, tail) passes this many bytes: every suspension rewrites the
+#: head, so the tail it carries stays bounded
+SEAL_ENTRIES = 16
+SEAL_BYTES = 64 * 1024
 
-    def __init__(self, directory: str):
+
+class _BlobCheckpointStorage(CheckpointStorage):
+    """Durable checkpoints as blobs under string keys: one HEAD per flow
+    (``<run_id>``: fields, session table, the log's unsealed tail) and
+    sealed log SEGMENTS (``<run_id>.<n>``) that are written once and never
+    again. A suspension rewrites the head alone; segments are written
+    before the head that counts them, so a crash between the two leaves
+    the older head and an orphan segment that loading ignores. Subclasses
+    store the blobs: ``_put``, ``_delete``, ``_load``."""
+
+    def __init__(self):
         super().__init__()
-        self.directory = directory
-        os.makedirs(directory, exist_ok=True)
-        for name in os.listdir(directory):
-            if name.endswith(".ckpt"):
-                with open(os.path.join(directory, name), "rb") as f:
-                    cp = _checkpoint_from_bytes(f.read())
-                self._checkpoints[cp.id] = cp
-
-    def _path(self, cp_id: str) -> str:
-        return os.path.join(self.directory, f"{cp_id}.ckpt")
+        #: run_id -> (sealed segments, log entries they hold)
+        self._sealed: dict[str, tuple[int, int]] = {}
+        blobs = self._load()
+        for key in [k for k in blobs if "." not in k]:
+            head = deserialize(blobs[key])
+            run_id, flow_class, fields, tail, sessions = head[:5]
+            n_seg = head[5] if len(head) > 5 else 0
+            log = []
+            for i in range(n_seg):
+                log.extend(deserialize(blobs.pop(f"{key}.{i}")))
+            self._sealed[run_id] = (n_seg, len(log))
+            self._checkpoints[run_id] = Checkpoint(
+                run_id, flow_class, fields, log + list(tail),
+                [SessionSnapshot(*s) for s in sessions])
+            del blobs[key]
+        for orphan in blobs:      # a segment no head counts
+            self._delete(orphan)
 
     def add_checkpoint(self, cp: Checkpoint) -> None:
         super().add_checkpoint(cp)
-        tmp = self._path(cp.id) + ".tmp"
-        with open(tmp, "wb") as f:
-            f.write(_checkpoint_to_bytes(cp))
-            f.flush()
-            os.fsync(f.fileno())
-        os.replace(tmp, self._path(cp.id))
+        held = self._checkpoints[cp.id]
+        n_seg, n_sealed = self._sealed.get(cp.id, (0, 0))
+        if cp.log_from < n_sealed:      # no delta over what is sealed:
+            self._drop(cp.id)           # start the flow's blobs over
+            n_seg = n_sealed = 0
+        tail = held.response_log[n_sealed:]
+        blob = self._head_blob(held, tail, n_seg)
+        if tail and (len(tail) >= SEAL_ENTRIES or len(blob) > SEAL_BYTES):
+            self._put(f"{cp.id}.{n_seg}", serialize(tail))
+            n_seg, n_sealed = n_seg + 1, n_sealed + len(tail)
+            blob = self._head_blob(held, [], n_seg)
+        self._put(cp.id, blob)
+        self._sealed[cp.id] = (n_seg, n_sealed)
+
+    @staticmethod
+    def _head_blob(cp: Checkpoint, tail: list, n_seg: int) -> bytes:
+        return serialize([
+            cp.run_id, cp.flow_class, cp.flow_fields, tail,
+            [[s.peer_name, s.our_session_id, s.peer_session_id, s.state,
+              s.received, s.pending_out, s.group] for s in cp.sessions],
+            n_seg])
 
     def remove_checkpoint(self, cp_or_id) -> None:
         cp_id = cp_or_id if isinstance(cp_or_id, str) else cp_or_id.id
         super().remove_checkpoint(cp_id)
+        self._drop(cp_id)
+
+    def _drop(self, cp_id: str) -> None:
+        n_seg, _n = self._sealed.pop(cp_id, (0, 0))
+        self._delete(cp_id)   # the head first: segments without it are orphans
+        for i in range(n_seg):
+            self._delete(f"{cp_id}.{i}")
+
+
+class FileCheckpointStorage(_BlobCheckpointStorage):
+    """Durable variant: canonical-codec blobs, one file per blob, atomic
+    replace (the node_checkpoints table analog)."""
+
+    def __init__(self, directory: str):
+        self.directory = directory
+        os.makedirs(directory, exist_ok=True)
+        super().__init__()
+
+    def _path(self, key: str) -> str:
+        return os.path.join(self.directory, f"{key}.ckpt")
+
+    def _load(self) -> dict:
+        blobs = {}
+        for name in os.listdir(self.directory):
+            if name.endswith(".ckpt"):
+                with open(os.path.join(self.directory, name), "rb") as f:
+                    blobs[name[:-len(".ckpt")]] = f.read()
+        return blobs
+
+    def _put(self, key: str, blob: bytes) -> None:
+        tmp = self._path(key) + ".tmp"
+        with open(tmp, "wb") as f:
+            f.write(blob)
+            f.flush()
+            os.fsync(f.fileno())
+        os.replace(tmp, self._path(key))
+
+    def _delete(self, key: str) -> None:
         try:
-            os.remove(self._path(cp_id))
+            os.remove(self._path(key))
         except FileNotFoundError:
             pass
 
 
-class KvCheckpointStorage(CheckpointStorage):
+class KvCheckpointStorage(_BlobCheckpointStorage):
     """Checkpoints on the native kvlog engine (corda_tpu.storage): synced
     crc-framed appends with torn-tail recovery — the DBCheckpointStorage
     durability class without an embedded SQL database."""
 
     def __init__(self, path: str, use_native: bool | None = None):
-        super().__init__()
         from ..storage import KvStore
         self._kv = KvStore(path, use_native=use_native)
-        for key, blob in self._kv.items():
-            cp = _checkpoint_from_bytes(blob)
-            self._checkpoints[cp.id] = cp
+        super().__init__()
 
-    def add_checkpoint(self, cp: Checkpoint) -> None:
-        super().add_checkpoint(cp)
-        self._kv[cp.id.encode()] = _checkpoint_to_bytes(cp)
+    def _load(self) -> dict:
+        return {key.decode(): blob for key, blob in self._kv.items()}
 
-    def remove_checkpoint(self, cp_or_id) -> None:
-        cp_id = cp_or_id if isinstance(cp_or_id, str) else cp_or_id.id
-        super().remove_checkpoint(cp_id)
-        key = cp_id.encode()
-        if key in self._kv:
-            del self._kv[key]
+    def _put(self, key: str, blob: bytes) -> None:
+        self._kv[key.encode()] = blob
+
+    def _delete(self, key: str) -> None:
+        if key.encode() in self._kv:
+            del self._kv[key.encode()]
 
     def close(self) -> None:
         self._kv.close()
-
-
-def _checkpoint_to_bytes(cp: Checkpoint) -> bytes:
-    return serialize([
-        cp.run_id, cp.flow_class, cp.flow_fields, cp.response_log,
-        [[s.peer_name, s.our_session_id, s.peer_session_id, s.state,
-          s.received, s.pending_out, s.group] for s in cp.sessions]])
-
-
-def _checkpoint_from_bytes(data: bytes) -> Checkpoint:
-    run_id, flow_class, fields, log, sessions = deserialize(data)
-    return Checkpoint(run_id, flow_class, fields, log,
-                      [SessionSnapshot(*s) for s in sessions])

@@ -96,6 +96,9 @@ class FlowStateMachine:
         self.smm = smm
         self.generator = None
         self.response_log: list = []     # entries: (kind, value)
+        # how much of response_log the checkpoint storage already holds: a
+        # suspension writes the entries after it, not the whole log again
+        self.log_checkpointed: int = 0
         self.replay_queue: list = []     # prefix of response_log on restore
         # (session group, peer name) -> session; group 0 = the top-level flow,
         # each @initiating_flow sub-flow gets a deterministic fresh group
@@ -1054,10 +1057,16 @@ class StateMachineManager:
     # -- checkpointing -------------------------------------------------------
     def _checkpoint(self, fsm: FlowStateMachine) -> None:
         """Atomic checkpoint at suspension (updateCheckpoint,
-        StateMachineManager.kt:526-543). Its cost rides on the running
-        flow.step as ``checkpoint_s`` (tracing on)."""
+        StateMachineManager.kt:526-543). What it writes is bounded by what
+        is live: the sessions still in the table (``_prune_sessions``) and
+        the log entries since the last suspension (``Checkpoint.log_from``);
+        a flow that has opened D sessions and logged D answers pays for
+        neither again. Its cost rides on the running flow.step as
+        ``checkpoint_s`` (tracing on); what it wrote, sessions + log
+        entries, is the ``checkpoint_entries`` histogram."""
         step = fsm.step_span
         t0 = _time.perf_counter() if step is not None else 0.0
+        self._prune_sessions(fsm)
         fields = {k: v for k, v in vars(fsm.flow).items()
                   if k not in ("state_machine", "service_hub")}
         sessions = [SessionSnapshot(
@@ -1070,12 +1079,31 @@ class StateMachineManager:
         cp = Checkpoint(run_id=fsm.run_id,
                         flow_class=flow_name(type(fsm.flow)),
                         flow_fields=fields,
-                        response_log=list(fsm.response_log),
-                        sessions=sessions)
+                        response_log=fsm.response_log[fsm.log_checkpointed:],
+                        sessions=sessions, log_from=fsm.log_checkpointed)
         self.checkpoints.add_checkpoint(cp)
+        fsm.log_checkpointed = len(fsm.response_log)
+        monitoring = getattr(self.hub, "monitoring", None)
+        if monitoring is not None:
+            monitoring.histogram("checkpoint_entries").update(
+                len(sessions) + len(cp.response_log))
         if step is not None:
             step.tags["checkpoint_s"] = step.tags.get("checkpoint_s", 0.0) \
                 + _time.perf_counter() - t0
+
+    def _prune_sessions(self, fsm: FlowStateMachine) -> None:
+        """Forget the sessions no request of this flow can address again:
+        those of a finished ``@initiating_flow`` sub-flow (its session group
+        left the stack, and group ids are never reused) that the peer has
+        ended or failed. A walk of D fetches opens D such sessions; kept,
+        every suspension would snapshot all of them. One still open stays:
+        the flow's end owes its peer a ``NormalSessionEnd``."""
+        live = {group for group, _name in fsm.session_group_stack}
+        dead = [key for key, s in fsm.sessions.items()
+                if key[0] not in live and s.state in ("ended", "errored")]
+        for key in dead:
+            self._session_index.pop(fsm.sessions.pop(key).our_session_id,
+                                    None)
 
     def _restore(self, cp: Checkpoint) -> None:
         """Rebuild a flow from its checkpoint and replay it to its suspension
@@ -1086,6 +1114,7 @@ class StateMachineManager:
             setattr(flow, k, v)
         fsm = FlowStateMachine(cp.run_id, flow, self)
         fsm.response_log = list(cp.response_log)
+        fsm.log_checkpointed = len(fsm.response_log)
         fsm.replay_queue = list(cp.response_log)
         self._register(fsm)
         for snap in cp.sessions:
