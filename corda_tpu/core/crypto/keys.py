@@ -14,6 +14,8 @@ from __future__ import annotations
 
 import functools
 import os
+import threading
+from collections import OrderedDict
 from dataclasses import dataclass, field
 from functools import total_ordering
 
@@ -98,19 +100,72 @@ def sec1_compress(curve: ecmath.WeierstrassCurve, point) -> bytes:
     return bytes([2 + (y & 1)]) + x.to_bytes(32, "big")
 
 
+class SignerTable:
+    """Bounded LRU of decoded signer keys: (curve name, the key's encoded
+    bytes) -> its affine point, or None for an encoding that does not decode
+    (the refusal is cached like a point). The decode is a modular square
+    root in Python bigints (0.4 ms) and signers repeat, so every route that
+    needs a key's point asks here: ``Crypto.is_valid`` (the host route), the
+    Ed25519 device prep (``ops/ed25519.py`` ``_decompress_a``) and the ECDSA
+    preps (``sec1_decompress_cached``). Two threads that miss on one key both
+    decode it and store the same value; nothing is computed under the lock."""
+
+    def __init__(self, maxsize: int = 65536):
+        self.maxsize = maxsize
+        self._points: OrderedDict = OrderedDict()
+        self._lock = threading.Lock()
+
+    def point(self, curve_name: str, encoded: bytes):
+        key = (curve_name, encoded)
+        with self._lock:
+            if key in self._points:
+                self._points.move_to_end(key)
+                return self._points[key]
+        if curve_name == "ed25519":
+            point = ecmath.ed_point_decompress(encoded)
+        else:
+            point = sec1_decompress(_CURVES_BY_NAME[curve_name], encoded)
+        with self._lock:
+            self._points[key] = point
+            if len(self._points) > self.maxsize:
+                self._points.popitem(last=False)
+        return point
+
+    def __contains__(self, key) -> bool:
+        return key in self._points      # a peek: no promotion, no decode
+
+    def __len__(self) -> int:
+        return len(self._points)
+
+
+#: THE process-wide table (the size `_openssl_ed_key` and the per-signer row
+#: caches use). A deployment with more live signers than this pays a decode
+#: per miss, as every call did before the table.
+_SIGNERS = SignerTable()
+
+
+def signer_point(curve_name: str, encoded: bytes):
+    """The affine point of a signer's key (``"ed25519"`` or a Weierstrass
+    curve's name), or None where the encoding does not decode."""
+    return _SIGNERS.point(curve_name, encoded)
+
+
+def signer_decoded(public: PublicKey) -> bool | None:
+    """Whether ``signer_point`` would answer for this key without decoding
+    it; None for a scheme that has no point to decode (RSA, SPHINCS, a
+    composite key)."""
+    curve_name = _DECODED_SCHEMES.get(public.scheme.scheme_number_id)
+    if curve_name is None:
+        return None
+    return (curve_name, public.encoded) in _SIGNERS
+
+
 def sec1_decompress_cached(curve: ecmath.WeierstrassCurve, data: bytes):
-    """sec1_decompress with the modular square root memoized per (curve,
-    encoding). Decompression costs a 256-bit modpow; verification workloads
-    see the same signer keys over and over (per-party keys across a ledger),
-    so the batcher's host prep rides this cache."""
-    return _decompress_lru(curve.name, data)
-
-
-@functools.lru_cache(maxsize=65536)
-def _decompress_lru(curve_name: str, data: bytes):
-    curve = (ecmath.SECP256K1 if curve_name == "secp256k1"
-             else ecmath.SECP256R1)
-    return sec1_decompress(curve, data)
+    """sec1_decompress through the signer table: decompression costs a
+    256-bit modpow; verification workloads see the same signer keys over and
+    over (per-party keys across a ledger), so the batcher's host prep and
+    ``Crypto.is_valid`` ride it."""
+    return signer_point(curve.name, data)
 
 
 def sec1_pub_row_cached(curve: ecmath.WeierstrassCurve, data: bytes):
@@ -126,7 +181,7 @@ def sec1_pub_row_cached(curve: ecmath.WeierstrassCurve, data: bytes):
 @functools.lru_cache(maxsize=65536)
 def _pub_row_lru(curve_name: str, data: bytes):
     import numpy as np
-    pt = _decompress_lru(curve_name, data)
+    pt = signer_point(curve_name, data)
     if pt is None:
         return None
     # frombuffer over bytes is read-only — safe to share across batches
@@ -157,6 +212,11 @@ _ECDSA_CURVES = {
     ECDSA_SECP256K1_SHA256.scheme_number_id: ecmath.SECP256K1,
     ECDSA_SECP256R1_SHA256.scheme_number_id: ecmath.SECP256R1,
 }
+
+
+_CURVES_BY_NAME = {c.name: c for c in _ECDSA_CURVES.values()}
+_DECODED_SCHEMES = {EDDSA_ED25519_SHA512.scheme_number_id: "ed25519",
+                    **{sid: c.name for sid, c in _ECDSA_CURVES.items()}}
 
 
 def curve_for_scheme(scheme: SignatureScheme) -> ecmath.WeierstrassCurve:

@@ -14,7 +14,9 @@ import hashlib
 from dataclasses import dataclass
 
 from . import ecmath
-from .keys import PublicKey, PrivateKey, KeyPair, curve_for_scheme, sec1_decompress
+from .keys import (
+    PublicKey, PrivateKey, KeyPair, curve_for_scheme, sec1_decompress_cached,
+    signer_point)
 from .schemes import (
     SignatureScheme, RSA_SHA256, ECDSA_SECP256K1_SHA256, ECDSA_SECP256R1_SHA256,
     EDDSA_ED25519_SHA512, SPHINCS256_SHA256,
@@ -125,6 +127,9 @@ def _openssl_key(scheme_id: int, encoded: bytes):
     return ec.EllipticCurvePublicKey.from_encoded_point(curve_obj, encoded)
 
 
+_ED_Y_MASK = (1 << 255) - 1     # an Ed25519 encoding's y (bit 255 is x's sign)
+
+
 class Crypto:
     """Scheme dispatch (mirror of the reference ``Crypto`` object)."""
 
@@ -166,14 +171,24 @@ class Crypto:
     def is_valid(public: PublicKey, signature: bytes, content: bytes) -> bool:
         sid = public.scheme.scheme_number_id
         if sid == EDDSA_ED25519_SHA512.scheme_number_id:
-            # structural policy (canonical point decodes, s < L) decided by
-            # OUR decoder — identical to the device kernel precheck; the
-            # verification equation itself then rides OpenSSL when present
-            # (RFC 8032 cofactorless, same equation as ecmath/kernels)
+            # The structural policy is ours, the equation rides OpenSSL when
+            # present (RFC 8032 cofactorless, as ecmath and the kernels):
+            # length 64, the key decodes (once per signer: the table the
+            # device prep reads too), s < L, R's y canonical (y < p). R is
+            # NOT decoded, as the split kernel's prep does not decode it
+            # (ops/ed25519.py prepare_batch_split): OpenSSL compares R's 32
+            # bytes with the ENCODING of the point it computes, [s]B - [k]A,
+            # and no point encodes to bytes that fail to decode (y >= p;
+            # x = 0 with the sign bit set; y off the curve), so the equation
+            # refuses exactly what a decode of R refuses, without a square
+            # root per signature. ecmath's check, the fallback, decodes R
+            # itself. tests/test_crypto_host_policy.py holds the corpus that
+            # proves it row for row against ecmath.ed25519_verify.
             if (len(signature) != 64
-                    or ecmath.ed_point_decompress(public.encoded) is None
-                    or ecmath.ed_point_decompress(signature[:32]) is None
-                    or int.from_bytes(signature[32:], "little") >= ecmath.ED_L):
+                    or signer_point("ed25519", public.encoded) is None
+                    or int.from_bytes(signature[32:], "little") >= ecmath.ED_L
+                    or (int.from_bytes(signature[:32], "little")
+                        & _ED_Y_MASK) >= ecmath.ED_P):
                 return False
             fast = _openssl_ed25519_verify(public.encoded, content, signature)
             if fast is not None:
@@ -182,7 +197,7 @@ class Crypto:
         if sid in (ECDSA_SECP256K1_SHA256.scheme_number_id,
                    ECDSA_SECP256R1_SHA256.scheme_number_id):
             curve = curve_for_scheme(public.scheme)
-            point = sec1_decompress(curve, public.encoded)
+            point = sec1_decompress_cached(curve, public.encoded)
             if point is None:
                 return False
             try:

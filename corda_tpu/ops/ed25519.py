@@ -8,7 +8,8 @@ limb-decomposed lanes; no data-dependent control flow; `lax.scan` ladder so
 the graph stays one-iteration-sized.
 
 Host/device split (host = cheap per-item prep, device = the EC heavy lifting):
-- host: point decompression (one sqrt per unique key — cacheable), SHA-512
+- host: point decompression (one sqrt per unique key, kept in the signer
+  table of core/crypto/keys.py that the host route reads too), SHA-512
   challenge k = H(R ‖ A ‖ M) mod L (hashlib), range checks, limb packing.
 - device: [s]B + [k](-A) via a Shamir/Straus interleaved ladder with unified
   (complete) extended-coordinate addition, projective comparison against R.
@@ -29,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.crypto import ecmath
+from ..core.crypto.keys import signer_point
 from . import field as F
 
 P = F.P25519
@@ -417,12 +419,13 @@ def _pack_point_ext(pts) -> tuple:
     return tuple(jnp.asarray(v) for v in (xs, ys, zs, ts))
 
 
-@functools.lru_cache(maxsize=65536)
 def _decompress_a(pub: bytes):
-    """Per-signer decompression cache: the sqrt inside ed_point_decompress
-    is ~2 modpows of host bigint work per call, and a node verifies the
-    same signers' keys over and over (the service path is host-CPU-bound)."""
-    return ecmath.ed_point_decompress(pub)
+    """The signer's key as an affine point, from the ONE per-signer table
+    (core/crypto/keys.py ``SignerTable``) that ``Crypto.is_valid`` reads
+    too: the sqrt inside ed_point_decompress is ~2 modpows of host bigint
+    work per call, and a node verifies the same signers' keys over and over
+    (the service path is host-CPU-bound), on whichever route."""
+    return signer_point("ed25519", pub)
 
 
 def _row_from_affine(A) -> np.ndarray:

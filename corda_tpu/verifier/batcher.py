@@ -45,7 +45,7 @@ import numpy as np
 
 from ..core.crypto import ecmath
 from ..core.crypto.keys import (
-    PublicKey, sec1_decompress_cached, sec1_pub_row_cached)
+    PublicKey, sec1_decompress_cached, sec1_pub_row_cached, signer_decoded)
 from ..core.crypto.schemes import (
     ECDSA_SECP256K1_SHA256, ECDSA_SECP256R1_SHA256, EDDSA_ED25519_SHA512)
 from ..core.crypto.signatures import Crypto
@@ -942,6 +942,12 @@ class SignatureBatcher:
         prep-pool worker, or the caller of ``collect_group``)."""
         if bucket != "host":
             self.metrics.meter("SigBatcher.HostRouted").mark(len(items))
+        # rows whose check needs the signer's key as a point, and those whose
+        # key the signer table (core/crypto/keys.py) held when the flush began
+        known = [k for k in (signer_decoded(p.key) for p in items)
+                 if k is not None]
+        self.metrics.meter("SigBatcher.SignerDecodeLookup").mark(len(known))
+        self.metrics.meter("SigBatcher.SignerDecodeHit").mark(sum(known))
         t0 = _time.perf_counter()
         with tracer.span("batcher.dispatch", parent=bctx, bucket=bucket,
                          batch_size=len(items), route="host"):

@@ -30,14 +30,29 @@ def bus():
     return InMemoryMessagingNetwork()
 
 
+#: What a host check cost when these tests were written (two point decodes a
+#: signature, in Python bigints). The signer table took them out (0.1 ms a
+#: row now), and a backlog that has to stay parked while a steal is lost,
+#: retried or raced by the overdue scan must not lean on that cost: every
+#: worker here verifies at the old pace.
+ROW_DELAY_S = 0.0007
+
+
 def _host_worker(bus, name, max_inflight_groups=1):
     """A fleet worker on the host route (no kernels — chaos tests exercise
     protocol, not device math) with a finite in-flight window so a deep
     backlog stays parked and stealable."""
     from corda_tpu.verifier.batcher import SignatureBatcher
+    batcher = SignatureBatcher(use_device=False, max_latency_s=0.002)
+    run_host = batcher._run_host
+
+    def paced(items):
+        time.sleep(ROW_DELAY_S * len(items))
+        return run_host(items)
+
+    batcher._run_host = paced
     return VerifierWorker(
-        bus.create_node(name), "node",
-        batcher=SignatureBatcher(use_device=False, max_latency_s=0.002),
+        bus.create_node(name), "node", batcher=batcher,
         use_device=False, capacity=1,
         max_inflight_groups=max_inflight_groups)
 
