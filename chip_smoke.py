@@ -426,34 +426,46 @@ def phase_service(seed: int, n_tx: int = ROWS, distinct: int = 256,
             **counters, "compiles_since_warm": since_warm}
 
 
-def phase_ledger(seed: int) -> dict:
-    """The served path as it ships: bench.py's ledger smoke shape (two
-    shards, cross-shard 2PC, compaction), chaos off, default routing."""
-    from corda_tpu.observability import get_profiler
-    from corda_tpu.observability.ledger_harness import (
-        LedgerScenarioConfig, run_ledger_scenario)
+#: the ledger phase's window: a few seconds of the steady cell's offered load
+LEDGER_SECONDS = 5.0
 
-    seen = []
-    cfg = LedgerScenarioConfig(shards=2, cross_shard_pct=0.25,
-                               raft_snapshot_entries=4,
-                               coordlog_compact_bytes=1024, seed=seed,
-                               on_verifier=seen.append)
-    out = run_ledger_scenario(cfg)
-    probes = ("exactly_once_ok", "replicas_agree", "counter_invariant_ok")
-    _require(out["ops_committed"] > 0, "no operation committed")
-    for probe in probes:
-        _require(out[probe], f"{probe} is false")
-    counters = check_batcher(seen[0].batcher)
-    since_warm = get_profiler().compiles_since_warm()
-    _require(since_warm == 0, f"{since_warm} compile(s) on the served path")
-    return {"ops_committed": out["ops_committed"],
-            "ops_total": out.get("ops_total"),
-            "committed_tx_per_sec": out.get("committed_tx_per_sec"),
-            "cross_shard_committed": out.get("ledger_shard_cross_committed"),
-            "raft_snapshots_taken": out.get("ledger_raft_snapshots_taken"),
-            **{probe: out[probe] for probe in probes}, **counters,
-            "compiles_since_warm": since_warm,
-            # finding for ROADMAP A2, not a failure: at today's thresholds
+
+def phase_ledger(seed: int, seconds: float = LEDGER_SECONDS,
+                 scale: dict | None = None) -> dict:
+    """The served path as the benchmark's cells run it: ``seconds`` of
+    ``crosscash-raft.steady`` (24 parties, a validating notary over 3 durable
+    raft replicas, one shared verifier at default routing) through the
+    benchmark's own driver, which judges the guarantees against its plain
+    references. ``scale`` is the tiny-size override of the CPU rehearsal."""
+    bench = str(ROOT / "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import run as bench_run  # benchmarks/run.py
+
+    cell = bench_run.Cell("crosscash-raft.steady")
+    ctx = bench_run.RunContext(cell, seed, seconds, False, scale=scale,
+                               quiet=True)
+    try:
+        out = bench_run.load_module("drivers", cell.driver_name).run(ctx)
+    finally:
+        ctx.cleanup()
+    checks = {c["check"]: c for c in ctx.checks}
+    failed = [c for c in ctx.checks if not c["ok"]]
+    _require(ctx.correct, f"the driver's checks failed: {failed}")
+    committed = out["attempted"] - out["failed"]
+    _require(committed > 0, "no operation committed")
+    (counters,) = [n for n in ctx.notes if n["note"] == "batcher"]
+    return {"correct": True, "ops_committed": committed,
+            "ops_total": out["attempted"],
+            "commit_ms_p50": out["end_to_end"]["commit_ms_p50"],
+            "exactly_once_ok": checks["exactly_once_violations"]["ok"],
+            "replicas_agree": checks["replica_disagreements"]["ok"],
+            **{name: counters[name] for name in
+               ("DeviceChecked", "HostRouted", "BatchFailure",
+                "BreakerRouted")},
+            "compiles_since_warm":
+                checks["compiles_after_mark_warm"]["value"],
+            # finding for ROADMAP A3, not a failure: at today's thresholds
             # the served path sends every check to the host
             "finding": "served path device/host split at default routing"}
 

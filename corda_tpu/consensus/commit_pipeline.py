@@ -1,8 +1,7 @@
 """Group-commit pipeline for notary uniqueness.
 
-LEDGER_r01 spent one raft consensus round per committed transaction
-(10.2 tx/s against 42.2k service verifies/s); this module closes that
-gap the same way continuous batching closed it for signatures —
+Without it every committed transaction spends one raft consensus round;
+this module closes that gap the same way continuous batching closed it for signatures —
 accumulate, cut batches, pipeline. Many concurrently suspended flows
 call :meth:`GroupCommitter.submit`; a stall-tick dispatcher coalesces
 their requests and submits ONE ``put_all_batch`` raft append carrying

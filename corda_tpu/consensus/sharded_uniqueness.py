@@ -1,8 +1,8 @@
 """Sharded notary uniqueness: N raft groups + cross-shard 2PC.
 
 One raft cluster owning every StateRef caps global committed tx/s at a
-single consensus group no matter how fat the group-commit batches get
-(LEDGER_r03: 19.3 tx/s). This module partitions the uniqueness domain
+single consensus group no matter how fat the group-commit batches get.
+This module partitions the uniqueness domain
 across N notary shards, each backed by its own 3-replica raft group and
 ``put_all_batch`` GroupCommitter, keyed by StateRef hash
 (:func:`shard_of`). The reference precedent is multi-notary operation

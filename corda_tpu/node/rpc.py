@@ -375,9 +375,9 @@ class CordaRPCOps:
         with the resource accounting plane — live size, declared kind
         (bounded vs grows-by-design), leak verdict over its retained
         ``Resource.*`` series — plus the subsystem CPU-attribution
-        snapshot when a profiler is active (observability/soak.py).
+        snapshot when a profiler is active (observability/resprof.py).
         Well-formed and empty on a node with no registered probes."""
-        from ..observability.soak import soak_report
+        from ..observability.resprof import soak_report
         return soak_report()
 
     def vault_feed(self, state_type: type | None = None) -> DataFeed:

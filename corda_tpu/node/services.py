@@ -306,13 +306,13 @@ class ServiceHub:
         self.monitoring.gauge(
             "Tracing.SpansBuffered",
             lambda: len(getattr(get_tracer(), "ring", None) or ()))
-        # resource accounting plane (soak observatory): the span ring and
-        # its cumulative drop counter register size probes with the
-        # process-global registry, so any sampler (harness soak observer,
-        # an operator scraping /debug/soak) gets their leak verdicts and
-        # the windowed drop RATE for free. Registration is by-name
-        # idempotent — a fleet of hubs in one process re-registers the
-        # same process-wide structures harmlessly.
+        # resource accounting plane: the span ring and its cumulative
+        # drop counter register size probes with the process-global
+        # registry, so whoever samples it (an operator scraping
+        # /debug/soak) gets their leak verdicts and the windowed drop
+        # RATE for free. Registration is by-name idempotent — a fleet of
+        # hubs in one process re-registers the same process-wide
+        # structures harmlessly.
         from ..observability.resprof import get_resources, process_rss_bytes
         _resources = get_resources()
         _resources.register(

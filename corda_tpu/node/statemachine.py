@@ -291,8 +291,8 @@ class StateMachineManager:
         return fsm
 
     def _register(self, fsm: FlowStateMachine) -> None:
-        # wall-clock anchor for the flow_run commit-path stage histogram
-        # (observability/stages.LEDGER_STAGE_METRICS), closed in _finalize
+        # wall-clock anchor for the flow_run_seconds histogram, closed in
+        # _finalize
         fsm.started_at = _time.perf_counter()
         monitoring = getattr(self.hub, "monitoring", None)
         if monitoring is not None:   # Flows.StartedPerSecond analog

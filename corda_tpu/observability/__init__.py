@@ -1,6 +1,6 @@
 """End-to-end observability for the TPU verification pipeline.
 
-Three pieces (docs/OBSERVABILITY.md):
+The pieces (docs/OBSERVABILITY.md):
 
 - tracing.py — span tracer with explicit SpanContext propagation across
   the flow state machine, verifier service, SignatureBatcher threads,
@@ -8,8 +8,6 @@ Three pieces (docs/OBSERVABILITY.md):
   ``enable_tracing()`` turns it on.
 - ring.py — the bounded in-memory span buffer behind a live tracer, with
   JSONL export and the /traces endpoint's query surface.
-- stages.py — per-stage (prep/dispatch/finish) percentile flattening for
-  bench.py's JSON artifact.
 - profiling.py — the kernel flight recorder: compile-cache accounting,
   device dispatch/wait wall time, batch occupancy, prep/device overlap;
   always-on, exported through /metrics and /debug/profile.
@@ -19,33 +17,29 @@ Three pieces (docs/OBSERVABILITY.md):
 - lifecycle.py — bounded per-request event timelines (/debug/requests).
 - slo.py — availability/latency objectives, error budgets, multi-window
   burn-rate alerts (surfaced on /readyz as ``degraded.slo``).
-- ledger_harness.py — open-loop end-to-end commit-path load scenario
-  (bench.py --ledger / tools/scenario.py).
 - critpath.py — tail forensics: critical-path (blocking chain) extraction
-  over stitched span trees, wait_kind blame attribution, the
-  ``ledger_critpath_*`` artifact fields and /debug/critpath payload.
+  over stitched span trees, wait_kind blame attribution and the
+  /debug/critpath payload.
 - timeseries.py — the retained time-series plane: memory-bounded,
   downsampled history (fine recent rings cascading into coarse older
   rings) behind /api/timeseries and the consensus_stat CLI.
 - consensus_obs.py — the consensus observatory: raft stats pooling
-  (/debug/raft), Raft.* metric families, growth watchdogs, and the
-  ``ledger_raft_*`` artifact fields.
+  (/debug/raft), Raft.* metric families, growth watchdogs.
 - resprof.py — the resource accounting plane (per-structure size probes
-  → ``Resource.*`` series → ``bounded | growing | leaking`` verdicts)
-  and the subsystem CPU sampling profiler.
-- soak.py — drift-gated endurance runs: recurring chaos, per-phase
-  committed-rate/tail/budget series, mid-run invariant re-checks, the
-  ``soak_*`` artifact fields and /debug/soak payload.
+  → ``Resource.*`` series → ``bounded | growing | leaking`` verdicts),
+  the subsystem CPU sampling profiler and the /debug/soak payload over
+  both.
 
-The Histogram metric type itself lives in utils/metrics.py with the rest
-of the registry.
+The package imports nothing of corda_tpu but ``utils``
+(tests/test_layering.py). The Histogram metric type itself lives in
+utils/metrics.py with the rest of the registry.
 """
 from .consensus_obs import (ATTRIBUTION_COMPONENTS, GrowthWatch,
-                            install_raft_collector, ledger_raft_fields,
-                            raft_report, sample_timeseries)
+                            install_raft_collector, raft_report,
+                            sample_timeseries)
 from .critpath import (COMPONENTS, WAIT_KINDS, aggregate_critpaths,
                        component_of, critical_path, critpath_report,
-                       flow_kind, ledger_critpath_fields)
+                       flow_kind)
 from .federation import FleetMetricsFederation
 from .lifecycle import RequestLog
 from .profiling import (KernelProfiler, OverlapTracker, get_profiler,
@@ -53,13 +47,10 @@ from .profiling import (KernelProfiler, OverlapTracker, get_profiler,
 from .resprof import (COMMIT_PATH_COMPONENTS, CPU_COMPONENTS,
                       ResourceRegistry, SubsystemProfiler, classify_stack,
                       get_resources, leak_verdict, process_rss_bytes,
-                      set_resources, theil_sen_slope)
+                      set_resources, soak_report, theil_sen_slope)
 from .ring import SpanRing
 from .slog import jlog
-from .soak import SoakConfig, SoakObserver, run_soak, soak_report
 from .slo import DEFAULT_OBJECTIVES, SLObjective, SLOTracker
-from .stages import (LEDGER_STAGE_METRICS, STAGE_METRICS,
-                     ledger_stage_percentiles, stage_percentiles)
 from .timeseries import (TimeSeries, TimeSeriesStore, get_timeseries,
                          set_timeseries)
 from .tracing import (NOOP_SPAN, NOOP_TRACER, NoopTracer, Span, SpanContext,
@@ -68,24 +59,16 @@ from .tracing import (NOOP_SPAN, NOOP_TRACER, NoopTracer, Span, SpanContext,
 
 __all__ = [
     "ATTRIBUTION_COMPONENTS", "COMMIT_PATH_COMPONENTS", "COMPONENTS",
-    "CPU_COMPONENTS", "DEFAULT_OBJECTIVES",
-    "FleetMetricsFederation", "GrowthWatch",
-    "KernelProfiler", "LEDGER_STAGE_METRICS", "NOOP_SPAN", "NOOP_TRACER",
+    "CPU_COMPONENTS", "DEFAULT_OBJECTIVES", "FleetMetricsFederation",
+    "GrowthWatch", "KernelProfiler", "NOOP_SPAN", "NOOP_TRACER",
     "NoopTracer", "OverlapTracker", "RequestLog", "ResourceRegistry",
-    "SLObjective",
-    "SLOTracker", "SoakConfig", "SoakObserver", "Span", "SpanContext",
-    "SpanRing", "STAGE_METRICS", "SubsystemProfiler",
-    "TimeSeries", "TimeSeriesStore",
-    "Tracer", "WAIT_KINDS", "aggregate_critpaths", "classify_stack",
-    "component_of",
+    "SLObjective", "SLOTracker", "Span", "SpanContext", "SpanRing",
+    "SubsystemProfiler", "TimeSeries", "TimeSeriesStore", "Tracer",
+    "WAIT_KINDS", "aggregate_critpaths", "classify_stack", "component_of",
     "critical_path", "critpath_report", "disable_tracing",
     "enable_tracing", "flow_kind", "get_profiler", "get_resources",
-    "get_timeseries",
-    "get_tracer", "install_raft_collector", "jlog", "leak_verdict",
-    "ledger_critpath_fields", "ledger_raft_fields",
-    "ledger_stage_percentiles", "make_span_dict", "process_rss_bytes",
-    "raft_report", "run_soak",
+    "get_timeseries", "get_tracer", "install_raft_collector", "jlog",
+    "leak_verdict", "make_span_dict", "process_rss_bytes", "raft_report",
     "sample_timeseries", "set_profiler", "set_resources",
-    "set_timeseries", "set_tracer", "soak_report",
-    "stage_percentiles", "theil_sen_slope",
+    "set_timeseries", "set_tracer", "soak_report", "theil_sen_slope",
 ]

@@ -1,16 +1,15 @@
 """Consensus observatory: raft introspection pooling, shard heat rollup,
 growth watchdogs, and the Raft.* metric families.
 
-critpath blames ``raft.commit``/``raft.leaderless`` as the dominant tail
-component (LEDGER_r03/r04) but nothing inside the consensus tier says
-*why* — election churn vs per-append fsync vs replication RTT vs apply.
-The raft nodes now self-attribute every committed entry
+critpath can blame ``raft.commit``/``raft.leaderless`` as the dominant
+tail component but nothing inside the consensus tier says *why* —
+election churn vs per-append fsync vs replication RTT vs apply.
+The raft nodes self-attribute every committed entry
 (``RaftNode.stats()`` / ``attribution_samples()``); this module is the
 read side: it pools those per-node surfaces into one per-group report
-(``raft_report`` → /debug/raft and fleetstat), flattens them into the
-``ledger_raft_*`` bench artifact fields (benchguard-locked, with the
-attribution-sum validity probe), installs the labeled ``Raft.*`` metric
-families on a registry, feeds the retained time-series plane
+(``raft_report`` → /debug/raft and fleetstat), installs the labeled
+``Raft.*`` metric families on a registry, feeds the retained time-series
+plane
 (timeseries.py), and watches the two known growth hazards
 (``Raft.LogEntries``, ``CoordinatorLog.Bytes``) for doubling within a
 run. With compaction landed (ISSUE 20) those gauges are expected to
@@ -34,14 +33,13 @@ log = logging.getLogger("corda_tpu.consensus_obs")
 
 __all__ = [
     "ATTRIBUTION_COMPONENTS", "GrowthWatch", "install_raft_collector",
-    "ledger_raft_fields", "pool_attribution", "pooled_percentiles",
+    "pool_attribution", "pooled_percentiles",
     "raft_report", "sample_timeseries",
 ]
 
 #: Per-entry commit attribution components, pipeline order. Their sum
 #: telescopes to submit→apply-end by construction (contiguous perf_counter
-#: clocks in RaftNode._record_attribution) — the conservation property the
-#: bench validity probe locks against raft_commit_seconds.
+#: clocks in RaftNode._record_attribution).
 ATTRIBUTION_COMPONENTS = ("append_wait", "fsync", "replicate", "apply")
 
 
@@ -325,7 +323,7 @@ class GrowthWatch:
                    if self.observe(name, v))
 
 
-# -- time-series + bench artifact flattening ----------------------------------
+# -- time-series ----------------------------------------------------------------
 
 def sample_timeseries(store, groups: dict, sharded=None,
                       watch: GrowthWatch | None = None,
@@ -375,52 +373,3 @@ def sample_timeseries(store, groups: dict, sharded=None,
         except Exception:
             pass   # a broken probe must not stall the consensus sampler
     return values
-
-
-def ledger_raft_fields(groups: dict, round_samples=None) -> dict:
-    """Flat ``ledger_raft_*`` artifact fields (benchguard-locked; always
-    present with typed defaults — the group_commit_fields discipline).
-    ``round_samples`` is the pooled list of exact per-batch consensus
-    round durations (GroupCommitter.round_samples() across committers),
-    the measured side of the attribution-sum validity probe."""
-    pooled: dict = {}
-    for nodes in (groups or {}).values():
-        for comp, values in pool_attribution(nodes).items():
-            pooled.setdefault(comp, []).extend(values)
-    pct = pooled_percentiles(pooled)
-    out: dict = {}
-    for comp in ATTRIBUTION_COMPONENTS:
-        stats = pct.get(comp) or {}
-        out[f"ledger_raft_{comp}_ms_p50"] = round(
-            float(stats.get("p50_ms", 0.0)), 4)
-        out[f"ledger_raft_{comp}_ms_p99"] = round(
-            float(stats.get("p99_ms", 0.0)), 4)
-    total = pct.get("total") or {}
-    out["ledger_raft_attrib_samples"] = int(total.get("n", 0))
-    out["ledger_raft_attrib_sum_ms_p50"] = round(
-        float(total.get("p50_ms", 0.0)), 4)
-    rounds = [v for v in (_num(x) for x in (round_samples or ()))
-              if v is not None]
-    out["ledger_raft_round_ms_p50"] = round(
-        _pctl(sorted(rounds), 0.50) * 1000.0, 4) if rounds else 0.0
-    out["ledger_raft_elections_total"] = int(sum(
-        v for g in (groups or {}).values()
-        for v in (_num((_node_stats(n) or {}).get("elections_total"))
-                  for n in g) if v is not None))
-    # compaction rollup (ISSUE 20): typed-default ints over every replica
-    # of every group — zeros on a native fleet (absent per-node stats),
-    # real counts on compacting python replicas
-    all_stats = [s for g in (groups or {}).values()
-                 for s in (_node_stats(n) for n in g) if s is not None]
-
-    def _agg(field, fn):
-        vals = [v for v in (_num(s.get(field)) for s in all_stats)
-                if v is not None]
-        return int(fn(vals)) if vals else 0
-
-    out["ledger_raft_snapshot_index"] = _agg("snapshot_index", max)
-    out["ledger_raft_snapshots_taken"] = _agg("snapshots_taken", sum)
-    out["ledger_raft_installs_sent"] = _agg("installs_sent", sum)
-    out["ledger_raft_installs_received"] = _agg("installs_received", sum)
-    out["ledger_raft_snapshot_bytes"] = _agg("snapshot_bytes", max)
-    return out

@@ -15,8 +15,8 @@ tree and computes the **blocking chain** from submit to resolution:
   parent's **self-time**. This is the standard trace critical-path
   algorithm (Anderson-style, as in Jaeger's CPD): every critical-path
   millisecond is attributed to exactly ONE span, so the per-component
-  blame vector sums to the end-to-end duration by construction — the
-  conservation property benchguard locks.
+  blame vector sums to the end-to-end duration by construction (the
+  conservation property tests/test_critpath.py holds it to).
 * Each critical-path segment is charged to a **component** (
   ``flow.compute`` / ``scheduler.wait`` / ``verify`` /
   ``notary.batch_wait`` / ``raft.commit`` / ``raft.leaderless`` /
@@ -35,8 +35,7 @@ from __future__ import annotations
 
 __all__ = [
     "COMPONENTS", "WAIT_KINDS", "component_of", "critical_path",
-    "flow_kind", "aggregate_critpaths", "ledger_critpath_fields",
-    "critpath_report", "LEDGER_CRITPATH_KINDS",
+    "flow_kind", "aggregate_critpaths", "critpath_report",
 ]
 
 #: Blame components, display order. Every critical-path millisecond lands
@@ -317,30 +316,6 @@ def _cap_segments(segments: list, keep: int = 8) -> list:
     longest = sorted(segments, key=lambda s: s["ms"], reverse=True)[:keep]
     ids = {id(s) for s in longest}
     return [s for s in segments if id(s) in ids]
-
-
-#: flow classes the LEDGER artifact carries critpath fields for
-LEDGER_CRITPATH_KINDS = ("issue", "pay", "settle")
-
-
-def ledger_critpath_fields(traces: dict, top_k: int = 5) -> dict:
-    """Flat ``ledger_critpath_*`` artifact fields (benchguard-locked;
-    always present, zero/empty-valued when a class never ran — the
-    group_commit_fields always-present-with-defaults discipline)."""
-    agg = aggregate_critpaths(traces, top_k=top_k)
-    out = {"ledger_critpath_traces": agg["traces"],
-           "ledger_critpath_top": agg["top"]}
-    for kind in LEDGER_CRITPATH_KINDS:
-        cls = agg["per_class"].get(kind)
-        out[f"ledger_critpath_blame_p50_{kind}"] = \
-            cls["blame_p50"] if cls else {}
-        out[f"ledger_critpath_blame_p99_{kind}"] = \
-            cls["blame_p99"] if cls else {}
-        out[f"ledger_critpath_e2e_p50_ms_{kind}"] = \
-            cls["e2e_ms_p50"] if cls else 0.0
-        out[f"ledger_critpath_dominant_{kind}"] = \
-            cls["dominant"] if cls else "-"
-    return out
 
 
 def critpath_report(traces: dict, top_k: int = 10) -> dict:

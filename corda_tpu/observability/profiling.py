@@ -1,6 +1,6 @@
 """Flight recorder: JIT/compile + dispatch profiling for the device kernels.
 
-The bench numbers (BENCH_r*.json) say *how fast* the pipeline is; this
+The benchmark (benchmarks/run.py) says *how fast* the pipeline is; this
 module answers *why it is slow right now*: was a p99 a compile storm (a new
 batch-size bucket hitting XLA), padding waste (tiny live batches padded to
 power-of-two buckets), or a starved pipeline (host prep not overlapping
@@ -263,8 +263,8 @@ class KernelProfiler:
     # -- warmup boundary ----------------------------------------------------
     def mark_warm(self) -> None:
         """Stamp the current compile count as the warmup boundary. Any
-        compile after this is a steady-state cache miss — the bench smoke
-        gate asserts compiles_since_warm() == 0 after the warm phase."""
+        compile after this is a steady-state cache miss — chip_smoke.py
+        asserts compiles_since_warm() == 0 after its warm phase."""
         with self._lock:
             self._warm_compiles = sum(s.compiles
                                       for s in self._kernels.values())
@@ -317,8 +317,8 @@ class KernelProfiler:
     def publish(self, registry: MetricRegistry) -> None:
         """Mirror the recorder into a MetricRegistry: live gauges reading
         the shared singleton, plus the shared histograms installed by
-        reference — publishing into N registries (node monitoring, bench's
-        private one) shows ONE process-wide distribution in each."""
+        reference — publishing into N registries (node monitoring, a
+        benchmark's private one) shows ONE process-wide distribution in each."""
         registry.gauge("Profiler.CompileSecondsTotal",
                        lambda: self.compile_totals()["compile_s_total"])
         registry.gauge("Profiler.Compiles",

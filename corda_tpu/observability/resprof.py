@@ -1,7 +1,7 @@
-"""Resource accounting plane + subsystem CPU profiler (soak observatory).
+"""Resource accounting plane + subsystem CPU profiler, and the live view
+over both (``soak_report``, behind ``/debug/soak`` and the rpc op).
 
-Two instruments the multi-minute soak mode (observability/soak.py)
-stands on, both cheap enough to run continuously:
+Two instruments, both cheap enough to run continuously:
 
 **Resource accounting** — every bounded/growing structure in the process
 (raft logs per group, CoordinatorLog bytes, the span ring, RequestLog
@@ -55,10 +55,13 @@ import sys
 import threading
 import time
 
+from .timeseries import get_timeseries
+
 __all__ = [
     "COMMIT_PATH_COMPONENTS", "CPU_COMPONENTS", "ResourceRegistry",
     "SubsystemProfiler", "classify_stack", "get_resources", "leak_verdict",
     "process_rss_bytes", "set_resources", "theil_sen_slope",
+    "get_cpu_profiler", "set_cpu_profiler", "soak_report", "verdict_rows",
 ]
 
 
@@ -501,3 +504,72 @@ def set_resources(registry: ResourceRegistry | None
     with _global_lock:
         prev, _global_registry = _global_registry, registry
         return prev
+
+
+# ---------------------------------------------------------------------------
+# live surface: /debug/soak + rpc soak_report
+# ---------------------------------------------------------------------------
+
+_active_profiler: SubsystemProfiler | None = None
+
+
+def get_cpu_profiler() -> "SubsystemProfiler | None":
+    with _global_lock:
+        return _active_profiler
+
+
+def set_cpu_profiler(profiler: "SubsystemProfiler | None"
+                     ) -> "SubsystemProfiler | None":
+    global _active_profiler
+    with _global_lock:
+        prev, _active_profiler = _active_profiler, profiler
+        return prev
+
+
+def verdict_rows(rings: list) -> list:
+    """Pick the ring a leak fit should run over: the coarsest resolution
+    holding at least 5 points (the 60 s ring after hours of sampling),
+    falling back to the best-populated finer ring on a short history."""
+    best: list = []
+    for ring in rings or ():
+        points = ring.get("points") if isinstance(ring, dict) else None
+        if not isinstance(points, list):
+            continue
+        if len(points) >= 5:
+            best = points          # rings come finest-first: keep coarsest
+        elif not best and len(points) > len(best):
+            best = points
+    if not best:
+        for ring in rings or ():
+            points = ring.get("points") if isinstance(ring, dict) else None
+            if isinstance(points, list) and len(points) > len(best):
+                best = points
+    return best
+
+
+def soak_report() -> dict:
+    """The /debug/soak payload: every registered structure's live size,
+    declared kind, and leak verdict over the retained ``Resource.*``
+    series, plus the CPU-attribution snapshot when a profiler is
+    running. Well-formed and empty on a node with no probes — scraping
+    any node is safe."""
+    reg = get_resources()
+    kinds = reg.kinds()
+    sizes = reg.sizes()
+    bounds = reg.bounds()
+    snap = get_timeseries().snapshot(
+        names=[f"Resource.{n}" for n in kinds]) if kinds else {"series": {}}
+    resources = {}
+    for name in sorted(kinds):
+        rings = snap["series"].get(f"Resource.{name}")
+        resources[name] = {
+            "size": sizes.get(name),
+            "kind": kinds[name],
+            **leak_verdict(verdict_rows(rings or []), kind=kinds[name],
+                           bound=bounds.get(name)),
+        }
+    prof = get_cpu_profiler()
+    return {"resources": resources,
+            "leaking": sorted(n for n, r in resources.items()
+                              if r["verdict"] == "leaking"),
+            "cpu": prof.snapshot() if prof is not None else None}

@@ -1,10 +1,9 @@
 """Service-level objectives over the ledger commit path.
 
-The ledger harness (observability/ledger_harness.py) turns the commit
-path into a stream of per-transaction outcomes: did it commit, and how
-long from *intended* send to vault write. This module folds that stream
-into the two SLO shapes operators actually page on (the SRE-workbook
-model):
+Whoever drives the commit path hands ``record()`` a stream of
+per-transaction outcomes: did it commit, and how long from *intended*
+send to vault write. This module folds that stream into the two SLO
+shapes operators actually page on (the SRE-workbook model):
 
 - an **availability** objective — the fraction of submitted transactions
   that commit must stay above ``target`` (e.g. 99.9%);

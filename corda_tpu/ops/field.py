@@ -760,8 +760,8 @@ def donating_jit(key, fn, donate_argnums, **jit_kwargs):
       :func:`device_table_cache` are reused across every dispatch —
       donating one would invalidate the cache and crash the next batch.
     - donated variants are SEPARATE jit handles from the plain kernels:
-      synchronous callers (bench.py's ``_kernel_rate``) re-invoke with
-      the same prepared args, which donation would have deleted.
+      synchronous callers that re-invoke with the same prepared args
+      would find them deleted by donation.
 
     Resolved lazily at first call (never at import) so pulling in an ops
     module does not force backend initialization; on CPU this degrades

@@ -980,16 +980,11 @@ def _record_hg_stats(items: int, fallback: int) -> None:
         _R1_HG_STATS["fallback"] += int(fallback)
 
 
-def r1_split_stats(reset: bool = False) -> dict:
+def r1_split_stats() -> dict:
     """Process-cumulative half-gcd split counters: items prepped through
-    the split path and how many fell back to the host oracle (hg_ok=0).
-    bench.py reads (and resets) these for r1_halfgcd_fallback_pct."""
+    the split path and how many fell back to the host oracle (hg_ok=0)."""
     with _R1_HG_LOCK:
-        out = dict(_R1_HG_STATS)
-        if reset:
-            _R1_HG_STATS["items"] = 0
-            _R1_HG_STATS["fallback"] = 0
-    return out
+        return dict(_R1_HG_STATS)
 
 
 def _r1_host_verify_scalars(curve: WeierstrassCurve, pub, e_raw: int,

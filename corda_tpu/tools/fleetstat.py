@@ -138,7 +138,7 @@ def render(fleet: dict, metrics: dict, critpath: dict | None = None,
     # sharded-notary commit counts (ISSUE 15): per-shard labeled meters
     # ``GroupCommit.Committed{shard="s0"}``. Pre-shard nodes expose only
     # the unlabeled family — render "-" so an operator sees the surface
-    # exists but carries no per-shard split (the benchtrend "-" stance).
+    # exists but carries no per-shard split.
     shard_cells = []
     for key in sorted(k for k in metrics
                       if isinstance(k, str)

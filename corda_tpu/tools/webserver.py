@@ -480,14 +480,14 @@ class NodeWebServer:
         structure registered with the resource accounting plane (size,
         declared kind, leak verdict over its retained ``Resource.*``
         series) plus the subsystem CPU-attribution snapshot when a
-        profiler is running (observability/soak.py). Served from the ops
+        profiler is running (observability/resprof.py). Served from the ops
         object when it exposes ``soak_report``, straight off the process
         globals otherwise; well-formed and empty on a node with no
         registered probes — scraping any node is safe."""
         report_fn = getattr(self.ops, "soak_report", None)
         if report_fn is not None:
             return report_fn()
-        from ..observability.soak import soak_report
+        from ..observability.resprof import soak_report
         return soak_report()
 
     def handle_api_timeseries(self, path: str) -> dict:

@@ -1,6 +1,6 @@
 """The one persistent-compile-cache rule, for every entry point that may
-compile a device kernel (pytest, bench.py, chip_smoke.py, the verifier
-worker, a node with the Tpu verifier).
+compile a device kernel (pytest, benchmarks/run.py, chip_smoke.py, the
+verifier worker, a node with the Tpu verifier).
 
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it on its own and
 this sets no directory in code. Where it is not, the cache is

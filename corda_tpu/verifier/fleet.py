@@ -1,6 +1,6 @@
 """In-process verifier fleet: N device-sharded workers behind one queue.
 
-The MULTICHIP / ``bench.py --fleet`` harness. Everything rides the REAL
+The one-process fleet harness. Everything rides the REAL
 out-of-process protocol — ``OutOfProcessTransactionVerifierService``'s
 load-aware router, ``VerifierWorker``'s stealable backlog, WorkerLoadReport
 / StealRequest / WorkReturned — but over the deterministic in-memory bus
@@ -48,8 +48,8 @@ from .out_of_process import (OutOfProcessTransactionVerifierService,
 
 def make_sig_checks(n: int, unique: int = 16, seed: int = 7):
     """Deterministic honestly-signed ed25519 ``(key, sig, content)`` checks,
-    ``unique`` distinct tiled to ``n`` (the bench corpus shape — signing is
-    pure Python, so uniqueness is bounded like bench.py's UNIQUE)."""
+    ``unique`` distinct tiled to ``n`` (signing is pure Python, so the
+    number of distinct rows is bounded)."""
     base = []
     for i in range(min(n, unique)):
         entropy = (seed * 1000003 + i).to_bytes(32, "little")
@@ -323,7 +323,7 @@ def fleet_bench(n_workers: int, groups: int = 64, group_size: int = 16,
                 unique: int = 16, timeout_s: float = 600.0) -> dict:
     """Run ``groups`` signature groups of ``group_size`` ed25519 checks
     through an N-worker fleet and measure aggregate throughput + busy-time
-    scaling efficiency. Returns the MULTICHIP artifact fields.
+    scaling efficiency. Returns the run as a flat dict.
 
     Runs under a PRIVATE recording tracer (restored on exit) so the
     artifact can report ``stitched_trace_depth`` — proof the cross-process
@@ -333,7 +333,7 @@ def fleet_bench(n_workers: int, groups: int = 64, group_size: int = 16,
     A FleetController rides along in OBSERVE trim (no SLO tracker,
     infinite queue thresholds, scale range pinned to ``n_workers``): an
     unstressed bench must report ``controller_state == "steady"`` with
-    zero actions, and that invariant is asserted by the smoke gate — a
+    zero actions (tests/test_fleet_smoke.py asserts it) — a
     controller that acts on a healthy fleet is a regression."""
     from .controller import ControllerConfig
     prev_tracer = get_tracer()

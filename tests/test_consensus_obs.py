@@ -1,8 +1,7 @@
 """Consensus observatory: per-entry commit attribution (the telescoping
-property the bench validity probe relies on), election episodes, the
-pooled /debug/raft report, Raft.* metric families (absent-never-zero
-native parity), growth watchdogs, shard heat/skew, and the flattened
-ledger_raft_* artifact fields."""
+property), election episodes, the pooled /debug/raft report, Raft.*
+metric families (absent-never-zero native parity), growth watchdogs and
+shard heat/skew."""
 import logging
 
 import pytest
@@ -16,7 +15,7 @@ from corda_tpu.core.crypto.secure_hash import SecureHash
 from corda_tpu.network.inmemory import InMemoryMessagingNetwork
 from corda_tpu.observability.consensus_obs import (
     ATTRIBUTION_COMPONENTS, GrowthWatch, install_raft_collector,
-    ledger_raft_fields, pool_attribution, raft_report, sample_timeseries)
+    pool_attribution, raft_report, sample_timeseries)
 from corda_tpu.observability.timeseries import TimeSeriesStore
 from corda_tpu.utils.metrics import MetricRegistry
 
@@ -267,32 +266,6 @@ def test_growth_watch_doubles(caplog):
               if r.levelno == logging.WARNING
               and "consensus.growth.doubled" in r.getMessage()]
     assert len(warned) == 3
-
-
-def test_ledger_raft_fields_always_present_with_defaults():
-    out = ledger_raft_fields({})
-    for comp in ATTRIBUTION_COMPONENTS:
-        assert out[f"ledger_raft_{comp}_ms_p50"] == 0.0
-        assert out[f"ledger_raft_{comp}_ms_p99"] == 0.0
-    assert out["ledger_raft_attrib_samples"] == 0
-    assert out["ledger_raft_attrib_sum_ms_p50"] == 0.0
-    assert out["ledger_raft_round_ms_p50"] == 0.0
-    assert out["ledger_raft_elections_total"] == 0
-
-
-def test_ledger_raft_fields_from_live_cluster():
-    _, nodes, leader = committed_cluster(n_commits=4)
-    rounds = [t for t in leader.attribution_samples()["total"]]
-    out = ledger_raft_fields({"s0": nodes}, round_samples=rounds)
-    assert out["ledger_raft_attrib_samples"] >= 4
-    assert out["ledger_raft_attrib_sum_ms_p50"] > 0
-    # rounds fed straight from the attribution totals: the two p50s agree
-    assert out["ledger_raft_round_ms_p50"] == pytest.approx(
-        out["ledger_raft_attrib_sum_ms_p50"], rel=1e-6)
-    assert out["ledger_raft_elections_total"] >= 1
-    summed = sum(out[f"ledger_raft_{c}_ms_p50"]
-                 for c in ATTRIBUTION_COMPONENTS)
-    assert summed > 0
 
 
 def test_sample_timeseries_records_and_flushes():

@@ -1,15 +1,13 @@
 """Critical-path extractor (observability/critpath.py) on synthetic span
 trees: blame conservation, overlapping children, pre-root admission
 waits, orphans, zero-duration spans, and parent-pointer cycles (must
-terminate, never hang). Plus the flat ledger_critpath_* artifact fields
-and the critpath CLI renderer."""
+terminate, never hang). Plus the critpath CLI renderer."""
 import pytest
 
 from corda_tpu.observability.critpath import (COMPONENTS, WAIT_KINDS,
                                               aggregate_critpaths,
                                               component_of, critical_path,
-                                              critpath_report, flow_kind,
-                                              ledger_critpath_fields)
+                                              critpath_report, flow_kind)
 
 PAY = "corda_tpu.finance.cash.CashPaymentFlow"
 
@@ -203,29 +201,6 @@ def test_aggregate_per_class_percentile_vectors():
     assert agg["per_class"]["issue"]["dominant"] == "flow.compute"
     # top-K slowest first, capped
     assert [cp["e2e_ms"] for cp in agg["top"]] == [9000.0, 5000.0]
-
-
-def test_ledger_fields_always_present_with_defaults():
-    fields = ledger_critpath_fields({})
-    assert fields["ledger_critpath_traces"] == 0
-    assert fields["ledger_critpath_top"] == []
-    for kind in ("issue", "pay", "settle"):
-        assert fields[f"ledger_critpath_blame_p50_{kind}"] == {}
-        assert fields[f"ledger_critpath_blame_p99_{kind}"] == {}
-        assert fields[f"ledger_critpath_e2e_p50_ms_{kind}"] == 0.0
-        assert fields[f"ledger_critpath_dominant_{kind}"] == "-"
-
-
-def test_ledger_fields_populated_and_conserved():
-    traces = _traces_of([(PAY, 2.0), (PAY, 4.0)])
-    fields = ledger_critpath_fields(traces)
-    assert fields["ledger_critpath_traces"] == 2
-    e2e = fields["ledger_critpath_e2e_p50_ms_pay"]
-    assert e2e > 0
-    blame = fields["ledger_critpath_blame_p50_pay"]
-    assert sum(blame.values()) == pytest.approx(e2e)
-    assert fields["ledger_critpath_dominant_pay"] == "flow.compute"
-    assert fields["ledger_critpath_blame_p50_settle"] == {}
 
 
 def test_critpath_cli_render_is_pure_and_tolerant():

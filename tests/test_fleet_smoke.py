@@ -1,34 +1,15 @@
-"""bench.py --smoke --fleet: the in-process fleet wiring check for tier-1.
+"""The in-process fleet wiring check for tier-1.
 
 Two host-route workers behind the out-of-process queue's load-aware
-router must both receive and complete work, every future must resolve,
-and the one-line JSON aggregate must carry the MULTICHIP artifact fields
-(fleet_verifies_per_sec / scaling_efficiency_pct / n_workers) that
-tools/benchguard.py locks on device runs.
+router must both receive and complete work, every future must resolve
+(``fleet_bench`` reads each result), and one trace must cross the process
+seam. What a live fleet serves over HTTP is tests/test_traces_endpoint.py's.
 """
-import json
-import os
-import subprocess
-import sys
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from corda_tpu.verifier.fleet import fleet_bench
 
 
 def test_fleet_smoke_two_workers_share_the_run():
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--smoke",
-         "--fleet"],
-        capture_output=True, text=True, timeout=420, env=env, cwd=REPO)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    for field in ("fleet_verifies_per_sec", "scaling_efficiency_pct",
-                  "n_workers", "n_devices", "fleet_steals", "fleet_stolen",
-                  "worker_busy_skew_pct", "steals_total",
-                  "stitched_trace_depth",
-                  "groups", "group_size", "wall_s", "per_worker_sigs"):
-        assert field in out, f"missing fleet JSON field: {field}"
-    assert out["smoke"] is True and out["fleet"] is True
+    out = fleet_bench(2, groups=24, group_size=16, use_device=False)
     assert out["n_workers"] == 2
     assert out["fleet_verifies_per_sec"] > 0
     assert 0 < out["scaling_efficiency_pct"] <= 100
@@ -42,9 +23,6 @@ def test_fleet_smoke_two_workers_share_the_run():
     # device_dispatch crossed the process seam under one trace id
     assert out["stitched_trace_depth"] >= 2
     assert 0 <= out["worker_busy_skew_pct"] <= 100
-    # smoke acceptance rode real HTTP: federated worker families on
-    # /metrics, a stitched cross-process trace on /traces, lifecycle
-    # timelines on /debug/requests
-    assert out["http_federated_families"] >= 1
-    assert out["http_stitched_traces"] >= 1
-    assert out["http_request_timelines"] >= 1
+    # a controller that acts on a healthy fleet is a regression
+    assert out["controller_state"] == "steady"
+    assert out["controller_actions"] == 0

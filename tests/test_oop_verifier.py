@@ -95,9 +95,11 @@ def test_failure_propagates(bus):
     assert svc.metrics.snapshot()["Verification.Failure"]["count"] == 1
 
 
-def _pump_until(bus, futures, timeout=90.0):
+def _pump_until(bus, futures, timeout=300.0):
     """Pump the manual bus until every future resolves (the device path
-    replies from worker threads, so replies land between pumps)."""
+    replies from worker threads, so replies land between pumps). The limit
+    only ends a hang: three EC kernels' trace + lower take 60 s here alone
+    and over 90 s beside five other test workers."""
     import time
     deadline = time.monotonic() + timeout
     while not all(f.done() for f in futures):

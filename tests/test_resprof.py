@@ -1,14 +1,16 @@
 """Resource accounting plane + leak detector + subsystem CPU profiler
-(observability/resprof.py) — all over synthetic series and injected
-frames, no real sleeping (tier-1 discipline)."""
+(observability/resprof.py) and the live /debug/soak payload over them —
+all over synthetic series and injected frames, no real sleeping (tier-1
+discipline)."""
 import pytest
 
 from corda_tpu.observability.consensus_obs import GrowthWatch
 from corda_tpu.observability.resprof import (
     COMMIT_PATH_COMPONENTS, CPU_COMPONENTS, ResourceRegistry,
     SubsystemProfiler, classify_stack, get_resources, is_wait_frame,
-    leak_verdict, process_rss_bytes, set_resources, theil_sen_slope)
-from corda_tpu.observability.timeseries import TimeSeriesStore
+    leak_verdict, process_rss_bytes, set_resources, soak_report,
+    theil_sen_slope, verdict_rows)
+from corda_tpu.observability.timeseries import TimeSeriesStore, set_timeseries
 
 
 def rows(pts):
@@ -310,3 +312,56 @@ def test_profiler_walks_caller_chain_for_classification():
     prof = SubsystemProfiler()
     prof.sample_once(current_frames={1: inner}, thread_names={1: "t"})
     assert prof.snapshot()["shares_pct"]["raft_pump"] == 100.0
+
+
+# ---------------------------------------------------------------------------
+# the ring a leak fit reads
+# ---------------------------------------------------------------------------
+
+def test_verdict_rows_prefers_coarsest_populated_ring():
+    fine = [[float(t), 1, 0, 0, float(t), 0] for t in range(100)]
+    coarse = [[60.0 * t, 10, 0, 0, float(t), 0] for t in range(8)]
+    rings = [{"bucket_s": 0.5, "points": fine},
+             {"bucket_s": 60.0, "points": coarse}]
+    assert verdict_rows(rings) == coarse       # coarsest with ≥5 points
+    # a smoke run never fills the 60 s ring: fall back to the fine one
+    rings = [{"bucket_s": 0.5, "points": fine},
+             {"bucket_s": 60.0, "points": coarse[:2]}]
+    assert verdict_rows(rings) == fine
+    assert verdict_rows([]) == []
+    assert verdict_rows([{"bucket_s": 0.5}, "junk", None]) == []
+
+
+# ---------------------------------------------------------------------------
+# the live /debug/soak payload
+# ---------------------------------------------------------------------------
+
+def test_soak_report_composes_live_registry_and_retained_series():
+    reg = ResourceRegistry()
+    size = {"v": 5.0}
+    reg.register("Live.Thing", lambda: size["v"], kind="bounded")
+    store = TimeSeriesStore(resolutions=((1.0, 16),))
+    prev_reg, prev_store = set_resources(reg), set_timeseries(store)
+    try:
+        for t in range(10):
+            reg.sample(store=store, t=float(t))
+        store.flush()
+        out = soak_report()
+        assert list(out["resources"]) == ["Live.Thing"]
+        r = out["resources"]["Live.Thing"]
+        assert r["size"] == 5.0 and r["kind"] == "bounded"
+        assert r["verdict"] == "bounded"
+        assert out["leaking"] == []
+        assert out["cpu"] is None              # no profiler running
+    finally:
+        set_resources(prev_reg)
+        set_timeseries(prev_store)
+
+
+def test_soak_report_empty_node_is_well_formed():
+    prev_reg = set_resources(ResourceRegistry())
+    try:
+        out = soak_report()
+        assert out == {"resources": {}, "leaking": [], "cpu": None}
+    finally:
+        set_resources(prev_reg)

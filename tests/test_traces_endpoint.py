@@ -169,6 +169,51 @@ def test_traces_endpoint_stitches_cross_process_fleet_trace(web):
     worker.stop()
 
 
+def test_live_fleet_serves_federated_metrics_and_request_timelines():
+    """A live two-worker fleet behind NodeWebServer: /metrics carries a
+    worker-labeled federated family (the workers ship their snapshots on
+    load reports) and /debug/requests holds the requests' timelines."""
+    import time
+    from corda_tpu.tools.webserver import NodeWebServer
+    from corda_tpu.verifier.fleet import InProcessFleet, make_sig_checks
+
+    class FleetOps:
+        def __init__(self, fleet):
+            self.fleet = fleet
+
+        def metrics_snapshot(self):
+            return self.fleet.metrics.snapshot()
+
+        def request_timelines(self, limit=None):
+            return self.fleet.service.request_log.snapshot(limit=limit)
+
+    fleet = InProcessFleet(2, use_device=False)
+    server = NodeWebServer(FleetOps(fleet)).start()
+    try:
+        checks = make_sig_checks(16)
+        for fut in [fleet.verify_signatures(checks) for _ in range(8)]:
+            fut.result(timeout=120)
+        deadline = time.monotonic() + 30   # the next load reports arrive
+        while True:
+            with urllib.request.urlopen(
+                    f"http://127.0.0.1:{server.port}/metrics",
+                    timeout=10) as r:
+                text = r.read().decode()
+            shipped = any(
+                line.startswith("corda_tpu_sigbatcher_checked_count{")
+                and 'worker="' in line for line in text.splitlines())
+            if shipped or time.monotonic() > deadline:
+                break
+            time.sleep(0.05)
+        assert shipped, "no worker's SigBatcher.Checked on /metrics"
+        timelines = _get_json(server, "/debug/requests")["requests"]
+        assert len(timelines) >= 8
+        assert all(tl[0]["event"] == "submitted" for tl in timelines.values())
+    finally:
+        server.stop()
+        fleet.close()
+
+
 def test_traces_endpoint_min_duration_filter(web):
     """?min_duration_ms= keeps only traces whose longest span clears the
     threshold — the tail-forensics entry point (find the slow ones)."""
