@@ -104,7 +104,8 @@ class OverlapTracker:
 
 class _KernelStats:
     __slots__ = ("dispatches", "dispatch_s", "compiles", "compile_s",
-                 "cache_hits", "device_waits", "device_wait_s")
+                 "cache_hits", "device_waits", "device_wait_s",
+                 "field_products_per_row")
 
     def __init__(self):
         self.dispatches = 0
@@ -114,15 +115,19 @@ class _KernelStats:
         self.cache_hits = 0
         self.device_waits = 0
         self.device_wait_s = 0.0
+        self.field_products_per_row = None
 
     def as_dict(self) -> dict:
-        return {"dispatches": self.dispatches,
-                "dispatch_s": self.dispatch_s,
-                "compiles": self.compiles,
-                "compile_s": self.compile_s,
-                "cache_hits": self.cache_hits,
-                "device_waits": self.device_waits,
-                "device_wait_s": self.device_wait_s}
+        out = {"dispatches": self.dispatches,
+               "dispatch_s": self.dispatch_s,
+               "compiles": self.compiles,
+               "compile_s": self.compile_s,
+               "cache_hits": self.cache_hits,
+               "device_waits": self.device_waits,
+               "device_wait_s": self.device_wait_s}
+        if self.field_products_per_row is not None:
+            out["field_products_per_row"] = self.field_products_per_row
+        return out
 
 
 #: Cap on the pending-handle → kernel-name attribution table: entries are
@@ -158,19 +163,25 @@ class KernelProfiler:
     # -- kernel dispatch ----------------------------------------------------
     def call(self, name: str, fn, *args, live: int | None = None,
              capacity: int | None = None, scheme: str | None = None,
-             **kwargs):
+             field_products_per_row=None, **kwargs):
         """Invoke ``fn(*args, **kwargs)`` under the recorder.
 
         Books the call's wall time as compile time when the jitted
         function's compile cache grew (or, for plain callables, when this
         argument-shape signature is new), as a cache-hit dispatch
         otherwise. ``live``/``capacity``/``scheme`` record batch occupancy
-        for the padded device batch."""
+        for the padded device batch. ``field_products_per_row`` says WHICH
+        kernel ran, as the limb multiplications a row costs in it: a
+        zero-argument callable, read after the dispatch (a count taken from
+        the kernel's own trace then finds that trace made, and its time
+        stays out of the compile's); the kernel's record keeps the last."""
         cache_size = getattr(fn, "_cache_size", None)
         before = cache_size() if cache_size is not None else None
         t0 = time.perf_counter()
         out = fn(*args, **kwargs)
         dt = time.perf_counter() - t0
+        if field_products_per_row is not None:
+            field_products_per_row = field_products_per_row()
         if cache_size is not None:
             compiled = cache_size() > before
         else:
@@ -181,6 +192,8 @@ class KernelProfiler:
                 st = self._kernels[name] = _KernelStats()
             st.dispatches += 1
             st.dispatch_s += dt
+            if field_products_per_row is not None:
+                st.field_products_per_row = field_products_per_row
             if compiled:
                 st.compiles += 1
                 st.compile_s += dt

@@ -66,8 +66,43 @@ def add(Pt, Qt):
     return (F.mul(e, f, P), F.mul(g, h, P), F.mul(f, g, P), F.mul(e, h, P))
 
 
+def to_cached(Pt):
+    """Extended (X, Y, Z, T) → cached (Y+X, Y−X, 2Z, 2d·T): what the unified
+    addition computes from its SECOND operand, computed once for an operand
+    that is added many times (the split ladder's joint table)."""
+    x, y, z, t = Pt
+    return (F.add(y, x, P), F.sub(y, x, P), F.mul_const(z, 2, P),
+            F.mul(t, _const(_D2), P))
+
+
+def cached_identity(shape) -> tuple:
+    """The identity (0, 1, 1, 0) in cached form: (1, 1, 2, 0)."""
+    z = jnp.zeros(shape + (F.NLIMB,), dtype=jnp.uint64)
+    one = z.at[..., 0].set(1)
+    return (one, one, z.at[..., 0].set(2), z)
+
+
+def add_cached(Pt, Ct):
+    """The unified addition of :func:`add` with its second operand in cached
+    form (:func:`to_cached`): 8 products against 9 (no constant product, no
+    Z product doubled, no sums on the cached side). As complete as
+    :func:`add`: the same formula, the same values."""
+    x1, y1, z1, t1 = Pt
+    ypx, ymx, z2, t2d = Ct
+    a = F.mul(F.sub(y1, x1, P), ymx, P)
+    b = F.norm(F.mul_cols(F.rel_add(y1, x1), ypx), P)
+    c = F.mul(t1, t2d, P)
+    d = F.mul(z1, z2, P)
+    e = F.sub(b, a, P)
+    f = F.sub(d, c, P)
+    g = F.add(d, c, P)
+    h = F.add(b, a, P)
+    return (F.mul(e, f, P), F.mul(g, h, P), F.mul(f, g, P), F.mul(e, h, P))
+
+
 def double(Pt):
-    """dbl-2008-hwcd (valid for all inputs; mirrors ecmath.ed_point_double)."""
+    """dbl-2008-hwcd (valid for all inputs; mirrors ecmath.ed_point_double).
+    Reads X, Y, Z of its input and never T."""
     x1, y1, z1, _ = (jnp.asarray(c, jnp.uint64) for c in Pt)
     a = F.sqr(x1, P)
     b = F.sqr(y1, P)
@@ -126,6 +161,10 @@ def shamir_ladder(bits1, bits2, P1, P2):
 #: Constant-base window width: one mixed B add per w bits from a 2^w-entry
 #: Niels table. 256 = 16x16 divides exactly; the table is ~6MB of u16.
 B_WINDOW = 16
+
+#: Constant-base window width for the split-k ladder (128 = 8x16 divides
+#: exactly: 8 outer steps of 16 doubles + 8 joint A adds + 1 B + 1 B' add).
+SPLIT_B_WINDOW = 16
 
 _B_TABLES: dict[tuple, tuple] = {}
 
@@ -273,32 +312,52 @@ _verify_kernel_windowed = jax.jit(verify_core_windowed,
 # cached host-side like the decompression):
 #   [s]B + [k](−A) = [s_lo]B + [s_hi]B' + [k_lo](−A) + [k_hi](−A')
 # with B' = [2^128]B (a CONSTANT → second Niels table) and A' = [2^128]A.
-# Ladder: 128 doubles + 64 joint (k_lo, k_hi) table adds + 8 + 8 mixed
-# B/B' adds + a 13-op joint-table build, vs the plain windowed ladder's
-# 256 doubles + 128 A adds + 16 B adds — measured on v5e (BASELINE.md r5).
+#
+# Field operations a signature at w = 16 (M a product, S a squaring; counted
+# from the code below, pinned by split_field_products and its test):
+#   joint table    2 doubles + 11 unified adds + 15 cached forms   8 S + 122 M
+#   126 doublings  4 S + 4 M (the compiler drops the T product of the
+#                  first of each pair: a doubling reads no T)      504 S + 441 M
+#   63 joint adds  cached form: 8 M                                      504 M
+#   16 Niels adds  7 M                                                   112 M
+#   tail           one inversion per INV_BATCH_STOP rows + 3 M a row,
+#                  2 M to land affine, 3 canonical forms                  ~5 M
+# against the plain windowed ladder's 256 doublings + 128 A adds + 16 B adds.
+# T IS computed by every joint add though only the last of a window is read
+# (by the Niels add after it): leaving those 55 products out, by flags and a
+# window peeled or reordered, was measured 5% SLOWER on v5e (PERF.md, PR 30).
 # ---------------------------------------------------------------------------
+
+def _stack(*pts) -> tuple:
+    """Point batches → one point batch with a new leading axis."""
+    return tuple(jnp.stack(cs) for cs in zip(*pts))
+
+
+def _unstack(pt, n: int) -> list:
+    return [tuple(c[i] for c in pt) for i in range(n)]
+
 
 def _joint_a_table(neg_a, neg_a2):
     """16-entry per-item table T[i + 4j] = [i](−A) + [j](−A') (i, j ∈ [0,4))
-    from AFFINE (x, y, t) triples (z = 1 implied): 2 doubles + 11 unified
-    adds, one-time per batch — the Edwards sibling of the k1 Q window table
-    (weierstrass._q_window_table)."""
+    in CACHED form (see to_cached), from AFFINE (x, y, t) triples (z = 1
+    implied): 2 doubles + 11 unified adds + 15 cached forms, one-time per
+    batch — the Edwards sibling of the k1 Q window table
+    (weierstrass._q_window_table). Operations of one kind on independent
+    entries are traced ONCE over a stacked axis (3 formula graphs, not 13:
+    the field work is the same, the program a third of the size)."""
     ax, ay, at = neg_a
     a2x, a2y, a2t = neg_a2
     one = F.one_like(ax)
-    batch_shape = ax.shape[:-1]
-    T = [identity(batch_shape)] * 16
-    T[1] = (ax, ay, one, at)
-    T[2] = double(T[1])
-    T[3] = add(T[2], T[1])
-    T[4] = (a2x, a2y, one, a2t)
-    T[8] = double(T[4])
-    T[12] = add(T[8], T[4])
-    for j in (4, 8, 12):
-        T[j + 1] = add(T[j], T[1])
-        T[j + 2] = add(T[j + 1], T[1])
-        T[j + 3] = add(T[j + 2], T[1])
-    return T
+    t1, t4 = (ax, ay, one, at), (a2x, a2y, one, a2t)
+    base = _stack(t1, t4)
+    t2, t8 = _unstack(double(base), 2)
+    t3, t12 = _unstack(add(_stack(t2, t8), base), 2)
+    lo, hi = (t1, t2, t3), (t4, t8, t12)
+    mixed = _unstack(add(_stack(*(h for h in hi for _ in lo)),
+                         _stack(*(l for _ in hi for l in lo))), 9)
+    entries = [t1, t2, t3, t4, *mixed[0:3], t8, *mixed[3:6], t12, *mixed[6:9]]
+    return [cached_identity(ax.shape[:-1]),
+            *_unstack(to_cached(_stack(*entries)), 15)]
 
 
 def split_ladder(b_idx, b2_idx, a_packed, neg_a, neg_a2, btab, b2tab,
@@ -329,7 +388,7 @@ def split_ladder(b_idx, b2_idx, a_packed, neg_a, neg_a2, btab, b2tab,
 
     def a_step(acc, qb):
         acc = double(double(acc))
-        return add(acc, joint_addend(qb)), None
+        return add_cached(acc, joint_addend(qb)), None
 
     def step(acc, ins):
         bi, b2i, qbs = ins
@@ -337,12 +396,29 @@ def split_ladder(b_idx, b2_idx, a_packed, neg_a, neg_a2, btab, b2tab,
         return b_adds(acc, bi, b2i), None
 
     # peel step 0: the accumulator is the identity, so the leading
-    # double-double-add collapses to selecting the first joint addend
-    acc = joint_addend(a_packed[0][0])
+    # double-double-add collapses to selecting the first joint addend; a
+    # doubling comes next, which reads (2X, 2Y, 2Z): the same point
+    ypx, ymx, z2, _ = joint_addend(a_packed[0][0])
+    acc = (F.sub(ypx, ymx, P), F.add(ypx, ymx, P), z2, jnp.zeros_like(z2))
     acc, _ = jax.lax.scan(a_step, acc, a_packed[0][1:])
     acc = b_adds(acc, b_idx[0], b2_idx[0])
     acc, _ = jax.lax.scan(step, acc, (b_idx[1:], b2_idx[1:], a_packed[1:]))
     return acc
+
+
+def reencode_verdict(acc, r_y, r_sign):
+    """RFC 8032 re-encoding acceptance of a projective result: the affine
+    y, canonical, equals the wire y and the affine x's parity the wire sign
+    bit. ONE field inversion serves the batch (F.inv_batch), and no row's
+    verdict reads another row's Z: a row whose Z is ≡ 0 (the complete
+    formulas give none for points on the curve) is refused by itself."""
+    x, y, z, _ = acc
+    zi, nonzero = F.inv_batch(z, P)
+    x_aff = F.canon(F.mul(x, zi, P), P)
+    y_aff = F.canon(F.mul(y, zi, P), P)
+    ok_y = jnp.all(y_aff == r_y, axis=-1)
+    ok_sign = (x_aff[..., 0] & 1) == r_sign
+    return nonzero & ok_y & ok_sign
 
 
 def verify_core_split(bb_idx, a_packed, rows, r_packed,
@@ -369,16 +445,42 @@ def verify_core_split(bb_idx, a_packed, rows, r_packed,
     r_y = r_packed.at[..., 15].set(r_packed[..., 15] & 0x7FFF)
     acc = split_ladder(b_idx, b2_idx, a_packed, neg_a, neg_a2,
                        (tab_p, tab_m, tab_td), (tab2_p, tab2_m, tab2_td), w)
-    x, y, z, _ = acc
-    zi = F.inv(z, P)
-    x_aff = F.canon(F.mul(x, zi, P), P)
-    y_aff = F.canon(F.mul(y, zi, P), P)
-    ok_y = jnp.all(y_aff == r_y, axis=-1)
-    ok_sign = (x_aff[..., 0] & 1) == r_sign
-    return ok_y & ok_sign
+    return reencode_verdict(acc, r_y, r_sign)
 
 
 _verify_kernel_split = jax.jit(verify_core_split, static_argnames=("w",))
+
+
+def _u64_multiplies(jaxpr) -> int:
+    """Elements of every u64 ``mul`` in a jaxpr, a scan's body counted once
+    per iteration."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "mul":
+            out = eqn.outvars[0].aval
+            if out.dtype == jnp.uint64:
+                total += out.size
+        times = eqn.params["length"] if eqn.primitive.name == "scan" else 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            total += times * _u64_multiplies(sub)
+    return total
+
+
+@functools.lru_cache(maxsize=None)
+def split_field_products(rows: int, w: int = SPLIT_B_WINDOW) -> int:
+    """u64 limb multiplications one signature costs in ``verify_core_split``
+    at a ``rows``-wide batch, counted from the program itself (its jaxpr,
+    scan bodies times their lengths; a full field product is 256 of them
+    plus its folds, a squaring 152). The flight recorder's ``ed25519.split``
+    record carries it, so a trace says which kernel ran; the trace behind
+    it is the one the first dispatch of the bucket makes anyway."""
+    S = jax.ShapeDtypeStruct
+    table = S((1 << w, F.NLIMB), jnp.uint16)
+    traced = _verify_kernel_split.trace(
+        S((256 // w, rows), jnp.int32), S((128 // w, w // 2, rows), jnp.uint8),
+        S((rows, 6, F.NLIMB), jnp.uint16), S((rows, F.NLIMB), jnp.uint16),
+        *(table,) * 6, w=w)
+    return round(_u64_multiplies(traced.jaxpr) / rows)
 
 
 def verify_core(s_bits, k_bits, neg_a, r_affine):
@@ -558,11 +660,6 @@ def prepare_batch_windowed(items: list[tuple[bytes, bytes, bytes]],
 
 
 
-#: Constant-base window width for the split-k ladder (128 = 8x16 divides
-#: exactly: 8 outer steps of 16 doubles + 8 joint A adds + 1 B + 1 B' add).
-SPLIT_B_WINDOW = 16
-
-
 def prepare_batch_split(items: list[tuple[bytes, bytes, bytes]],
                         w: int = SPLIT_B_WINDOW, device_tables: bool = True,
                         staging=None):
@@ -683,8 +780,8 @@ def _service_kernel_split():
 def verify_batch_async(items: list[tuple[bytes, bytes, bytes]]):
     """Dispatch without forcing (see weierstrass.verify_batch_async): the
     device computes while the caller preps the next batch. Rides the
-    split-k half-length ladder — the fastest measured path (BASELINE.md
-    round 5) — with donated per-batch device buffers and leased host
+    split-k half-length ladder — the fastest measured path (PERF.md
+    section 5) — with donated per-batch device buffers and leased host
     staging arrays (ops.staging) on the service path. Dispatches go
     through the kernel flight recorder (observability.profiling):
     compile-cache accounting + batch occupancy."""
@@ -698,9 +795,11 @@ def verify_batch_async(items: list[tuple[bytes, bytes, bytes]]):
     lease = pool.lease()
     *args, precheck = prepare_batch_split(padded, SPLIT_B_WINDOW,
                                           staging=lease)
-    dev = get_profiler().call("ed25519.split", _service_kernel_split(),
-                              *args, w=SPLIT_B_WINDOW, live=n,
-                              capacity=len(padded), scheme="ed25519")
+    dev = get_profiler().call(
+        "ed25519.split", _service_kernel_split(), *args, w=SPLIT_B_WINDOW,
+        live=n, capacity=len(padded), scheme="ed25519",
+        field_products_per_row=functools.partial(
+            split_field_products, len(padded), SPLIT_B_WINDOW))
     pending = (dev, precheck, n)
     # the lease rides the pending handle: finish_batch releases it after
     # the force, the earliest point the device provably no longer reads

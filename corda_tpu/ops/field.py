@@ -708,6 +708,52 @@ def inv25519(a):
     return mul(_sqr_n(z_250_0, 5, p), z11, p)        # 2^255 - 21
 
 
+#: Batch width at which :func:`inv_batch` stops halving and pays the
+#: per-row chain, chosen on a TPU v5e (PR 30, the Ed25519 tail at 8192
+#: rows, ms by stop width): 8: 5.755, 16: 5.727, 32: 5.698, 64: 5.667,
+#: 128: 5.636, 256: 6.058, 512: 6.879, 1024: 8.496, 2048: 8.700, the
+#: chain on every row: 13.127. Below 128 rows the chain of ~265 dependent
+#: operations costs its latency whatever the width, and each halving adds
+#: two product graphs to the program.
+INV_BATCH_STOP = 128
+
+
+def inv_batch(z, p: int):
+    """``(inverse, nonzero)`` of a (B, NLIMB) batch from ONE inversion chain
+    per ``INV_BATCH_STOP`` rows: Montgomery's trick as a tree over the batch
+    axis. Going down, each level multiplies the first half of its rows by
+    the second half (contiguous halves: a strided pairing forces a relayout
+    on TPU) until ``INV_BATCH_STOP`` rows are left; those are inverted with
+    :func:`inv`; coming up, a row's inverse is its pair's inverse times its
+    partner. About 3 products a row in place of a ~255-squaring chain.
+
+    One row never decides another's result: a row that is ≡ 0 would zero
+    the product of its whole subtree, so it enters the tree as 1, reads
+    ``nonzero`` False and inverse 0 (what :func:`inv` gives it).
+
+    The tree needs a power-of-two batch at or over the stop width (every
+    bucket :func:`bucket_size` makes, and each shard of one under
+    ``parallel/sharded.py``); any other shape keeps the per-row chain."""
+    zero = is_zero(z, p)
+    n = z.shape[0] if z.ndim >= 2 else 0
+    if n < INV_BATCH_STOP or n & (n - 1):
+        return inv(z, p), ~zero
+    v = select(zero, one_like(z), z)
+    levels = []
+    while v.shape[0] > INV_BATCH_STOP:
+        levels.append(v)
+        h = v.shape[0] // 2
+        v = mul(v[:h], v[h:], p)
+    v = inv(v, p)
+    for lvl in reversed(levels):
+        # inverse(a) = inverse(a·b)·b and inverse(b) = inverse(a·b)·a: one
+        # product over the whole level against its halves swapped
+        h = lvl.shape[0] // 2
+        v = mul(jnp.concatenate([v, v]),
+                jnp.concatenate([lvl[h:], lvl[:h]]), p)
+    return select(zero, jnp.zeros_like(v), v), ~zero
+
+
 # ---------------------------------------------------------------------------
 # Scalar bit decomposition (for curve scalar-mul ladders)
 # ---------------------------------------------------------------------------

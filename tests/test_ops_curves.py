@@ -4,12 +4,17 @@ Mirrors the reference's crypto unit tests (core/src/test/.../crypto/
 CryptoUtilsTest: sign/verify roundtrip + malformed-input rejection per
 scheme) as the bit-exactness oracle for the TPU kernels (SURVEY.md §4.1).
 """
+import functools
 import hashlib
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from test_crypto_host_policy import ROWS as CORPUS_ROWS, _corpus
 
 from corda_tpu.core.crypto import ecmath
+from corda_tpu.observability.profiling import get_profiler
 from corda_tpu.ops import ed25519 as ed_ops
 from corda_tpu.ops import field as F
 from corda_tpu.ops import weierstrass as wc_ops
@@ -44,13 +49,9 @@ def test_ed_add_double_matches_host():
     for i, (pa, qa) in enumerate(zip(pts, qts)):
         want = ecmath.ed_to_affine(ecmath.ed_point_add(
             ecmath.ed_to_extended(pa), ecmath.ed_to_extended(qa)))
-        x, y, z, _ = (F.from_limbs(c[i]) for c in got_add)
-        zi = pow(z, ecmath.ED_P - 2, ecmath.ED_P)
-        assert (x * zi % ecmath.ED_P, y * zi % ecmath.ED_P) == want
+        assert _affine(got_add, i) == want
         want_d = ecmath.ed_to_affine(ecmath.ed_point_double(ecmath.ed_to_extended(pa)))
-        x, y, z, _ = (F.from_limbs(c[i]) for c in got_dbl)
-        zi = pow(z, ecmath.ED_P - 2, ecmath.ED_P)
-        assert (x * zi % ecmath.ED_P, y * zi % ecmath.ED_P) == want_d
+        assert _affine(got_dbl, i) == want_d
 
 
 def test_ed25519_verify_batch():
@@ -108,6 +109,138 @@ def test_ed25519_r_encoding_edge_cases():
             for _, s, _ in items]
     assert want == [True, False, False, False]  # oracle sanity
     assert list(ed_ops.verify_batch(items)) == want
+
+
+def _affine(pt, i):
+    """Row i of a device point batch → host affine (x, y), read from X, Y, Z."""
+    x, y, z = (F.from_limbs(c[i]) for c in pt[:3])
+    zi = pow(z, ecmath.ED_P - 2, ecmath.ED_P)
+    return (x * zi % ecmath.ED_P, y * zi % ecmath.ED_P)
+
+
+#: name → (device formula over (P batch, Q batch), host formula)
+_ED_CACHED = {
+    "add_cached": (lambda P, Q: ed_ops.add_cached(P, ed_ops.to_cached(Q)),
+                   ecmath.ed_point_add),
+    "add_cached_identity": (lambda P, Q: ed_ops.add_cached(
+        P, ed_ops.cached_identity((4,))), lambda p, q: p),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_ED_CACHED))
+def test_ed_add_cached_matches_host(name):
+    """add_cached gives ecmath's X, Y, Z (as the affine point they stand
+    for), and a T that is X·Y/Z."""
+    device, host = _ED_CACHED[name]
+    pts, qts = ed_rand_points(4), ed_rand_points(4)
+    pts[3] = qts[3]                      # a doubling through the addition
+    # u64 lanes, as inside a kernel (_pack_point_ext ships u16)
+    Pb, Qb = (tuple(jnp.asarray(c, jnp.uint64)
+                    for c in ed_ops._pack_point_ext(v)) for v in (pts, qts))
+    got = device(Pb, Qb)
+    for i, (pa, qa) in enumerate(zip(pts, qts)):
+        want = ecmath.ed_to_affine(host(ecmath.ed_to_extended(pa),
+                                        ecmath.ed_to_extended(qa)))
+        assert _affine(got, i) == want
+        x, y, z, t = (F.from_limbs(c[i]) for c in got)
+        assert (t * z - x * y) % ecmath.ED_P == 0
+
+
+#: the tail at a width that takes F.inv_batch's tree and at one that keeps
+#: the per-row chain
+TAIL_WIDTHS = (256, 24)
+
+
+@functools.cache
+def _tail_verdicts():
+    """Crafted accumulators through reencode_verdict, both widths in one
+    compiled program. Rows: a projective form (X, Y, Z) = (x·z, y·z, z) of a
+    point, judged against its own encoding (accept), against another point's
+    y (refuse) and against a flipped sign bit (refuse); and rows with Z ≡ 0
+    (written 0 and p), which a product tree would spread over their whole
+    subtree. Their wire y is 0 with sign 0: what X·0 and Y·0 re-encode to."""
+    rng = np.random.default_rng(11)
+    pts = ed_rand_points(8)
+    p = ecmath.ED_P
+    cases, want = {}, {}
+    for n in TAIL_WIDTHS:
+        xs, ys, zs, r_y, r_sign, verdicts = [], [], [], [], [], []
+        for i in range(n):
+            (x, y), z = pts[i % 8], int.from_bytes(rng.bytes(32), "little") % p
+            kind = ("accept", "wrong_y", "wrong_sign", "accept")[i % 4]
+            if i in (1, n // 2 + 3, n - 1):
+                kind, z = "zero_z", (0, p, 0)[i % 3]
+            xs.append(x * z % p)
+            ys.append(y * z % p)
+            zs.append(z)
+            r_y.append({"wrong_y": pts[(i + 1) % 8][1], "zero_z": 0}.get(kind, y))
+            r_sign.append({"wrong_sign": 1 - (x & 1), "zero_z": 0}.get(kind, x & 1))
+            verdicts.append(kind == "accept")
+        cases[n] = ((jnp.asarray(F.to_limbs(xs)), jnp.asarray(F.to_limbs(ys)),
+                     jnp.asarray(F.to_limbs(zs)), None),
+                    jnp.asarray(F.to_limbs(r_y)),
+                    jnp.asarray(np.asarray(r_sign, dtype=np.uint64)))
+        want[n] = verdicts
+    got = jax.jit(lambda cs: {n: ed_ops.reencode_verdict(*c)
+                              for n, c in cs.items()})(cases)
+    return want, got
+
+
+@pytest.mark.parametrize("n", TAIL_WIDTHS)
+def test_a_zero_z_row_is_refused_and_decides_no_other_rows_verdict(n):
+    assert (n >= 2 * F.INV_BATCH_STOP) == (n == TAIL_WIDTHS[0])
+    want, got = _tail_verdicts()
+    assert list(np.asarray(got[n])) == want[n]
+    assert want[n].count(True) >= n // 3 and want[n].count(False) >= n // 3
+
+
+#: the corpus through the device kernel at a bucket that takes the tree (five
+#: rotations of it in one call, padded to 256) and at one that does not (8
+#: rows a call)
+@pytest.mark.parametrize("width", [256, 8])
+def test_split_kernel_agrees_with_the_oracle_on_the_edge_corpus(width):
+    """Non-canonical y, small-order points, s at and over L, flipped R / A /
+    message: tests/test_crypto_host_policy.py's corpus, row for row against
+    the pure oracle, through verify_core_split."""
+    assert (width >= 2 * F.INV_BATCH_STOP) == (width == 256)
+    corpus = _corpus()
+    rows = [corpus[n] for n in CORPUS_ROWS]
+    assert [ecmath.ed25519_verify(k, m, s) for k, s, m, _ in rows] \
+        == [meant for *_, meant in rows]
+    if width == 256:      # every row meets other neighbours in every half
+        rows = [rows[(i + 7 * turn) % len(rows)]
+                for turn in range(5) for i in range(len(rows))]
+    got = []
+    for at in range(0, len(rows), width):
+        chunk = rows[at:at + width]
+        assert F.bucket_size(len(chunk)) == width
+        got.extend(ed_ops.verify_batch([(k, s, m) for k, s, m, _ in chunk]))
+    assert got == [meant for *_, meant in rows]
+
+
+#: u64 limb multiplications a row in verify_core_split at the parent commit
+#: ed7d89b (PR 29), read once with this PR's counter (the same at 64 and at
+#: 8192 rows: the parent inverts per row)
+PARENT_FIELD_PRODUCTS = 490_535
+
+
+def test_the_split_kernel_spends_an_eighth_fewer_field_products():
+    """At the bucket the service and the benchmark dispatch. (Under
+    F.INV_BATCH_STOP rows the tail pays its chain per row, as the parent.)
+    The count is the program's as traced: it includes the T products of the
+    first doublings, which no compiler keeps, on both sides."""
+    got = ed_ops.split_field_products(8192, 16)
+    assert 0.5 * PARENT_FIELD_PRODUCTS < got <= 0.89 * PARENT_FIELD_PRODUCTS
+
+
+def test_the_flight_recorder_says_which_split_kernel_ran():
+    seed = RNG.bytes(32)
+    pub = ecmath.ed25519_public_key(seed)
+    assert list(ed_ops.verify_batch(
+        [(pub, ecmath.ed25519_sign(seed, b"recorded", pub), b"recorded")]))
+    record = get_profiler().snapshot()["kernels"]["ed25519.split"]
+    assert record["field_products_per_row"] == ed_ops.split_field_products(
+        8, ed_ops.SPLIT_B_WINDOW)
 
 
 # ---------------------------------------------------------------------------

@@ -4,6 +4,10 @@ The field ops are lazily reduced (relaxed limbs < 1.5*2^16, any residue
 mod p) — tests canonicalise with F.canon before comparing against Python
 modular arithmetic, and separately check the relaxed-limb invariant.
 """
+import functools
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -120,3 +124,45 @@ def test_inv(p):
     la = F.to_limbs(a)
     out = canon_int(F.inv(la, p), p)
     assert out == [pow(x, p - 2, p) for x in a]
+
+
+# ---------------------------------------------------------------------------
+# inv_batch: one inversion chain a batch (Montgomery's trick as a tree)
+# ---------------------------------------------------------------------------
+
+#: 1, 8, 24 and 64 keep the per-row chain (no power of two, or under the stop
+#: width); 256 and 1024 go through the tree, one level and three
+INV_WIDTHS = (1, 8, 24, 64, 256, 1024)
+
+
+@functools.cache
+def _inv_batch_results(p):
+    """Every width through ONE compiled program per prime (the chain is
+    ~255 squarings: compiled, not dispatched operation by operation)."""
+    rng = np.random.default_rng(p % 1000)
+    vals = {}
+    for n in INV_WIDTHS:
+        row = [int.from_bytes(rng.bytes(32), "little") % p or 1
+               for _ in range(n)]
+        row[0] = p - 1
+        if n >= 8:
+            # rows that are zero mod p, one of them not canonical (p itself):
+            # inside the first and the second half of every level
+            row[n // 3], row[n - 2] = 0, p
+        vals[n] = row
+    out = jax.jit(lambda zs: {n: F.inv_batch(z, p) for n, z in zs.items()})(
+        {n: jnp.asarray(F.to_limbs(v)) for n, v in vals.items()})
+    return vals, out
+
+
+@pytest.mark.parametrize("n", INV_WIDTHS)
+@pytest.mark.parametrize("p", [F.P25519, F.PSECP], ids=["p25519", "psecp"])
+def test_inv_batch_equals_inv_row_for_row(p, n):
+    """What F.inv gives a row (a^(p-2), 0 for 0: test_inv) is what
+    inv_batch gives it, whatever stands in the other rows."""
+    assert INV_WIDTHS[-3] < F.INV_BATCH_STOP < INV_WIDTHS[-2], \
+        "the widths above no longer stand on both sides of the stop width"
+    vals, out = _inv_batch_results(p)
+    inverse, nonzero = out[n]
+    assert canon_int(inverse, p) == [pow(x, p - 2, p) for x in vals[n]]
+    assert list(np.asarray(nonzero)) == [x % p != 0 for x in vals[n]]
