@@ -181,7 +181,10 @@ def signed_rows(scheme: str, rows: int, unique: int, seed: int,
             priv = int.from_bytes(rng.bytes(32), "little") % (curve.n - 1) + 1
             key = PublicKey(spec, sec1_compress(curve,
                                                 curve.mul(priv, curve.g)))
-            sig = ecmath.ecdsa_sig_to_der(*ecmath.ecdsa_sign(curve, priv, msg))
+            r, s = ecmath.ecdsa_sign(curve, priv, msg)
+            if len(base) % 2:     # the n - s twin: Crypto.doVerify takes both
+                s = curve.n - s
+            sig = ecmath.ecdsa_sig_to_der(r, s)
         base.append((key, sig, msg))
     checks, corrupted = [], []
     for i in range(rows):

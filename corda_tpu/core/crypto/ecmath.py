@@ -279,7 +279,8 @@ def rfc6979_nonce(curve: WeierstrassCurve, priv: int, digest: bytes) -> int:
 
 
 def ecdsa_sign(curve: WeierstrassCurve, priv: int, msg: bytes) -> tuple[int, int]:
-    """Sign SHA-256(msg); returns (r, s) with low-s normalisation."""
+    """Sign SHA-256(msg); returns (r, s) with low-s normalisation (the
+    verifier accepts the n - s twin as well, see :func:`ecdsa_verify`)."""
     digest = hashlib.sha256(msg).digest()
     e = _bits2int(digest, curve.n) % curve.n
     while True:
@@ -297,9 +298,11 @@ def ecdsa_sign(curve: WeierstrassCurve, priv: int, msg: bytes) -> tuple[int, int
 
 
 def ecdsa_verify(curve: WeierstrassCurve, pub, msg: bytes, r: int, s: int) -> bool:
-    # Low-s only (matching the signer's normalisation): rejects the s' = n - s
-    # malleated twin so each message/key pair has exactly one accepted signature.
-    if not (1 <= r < curve.n and 1 <= s <= curve.n // 2):
+    # Crypto.doVerify's rule (BouncyCastle SHA256withECDSA): any r, s in
+    # [1, n-1]. The signer above normalises to low s, the verifier takes both
+    # twins: a transaction's id covers no signature, so (r, n - s) is a second
+    # valid signature by the same key over the same id, as in the reference.
+    if not (1 <= r < curve.n and 1 <= s < curve.n):
         return False
     if pub is None or not curve.is_on_curve(pub):
         return False

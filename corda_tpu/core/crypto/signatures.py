@@ -66,7 +66,7 @@ TransactionSignature = DigitalSignatureWithKey
 def _openssl_ecdsa_verify(scheme_id: int, encoded: bytes, content: bytes,
                           r: int, s: int):
     """OpenSSL-backed ECDSA curve-equation check, or None when the
-    ``cryptography`` package is unavailable. Policy (ranges, low-s, curve
+    ``cryptography`` package is unavailable. Policy (ranges, curve
     membership, DER canonicalisation) is enforced by the CALLER; the (r, s)
     pair is re-encoded to canonical DER here so OpenSSL never sees the
     caller's encoding quirks."""
@@ -204,14 +204,15 @@ class Crypto:
                 r, s = ecmath.ecdsa_sig_from_der(signature)
             except (ValueError, IndexError):
                 return False
-            # The acceptance POLICY (ranges incl. low-s, on-curve key,
-            # canonical DER) is decided above/by ecdsa_verify's prechecks —
+            # The acceptance POLICY (r, s in [1, n-1] as Crypto.doVerify's
+            # BouncyCastle verifier takes them, on-curve key, strict DER)
+            # is decided above/by ecdsa_verify's prechecks —
             # identically to the device kernels' precheck. Once policy
             # passes, the curve-equation check itself is implementation-
             # independent, so the host path may ride OpenSSL (~100x the
             # pure-Python ladder; this is the batcher's sub-crossover /
             # p50@batch=1 path) with the pure ladder as fallback oracle.
-            if not (1 <= r < curve.n and 1 <= s <= curve.n // 2):
+            if not (1 <= r < curve.n and 1 <= s < curve.n):
                 return False
             fast = _openssl_ecdsa_verify(public.scheme.scheme_number_id,
                                          public.encoded, content, r, s)

@@ -29,8 +29,10 @@ _CANDIDATES = [
 
 #: ABI gate: the .so and this module move together (docs/PERFORMANCE.md
 #: "sharp edges").  Version 3 added sm_r1_halfgcd / sm_r1_prep_hg /
-#: sm_r1p_mulfast (the secp256r1 half-gcd split ladder).
-SM_VERSION = 3
+#: sm_r1p_mulfast (the secp256r1 half-gcd split ladder).  Version 4 changed
+#: the ECDSA preps' range check to Crypto.doVerify's (s in [1, n-1], no
+#: low-s bound): a version-3 library would refuse every high-s signature.
+SM_VERSION = 4
 
 _log = logging.getLogger(__name__)
 

@@ -554,14 +554,14 @@ def _is_on_curve_memo(curve_name: str, pub) -> bool:
 
 def _precheck_and_scalars(curve: WeierstrassCurve, items):
     """Shared ECDSA acceptance policy for both kernel preps: structural checks
-    (r/s ranges incl. low-s rule, on-curve key), e/w/u1/u2 derivation, the
+    (r, s in [1, n-1], on-curve key), e/w/u1/u2 derivation, the
     neutral substitution for invalid items, and the r / r+n x-candidates.
     Returns (precheck, pubs, u1s, u2s, r0, r1). The s-inversions are batched
     (Montgomery's trick) so host prep stays off the service's critical path."""
     precheck = np.ones(len(items), dtype=bool)
     pubs, rs, es, ss = [], [], [], []
     for i, (pub, msg, r, s) in enumerate(items):
-        ok = (1 <= r < curve.n and 1 <= s <= curve.n // 2
+        ok = (1 <= r < curve.n and 1 <= s < curve.n
               and pub is not None and _is_on_curve_memo(curve.name, pub))
         if ok:
             es.append(_bits2int(hashlib.sha256(msg).digest(), curve.n)
@@ -993,7 +993,7 @@ def _r1_host_verify_scalars(curve: WeierstrassCurve, pub, e_raw: int,
     path never sees the message). Must stay verdict-identical to the
     oracle — pinned in tests/test_scalarprep.py."""
     n = curve.n
-    if not (1 <= r < n and 1 <= s <= n // 2):
+    if not (1 <= r < n and 1 <= s < n):
         return False
     if pub is None or not curve.is_on_curve(pub):
         return False
@@ -1452,8 +1452,8 @@ def prepare_batch(curve: WeierstrassCurve,
                   items: list[tuple[tuple[int, int] | None, bytes, int, int]]):
     """Host prep: (pub_point, message, r, s) → kernel inputs + precheck mask.
 
-    Structural checks mirror the host oracle ecmath.ecdsa_verify (low-s rule
-    included). Message hashing (SHA-256) stays host-side here; bulk Merkle
+    Structural checks mirror the host oracle ecmath.ecdsa_verify (r, s in
+    [1, n-1]). Message hashing (SHA-256) stays host-side here; bulk Merkle
     hashing is the device path in ops/sha256.py.
     """
     precheck, q_pts, u1s, u2s, r0, r1 = _precheck_and_scalars(curve, items)
@@ -1625,7 +1625,7 @@ def pad_word_rows(arrays, m: int, staging=None, tags=None):
 
 
 def verify_batch_async_words(curve: WeierstrassCurve, e_words, r_words,
-                             s_words, pub_words):
+                             s_words, pub_words, trace_parent=None):
     """Word-form async dispatch — the batcher's cached/vectorized ECDSA
     prep path: items arrive as the native preps' LE u64 rows (per-signer
     pub rows from keys.sec1_pub_row_cached, r/s from the batched DER
@@ -1634,7 +1634,10 @@ def verify_batch_async_words(curve: WeierstrassCurve, e_words, r_words,
     :func:`verify_batch_async`; callers gate on words_prep_available.
     Padding goes through reused staging buffers and the kernel call uses
     the donated twin, so steady-state flushes neither allocate fresh host
-    rows nor leave stale device input buffers behind."""
+    rows nor leave stale device input buffers behind. The native scalar
+    prep (range check, s^-1, the split, window digits) is the span
+    ``ecdsa.prep.scalars`` under ``trace_parent``, the caller's span."""
+    from ..observability import get_tracer
     from ..observability.profiling import get_profiler
     from .staging import get_staging_pool
     prof = get_profiler()
@@ -1651,17 +1654,21 @@ def verify_batch_async_words(curve: WeierstrassCurve, e_words, r_words,
     e_words, r_words, s_words, pub_words = pad_word_rows(
         (e_words, r_words, s_words, pub_words), capacity,
         staging=lease, tags=tags)
+    prep_span = get_tracer().span("ecdsa.prep.scalars", parent=trace_parent,
+                                  bucket=curve.name, rows=n)
     if curve.name == "secp256k1":
-        *args, precheck = _prepare_hybrid_native_words(
-            e_words, r_words, s_words, pub_words, HYBRID_G_WINDOW)
+        with prep_span:
+            *args, precheck = _prepare_hybrid_native_words(
+                e_words, r_words, s_words, pub_words, HYBRID_G_WINDOW)
         pending = (prof.call("weierstrass.hybrid_k1",
                              _service_kernel_hybrid_wide(),
                              *args, g_w=HYBRID_G_WINDOW, live=n,
                              capacity=capacity, scheme=curve.name),
                    precheck, n)
     else:
-        *args, precheck, forced = _prepare_r1_split_native_words(
-            e_words, r_words, s_words, pub_words, R1_G_WINDOW)
+        with prep_span:
+            *args, precheck, forced = _prepare_r1_split_native_words(
+                e_words, r_words, s_words, pub_words, R1_G_WINDOW)
         pending = (prof.call("weierstrass.r1_split",
                              _service_kernel_r1_split(),
                              *args, curve_name=curve.name, w=R1_G_WINDOW,

@@ -1053,7 +1053,8 @@ class SignatureBatcher:
                     if bucket == "ed25519":
                         pending, finish = self._start_ed25519(items)
                     else:
-                        pending, finish = self._start_ecdsa(bucket, items)
+                        pending, finish = self._start_ecdsa(bucket, items,
+                                                            dspan)
         except Exception:
             # batch-level failure (kernel/compile/transfer): fall back to
             # per-item host verification so one malformed member — or a
@@ -1199,7 +1200,9 @@ class SignatureBatcher:
 
     @staticmethod
     def _ecdsa_kernel_items(curve, items: list[_Pending]):
-        kitems = []
+        """The item-form prep's rows, and how many of them had a DER or a
+        key that was refused here."""
+        kitems, bad = [], 0
         for p in items:
             # per-item isolation: ANY malformed member becomes a False
             # verdict for that member alone, never a batch failure
@@ -1207,12 +1210,15 @@ class SignatureBatcher:
                 point = sec1_decompress_cached(curve, p.key.encoded)
                 r, s = ecmath.ecdsa_sig_from_der(p.signature)
             except Exception:
-                point, r, s = None, 0, 0  # fails the range precheck → False
+                point = None
+            if point is None:
+                r, s = 0, 0               # fails the range precheck → False
+                bad += 1
             kitems.append((point, p.content, r, s))
-        return kitems
+        return kitems, bad
 
     @staticmethod
-    def _ecdsa_words(curve, items: list[_Pending]):
+    def _ecdsa_words(curve, items: list[_Pending], parent=None):
         """Cached + vectorized ECDSA kernel prep: per-signer pub rows from
         keys.sec1_pub_row_cached (the Weierstrass sibling of the Ed25519
         kernel's _signer_row cache), ONE batched DER parse
@@ -1221,11 +1227,17 @@ class SignatureBatcher:
         + DER parse + bigint to_bytes loop of _ecdsa_kernel_items.
         Per-item isolation is preserved: any malformed member gets r := 0,
         which the native range precheck rejects into a False verdict for
-        that member alone."""
+        that member alone. Returns the four word arrays and the rows whose
+        DER or key was refused here. ``parent`` is the batch's
+        ``batcher.dispatch`` span: the DER parse and the digest loop are its
+        children ``ecdsa.prep.der`` / ``ecdsa.prep.digest``."""
         import hashlib
         from ..ops import scalarprep as sp
-        r_words, s_words, ok = sp.ecdsa_sigs_to_words(
-            [p.signature for p in items])
+        tracer = get_tracer()
+        tags = {"bucket": curve.name, "rows": len(items)}
+        with tracer.span("ecdsa.prep.der", parent=parent, **tags):
+            r_words, s_words, ok = sp.ecdsa_sigs_to_words(
+                [p.signature for p in items])
         pub_words = np.zeros((len(items), 8), dtype=np.uint64)
         for i, p in enumerate(items):
             row = sec1_pub_row_cached(curve, p.key.encoded)
@@ -1234,9 +1246,10 @@ class SignatureBatcher:
             else:
                 pub_words[i] = row
         r_words[~ok] = 0     # force the range precheck to reject
-        e_words = sp.digests_to_words(
-            [hashlib.sha256(p.content).digest() for p in items], 4)
-        return e_words, r_words, s_words, pub_words
+        with tracer.span("ecdsa.prep.digest", parent=parent, **tags):
+            e_words = sp.digests_to_words(
+                [hashlib.sha256(p.content).digest() for p in items], 4)
+        return (e_words, r_words, s_words, pub_words), int((~ok).sum())
 
     def _run_ecdsa(self, bucket: str, items: list[_Pending]):
         from ..ops import weierstrass as wc_ops
@@ -1247,9 +1260,9 @@ class SignatureBatcher:
                 sharded_verify_batch_secp256k1_words)
             if wc_ops.words_prep_available(curve):
                 return sharded_verify_batch_secp256k1_words(
-                    self.mesh, *self._ecdsa_words(curve, items))
+                    self.mesh, *self._ecdsa_words(curve, items)[0])
             return sharded_verify_batch_secp256k1(
-                self.mesh, self._ecdsa_kernel_items(curve, items))
+                self.mesh, self._ecdsa_kernel_items(curve, items)[0])
         if (self.mesh is not None and bucket == "secp256r1"
                 and wc_ops.words_prep_available(curve)):
             # the half-gcd split kernel's mesh variant (no item-tuple mesh
@@ -1257,17 +1270,37 @@ class SignatureBatcher:
             # is the same python prep the mesh would run host-side anyway)
             from ..parallel import sharded_verify_batch_secp256r1_words
             return sharded_verify_batch_secp256r1_words(
-                self.mesh, *self._ecdsa_words(curve, items))
-        return wc_ops.verify_batch(curve, self._ecdsa_kernel_items(curve,
-                                                                   items))
+                self.mesh, *self._ecdsa_words(curve, items)[0])
+        return wc_ops.verify_batch(
+            curve, self._ecdsa_kernel_items(curve, items)[0])
 
-    def _start_ecdsa(self, bucket: str, items: list[_Pending]):
+    def _start_ecdsa(self, bucket: str, items: list[_Pending], dspan=None):
+        """Prep + async launch of one ECDSA batch. Which of the two preps
+        ran is metered by rows (``EcdsaWordsPrep`` is the native word form,
+        ``EcdsaItemsPrep`` the pure-Python item form taken in silence when
+        libscalarmath.so is missing or stale), as are the rows refused
+        before the kernel: by their DER or key (``EcdsaRefusedEncoding``)
+        and by the range precheck (``EcdsaRefusedRange``; for secp256r1 also
+        a row the split prep handed to the host oracle and the oracle
+        refused, as one whose ``r`` is no x-coordinate of the curve)."""
         from ..ops import weierstrass as wc_ops
         curve = ecmath.SECP256K1 if bucket == "secp256k1" else ecmath.SECP256R1
+        n = len(items)
         if wc_ops.words_prep_available(curve):
-            pending = wc_ops.verify_batch_async_words(
-                curve, *self._ecdsa_words(curve, items))
+            words, bad_encoding = self._ecdsa_words(curve, items, dspan)
+            pending = wc_ops.verify_batch_async_words(curve, *words,
+                                                      trace_parent=dspan)
+            self.metrics.meter("SigBatcher.EcdsaWordsPrep").mark(n)
         else:
-            pending = wc_ops.verify_batch_async(
-                curve, self._ecdsa_kernel_items(curve, items))
+            kitems, bad_encoding = self._ecdsa_kernel_items(curve, items)
+            pending = wc_ops.verify_batch_async(curve, kitems)
+            self.metrics.meter("SigBatcher.EcdsaItemsPrep").mark(n)
+        # a refused encoding fails the precheck too (r := 0); the r1 split
+        # masks the rows it handed to the host oracle out of the precheck
+        # and carries the oracle's verdicts in pending[3]
+        passed = pending[1] | pending[3] if len(pending) == 4 else pending[1]
+        self.metrics.meter("SigBatcher.EcdsaRefusedEncoding").mark(
+            bad_encoding)
+        self.metrics.meter("SigBatcher.EcdsaRefusedRange").mark(
+            n - int(passed[:n].sum()) - bad_encoding)
         return pending, wc_ops.finish_batch

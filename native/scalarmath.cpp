@@ -98,7 +98,7 @@ struct Mod {
     u64 m[4];
     u64 m5[5];     // m zero-extended to 5 words (for the k+1-word compare)
     u64 mu[5];
-    u64 half[4];   // floor(m / 2) (the ECDSA low-s bound)
+    u64 half[4];   // floor(m / 2) (the GLV split's rounding bias)
 };
 
 // mu = floor(2^512 / m) by restoring bitwise division (one-time per modulus).
@@ -656,7 +656,7 @@ bool r1p_sqrt(const u64 z[4], u64 y[4]) {
 
 extern "C" {
 
-int sm_version() { return 3; }
+int sm_version() { return 4; }
 
 // Differential-test seam: r = a*b mod m for mod_id in
 // {0: k1 n, 1: k1 p, 2: r1 n, 3: r1 p, 4: ed L, 5: ed P}.
@@ -712,7 +712,7 @@ int sm_k1_prep(int64_t n,
         const u64* x4 = pub + 8 * i;
         const u64* y4 = pub + 8 * i + 4;
         bool ok = !mp_is_zero(r4, 4) && mp_cmp(r4, N->m, 4) < 0
-               && !mp_is_zero(s4, 4) && mp_cmp(s4, N->half, 4) <= 0
+               && !mp_is_zero(s4, 4) && mp_cmp(s4, N->m, 4) < 0
                && on_curve(P, x4, y4, K1_B, false);
         precheck[i] = ok ? 1 : 0;
         if (ok) {
@@ -820,7 +820,7 @@ int sm_r1_prep(int64_t n,
         const u64* x4 = pub + 8 * i;
         const u64* y4 = pub + 8 * i + 4;
         bool ok = !mp_is_zero(r4, 4) && mp_cmp(r4, N->m, 4) < 0
-               && !mp_is_zero(s4, 4) && mp_cmp(s4, N->half, 4) <= 0
+               && !mp_is_zero(s4, 4) && mp_cmp(s4, N->m, 4) < 0
                && on_curve(P, x4, y4, R1_B, true);
         precheck[i] = ok ? 1 : 0;
         if (ok) {
@@ -939,7 +939,7 @@ int sm_r1_prep_hg(int64_t n,
         const u64* x4 = pub + 8 * i;
         const u64* y4 = pub + 8 * i + 4;
         bool ok = !mp_is_zero(r4, 4) && mp_cmp(r4, N->m, 4) < 0
-               && !mp_is_zero(s4, 4) && mp_cmp(s4, N->half, 4) <= 0
+               && !mp_is_zero(s4, 4) && mp_cmp(s4, N->m, 4) < 0
                && on_curve(P, x4, y4, R1_B, true);
         precheck[i] = ok ? 1 : 0;
         if (ok) {

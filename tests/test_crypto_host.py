@@ -85,14 +85,7 @@ def test_ecdsa_interop_with_cryptography(scheme, curve_name):
     curve = {"SECP256K1": ec.SECP256K1(), "SECP256R1": ec.SECP256R1()}[curve_name]
     oracle = ec.generate_private_key(curve)
     der_sig = oracle.sign(msg, ec.ECDSA(hashes.SHA256()))
-    # Our verifier enforces low-s canonical signatures; normalise the oracle's.
-    from corda_tpu.core.crypto.ecmath import (ecdsa_sig_from_der, ecdsa_sig_to_der)
-    from corda_tpu.core.crypto.keys import curve_for_scheme as _cfs
-    _r, _s = ecdsa_sig_from_der(der_sig)
-    _n = _cfs(scheme).n
-    if _s > _n // 2:
-        _s = _n - _s
-    der_sig = ecdsa_sig_to_der(_r, _s)
+    # as the signer emits it, high s or low: Crypto.doVerify takes both
     pub_compressed = oracle.public_key().public_bytes(
         serialization.Encoding.X962, serialization.PublicFormat.CompressedPoint)
     from corda_tpu.core.crypto.keys import PublicKey

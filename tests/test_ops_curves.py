@@ -334,17 +334,20 @@ def test_hybrid_wide_window_widths_agree():
         wc_ops.prepare_batch_hybrid_wide(items, 3)
 
 
-def test_ecdsa_rejects_high_s_and_off_curve():
+def test_ecdsa_accepts_the_high_s_twin_and_rejects_off_curve():
+    """Crypto.doVerify's rule (BouncyCastle): any s in [1, n-1], so the
+    n - s twin of a valid signature is valid too."""
     curve = ecmath.SECP256K1
     priv = rand_scalar(curve.n - 1) + 1
     pub = curve.mul(priv, curve.g)
     msg = b"m"
     r, s = ecmath.ecdsa_sign(curve, priv, msg)
+    assert s <= curve.n // 2                    # the signer still normalises
     items = [
-        (pub, msg, r, curve.n - s),            # malleated high-s twin
+        (pub, msg, r, curve.n - s),            # the high-s twin
         ((pub[0], (pub[1] + 1) % curve.p), msg, r, s),  # off-curve key
         (None, msg, r, s),                      # missing key
         (pub, msg, r, s),                       # control
     ]
     got = wc_ops.verify_batch(curve, items)
-    assert list(got) == [False, False, False, True]
+    assert list(got) == [True, False, False, True]

@@ -96,7 +96,7 @@ def _mixed_items():
         (pub0, msg0, (r0 + 1) % N or 1, s0),            # tampered r
         (pub0, msg0, 0, s0),                            # r = 0 (DER clamp)
         (pub0, msg0, N + 5, s0),                        # r >= n
-        (pub0, msg0, r0, N - s0),                       # high-s twin
+        (pub0, msg0, r0, N - s0),                       # high-s twin: valid
         ((pub0[0], (pub0[1] + 1) % CURVE.p), msg0, r0, s0),  # off-curve
         (None, msg0, r0, s0),                           # missing key
     ]
@@ -166,6 +166,8 @@ def test_split_verdicts_match_oracle_python(monkeypatch):
     items = _mixed_items()
     got = wc.verify_batch(CURVE, items, mode="halfgcd")
     np.testing.assert_array_equal(got, _oracle(items))
+    # Crypto.doVerify's rule: the six signed rows and the n - s twin
+    assert list(np.nonzero(got)[0]) == [0, 1, 2, 3, 4, 5, 10]
 
 
 def test_fallback_parity_end_to_end():

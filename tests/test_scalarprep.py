@@ -58,14 +58,16 @@ def _k1_items(n_valid: int):
         msg = rng.bytes(48)
         r, s = ecmath.ecdsa_sign(curve, priv, msg)
         items.append((pub, msg, r, s))
-    # malformed rows: None point, r = 0, s = 0, high-s, r >= n, off-curve,
-    # oversized r (DER can carry > 2^256 ints)
+    # malformed rows: None point, r = 0, s = 0, s = n - 1 (in range since
+    # sm_version 4: both preps hand it to the kernel, which refuses it), s = n,
+    # r >= n, off-curve, oversized r (DER can carry > 2^256 ints)
     pub0 = items[0][0]
     items += [
         (None, b"x", 5, 7),
         (pub0, b"m", 0, 7),
         (pub0, b"m", 5, 0),
-        (pub0, b"m", 5, curve.n - 1),           # violates low-s
+        (pub0, b"m", 5, curve.n - 1),           # the largest s in range
+        (pub0, b"m", 5, curve.n),               # the smallest out of range
         (pub0, b"m", curve.n, 7),
         ((pub0[0], (pub0[1] + 1) % curve.p), b"m", 5, 7),
         (pub0, b"m", 1 << 300, 7),
@@ -278,7 +280,7 @@ def test_stale_so_falls_back_loudly(caplog):
     # the matching version loads fine (the gate, not the loader, refused)
     assert sp._load(candidates=[real]) is not None
     # and a refused library means available() gates every native seam
-    assert sp.SM_VERSION == 3  # bumped 2→3 with sm_r1_halfgcd/sm_r1_prep_hg
+    assert sp.SM_VERSION == 4  # 3→4: the ECDSA preps' s bound is n, not n/2
 
 
 def test_k1_verify_through_native_prep():
