@@ -451,36 +451,38 @@ def verify_core_split(bb_idx, a_packed, rows, r_packed,
 _verify_kernel_split = jax.jit(verify_core_split, static_argnames=("w",))
 
 
-def _u64_multiplies(jaxpr) -> int:
-    """Elements of every u64 ``mul`` in a jaxpr, a scan's body counted once
-    per iteration."""
+def _limb_multiplies(jaxpr) -> int:
+    """Elements of every integer ``mul`` in a jaxpr, whatever its lanes
+    (the product's int32 digits, a fold's or a scale's constant, the tails'
+    uint64), a scan's body counted once per iteration."""
     total = 0
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "mul":
             out = eqn.outvars[0].aval
-            if out.dtype == jnp.uint64:
+            if jnp.issubdtype(out.dtype, jnp.integer):
                 total += out.size
         times = eqn.params["length"] if eqn.primitive.name == "scan" else 1
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            total += times * _u64_multiplies(sub)
+            total += times * _limb_multiplies(sub)
     return total
 
 
 @functools.lru_cache(maxsize=None)
 def split_field_products(rows: int, w: int = SPLIT_B_WINDOW) -> int:
-    """u64 limb multiplications one signature costs in ``verify_core_split``
+    """Limb multiplications one signature costs in ``verify_core_split``
     at a ``rows``-wide batch, counted from the program itself (its jaxpr,
-    scan bodies times their lengths; a full field product is 256 of them
-    plus its folds, a squaring 152). The flight recorder's ``ed25519.split``
-    record carries it, so a trace says which kernel ran; the trace behind
-    it is the one the first dispatch of the bucket makes anyway."""
+    scan bodies times their lengths; a full field product is 16 x 16
+    balanced digits, two rows for the operands' top carries, and its
+    folds: 307; a squaring 171). The flight recorder's ``ed25519.split`` record carries it,
+    so a trace says which kernel ran; the trace behind it is the one the
+    first dispatch of the bucket makes anyway."""
     S = jax.ShapeDtypeStruct
     table = S((1 << w, F.NLIMB), jnp.uint16)
     traced = _verify_kernel_split.trace(
         S((256 // w, rows), jnp.int32), S((128 // w, w // 2, rows), jnp.uint8),
         S((rows, 6, F.NLIMB), jnp.uint16), S((rows, F.NLIMB), jnp.uint16),
         *(table,) * 6, w=w)
-    return round(_u64_multiplies(traced.jaxpr) / rows)
+    return round(_limb_multiplies(traced.jaxpr) / rows)
 
 
 def verify_core(s_bits, k_bits, neg_a, r_affine):

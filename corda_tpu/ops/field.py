@@ -1,34 +1,53 @@
-"""Batched 256-bit prime-field arithmetic on 16-bit limbs in uint64 lanes.
+"""Batched 256-bit prime-field arithmetic on 16-bit limbs, computed at the
+vector unit's own width.
 
 The bigint engine under both curve kernels (ed25519.py, weierstrass.py).
 Design (SURVEY.md §7 phase 1 "limb-decomposed lanes"):
 
-- A field element is ``u64[..., 16]``, little-endian 16-bit limbs (limb i
-  holds value·2^16i). **Contract (lazy / relaxed limbs)**: limbs 0..14 are
-  < LMAX = 1.5·2^16; limb 15 is < 2^18. The value is NOT kept < p between
-  operations (any residue), and may exceed 2^256 — the top limb's headroom
-  absorbs the overflow that pure 2^256→fold_c folding can never eliminate
-  from a relaxed representation. Canonicalisation (compare/subtract chains)
-  happens only in ``canon``/``eq``/``is_zero`` at kernel tails.
+- A field element is ``u64[..., 16]`` AT THE SEAMS (kernel arguments, scan
+  carries, tables, what every formula takes and returns), little-endian
+  16-bit limbs (limb i holds value·2^16i). **Contract (lazy / relaxed
+  limbs)**: limbs 0..14 are < LMAX = 1.5·2^16; limb 15 is < 2^18. The value
+  is NOT kept < p between operations (any residue), and may exceed 2^256 —
+  the top limb's headroom absorbs the overflow that pure 2^256→fold_c
+  folding can never eliminate from a relaxed representation.
+  Canonicalisation (compare/subtract chains) happens only in
+  ``canon``/``eq``/``is_zero`` at kernel tails.
+- INSIDE an operation every value lives in ``int32`` (the TPU's vector unit
+  is 32 bits wide; a 64-bit multiply lowers as four 32-bit ones with their
+  carries): a limb product is ONE native multiply of two balanced 16-bit
+  digits (see "The limb product"), a product's columns stay near 2^21, and
+  the whole walk after it runs in 32-bit lanes. 64-bit lanes remain only
+  for bounds that do not fit (a ``mul_const`` by a wide constant) and for
+  the canonical tails.
 - Carry handling is *vectorized*: one carry pass computes
   ``(v & 0xffff) + shift(v >> 16)`` across the whole limb axis at once,
   versus a 16-32-step *sequential* sweep per op which serializes the VPU and
   made XLA graphs ~10x bigger (70 s compiles for one curve kernel).
 - **Exact per-limb bound tracking**: every internal step carries a Python
-  list of inclusive per-limb bounds; pass counts, fold counts, slice widths
-  and the final contract check are *derived* from exact integer arithmetic
-  at trace time, not hand-proven per op. A limb whose bound is 0 is sliced;
-  an op finishes when the bounds meet the contract. Host-side only — the
-  compiled graph contains zero data-dependent control flow.
+  list of inclusive per-limb bounds (signed intervals between operations);
+  pass counts, fold counts, lanes, slice widths and the final contract
+  check are *derived* from exact integer arithmetic at trace time, not
+  hand-proven per op. A limb whose bound is 0 is sliced; an op finishes
+  when the bounds meet the contract; a bound no lane holds is refused at
+  trace time. Host-side only — the compiled graph contains zero
+  data-dependent control flow.
+- Every shifted add is ``acc + pad(row)`` (``_place``), never a slice
+  update: the TPU compiler fuses a sum of pads into one program step, and
+  turns each ``.at[].add`` into four. On the chip the steps, not the
+  multiplies, set a field operation's time (PERF.md, PR 32).
 - Reduction exploits 16-limb alignment of 2^256 ≡ fold_c (mod p):
   p25519 → fold_c = 38; psecp → fold_c = 2^32+977; psecr1 → 224-bit Solinas
   constant (more fold rounds, still exact). The terminal width-17 state with
   a tiny limb-16 bound is folded *back* into limb 15's headroom.
-- Subtraction avoids borrows by adding a redundant-limb encoding of 32p
-  whose every limb dominates the contract bound of the subtrahend.
+- Subtraction is plain and SIGNED; the one place a value is made
+  non-negative again is the start of its walk, by adding a redundant-limb
+  encoding of a multiple of p whose every limb dominates the lowest value
+  its limb can take (``_lift``).
 """
 from __future__ import annotations
 
+import functools
 import threading
 
 import jax
@@ -49,9 +68,9 @@ PSECR1 = 0xFFFFFFFF00000001000000000000000000000000FFFFFFFFFFFFFFFFFFFFFFFF
 _FOLD = {p: TWO256 % p for p in (P25519, PSECP, PSECR1)}
 
 # Inclusive per-limb bounds of a contract-satisfying element.
-_CONTRACT = [LMAX - 1] * 15 + [LIMB15_MAX - 1]
+_CONTRACT = [(0, LMAX - 1)] * 15 + [(0, LIMB15_MAX - 1)]
 # Largest value a contract element can take (drives fold bound walks).
-VMAX = sum(b << (LIMB_BITS * i) for i, b in enumerate(_CONTRACT))
+VMAX = sum(b << (LIMB_BITS * i) for i, (_, b) in enumerate(_CONTRACT))
 
 
 def _c_limbs_of(p: int) -> list[int]:
@@ -92,7 +111,53 @@ def from_limbs(a):
 
 # ---------------------------------------------------------------------------
 # Bound-tracked carry/fold machinery (host-derived, trace-time static)
+#
+# Two kinds of exact bounds, both plain Python, both derived at trace time:
+# - a RELAXED pair ``(value, bounds)`` between operations carries one
+#   inclusive ``(lo, hi)`` interval per limb: a product's columns and a
+#   borrow-free-by-sign subtraction are SIGNED;
+# - inside the walk (``_normalize`` after its dominator step) every limb is
+#   non-negative and ``bounds`` is the list of inclusive upper bounds.
+# A value is computed in the vector unit's own 32-bit lanes wherever its
+# bounds fit them and in 64-bit lanes otherwise (``_lanes``).
 # ---------------------------------------------------------------------------
+
+_S32 = 1 << 31
+_S64 = 1 << 63
+
+
+def _span(bounds):
+    """(lowest, highest) value any limb can take, from intervals or from a
+    list of non-negative upper bounds."""
+    if isinstance(bounds[0], tuple):
+        return min(lo for lo, _ in bounds), max(hi for _, hi in bounds)
+    return 0, max(bounds)
+
+
+def _lanes(bounds):
+    """The dtype a value with these exact bounds is computed in: int32
+    where every limb fits it, int64 otherwise. A bound no lane holds is
+    refused here, at trace time, and never wraps."""
+    lo, hi = _span(bounds)
+    assert -_S64 <= lo and hi < _S64, "limb bound overflows the 64-bit lanes"
+    return jnp.int32 if -_S32 <= lo and hi < _S32 else jnp.int64
+
+
+def _place(v, off: int, n: int):
+    """``v`` at limbs [off, off + width) of an ``n``-limb zero value. Every
+    shifted add in this module is ``acc + _place(row, off, n)``: the
+    compiler fuses a sum of pads into ONE step, where a slice update
+    (``.at[].add``, a scatter-add) costs four steps a row."""
+    w = v.shape[-1]
+    if off == 0 and w == n:
+        return v
+    return jax.lax.pad(v, v.dtype.type(0),
+                       [(0, 0, 0)] * (v.ndim - 1) + [(off, n - off - w, 0)])
+
+
+def _pad_to(v, n: int):
+    return _place(v, 0, n)
+
 
 def _trim(v, bounds):
     """Drop trailing limbs whose exact bound is 0 (provably zero lanes)."""
@@ -102,19 +167,13 @@ def _trim(v, bounds):
 
 
 def _pass(v, bounds):
-    """One vectorized carry pass. Exact new bounds:
-    limb'_i = (limb_i & mask) + (limb_{i-1} >> 16).
-
-    When every incoming bound fits u32 the pass runs in uint32 — the TPU
-    VPU is natively 32-bit, so u64 mask/shift/add lower as emulated pairs;
-    the downcast is lossless by the exact bounds and jnp's promotion rules
-    carry the narrow dtype through downstream adds harmlessly."""
-    if max(bounds) < (1 << 32) and v.dtype == jnp.uint64:
-        v = v.astype(jnp.uint32)
+    """One vectorized carry pass over non-negative limbs. Exact new bounds:
+    limb'_i = (limb_i & mask) + (limb_{i-1} >> 16)."""
+    v = v.astype(_lanes(bounds))
     lo = v & v.dtype.type(MASK)
     hi = v >> v.dtype.type(LIMB_BITS)
-    pad_cfg = [(0, 0)] * (v.ndim - 1)
-    v = jnp.pad(lo, pad_cfg + [(0, 1)]) + jnp.pad(hi, pad_cfg + [(1, 0)])
+    n = len(bounds) + 1
+    v = _place(lo, 0, n) + _place(hi, 1, n)
     nb = [min(b, MASK) for b in bounds] + [0]
     for i, b in enumerate(bounds):
         nb[i + 1] += b >> LIMB_BITS
@@ -122,7 +181,7 @@ def _pass(v, bounds):
 
 
 def _fold_bounds(bounds, c_limbs):
-    """Exact post-fold bounds, or None when a fold would overflow u64."""
+    """Exact post-fold bounds, or None when a fold would overflow 64 bits."""
     lob, hib = bounds[:NLIMB], bounds[NLIMB:]
     acc_w = max(NLIMB, len(hib) + len(c_limbs))
     nb = list(lob) + [0] * (acc_w - NLIMB)
@@ -130,38 +189,36 @@ def _fold_bounds(bounds, c_limbs):
         if c:
             for i, hb in enumerate(hib):
                 nb[j + i] += hb * c
-    return nb if max(nb) < (1 << 63) else None
+    return nb if max(nb) < _S64 else None
 
 
 def _fold_once(v, bounds, c_limbs):
-    """lo + hi·c for a width>16 value (split at bit 256). Exact bounds."""
-    if v.dtype != jnp.uint64:       # a u32 carry pass may have narrowed v
-        v = v.astype(jnp.uint64)
-    lo = v[..., :NLIMB]
-    hi, hib = v[..., NLIMB:], bounds[NLIMB:]
-    nh = len(hib)
+    """lo + hi·c for a width>16 value (split at bit 256). Exact bounds; the
+    ``hi * c`` products are native 32-bit multiplies where the folded
+    bounds fit the 32-bit lanes."""
     nb = _fold_bounds(bounds, c_limbs)
-    assert nb is not None, "u64 column overflow"
-    hi = _mul_operand(hi, hib)
-    acc_w = max(NLIMB, nh + len(c_limbs))
-    acc = jnp.zeros(v.shape[:-1] + (acc_w,), dtype=jnp.uint64)
-    acc = acc.at[..., :NLIMB].add(lo)
+    assert nb is not None, "64-bit column overflow"
+    v = v.astype(_lanes(nb))
+    lo = v[..., :NLIMB]
+    hi = v[..., NLIMB:]
+    nh = len(bounds) - NLIMB
+    acc = _pad_to(lo, len(nb))
     for j, c in enumerate(c_limbs):
         if c:
-            acc = acc.at[..., j:j + nh].add(hi * jnp.uint64(c))
+            acc = acc + _place(hi * v.dtype.type(c), j, len(nb))
     return _trim(acc, nb)
 
 
 def _fold_bounds_r1(bounds):
     """Exact post-fold bounds of the SIGNED Solinas fold for P-256 (see
-    _fold_once_r1), or None when a column would overflow u64."""
+    _fold_once_r1), or None when a column would overflow 64 bits."""
     lob, hib = bounds[:NLIMB], bounds[NLIMB:]
     nh = len(hib)
     neg = [0] * (12 + nh)
     for i, b in enumerate(hib):
         neg[6 + i] += b
         neg[12 + i] += b
-    if max(neg) >= (1 << 63):
+    if max(neg) >= _S64:
         return None
     off, ob = _dominator_offset(tuple(neg), PSECR1)
     width = max(NLIMB, 14 + nh, len(ob))
@@ -173,39 +230,50 @@ def _fold_bounds_r1(bounds):
         nb[14 + i] += b
     for i, b in enumerate(ob):
         nb[i] += b
-    return nb if max(nb) < (1 << 63) else None
+    return nb if max(nb) < _S64 else None
 
 
 def _fold_once_r1(v, bounds):
     """Signed Solinas fold for p = 2^256 - 2^224 + 2^192 + 2^96 - 1:
     hi·2^256 ≡ hi·2^224 - hi·2^192 - hi·2^96 + hi, i.e. pure LIMB-SHIFTED
-    adds/subs (224/192/96 are multiples of 16) made borrow-free by a
-    dominator multiple of p — 4 shifted DUS ops instead of the generic
+    adds/subs (224/192/96 are multiples of 16) kept non-negative by a
+    dominator multiple of p — 4 shifted adds instead of the generic
     multiply-fold's ~14 per-limb multiply-adds (c = 2^256 mod p has 14
     nonzero limbs, which also made the generic fold's bounds blow up so it
     was rarely even ELIGIBLE, forcing extra carry passes first; this fold's
-    bounds grow additively, so it runs far earlier).  The r5 lever named in
-    BASELINE.md's round-4 r1 section."""
-    if v.dtype != jnp.uint64:
-        v = v.astype(jnp.uint64)
-    lo = v[..., :NLIMB]
-    hi, hib = v[..., NLIMB:], bounds[NLIMB:]
+    bounds grow additively, so it runs far earlier)."""
+    hib = bounds[NLIMB:]
     nh = len(hib)
     nb = _fold_bounds_r1(bounds)
-    assert nb is not None, "u64 column overflow in r1 Solinas fold"
+    assert nb is not None, "64-bit column overflow in r1 Solinas fold"
+    v = v.astype(_lanes(nb))
+    lo = v[..., :NLIMB]
+    hi = v[..., NLIMB:]
     neg = [0] * (12 + nh)
     for i, b in enumerate(hib):
         neg[6 + i] += b
         neg[12 + i] += b
     off, _ = _dominator_offset(tuple(neg), PSECR1)
-    acc = jnp.zeros(v.shape[:-1] + (len(nb),), dtype=jnp.uint64)
-    acc = acc.at[..., :NLIMB].add(lo)
-    acc = acc.at[..., :nh].add(hi)
-    acc = acc.at[..., 14:14 + nh].add(hi)
-    acc = acc.at[..., :len(off)].add(jnp.asarray(off))
-    acc = acc.at[..., 6:6 + nh].add(-hi)
-    acc = acc.at[..., 12:12 + nh].add(-hi)
+    n = len(nb)
+    acc = (_pad_to(lo, n) + _pad_to(hi, n) + _place(hi, 14, n)
+           + _pad_to(jnp.asarray(off, v.dtype), n)
+           - _place(hi, 6, n) - _place(hi, 12, n))
     return _trim(acc, nb)
+
+
+def _lift(v, bounds, p: int):
+    """Signed intervals → non-negative upper bounds: where any limb can be
+    negative, add a multiple of p whose redundant limb encoding dominates
+    every limb's lowest value (same residue, no borrow anywhere)."""
+    lo, _ = _span(bounds)
+    if lo >= 0:
+        return v, [hi for _, hi in bounds]
+    off, ob = _dominator_offset(tuple(max(0, -l) for l, _ in bounds), p)
+    nb = [hi for _, hi in bounds] + [0] * (len(ob) - len(bounds))
+    for i, x in enumerate(ob):
+        nb[i] += x
+    v = _pad_to(v.astype(_lanes(nb)), len(nb))
+    return v + jnp.asarray(off, v.dtype), nb
 
 
 def _normalize(v, bounds, p: int):
@@ -214,13 +282,16 @@ def _normalize(v, bounds, p: int):
     shrink the value bound and the terminal width-17/limb16≤tiny state folds
     back into limb 15's headroom.
 
-    Folds run EAGERLY — as soon as the exact post-fold bounds fit u64 —
-    instead of after carrying every limb below LMAX first: an early fold
-    shrinks the array from up-to-31 limbs to ~16, so the remaining carry
-    passes run at half the width (measured 4 passes + 2 folds per norm
-    before; the wide passes dominated the walk cost).  P-256 routes through
-    the signed Solinas fold (_fold_once_r1) instead of the generic
-    multiply-fold."""
+    ``bounds`` are a relaxed pair's intervals: a signed value is first made
+    non-negative (:func:`_lift`). Folds run EAGERLY — as soon as the exact
+    post-fold bounds fit the lanes the value is in — instead of after
+    carrying every limb below LMAX first: an early fold shrinks the array
+    from up-to-34 limbs to ~16, so the remaining carry passes run at half
+    the width. A value in the 32-bit lanes is never folded out of them: a
+    32-bit pass first costs less than a fold and a walk in 64-bit lanes.
+    P-256 routes through the signed Solinas fold (_fold_once_r1) instead
+    of the generic multiply-fold."""
+    v, bounds = _lift(v, list(bounds), p)
     c_limbs = _c_limbs_of(p)
     solinas = p == PSECR1
     for _ in range(64):
@@ -228,25 +299,22 @@ def _normalize(v, bounds, p: int):
             if (len(bounds) == NLIMB + 1
                     and bounds[15] + (bounds[16] << LIMB_BITS) < LIMB15_MAX):
                 # fold limb 16 back into limb 15's headroom: value-preserving
-                merged = v[..., 15] + (v[..., 16] << LIMB_BITS)
-                v = v[..., :NLIMB].at[..., 15].set(merged)
+                v = v[..., :NLIMB] + _place(v[..., NLIMB:] << LIMB_BITS, 15, NLIMB)
                 bounds = bounds[:15] + [bounds[15] + (bounds[16] << LIMB_BITS)]
                 continue
             nb = (_fold_bounds_r1(bounds) if solinas
                   else _fold_bounds(bounds, c_limbs))
-            if nb is not None:
+            if nb is not None and (max(nb) < _S32 or max(bounds) >= _S32):
                 v, bounds = (_fold_once_r1(v, bounds) if solinas
                              else _fold_once(v, bounds, c_limbs))
             else:
                 v, bounds = _pass(v, bounds)
             continue
-        if all(b <= t for b, t in zip(bounds, _CONTRACT)):
+        if all(b <= t for b, (_, t) in zip(bounds, _CONTRACT)):
             # contract outputs are uniformly u64: scan carries and DUS
-            # accumulators require exact dtype agreement, so the u32 pass
-            # narrowing stays internal to the walk
-            if v.dtype != jnp.uint64:
-                v = v.astype(jnp.uint64)
-            return v, bounds
+            # accumulators require exact dtype agreement, so the narrow
+            # lanes stay internal to the walk
+            return v.astype(jnp.uint64), bounds
         v, bounds = _pass(v, bounds)
     raise AssertionError("field normalization failed to converge")
 
@@ -308,44 +376,201 @@ def canon(a, p: int):
 
 
 # ---------------------------------------------------------------------------
+# The limb product: exact, on 32-bit lanes, one native multiply a digit pair
+#
+# An operand's limbs (any relaxed bounds that fit int32, signed or not) are
+# re-cut into BALANCED 16-bit digits in [-2^15, 2^15 + carry]: the product
+# of two digits then fits int32, so ``scalar · row`` is ONE native multiply
+# where 16-bit limbs in 64-bit lanes took four with their carries. Each
+# product is cut in its two 16-bit halves, added one column apart, so a
+# column of 16 of them stays near 2^21 and the walk after it runs in the
+# 32-bit lanes from its first pass. The columns are SIGNED and exact as an
+# integer: sum(col_k << 16k) == a · b. The carry out of the top limb (0..4
+# for a contract element) is kept beside the 16 digits, not as a 17th: its
+# rows are small enough to be added whole, and every array stays 16 wide
+# (two sublane tiles, where 17 take three: measured 27% slower, PR 32).
+#
+# Measured on a TPU v5e at (8192, 16) (PERF.md section 6, PR 32): F.mul
+# 64.2 -> 11.1 us. Most of that is HOW A ROW IS PLACED (pad + add: 12
+# program steps a multiply; the same digits with `.at[].add`: 54 steps,
+# 52 us), the rest the 32-bit lanes. Laws fitted to the uint64 product and
+# OPEN AGAIN (not re-measured): one level of limb Karatsuba was 18% slower
+# at batch 32k; `_add_k1`'s walked `bt2` and `_dbl_m3`'s normalize-before-
+# multiply (ops/weierstrass.py); `INV_BATCH_STOP` 128.
+# ---------------------------------------------------------------------------
+
+_HALF = 1 << (LIMB_BITS - 1)
+# a product (or a row's every product) under this is added to its column
+# whole: cutting it in halves saves no bits a column of them lacks
+_WHOLE = 1 << 20
+_REFUSED = "operand too wide for the 32-bit limb product"
+
+
+def _iv_mul(x, y):
+    c = (x[0] * y[0], x[0] * y[1], x[1] * y[0], x[1] * y[1])
+    return min(c), max(c)
+
+
+@functools.lru_cache(maxsize=None)
+def _digit_bounds(bounds: tuple):
+    """Exact intervals of :func:`_digits`' output: (main, top)."""
+    lo, hi = _span(bounds)
+    assert -_S32 <= lo and hi + _HALF < _S32, _REFUSED
+    div, civ = [], []
+    for l, h in bounds:
+        cl, ch = (l + _HALF) >> LIMB_BITS, (h + _HALF) >> LIMB_BITS
+        civ.append((cl, ch))
+        div.append((((l + _HALF) & MASK) - _HALF, ((h + _HALF) & MASK) - _HALF)
+                   if cl == ch else (-_HALF, _HALF - 1))
+    main = [div[0]] + [(dl + cl, dh + ch) for (dl, dh), (cl, ch)
+                       in zip(div[1:], civ[:-1])]
+    return tuple(main), civ[-1]
+
+
+def _digits(a, bounds):
+    """Limbs with exact bounds → ``(main, main_iv, top, top_iv)``: balanced
+    int32 digits of the same value, ``main`` as wide as ``a`` and ``top``
+    (..., 1) the carry out of its last limb (None where provably 0)."""
+    main_iv, top_iv = _digit_bounds(tuple(bounds))
+    t = a.astype(jnp.int32) + jnp.int32(_HALF)
+    d = (t & jnp.int32(MASK)) - jnp.int32(_HALF)
+    c = t >> jnp.int32(LIMB_BITS)
+    main = d + _place(c[..., :-1], 1, len(main_iv))
+    return main, main_iv, (None if top_iv == (0, 0) else c[..., -1:]), top_iv
+
+
+@functools.lru_cache(maxsize=None)
+def _row_bounds(siv: tuple, segiv: tuple, weights):
+    """Exact intervals of one row ``s · seg · w``: ``(whole, None)`` where
+    every product is small, else the two halves' ``(low, high)``."""
+    pivs = [_iv_mul(siv, x) for x in segiv]
+    assert all(-_S32 <= l and h < _S32 for l, h in pivs), _REFUSED
+    w = weights or (1,) * len(segiv)
+    if all(-_WHOLE < l and h < _WHOLE for l, h in pivs):
+        return tuple((l * k, h * k) for (l, h), k in zip(pivs, w)), None
+    low, high = [], []
+    for (l, h), k in zip(pivs, w):
+        cl, ch = l >> LIMB_BITS, h >> LIMB_BITS
+        ll, lh = (l & MASK, h & MASK) if cl == ch else (0, MASK)
+        low.append((ll * k, lh * k))
+        high.append((cl * k, ch * k))
+    return tuple(low), tuple(high)
+
+
+class _Columns:
+    """A product's signed column accumulator with its exact intervals."""
+
+    def __init__(self, width: int):
+        self.v = None
+        self.lo = [0] * width
+        self.hi = [0] * width
+
+    def add(self, row, riv, off: int):
+        """cols[off + j] += row_j, a row with exact intervals ``riv``."""
+        grow = off + len(riv) - len(self.lo)
+        if grow > 0:
+            self.lo += [0] * grow
+            self.hi += [0] * grow
+            if self.v is not None:
+                self.v = _pad_to(self.v, len(self.lo))
+        row = _place(row, off, len(self.lo))
+        self.v = row if self.v is None else self.v + row
+        for j, (l, h) in enumerate(riv):
+            self.lo[off + j] += l
+            self.hi[off + j] += h
+
+    def add_row(self, s, siv, seg, segiv, off: int, weights=None):
+        """cols[off + j] += s · seg_j · w_j: one multiply for the row; each
+        product cut in halves placed one column apart unless the whole row
+        is small. ``weights`` (the square's doubled cross terms) apply
+        AFTER the cut: twice a digit product can pass 2^31, twice its
+        halves cannot."""
+        if siv == (0, 0):
+            return
+        low, high = _row_bounds(siv, tuple(segiv), weights)
+        p = s * seg
+
+        def scale(x):       # weights are 1 or 2: a shift, not a multiply
+            if weights is None:
+                return x
+            by = jnp.asarray([k >> 1 for k in weights], jnp.int32)
+            return jax.lax.shift_left(x, jnp.broadcast_to(by, x.shape))
+
+        if high is None:
+            self.add(scale(p), low, off)
+            return
+        lo = scale(p & jnp.int32(MASK))
+        hi = scale(p >> jnp.int32(LIMB_BITS))
+        self.add(lo, low, off)
+        self.add(hi, high, off + 1)
+
+    def done(self):
+        bounds = list(zip(self.lo, self.hi))
+        lo, hi = _span(bounds)
+        assert -_S32 <= lo and hi < _S32, _REFUSED
+        return self.v, bounds
+
+
+def _top_top(cols, t, tiv, at: int):
+    """The product of two top carries belongs one column past the last:
+    where it is small it goes into the last column times 2^16 (the same
+    value) and the accumulator stays a whole number of sublane tiles."""
+    if max(abs(tiv[0]), abs(tiv[1])) < (1 << 8):
+        k = 1 << LIMB_BITS
+        cols.add(t * jnp.int32(k), [(tiv[0] * k, tiv[1] * k)], at - 1)
+    else:
+        cols.add(t, [tiv], at)
+
+
+def _product(a, ab, b, bb):
+    """Signed exact columns of a · b (see the section note)."""
+    A, aiv, at, ativ = _digits(a, ab)
+    B, biv, bt, btiv = _digits(b, bb)
+    na, nb = len(aiv), len(biv)
+    cols = _Columns(na + nb)
+    for i in range(na):
+        cols.add_row(A[..., i:i + 1], aiv[i], B, biv, i)
+    if at is not None:
+        cols.add_row(at, ativ, B, biv, na)
+    if bt is not None:
+        cols.add_row(bt, btiv, A, aiv, nb)
+    if at is not None and bt is not None:
+        _top_top(cols, at * bt, _iv_mul(ativ, btiv), na + nb)
+    return cols.done()
+
+
+def _square(a, ab):
+    """Signed exact columns of a², triangular: col_k = 2·Σ_{i<j, i+j=k}
+    a_i·a_j + [k even]·a_{k/2}² — ~n(n+1)/2 digit products instead of n²
+    (`dbl`'s Y² / Z² and Fermat's square chain are the beneficiaries).
+    Row i covers columns [2i, i+n]: the diagonal a_i² then the doubled
+    cross terms (j > i) — CONTIGUOUS placements (a strided cols[0::2]
+    diagonal forces a relayout on TPU)."""
+    A, aiv, at, ativ = _digits(a, ab)
+    n = len(aiv)
+    cols = _Columns(2 * n)
+    for i in range(n):
+        cols.add_row(A[..., i:i + 1], aiv[i], A[..., i:], aiv[i:], 2 * i,
+                     None if i == n - 1 else (1,) + (2,) * (n - 1 - i))
+    if at is not None:
+        cols.add_row(at, ativ, A, aiv, n, (2,) * n)
+        _top_top(cols, at * at, _iv_mul(ativ, ativ), 2 * n)
+    return cols.done()
+
+
+# ---------------------------------------------------------------------------
 # Core modular ops (shape-polymorphic over leading batch dims)
 # All take and return contract elements (see module docstring).
 # ---------------------------------------------------------------------------
 
-def _mul_operand(a, bounds):
-    """Route a multiplicand whose exact bounds fit u32 through a
-    u32→u64 convert: the value is unchanged (bounds prove the truncation
-    is lossless) but the convert ANNOTATES the range, letting the TPU
-    backend lower the u64 products to half-width multiplies."""
-    if max(bounds) < (1 << 32):
-        return a.astype(jnp.uint32).astype(jnp.uint64)
-    return a
-
-
 def raw_mul_bounded(a, b, a_bounds=None, b_bounds=None):
     """Full product with exact column bounds: bounded × bounded → wide.
     Input bounds default to the contract; callers passing *relaxed* operands
-    (e.g. un-normalized sums) supply their exact bounds instead.
-
-    Plain 16-DUS schoolbook. One level of limb Karatsuba (3 width-8
-    schoolbooks, 192 column MACs vs 256; borrow-free middle term) was
-    MEASURED 18% SLOWER on v5e at batch 32k — width-8 rows waste VPU lanes
-    and the extra combine ops outweigh the saved MACs. Don't re-try without
-    new hardware."""
-    a_bounds = _CONTRACT if a_bounds is None else a_bounds
-    b_bounds = _CONTRACT if b_bounds is None else b_bounds
-    a = _mul_operand(a, a_bounds)
-    b = _mul_operand(b, b_bounds)
-    cols = jnp.zeros(jnp.broadcast_shapes(a.shape[:-1], b.shape[:-1])
-                     + (2 * NLIMB - 1,), dtype=jnp.uint64)
-    for i in range(NLIMB):
-        cols = cols.at[..., i:i + NLIMB].add(a[..., i:i + 1] * b)
-    nb = [0] * (2 * NLIMB - 1)
-    for i, ab in enumerate(a_bounds):
-        for j, bb in enumerate(b_bounds):
-            nb[i + j] += ab * bb
-    assert max(nb) < (1 << 63), "u64 column overflow in schoolbook multiply"
-    return cols, nb
+    (e.g. un-normalized sums) supply their exact intervals instead. An
+    operand whose digits' product does not fit int32 is refused at trace
+    time."""
+    return _product(a, _CONTRACT if a_bounds is None else a_bounds,
+                    b, _CONTRACT if b_bounds is None else b_bounds)
 
 
 def mul(a, b, p: int):
@@ -359,10 +584,10 @@ def mul(a, b, p: int):
 #
 # The complete-addition formulas are full of `mul, mul, add/sub` triples that
 # each pay a full normalize walk. These primitives keep products as raw
-# column accumulators (value, exact bounds) so a whole linear combination
-# ± a·b ± c·d ± e normalizes ONCE. Negative terms are made borrow-free by
-# adding a multiple of p whose redundant limb encoding dominates their
-# column bounds (the wide generalization of the 32p trick in `sub`).
+# column accumulators (value, exact intervals) so a whole linear combination
+# ± a·b ± c·d ± e normalizes ONCE. Columns and relaxed pairs are signed: a
+# negative term is plainly subtracted, and the one place a value is made
+# non-negative again is the start of its walk (`_lift`).
 # ---------------------------------------------------------------------------
 
 def rel(a, bounds=None):
@@ -370,84 +595,72 @@ def rel(a, bounds=None):
     return (a, _CONTRACT if bounds is None else bounds)
 
 
+def _combine(terms):
+    """Σ sign·value over relaxed pairs of any widths → one relaxed pair,
+    computed in the lanes the exact result bounds ask for."""
+    n = max(len(nb) for _, nb, _ in terms)
+    lo, hi = [0] * n, [0] * n
+    for _, nb, sign in terms:
+        for i, (l, h) in enumerate(nb):
+            lo[i] += l if sign > 0 else -h
+            hi[i] += h if sign > 0 else -l
+    bounds = list(zip(lo, hi))
+    dt = _lanes(bounds)
+    out = None
+    for v, nb, sign in terms:
+        v = _pad_to(v.astype(dt), n)
+        out = (v if sign > 0 else -v) if out is None else (
+            out + v if sign > 0 else out - v)
+    return (out, bounds)
+
+
+def _pair(ar):
+    return ar if isinstance(ar, tuple) else rel(ar)
+
+
 def rel_add(ar, br):
     """Relaxed add: no normalize; bounds sum. Inputs: (v, bounds) pairs or
     plain arrays (contract bounds assumed)."""
-    a, ab = ar if isinstance(ar, tuple) else rel(ar)
-    b, bb = br if isinstance(br, tuple) else rel(br)
-    n = max(len(ab), len(bb))
-    ab = list(ab) + [0] * (n - len(ab))
-    bb = list(bb) + [0] * (n - len(bb))
-    if a.shape[-1] < n:
-        a = jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, n - a.shape[-1])])
-    if b.shape[-1] < n:
-        b = jnp.pad(b, [(0, 0)] * (b.ndim - 1) + [(0, n - b.shape[-1])])
-    return (a + b, [x + y for x, y in zip(ab, bb)])
+    return _combine([(*_pair(ar), 1), (*_pair(br), 1)])
 
 
 def rel_sub(ar, br, p: int):
-    """Relaxed borrow-free subtract: a + OFFSET(p, dominating b) - b, NO
-    normalize. The result is wider/looser; feed it to `mul_cols` (which takes
-    exact bounds) or normalize explicitly via `norm`."""
-    a, ab = ar if isinstance(ar, tuple) else rel(ar)
-    b, bb = br if isinstance(br, tuple) else rel(br)
-    off, ob = _dominator_offset(tuple(bb), p)
-    n = max(len(ab), len(ob))
-    v = jnp.zeros(jnp.broadcast_shapes(a.shape[:-1], b.shape[:-1]) + (n,),
-                  dtype=jnp.uint64)
-    v = v.at[..., :len(ab)].add(a)
-    v = v.at[..., :len(ob)].add(jnp.asarray(off))
-    v = v.at[..., :len(bb)].add(-b)   # u64 wrap-free: off dominates b
-    nb = [0] * n
-    for i, x in enumerate(ab):
-        nb[i] += x
-    for i, x in enumerate(ob):
-        nb[i] += x
-    return (v, nb)
+    """Relaxed subtract: a - b, signed, NO normalize. Feed it to `mul_cols`
+    (whose balanced digits take signed limbs as they are) or normalize
+    explicitly via `norm`. ``p`` is the formulas' calling convention: the
+    multiple of p that makes a value non-negative is added by its walk."""
+    return _combine([(*_pair(ar), 1), (*_pair(br), -1)])
 
 
 def norm(vr, p: int):
     """Normalize a relaxed (value, bounds) pair to a contract element."""
     v, nb = vr
-    return _normalize(v, list(nb), p)[0]
+    return _normalize(v, nb, p)[0]
 
 
 def mul_cols(ar, br):
     """Schoolbook product of relaxed pairs → raw (cols, bounds), NO
     normalize. Accepts plain arrays (contract bounds) or (v, bounds)."""
-    a, ab = ar if isinstance(ar, tuple) else rel(ar)
-    b, bb = br if isinstance(br, tuple) else rel(br)
-    a = _mul_operand(a, ab)
-    b = _mul_operand(b, bb)
-    na, nbw = len(ab), len(bb)
-    cols = jnp.zeros(jnp.broadcast_shapes(a.shape[:-1], b.shape[:-1])
-                     + (na + nbw - 1,), dtype=jnp.uint64)
-    for i in range(na):
-        cols = cols.at[..., i:i + nbw].add(a[..., i:i + 1] * b)
-    out = [0] * (na + nbw - 1)
-    for i, x in enumerate(ab):
-        for j, y in enumerate(bb):
-            out[i + j] += x * y
-    assert max(out) < (1 << 63), "u64 column overflow in fused schoolbook"
-    return (cols, out)
+    return _product(*_pair(ar), *_pair(br))
+
+
+def _scaled(v, nb, k: int):
+    assert k >= 0
+    out = [(l * k, h * k) for l, h in nb]
+    dt = _lanes(out)
+    return (v.astype(dt) * dt(k), out)
 
 
 def scale_rel(a, k: int, bounds=None):
     """Small-constant scale of a narrow element WITHOUT normalizing: returns
     a relaxed (value, bounds) pair for feeding rel_add/rel_sub/mul_cols."""
-    b = _CONTRACT if bounds is None else bounds
-    out = [x * k for x in b]
-    assert max(out) < (1 << 63)
-    return (_mul_operand(a, b) * jnp.uint64(k), out)
+    return _scaled(a, _CONTRACT if bounds is None else bounds, k)
 
 
 def scale_cols(cr, k: int):
     """Scale a raw (value, bounds) pair by a small host constant — folds a
     mul_const into an adjacent normalize for free."""
-    v, nb = cr
-    out = [b * k for b in nb]
-    assert max(out) < (1 << 63), "u64 column overflow in scale_cols"
-    return (_mul_operand(v, nb) * jnp.uint64(k), out)
+    return _scaled(*cr, k)
 
 
 _DOM_OFFSETS: dict = {}
@@ -476,75 +689,30 @@ def _dominator_offset(need: tuple, p: int):
         digits.append(int(extra))
     assert sum(d << (LIMB_BITS * i) for i, d in enumerate(digits)) == M * p
     assert all(d >= n for d, n in zip(digits, need))
-    out = (np.array(digits, dtype=np.uint64), digits)
+    out = (np.array(digits, dtype=np.int64), digits)
     _DOM_OFFSETS[key] = out
     return out
 
 
 def col_acc(p: int, plus=(), minus=()):
-    """Accumulate raw column products: sum(plus) - sum(minus) + dominator,
-    returning a relaxed (value, bounds) pair (normalize with `norm`).
-    Each entry is a (cols, bounds) pair from `mul_cols` (or a relaxed pair
-    from rel/rel_add — any (value, exact bounds))."""
-    neg_nb: list = []
-    for _, nb in minus:
-        if len(nb) > len(neg_nb):
-            neg_nb += [0] * (len(nb) - len(neg_nb))
-        for i, x in enumerate(nb):
-            neg_nb[i] += x
-    if minus:
-        off, ob = _dominator_offset(tuple(neg_nb), p)
-    else:
-        off, ob = None, []
-    width = max([len(nb) for _, nb in plus] + [len(ob)]
-                + [len(nb) for _, nb in minus])
-    shapes = [v.shape[:-1] for v, _ in list(plus) + list(minus)]
-    out = jnp.zeros(jnp.broadcast_shapes(*shapes) + (width,),
-                    dtype=jnp.uint64)
-    nb_out = [0] * width
-    for v, nb in plus:
-        out = out.at[..., :v.shape[-1]].add(v)
-        for i, x in enumerate(nb):
-            nb_out[i] += x
-    if off is not None:
-        out = out.at[..., :len(ob)].add(jnp.asarray(off))
-        for i, x in enumerate(ob):
-            nb_out[i] += x
-        for v, _ in minus:
-            out = out.at[..., :v.shape[-1]].add(-v)
-    assert max(nb_out) < (1 << 63), "u64 column overflow in col_acc"
-    return (out, nb_out)
+    """Accumulate raw column products: sum(plus) - sum(minus), returning a
+    relaxed (value, bounds) pair (normalize with `norm`). Each entry is a
+    (cols, bounds) pair from `mul_cols` (or a relaxed pair from
+    rel/rel_add — any (value, exact bounds))."""
+    return _combine([(v, nb, 1) for v, nb in plus]
+                    + [(v, nb, -1) for v, nb in minus])
 
 
 def raw_sqr_bounded(a, bounds):
-    """Triangular schoolbook square: col_k = 2·Σ_{i<j, i+j=k} a_i·a_j +
-    [k even]·a_{k/2}² — ~n(n+1)/2 column MACs instead of n² (the u64 lane
-    multiply dominates product cost, so squares run ~40% cheaper than
-    general products; `dbl`'s Y² / Z² and Fermat's square chain are the
-    beneficiaries). Bounds are identical to the general product's."""
-    n = len(bounds)
-    a = _mul_operand(a, bounds)
-    a2 = _mul_operand(a * jnp.uint64(2), [b * 2 for b in bounds])
-    cols = jnp.zeros(a.shape[:-1] + (2 * n - 1,), dtype=jnp.uint64)
-    # row i covers columns [2i, i+n): the diagonal a_i² then doubled cross
-    # terms a_i·2a_j (j > i) — CONTIGUOUS slice updates (a strided
-    # cols[0::2] diagonal scatter forces a relayout on TPU)
-    for i in range(n):
-        seg = jnp.concatenate([a[..., i:i + 1], a2[..., i + 1:]], axis=-1)
-        cols = cols.at[..., 2 * i: i + n].add(a[..., i:i + 1] * seg)
-    nb = [0] * (2 * n - 1)
-    for i, ab in enumerate(bounds):
-        for j, bb in enumerate(bounds):
-            nb[i + j] += ab * bb
-    assert max(nb) < (1 << 63), "u64 column overflow in squared schoolbook"
-    return cols, nb
+    """Triangular square with exact column bounds (see :func:`_square`).
+    Bounds are at least as tight as the general product's."""
+    return _square(a, bounds)
 
 
 def sqr_cols(ar):
     """Triangular square of a relaxed pair → raw (cols, bounds), NO
     normalize — the squared sibling of :func:`mul_cols`."""
-    a, ab = ar if isinstance(ar, tuple) else rel(ar)
-    return raw_sqr_bounded(a, ab)
+    return _square(*_pair(ar))
 
 
 def sqr(a, p: int):
@@ -552,59 +720,27 @@ def sqr(a, p: int):
     return _normalize(cols, nb, p)[0]
 
 
-_CONTRACT2 = [2 * c for c in _CONTRACT]
-
-
 def mul_of_sums(a1, a2, b1, b2, p: int):
     """(a1+a2)·(b1+b2) mod p without normalizing the sums: the adds' carry
-    passes are absorbed into the product's own normalize (2×-contract input
-    bounds keep every u64 column far under 2^63 — asserted exactly). Shaves
-    two normalize walks off the (X1+Y1)(X2+Y2)-style cross terms that
-    dominate complete-addition formulas."""
-    cols, nb = raw_mul_bounded(a1 + a2, b1 + b2, _CONTRACT2, _CONTRACT2)
-    return _normalize(cols, nb, p)[0]
+    passes are absorbed into the product's own normalize. Shaves two
+    normalize walks off the (X1+Y1)(X2+Y2)-style cross terms that dominate
+    complete-addition formulas."""
+    return norm(mul_cols(rel_add(a1, a2), rel_add(b1, b2)), p)
 
 
 def sqr_of_sum(a1, a2, p: int):
     """(a1+a2)² mod p without normalizing the sum."""
-    cols, nb = raw_sqr_bounded(a1 + a2, _CONTRACT2)
-    return _normalize(cols, nb, p)[0]
+    return norm(sqr_cols(rel_add(a1, a2)), p)
 
 
 def add(a, b, p: int):
-    nb = [x + y for x, y in zip(_CONTRACT, _CONTRACT)]
-    return _normalize(a + b, nb, p)[0]
-
-
-# 32p in a redundant limb encoding where limbs 0..15 each dominate the
-# contract bound, for borrow-free subtraction. 17 limbs total.
-def _offset_32p(p: int) -> np.ndarray:
-    base = to_limbs(32 * p, 17).astype(np.int64)
-    D = 1 << 17
-    base[0] += D
-    for i in range(1, 15):
-        base[i] += D - 2        # add dominator, repay 2 borrowed by limb i-1
-    base[15] += (1 << 18) - 2   # limb 15 dominates its 2^18 headroom
-    base[16] -= 4               # repay limb 15's dominator
-    out = base.astype(np.uint64)
-    assert all(int(out[i]) >= _CONTRACT[i] for i in range(NLIMB))
-    assert int(out[16]) >= 0
-    assert sum(int(v) << (LIMB_BITS * i) for i, v in enumerate(out)) == 32 * p
-    return out
-
-
-_OFFSETS = {p: _offset_32p(p) for p in _FOLD}
+    return norm(rel_add(a, b), p)
 
 
 def sub(a, b, p: int):
-    """a - b mod p via the borrow-free 32p offset (dominates contract limbs)."""
-    off = _OFFSETS[p]
-    t = jnp.zeros(jnp.broadcast_shapes(a.shape[:-1], b.shape[:-1]) + (NLIMB + 1,),
-                  dtype=jnp.uint64)
-    t = t.at[..., :NLIMB].add(a + jnp.asarray(off[:NLIMB]) - b)
-    t = t.at[..., NLIMB].add(jnp.uint64(off[NLIMB]))
-    nb = [cb + int(off[i]) for i, cb in enumerate(_CONTRACT)] + [int(off[16])]
-    return _normalize(t, nb, p)[0]
+    """a - b mod p: the signed difference, lifted by a dominating multiple
+    of p at the start of its walk."""
+    return norm(rel_sub(a, b, p), p)
 
 
 def neg(a, p: int):
@@ -612,7 +748,7 @@ def neg(a, p: int):
 
 
 # Bound on mul_const's scalar: limb bound (< 2^18) x constant must stay under
-# the u64 column capacity with headroom for the normalize walk.
+# the 64-bit column capacity with headroom for the normalize walk.
 MUL_CONST_MAX = 1 << 45
 
 
@@ -621,8 +757,7 @@ def mul_const(a, c: int, p: int):
     assert 0 <= c < MUL_CONST_MAX
     if c == 0:
         return jnp.zeros_like(a)
-    nb = [b * c for b in _CONTRACT]
-    return _normalize(_mul_operand(a, _CONTRACT) * jnp.uint64(c), nb, p)[0]
+    return norm(scale_rel(a, c), p)
 
 
 # ---------------------------------------------------------------------------

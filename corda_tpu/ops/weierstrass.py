@@ -96,9 +96,9 @@ def _add_k1(Pt, Qt, p: int, b3: int):
     Same mathematics as the a == 0 branch of :func:`add`, but products are
     kept as raw column accumulators (F.mul_cols) and every linear
     combination ±a·b ±c·d normalizes ONCE (F.col_acc + F.norm): ~10
-    normalize walks instead of ~22 for the same 12 schoolbook products —
-    the normalize walk is ~40% of a field mul, so this is the single
-    biggest per-add saving after the formula choice itself."""
+    normalize walks instead of ~22 for the same 12 schoolbook products
+    (measured when a walk was ~40% of a field multiply's time, before the
+    product moved to 32-bit lanes in PR 32)."""
     X1, Y1, Z1 = Pt
     X2, Y2, Z2 = Qt
     c0 = F.mul_cols(X1, X2)
@@ -643,9 +643,10 @@ def _q_window_table(Qc, Qd, curve: WeierstrassCurve):
     return T
 
 
-#: Default constant-G window width for the hybrid kernel. Measured on v5e
-#: at batch 32k (r4 kernel: affine u16 tables + mixed G adds + GLV 128):
-#: w=6 42.8k, w=8 45.4k verifies/s (medians of 5). The w=8 table is 2^18
+#: Default constant-G window width for the hybrid kernel: w = 8 measured 6%
+#: over w = 6 on v5e at batch 32k (r4 kernel: affine u16 tables + mixed G
+#: adds + GLV 128; the rates of that round are older than the tree, the
+#: cell's are in PERF.md). The w=8 table is 2^18
 #: affine u16 rows (~17MB baked constants) — 4x less gather footprint than
 #: the u64 projective layout that made w=8 a ~100MB non-starter in r3 —
 #: and 128 = 16x8 divides exactly: 128 dbls, 64 Q adds, 16 G adds.
@@ -1219,8 +1220,8 @@ def hybrid_ladder_wide(g_idx, q_bits, Qc, Qd, gtab, curve: WeierstrassCurve,
     joint Q digits (wc | wd<<2); ``gtab``: (tab_x, tab_y, tab_ok) arrays.
     """
     # (running the 15-deep select tree on u32-downcast table entries was
-    # measured FLAT — 50.2k vs 50.0k medians, within the noise band — so
-    # the tree stays on the native u64 limbs)
+    # measured FLAT, within the noise band, in an early round — so the
+    # tree stays on the u64 limbs the formulas take and return)
     table = _q_window_table(Qc, Qd, curve)
     tab_x, tab_y, tab_ok = gtab
     p = curve.p
@@ -1261,7 +1262,7 @@ def hybrid_ladder_wide(g_idx, q_bits, Qc, Qd, gtab, curve: WeierstrassCurve,
     acc = q_addend(qb0[0])
     acc, _ = jax.lax.scan(q_step, acc, qb0[1:])
     acc = g_add(acc, g_idx[0])
-    # unroll=2 measured SLOWER here (43.6k vs 44.9k/s on v5e): the wide
+    # unroll=2 measured 3% SLOWER here on v5e (an early round): the wide
     # step body is already 6 dbl + 4 adds — unrolling doubles an already
     # register-heavy body for nothing
     acc, _ = jax.lax.scan(step, acc, (g_idx[1:], q_bits[1:]))

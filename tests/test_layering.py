@@ -31,8 +31,10 @@ ALLOWED = {
     "samples": {"consensus", "core", "finance", "flows", "node", "testing"},
     "storage": {"utils"},
     "testing": {"client", "core", "flows", "network", "node", "utils"},
+    # ops, utils: tools/fieldsteps.py times the field layer's primitives and
+    # kernels on the chip and reads their optimised HLO without one (PR 32)
     "tools": {"client", "core", "finance", "flows", "node", "observability",
-              "testing"},
+              "ops", "testing", "utils"},
     "utils": {"observability"},
     "verifier": {"core", "network", "observability", "ops", "parallel",
                  "utils"},

@@ -218,10 +218,17 @@ def test_split_kernel_agrees_with_the_oracle_on_the_edge_corpus(width):
     assert got == [meant for *_, meant in rows]
 
 
-#: u64 limb multiplications a row in verify_core_split at the parent commit
-#: ed7d89b (PR 29), read once with this PR's counter (the same at 64 and at
-#: 8192 rows: the parent inverts per row)
-PARENT_FIELD_PRODUCTS = 490_535
+#: Limb multiplications a row in verify_core_split as it stood at ed7d89b
+#: (PR 29: an inversion a row, the joint table in extended form), BOTH SIDES
+#: COUNTED THE SAME WAY: that commit's ops/ed25519.py traced over today's
+#: ops/field.py with today's counter (every integer ``mul``, whatever its
+#: lanes). PR 30 pinned 490,535 here, u64 multiplies of a 16 x 16 schoolbook;
+#: since PR 32 a product is 16 x 16 balanced int32 digits plus two rows for
+#: the operands' top carries and the folds' constants (307 a product, 171
+#: a squaring), which moves the constant, not the ratio's meaning (today's
+#: kernel reads 475,015: 0.887 of it; the same at 64 and at 8192 rows: that
+#: kernel inverts per row).
+PARENT_FIELD_PRODUCTS = 535_769
 
 
 def test_the_split_kernel_spends_an_eighth_fewer_field_products():

@@ -105,6 +105,35 @@ def test_sharded_merkle_root_compiles_for_v5e_mesh(mesh4):
     assert "all-gather" in compiled.as_text()
 
 
+# -- tier-1: a field operation is a handful of program steps ---------------------
+
+#: Fusions (program steps) of the optimised v5e module, as PR 32 left them.
+#: On the chip the steps set a field operation's time, not its multiplies:
+#: the same product placed with ``.at[].add`` was 54 fusions and 5x slower
+#: (PERF.md, PR 32). Limits leave a third of room over today's counts.
+FIELD_STEPS = [
+    ("mul.k1", lambda a, b: F.mul(a, b, F.PSECP), 12, 16),
+    ("sqr.25519", lambda a, b: F.sqr(a, F.P25519), 25, 33),
+    ("sub.k1", lambda a, b: F.sub(a, b, F.PSECP), 3, 5),
+    ("mul.r1", lambda a, b: F.mul(a, b, F.PSECR1), 18, 24),
+    ("k1.dbl", lambda a, b: wc_ops.dbl((a, b, a), wc_ops.CURVES["secp256k1"]),
+     86, 115),
+]
+
+
+@pytest.mark.parametrize("name,fn,today,limit", FIELD_STEPS,
+                         ids=[row[0] for row in FIELD_STEPS])
+def test_a_field_operation_is_a_handful_of_program_steps(one_chip, name, fn,
+                                                         today, limit):
+    from corda_tpu.tools.fieldsteps import hlo_counts
+    el = jax.ShapeDtypeStruct((ROWS, F.NLIMB), jnp.uint64, sharding=one_chip)
+    counts = hlo_counts(_compile(jax.jit(fn), el, el).as_text())
+    print(name, counts)
+    assert counts["fusions"] <= limit, (name, counts, today)
+    # no slice update survives into the program: every shifted add is a pad
+    assert "dynamic-update-slice" not in counts and "scatter" not in counts
+
+
 # -- slow: the EC ladders at the bucket the smoke dispatches --------------------
 
 def _tile(base, n):
