@@ -1,17 +1,38 @@
 """Driver ``sigwaves``: closed-loop clients hand one verifier whole waves of
 signatures, one ``submit_group`` per wave, and wait for every verdict.
 
-Set-up signs a seeded pool of waves with the ``cryptography`` package (same
-bytes as the program's own signer by RFC 8032, which is pure Python at
-milliseconds a signature), corrupts 1 row in ``corrupt_every`` at seeded
-indices (flipped signature byte, another signer's key, altered message), builds
-ONE ``TpuTransactionVerifierService`` whose batcher takes ``batcher_args`` and
-nothing else, and runs one wave alone and then one round of the closed loop,
-unmeasured, so that every shape the window reaches has been seen (and is
-compiled once, not once per prep worker); then ``mark_warm()``. The window runs
-``clients`` threads for ``--seconds``; verdicts that return after it closes
-count for nothing. Afterwards every completed wave is compared row for row
-with the plain reference.
+What differs from one signature deployment to the next comes from its
+configuration: the scheme (``schemes``, one name of ``SCHEMES``), the seeded
+pool and the plain reference (``reference``). An Ed25519 pool is built here:
+signed with the ``cryptography`` package (same bytes as the program's own
+signer by RFC 8032, which is pure Python at milliseconds a signature), 1 row
+in ``corrupt_every`` corrupted at seeded indices (flipped signature byte,
+another signer's key, altered message). An ECDSA pool is ``ecdsa_pool``'s:
+compressed SEC1 party keys, DER signatures as the reference's signer emits
+them, about half with a high ``s``, five kinds of corrupted rows; for such a
+deployment set-up first hands ``Crypto.is_valid`` one high-s signature of
+that signer: a program that refuses it cannot run the deployment, and the run
+ends there with ``BenchError`` (exit 2), not 200 s later with half of every
+wave refused.
+
+Set-up then builds ONE ``TpuTransactionVerifierService`` whose batcher takes
+``batcher_args`` and nothing else, and runs one wave alone and then one round
+of the closed loop, unmeasured, so that every shape the window reaches has
+been seen (and is compiled once, not once per prep worker); then
+``mark_warm()``, and what set-up left on the heap (the pool: 65,536 rows, their
+keys) leaves the collector's reach (``gc.freeze()``), as ``latejoin`` does.
+The window runs ``clients`` threads for ``--seconds``. ``sigs_per_s`` is
+``bench_common.window_rate``'s, the one rule of every wave cell: the verdicts
+returned inside the window over the clock's window; verdicts that return
+after it closes count for nothing. Afterwards every completed wave is
+compared row for row with the plain reference.
+
+On top of that an ECDSA deployment checks that no row was prepared by the
+batcher's item-form fallback (``SigBatcher.EcdsaItemsPrep``, the pure-Python
+prep the program takes in silence when ``libscalarmath.so`` is missing or
+stale), and that the rows the prep refused before the kernel
+(``SigBatcher.EcdsaRefusedEncoding`` + ``EcdsaRefusedRange``) are the pool's
+rows with a padded DER integer or with ``s + n``, and no other.
 
 Controls (``--control``), each of which has to come out ``correct: false``:
 ``unchecked_rows`` puts the reference in the verifier's place with every other
@@ -19,21 +40,34 @@ row waved through.
 """
 from __future__ import annotations
 
+import gc
 import hashlib
+import importlib
+import pathlib
+import sys
 import threading
 import time
 
 import numpy as np
 
-from bench_common import check_device_path, nearest_rank
-from reference import genledger_ed25519 as ref  # benchmarks/reference/
+import ecdsa_pool
+from bench_common import (GcWatch, check_device_path, nearest_rank,
+                          window_rate)
+
+#: a configuration's scheme -> its name in ``corda_tpu.core.crypto.schemes``
+SCHEMES = {"ed25519": "EDDSA_ED25519_SHA512",
+           "secp256k1": "ECDSA_SECP256K1_SHA256",
+           "secp256r1": "ECDSA_SECP256R1_SHA256"}
+ECDSA_METERS = ("EcdsaWordsPrep", "EcdsaItemsPrep", "EcdsaRefusedEncoding",
+                "EcdsaRefusedRange")
 
 
 def build_pool(seed: int, waves: int, wave_size: int, n_keys: int,
                corrupt_every: int):
-    """``waves`` lists of ``wave_size`` (raw public key, signature, message)
-    rows, and per wave the set of corrupted row indices. Keys repeat as on a
-    ledger (``n_keys`` parties); every message is a fresh 32-byte id."""
+    """The Ed25519 pool: ``waves`` lists of ``wave_size`` (raw public key,
+    signature, message) rows, and per wave the set of corrupted row indices.
+    Keys repeat as on a ledger (``n_keys`` parties); every message is a fresh
+    32-byte id."""
     from cryptography.hazmat.primitives import serialization
     from cryptography.hazmat.primitives.asymmetric.ed25519 import \
         Ed25519PrivateKey
@@ -76,23 +110,64 @@ def pool_digest(pool) -> str:
     return h.hexdigest()
 
 
+def bench_error(ctx, message: str) -> Exception:
+    """The harness's ``BenchError`` (exit 2), from the module ``ctx`` is
+    of: the driver cannot import ``run``, which runs as ``__main__``."""
+    return sys.modules[type(ctx).__module__].BenchError(message)
+
+
+def load_reference(ctx):
+    """The configuration's plain reference (``reference/<name>.py``)."""
+    stem = pathlib.PurePosixPath(ctx.param("reference")).stem
+    return importlib.import_module(f"reference.{stem}")
+
+
+def high_s_probe(scheme: str):
+    """(compressed key, DER signature, message) by the deployment's signer,
+    with ``s > n / 2``: the first such among fixed messages of a fixed key."""
+    from cryptography.hazmat.primitives.asymmetric.utils import \
+        decode_dss_signature
+    (pub,) = ecdsa_pool.public_keys(scheme, [7])
+    msgs = [i.to_bytes(32, "big") for i in range(64)]
+    sigs = ecdsa_pool.sign_rows((scheme, [7], [0] * len(msgs), msgs))
+    for sig, msg in zip(sigs, msgs):
+        if decode_dss_signature(sig)[1] > ecdsa_pool.ORDERS[scheme] // 2:
+            return pub, sig, msg
+    raise AssertionError("no high-s signature in 64 tries")
+
+
+def refuse_unless_high_s_is_valid(ctx, curve: str, scheme) -> None:
+    from corda_tpu.core.crypto.keys import PublicKey
+    from corda_tpu.core.crypto.signatures import Crypto
+    try:
+        pub, sig, msg = high_s_probe(curve)
+    except ecdsa_pool.SignerUnavailable as e:
+        raise bench_error(ctx, str(e))
+    if not Crypto.is_valid(PublicKey(scheme, pub), sig, msg):
+        raise bench_error(
+            ctx, f"the program refuses a valid {curve} signature with "
+            f"s > n/2, which the deployment's signer emits for half its "
+            f"rows: {ctx.cell.config['name']} is not a deployment it can run")
+
+
 class ReferenceVerifier:
     """The control's stand-in for the service: same ``submit_group``
     surface, verdicts from ``ref.control_verdicts`` on the caller's thread."""
 
-    def __init__(self, raw_pool):
+    def __init__(self, ref, raw_pool):
+        self.ref = ref
         self.raw = {id(w): r for w, r in raw_pool}
 
     def submit_group(self, checks):
         from concurrent.futures import Future
         fut: Future = Future()
-        fut.set_result(ref.control_verdicts(self.raw[id(checks)]))
+        fut.set_result(self.ref.control_verdicts(self.raw[id(checks)]))
         return fut
 
 
 def run(ctx) -> dict:
+    from corda_tpu.core.crypto import schemes
     from corda_tpu.core.crypto.keys import PublicKey
-    from corda_tpu.core.crypto.schemes import EDDSA_ED25519_SHA512
     from corda_tpu.observability import (disable_tracing, enable_tracing,
                                          get_profiler, get_tracer)
     from corda_tpu.utils.metrics import MetricRegistry
@@ -100,20 +175,29 @@ def run(ctx) -> dict:
     from corda_tpu.verifier.service import TpuTransactionVerifierService
 
     p = ctx.param
+    (curve,) = p("schemes")
+    scheme = getattr(schemes, SCHEMES[curve])
+    ecdsa = curve in ecdsa_pool.ORDERS
+    ref = load_reference(ctx)
     clients = int(p("clients"))
     wave_size = int(p("wave_size"))
     n_waves = int(p("pool_waves"))
     timeout = float(p("wave_timeout_s", 1100.0))
+    if ecdsa:
+        refuse_unless_high_s_is_valid(ctx, curve, scheme)
     if ctx.trace:
         enable_tracing(int(p("trace_capacity", 65536)))
-    raw_pool, corrupted = build_pool(ctx.seed, n_waves, wave_size,
-                                     int(p("party_keys")),
-                                     int(p("corrupt_every")))
+    pool_args = (ctx.seed, n_waves, wave_size, int(p("party_keys")),
+                 int(p("corrupt_every")))
+    raw_pool, corrupted = ecdsa_pool.build_pool(*pool_args, curve) if ecdsa \
+        else build_pool(*pool_args)
     key_of: dict = {}
-    pool = [[(key_of.setdefault(pub, PublicKey(EDDSA_ED25519_SHA512, pub)),
-              sig, msg) for pub, sig, msg in rows] for rows in raw_pool]
-    ctx.say("pool", waves=n_waves, wave_size=wave_size,
-            corrupted_per_wave=len(corrupted[0]),
+    pool = [[(key_of.setdefault(pub, PublicKey(scheme, pub)), sig, msg)
+             for pub, sig, msg in rows] for rows in raw_pool]
+    about = {"high_s_share": ecdsa_pool.high_s_share(raw_pool, curve)} \
+        if ecdsa else {}
+    ctx.say("pool", scheme=curve, waves=n_waves, wave_size=wave_size,
+            corrupted_per_wave=len(corrupted[0]), **about,
             digest=pool_digest(raw_pool)[:16])
     registry = MetricRegistry()
     service = TpuTransactionVerifierService(
@@ -121,7 +205,7 @@ def run(ctx) -> dict:
         batcher=SignatureBatcher(metrics=registry,
                                  **dict(p("batcher_args"))))
     if ctx.control == "unchecked_rows":
-        target = ReferenceVerifier(list(zip(pool, raw_pool)))
+        target = ReferenceVerifier(ref, list(zip(pool, raw_pool)))
     elif ctx.control is None:
         target = service.batcher
     else:
@@ -134,6 +218,7 @@ def run(ctx) -> dict:
     lock = threading.Lock()
     stop = threading.Event()
     errors: list = []
+    gc_watch = GcWatch()
 
     def client(c: int, rounds: int | None) -> None:
         k = c * (n_waves // max(1, clients))
@@ -177,12 +262,19 @@ def run(ctx) -> dict:
         snap0 = registry.snapshot()
         size_hist = registry.histogram("verifier_batch_size")
         sizes0 = (size_hist.count, size_hist.total)
+        # the pool and the reference's rows live through the whole window:
+        # out of the collector's reach, so that a full collection walks the
+        # window's own garbage and not 65,536 rows each time
+        gc.collect()
+        gc.freeze()
+        gc_watch.start()
 
         ctx.window_opens()
-        t_open = time.perf_counter()
+        t_open, wall_open = time.perf_counter(), time.time()
         threads = run_clients(None)
         time.sleep(ctx.seconds)
-        t_close = time.perf_counter()
+        t_close, wall_close = time.perf_counter(), time.time()
+        collector = gc_watch.stop()
         snap1 = registry.snapshot()
         sizes1 = (size_hist.count, size_hist.total, size_hist.max_value)
         stop.set()
@@ -195,18 +287,19 @@ def run(ctx) -> dict:
                 spans.extend(trace_spans)
 
         window = done[warm_waves:]
-        inside = [d for d in window if d[2] <= t_close]
-        wave_s = sorted(d[2] - d[1] for d in inside)
-        e2e = {"sigs_per_s": len(inside) * wave_size / (t_close - t_open)}
-        ctx.say("window", waves_completed_inside=len(inside),
-                waves_finished_after=len(window) - len(inside),
-                window_s=t_close - t_open, sigs_per_s=e2e["sigs_per_s"],
+        rate = window_rate([d[2] - t_open for d in window], t_close - t_open,
+                           wave_size)
+        wave_s = sorted(d[2] - d[1] for d in window if d[2] <= t_close)
+        ctx.say("window", **rate,
                 wave_ms_p50=nearest_rank(wave_s, 0.5) * 1e3,
-                wave_ms_max=wave_s[-1] * 1e3 if wave_s else None)
+                wave_ms_max=wave_s[-1] * 1e3 if wave_s else None,
+                **collector)
 
         # every verdict of every wave against the plain reference
         t_ref = time.perf_counter()
-        valid = [ref.verdicts(rows) for rows in raw_pool]
+        valid = ecdsa_pool.parallel_map(f"{ref.__name__}:verdicts", raw_pool,
+                                        n_waves * wave_size) if ecdsa \
+            else [ref.verdicts(rows) for rows in raw_pool]
         known = sum(ok == (i in corrupted[w])
                     for w, oks in enumerate(valid) for i, ok in enumerate(oks))
         want = [np.packbits(np.asarray(oks, dtype=bool)) for oks in valid]
@@ -218,28 +311,43 @@ def run(ctx) -> dict:
                 waves_compared=len(done))
         ctx.check("client_errors", len(errors), 0)
         ctx.check("waves_completed_inside_window_missing",
-                  int(len(inside) == 0), 0)
+                  int(rate["waves_completed_inside"] == 0), 0)
         ctx.check("reference_disagrees_with_corrupted_set", known, 0)
         ctx.check("verdicts_differing_from_reference", mismatched, 0)
         n_batches = sizes1[0] - sizes0[0]
         rows_batched = sizes1[1] - sizes0[1]
         want_size = int(dict(p("batcher_args")).get("max_batch", wave_size))
         b = check_device_path(ctx, registry, service.batcher)
+        prep = {n: registry.meter(f"SigBatcher.{n}").count
+                for n in ECDSA_METERS} if ecdsa else {}
         if ctx.control is None:
             ctx.check("batches_not_of_the_pinned_size",
                       abs(rows_batched - n_batches * want_size)
                       + max(0.0, sizes1[2] - want_size), 0)
             ctx.check("host_routed_rows", b["HostRouted"],
                       int(p("host_routed_limit", 0)))
-        ctx.say("batcher", batches_in_window=n_batches, **b)
-        attempted = len(window)
-        return {"attempted": attempted, "failed": len(errors),
-                "end_to_end": e2e,
+        if ctx.control is None and ecdsa:
+            ctx.check("rows_prepared_by_the_item_form_fallback",
+                      prep["EcdsaItemsPrep"], 0)
+            # every wave handed over has returned by now (the clients are
+            # joined), and the meters count from the service's start
+            unparsable = sum(kind in ecdsa_pool.NO_WORDS for w, *_ in done
+                             for kind in corrupted[w].values())
+            ctx.check("rows_refused_before_the_kernel_beside_the_pools",
+                      abs(prep["EcdsaRefusedEncoding"]
+                          + prep["EcdsaRefusedRange"] - unparsable), 0)
+        ctx.say("batcher", batches_in_window=n_batches, **b, **prep)
+        return {"attempted": len(window), "failed": len(errors),
+                "end_to_end": {"sigs_per_s": rate["sigs_per_s"]},
                 "layer_data": {"snap0": snap0, "snap1": snap1, "spans": spans,
                                "samples": {"wave_s": wave_s},
+                               # the span readers take the window's spans
+                               "window_wall": (wall_open, wall_close),
                                "gap_prefixes": ("batcher.",)}}
     finally:
         stop.set()
+        gc_watch.stop()
+        gc.unfreeze()
         service.shutdown()
         if ctx.trace:
             disable_tracing()

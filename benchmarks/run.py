@@ -14,9 +14,10 @@ cell asks for. Everything that belongs to one cell is a file found by name:
 
 ``--trace 0`` prints the cell's end-to-end metrics; ``--trace 1`` turns on the
 program's tracer and a profiler trace of a short steady sub-window and prints
-the per-layer metrics. The last stdout line is the result object; everything
-else (sample counts, generator lateness, each number compared beside its
-limit) goes on earlier lines.
+the per-layer metrics. The last stdout line is the result object, whose last
+key ``checks`` holds each number compared beside its limit (they are also the
+last lines of standard error); everything else (sample counts, generator
+lateness, the same comparisons as they are made) goes on earlier lines.
 
 ``--control <name>`` puts a deliberately broken reference in the program's
 place (see the driver); such a run has to come out ``correct: false``. The
@@ -351,6 +352,19 @@ def sum_breakdowns(segments: list[dict]) -> dict:
 
 # -- one cell, once ------------------------------------------------------------
 
+def compared(checks: list[dict]) -> dict:
+    """``ctx.checks`` as the result line carries them: name -> [value,
+    limit, ok]; a name compared twice keeps both (``name#2``)."""
+    out: dict = {}
+    for c in checks:
+        name, n = c["check"], 1
+        while name in out:
+            n += 1
+            name = f"{c['check']}#{n}"
+        out[name] = [c["value"], c["limit"], c["ok"]]
+    return out
+
+
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
              device: dict, control: str | None = None,
              scale: dict | None = None, quiet: bool = False,
@@ -401,6 +415,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                      "clock_offset_known": s["clock_offset_known"]}
                     for s in segments], costs=ctx.trace_costs)
         result["device"] = dev
+        # last in the line: each number compared, beside its limit
+        result["checks"] = compared(ctx.checks)
         ctx.say("run", seconds_since_process_start=time.perf_counter()
                 - T_PROCESS_START)
         return result
@@ -437,7 +453,13 @@ def main(argv=None) -> int:
     except BenchError as e:
         print(f"benchmarks/run.py: {e}", file=sys.stderr, flush=True)
         return 2
-    print(json.dumps(result), flush=True)
+    print(json.dumps(result, default=str), flush=True)
+    # and as the last lines of standard error, where a run that is not
+    # correct is read first
+    for name, (value, limit, ok) in result["checks"].items():
+        print(f"{'ok  ' if ok else 'FAIL'} {name} = {value} (limit {limit})",
+              file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

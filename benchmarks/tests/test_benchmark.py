@@ -249,6 +249,122 @@ def test_wave_pool_is_byte_identical_for_a_seed():
         assert len({msg for _p, _s, msg in rows}) == len(rows)
 
 
+# -- the wave cells' one rate rule, on made-up completion times ------------------------
+
+def _steady(rate_hz, until, start=0.0):
+    """Completion seconds of a steady stream of waves, ``rate_hz`` a second
+    (each half way through its interval: none on a slice's edge)."""
+    n = int(round((until - start) * rate_hz))
+    return [start + (k + 0.5) / rate_hz for k in range(n)]
+
+
+RATE_CASES = {
+    # 10 waves a second for 30 s: every slice alike, the rule is the mean
+    "steady": (_steady(10, 30.0), 30.0,
+               dict(sigs_per_s=10 * 8192, inside=300, after=0, last=29.95,
+                    slices=[10 * 8192] * 6, median=10 * 8192)),
+    # the third slice is frozen: no verdict returns from 10 s to 15 s. The
+    # metric pays for the frozen seconds (all the time of the window); the
+    # median of slices, a note, does not
+    "one_frozen_slice": (_steady(10, 10.0) + _steady(10, 30.0, 15.0), 30.0,
+                         dict(sigs_per_s=250 * 8192 / 30.0, inside=250,
+                              after=0, last=29.95,
+                              slices=[81920, 81920, 0, 81920, 81920, 81920],
+                              median=81920)),
+    # a wave whose last verdict returns after the close counts for nothing,
+    # however little after
+    "a_wave_ends_after_the_close": ([1.0, 2.0, 29.5, 30.0, 30.0001, 31.0],
+                                    30.0,
+                                    dict(sigs_per_s=4 * 8192 / 30.0, inside=4,
+                                         after=2, last=30.0,
+                                         slices=[2 * 8192 / 5.0, 0, 0, 0, 0,
+                                                 2 * 8192 / 5.0],
+                                         median=0.0)),
+    # no wave inside: a rate of 0 (the drivers' check then fails the run)
+    "no_wave": ([], 30.0, dict(sigs_per_s=0.0, inside=0, after=0, last=0.0,
+                               slices=[0.0] * 6, median=0.0)),
+    "every_wave_late": ([30.5, 31.0], 30.0,
+                        dict(sigs_per_s=0.0, inside=0, after=2, last=0.0,
+                             slices=[0.0] * 6, median=0.0)),
+    # the clock's window is what the clock read, not --seconds
+    "a_window_the_clock_stretched": (_steady(10, 30.6), 30.6,
+                                     dict(sigs_per_s=306 * 8192 / 30.6,
+                                          inside=306, after=0, last=30.55,
+                                          slices=[51 * 8192 / 5.1] * 6,
+                                          median=51 * 8192 / 5.1)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RATE_CASES))
+def test_window_rate_on_made_up_completion_times(case):
+    from bench_common import window_rate
+    done_s, window_s, want = RATE_CASES[case]
+    got = window_rate(done_s, window_s, 8192)
+    assert got["sigs_per_s"] == pytest.approx(want["sigs_per_s"])
+    assert got["waves_completed_inside"] == want["inside"]
+    assert got["waves_finished_after"] == want["after"]
+    assert got["window_s"] == window_s
+    assert got["last_verdict_s"] == pytest.approx(want["last"])
+    assert got["slice_rates"] == pytest.approx(want["slices"])
+    assert got["rate_median_of_slices"] == pytest.approx(want["median"])
+    # order does not matter (four clients append as they finish)
+    assert window_rate(done_s[::-1], window_s, 8192) == got
+    if want["inside"]:
+        assert got["rate_to_last_verdict"] == pytest.approx(
+            want["inside"] * 8192 / want["last"])
+        # all the work over all the time: never above the rate to the last
+        # verdict, and under it by less than one wave's share
+        assert got["sigs_per_s"] <= got["rate_to_last_verdict"]
+    else:
+        assert got["rate_to_last_verdict"] == 0.0
+
+
+def test_a_stall_costs_the_metric_what_it_took():
+    """What the bound is about: the same stream with 2 s frozen reads
+    2 / 30 lower, in ``sigs_per_s`` and in no more than one slice."""
+    from bench_common import window_rate
+    sound = window_rate(_steady(18, 30.0), 30.0, 8192)
+    stalled = window_rate(_steady(18, 12.0) + _steady(18, 30.0, 14.0), 30.0,
+                          8192)
+    assert stalled["sigs_per_s"] / sound["sigs_per_s"] == pytest.approx(
+        28 / 30, abs=1e-3)
+    low = [a < b for a, b in zip(stalled["slice_rates"],
+                                 sound["slice_rates"])]
+    assert low == [False, False, True, False, False, False]
+    assert stalled["rate_median_of_slices"] == sound["rate_median_of_slices"]
+
+
+@pytest.mark.parametrize("workload", ["genledger-ed25519.wave8k",
+                                      "genledger-secp256k1.wave8k"])
+def test_both_wave_cells_resolve_to_the_one_driver_and_the_one_rule(workload):
+    import bench_common
+    cell = bench_run.Cell(workload, SPEC)
+    driver = load("drivers", cell.driver_name)
+    assert pathlib.Path(driver.run.__code__.co_filename) \
+        == BENCH / "drivers" / "sigwaves.py"
+    assert driver.run.__globals__["window_rate"] is bench_common.window_rate
+    # the rule is written once: no driver divides by the window itself
+    for name in ("sigwaves", "ecdsawaves"):
+        source = (BENCH / "drivers" / f"{name}.py").read_text()
+        assert "/ (t_close - t_open)" not in source
+    assert "sigs_per_s" in cell.end_to_end_names()
+
+
+def test_gc_watch_times_the_collections_between_start_and_stop():
+    import gc
+
+    from bench_common import GcWatch
+    watch = GcWatch().start()
+    gc.collect(0)
+    gc.collect(2)
+    seen = watch.stop()
+    assert watch not in gc.callbacks
+    assert seen["gc_collections"][0] >= 1 and seen["gc_collections"][2] >= 1
+    assert 0 < seen["gc_longest_ms"] / 1e3 <= seen["gc_s"]
+    gc.collect()
+    assert watch.stop() == seen          # stopped: counts no more, twice is fine
+
+
 # -- tiny-size CPU rehearsals: control flow only, no device metric printed ------------
 
 def rehearse(workload, scale, seconds, capsys, control=None, trace=False):
@@ -264,8 +380,13 @@ def rehearse(workload, scale, seconds, capsys, control=None, trace=False):
 def test_ledger_rehearsal(workload, capsys):
     result = rehearse(workload, LEDGER_TINY, 3.0, capsys)
     assert result["correct"] and result["attempted"] == 30
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "checks"]
+    # each number compared beside its limit, last in the line
+    assert result["checks"] and all(
+        ok for _value, _limit, ok in result["checks"].values())
+    assert json.loads(json.dumps(result, default=str))["checks"].keys() \
+        == result["checks"].keys()
     cell = bench_run.Cell(workload, SPEC)
     assert set(result["metrics"]) == set(cell.end_to_end_names())
 
@@ -323,6 +444,8 @@ def test_waves_rehearsal_control_and_broken_path(capsys, monkeypatch):
     sound = rehearse("genledger-ed25519.wave8k", WAVES_TINY, 2.0, capsys)
     assert sound["correct"] and set(sound["metrics"]) == {"sigs_per_s",
                                                           "setup_s"}
+    import gc
+    assert gc.get_freeze_count() == 0         # the driver thawed what it froze
     control = rehearse("genledger-ed25519.wave8k", WAVES_TINY, 1.0, capsys,
                        control="unchecked_rows")
     assert control["correct"] is False

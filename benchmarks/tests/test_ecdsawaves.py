@@ -169,7 +169,7 @@ def test_cost_function_counts_the_call_s_shapes():
     assert (wire // 8, table_row, g_idx.shape[0]) == (288, 65, 16)
     assert 288 + 1 + g_idx.shape[0] * table_row == per_row
     # the reader: bytes over the peak over the kernel's mean time
-    reader = bench_run.load_module("readers", "trace_roofline_share_from")
+    reader = bench_run.load_module("readers", "trace_roofline_share")
     args = dict(program="verify_core_hybrid_wide",
                 cost_module="kernel_cost_ecdsa", cost="secp256k1_hybrid",
                 rows_param="wave_size")
@@ -222,6 +222,42 @@ def test_k1_rehearsal_control_and_broken_path(capsys, monkeypatch):
     monkeypatch.setattr(SignatureBatcher, "_resolve", flipped)
     broken, _ = rehearse(1.0, capsys)
     assert broken["correct"] is False
+
+
+WINDOW_KEYS = {"note", "sigs_per_s", "waves_completed_inside",
+               "waves_finished_after", "window_s", "last_verdict_s",
+               "rate_to_last_verdict", "slice_rates", "rate_median_of_slices",
+               "wave_ms_p50", "wave_ms_max", "gc_s", "gc_longest_ms",
+               "gc_collections"}
+
+
+def test_both_wave_cells_print_the_same_window_line(capsys):
+    """One driver, one rule: the ``window`` line of the Ed25519 cell and of
+    this one carry the same keys, and each is ``window_rate``'s reading of
+    the run's own completion times."""
+    _result, notes = rehearse(1.0, capsys)
+    ed_notes: list = []
+    ed = bench_run.run_cell(
+        bench_run.Cell("genledger-ed25519.wave8k", SPEC), 3_000_000_023, 1.0,
+        False, CPU, quiet=True, notes=ed_notes,
+        scale={"wave_size": 16, "party_keys": 4, "corrupt_every": 4,
+               "batcher_args": {"max_batch": 16, "host_crossover": 0}})
+    assert capsys.readouterr().out == ""
+    for rows, result, wave in ((notes, _result, 8), (ed_notes, ed, 16)):
+        (w,) = [n for n in rows if n.get("note") == "window"]
+        assert set(w) == WINDOW_KEYS
+        assert result["metrics"]["sigs_per_s"]["value"] == w["sigs_per_s"] \
+            == pytest.approx(w["waves_completed_inside"] * wave / w["window_s"])
+        assert len(w["slice_rates"]) == 6
+        assert sum(w["slice_rates"]) * w["window_s"] / 6 == pytest.approx(
+            w["waves_completed_inside"] * wave)
+        assert sum(w["gc_collections"]) >= 0 and w["gc_s"] >= 0.0
+    (pool,) = [n for n in ed_notes if n.get("note") == "pool"]
+    assert pool["scheme"] == "ed25519" and "high_s_share" not in pool
+    assert not [c for c in ed["checks"] if "fallback" in c or "refused" in c]
+    assert {"rows_prepared_by_the_item_form_fallback",
+            "rows_refused_before_the_kernel_beside_the_pools"} \
+        <= set(_result["checks"])
 
 
 def test_k1_rehearsal_with_the_low_s_rule_back_is_not_correct(capsys,
