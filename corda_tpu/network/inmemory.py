@@ -150,6 +150,10 @@ class InMemoryMessaging(MessagingService):
     def remove_message_handler(self, reg: MessageHandlerRegistration) -> None:
         self._handlers.remove(reg)
 
+    def inbound_backlog(self) -> int:
+        # the message being delivered has left the queue (pump_receive)
+        return len(self._network._queues[self._name])
+
     def _deliver(self, transfer: MessageTransfer) -> None:
         handlers = self._handlers.matching(transfer.message)
         if not handlers:

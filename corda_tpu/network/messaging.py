@@ -85,6 +85,14 @@ class MessagingService:
     def my_address(self) -> str:
         raise NotImplementedError
 
+    def inbound_backlog(self) -> int:
+        """Messages this endpoint has received and not yet handed to a
+        handler: what stands BEHIND the message a handler is looking at. A
+        consumer that batches (the verifier worker) reads it to tell a lone
+        message from the head of a stream. A transport that cannot say
+        answers 0, and every message then looks lone."""
+        return 0
+
 
 class HandlerTable:
     """Thread-safe handler registry shared by transports."""

@@ -539,14 +539,32 @@ class SignatureBatcher:
         benchmarks). ``latency_class="interactive"`` puts the group on the
         short-deadline path (service.verify_signed uses it: one tx's few
         signatures are latency-bound, not throughput-bound)."""
-        group = _Group(len(checks))
-        pendings = [_Pending(key, sig, content, group=group, index=i)
+        return self.submit_groups([checks], None if ctx is None else [ctx],
+                                  latency_class)[0]
+
+    def submit_groups(self, groups, ctxs=None,
+                      latency_class: str = BULK) -> list[Future]:
+        """``submit_group`` for MANY groups in one lock round: one
+        verdict-list future per group, and the planner sees all their rows
+        at once. A feeder that has gathered a bucket's worth of small
+        groups (the out-of-process worker: 1-2 signatures a request) hands
+        them over whole, so the queue goes from empty to ``max_batch`` in
+        one step and the planner cuts a full bucket, where one enqueue a
+        group would wake it once a group. ``ctxs`` is a SpanContext per
+        group (None where a group is untraced)."""
+        futures, pendings = [], []
+        for g, checks in enumerate(groups):
+            group = _Group(len(checks))
+            mine = [_Pending(key, sig, content, group=group, index=i)
                     for i, (key, sig, content) in enumerate(checks)]
-        self._stamp_trace(pendings, ctx)
+            if ctxs is not None:
+                self._stamp_trace(mine, ctxs[g])
+            if not mine:
+                group.future.set_result([])
+            pendings.extend(mine)
+            futures.append(group.future)
         self._enqueue(pendings, latency_class)
-        if not pendings:
-            group.future.set_result([])
-        return group.future
+        return futures
 
     @staticmethod
     def _stamp_trace(pendings, ctx) -> None:
@@ -1066,6 +1084,7 @@ class SignatureBatcher:
             dspan.finish()
             self._resolve(bucket, items, self._run_host(items), bctx)
             return None
+        self._mark_device_flush(len(items), reason)
         if self.mesh is not None:
             breaker.record_success()
             self._mark_device(items)
@@ -1122,6 +1141,22 @@ class SignatureBatcher:
             self._breakers[bucket].record_failure()
             verdicts = self._run_host(items)
         self._resolve(bucket, items, verdicts, bctx)
+
+    def _mark_device_flush(self, rows: int, reason: str) -> None:
+        """One flush launched on the device route: its live rows
+        (``verifier_device_batch_rows``; ``verifier_batch_size`` holds the
+        host flushes too), why the planner cut it
+        (``SigBatcher.DeviceFlush.<reason>``) and the row count the kernels
+        pad it to (``SigBatcher.DevicePadded.<rows>``: each is a compiled
+        shape, so a name that first counts after warm-up is a compile in
+        the steady state; the mesh route pads by its own rule and is not
+        counted)."""
+        self.metrics.histogram("verifier_device_batch_rows").update(rows)
+        self.metrics.meter(f"SigBatcher.DeviceFlush.{reason}").mark()
+        if self.mesh is None:
+            from ..ops.field import bucket_size
+            self.metrics.meter(
+                f"SigBatcher.DevicePadded.{bucket_size(rows)}").mark()
 
     def _mark_device(self, items) -> None:
         self.metrics.meter("SigBatcher.DeviceBatches").mark()

@@ -28,6 +28,7 @@ from __future__ import annotations
 import itertools
 import json
 import logging
+import sys
 import threading
 import time
 from collections import deque
@@ -242,9 +243,14 @@ class VerifierRequestQueue:
         self._lock = threading.RLock()
         self._workers: list[str] = []
         self._rr = 0
-        self._pending: list[VerificationRequest] = []      # no worker yet
-        self._outstanding: dict[str, list[VerificationRequest]] = {}
+        self._pending: "deque[VerificationRequest]" = deque()  # no worker yet
+        # worker -> {vid: request}, in dealing order
+        self._outstanding: dict[str, dict[int, VerificationRequest]] = {}
         self._dealt_at: dict[int, tuple[str, float]] = {}  # vid -> (worker, t)
+        # worker -> weight dealt to it since its last load report arrived
+        # and still outstanding (the router's estimate, kept as it changes:
+        # a requestor holds thousands of requests outstanding)
+        self._dealt_since: dict[str, int] = {}
         self._last_activity: dict[str, float] = {}         # worker -> t
         # fleet state: per-worker shard/capacity from the hello, latest load
         # report (+ node arrival time), last-dealt scheme bucket (affinity),
@@ -277,7 +283,7 @@ class VerifierRequestQueue:
             with self._lock:
                 if payload.worker_address not in self._workers:
                     self._workers.append(payload.worker_address)
-                    self._outstanding.setdefault(payload.worker_address, [])
+                    self._outstanding.setdefault(payload.worker_address, {})
                 self._last_activity[payload.worker_address] = time.monotonic()
                 self._shards[payload.worker_address] = \
                     tuple(payload.device_shard)
@@ -319,13 +325,14 @@ class VerifierRequestQueue:
         with self._lock:
             if worker in self._workers:
                 self._workers.remove(worker)
-            held = self._outstanding.pop(worker, [])
+            held = list(self._outstanding.pop(worker, {}).values())
+            self._dealt_since.pop(worker, None)
             for req in held:
                 self._dealt_at.pop(req.verification_id, None)
             if held:
                 log.info("requeueing %d verifications from dead worker %s",
                          len(held), worker)
-            self._pending = held + self._pending
+            self._pending.extendleft(reversed(held))
             self._reports.pop(worker, None)
             self._capacity.pop(worker, None)
             self._shards.pop(worker, None)
@@ -348,6 +355,7 @@ class VerifierRequestQueue:
                 return   # detached (or never attached): its re-hello re-joins
             now = time.monotonic()
             self._reports[worker] = (report, now)
+            self._dealt_since[worker] = 0    # the report accounts for them
             self._last_activity[worker] = now
             if report.capacity:
                 self._capacity[worker] = max(1, int(report.capacity))
@@ -384,11 +392,9 @@ class VerifierRequestQueue:
                                                (None, 0.0))
                 if owner != victim or still_held is None:
                     continue
-                del self._dealt_at[req.verification_id]
-                still_held[:] = [r for r in still_held
-                                 if r.verification_id != req.verification_id]
+                self._uncharge_locked(victim, req.verification_id)
                 requeued.append(req)
-            self._pending = requeued + self._pending
+            self._pending.extendleft(reversed(requeued))
         if requeued:
             self.metrics.meter("Fleet.Stolen").mark(len(requeued))
             tracer = get_tracer()
@@ -460,15 +466,23 @@ class VerifierRequestQueue:
         report already accounts for earlier deals). No report yet → the
         full outstanding weight."""
         rep = self._reports.get(worker)
-        if rep is None:
-            base, since = 0, 0.0
-        else:
-            report, t_rep = rep
-            base, since = report.pending + report.in_flight, t_rep
-        dealt = sum(_weight(r) for r in self._outstanding.get(worker, ())
-                    if self._dealt_at.get(r.verification_id,
-                                          (None, 0.0))[1] > since)
-        return (base + dealt) / max(1, self._capacity.get(worker, 1))
+        base = 0 if rep is None else rep[0].pending + rep[0].in_flight
+        return (base + self._dealt_since.get(worker, 0)) \
+            / max(1, self._capacity.get(worker, 1))
+
+    def _uncharge_locked(self, worker: str, verification_id: int
+                         ) -> VerificationRequest | None:
+        """Take one request off ``worker``'s books (CALLER HOLDS THE
+        LOCK): out of ``_dealt_at`` and ``_outstanding``, and out of the
+        weight dealt since the worker's last report if it was dealt after
+        it."""
+        _w, dealt_t = self._dealt_at.pop(verification_id, (None, 0.0))
+        req = self._outstanding.get(worker, {}).pop(verification_id, None)
+        rep = self._reports.get(worker)
+        if req is not None and (rep is None or dealt_t > rep[1]):
+            self._dealt_since[worker] = max(
+                0, self._dealt_since.get(worker, 0) - _weight(req))
+        return req
 
     def _service_rate_ref_locked(self) -> float | None:
         """Median of the known per-worker service-rate EWMAs — the
@@ -570,16 +584,13 @@ class VerifierRequestQueue:
         service-rate EWMA (signatures completed per second between
         consecutive acknowledges) — the predictive-routing signal."""
         with self._lock:
-            worker, _ = self._dealt_at.pop(verification_id, (None, 0.0))
+            worker, _ = self._dealt_at.get(verification_id, (None, 0.0))
             if worker is None:
                 return None
             now = time.monotonic()
             self._last_activity[worker] = now
-            held = self._outstanding.get(worker, [])
-            weight = next((_weight(r) for r in held
-                           if r.verification_id == verification_id), 1)
-            self._outstanding[worker] = [
-                r for r in held if r.verification_id != verification_id]
+            req = self._uncharge_locked(worker, verification_id)
+            weight = _weight(req) if req is not None else 1
             prev_t = self._last_ack.get(worker)
             self._last_ack[worker] = now
             if prev_t is not None:
@@ -602,12 +613,14 @@ class VerifierRequestQueue:
             with self._lock:
                 if not self._pending or not self._workers:
                     return
-                req = self._pending.pop(0)
+                req = self._pending.popleft()
                 worker, reason, loads = self._pick_worker_locked(
                     req, time.monotonic())
-                self._outstanding[worker].append(req)
+                self._outstanding[worker][req.verification_id] = req
                 self._dealt_at[req.verification_id] = (worker,
                                                        time.monotonic())
+                self._dealt_since[worker] = \
+                    self._dealt_since.get(worker, 0) + _weight(req)
             self.request_log.append(req.verification_id, "routed",
                                     trace=req.trace or None, worker=worker,
                                     reason=reason, est_load=loads)
@@ -916,18 +929,79 @@ class VerifierWorker:
 
     Device path (VERDICT r2 #1): requests carrying ``signatures`` run their
     EC checks through this worker's ``SignatureBatcher`` — the message
-    handler parks them on a STEALABLE BACKLOG and a feeder admits at most
-    ``max_inflight_groups`` groups into the batcher at a time, so
-    consecutive requests' signatures still coalesce into one device batch
-    while everything beyond the in-flight window stays reclaimable: a
-    StealRequest pops the backlog's tail (LIFO — the feeder drains the
-    head) and hands it back to the node for re-dealing. The default
-    ``max_inflight_groups=None`` disables the holdback (everything goes
-    straight to the batcher, preserving the pre-fleet batch shapes and
-    their compile-cache hits); fleet deployments set a finite window so a
-    straggler keeps a stealable tail. Requests without signatures keep the
-    reference's synchronous host semantics (deterministic for the
-    manually-pumped test bus)."""
+    handler parks them on a STEALABLE BACKLOG and a feeder admits them into
+    the batcher in BURSTS, each one ``submit_groups`` call and one
+    completion task. A StealRequest pops the backlog's tail (LIFO — the
+    feeder drains the head) and hands it back to the node for re-dealing.
+
+    What the feeder admits, and when. A request carries 1-2 signatures, a
+    device bucket holds ``batcher.max_batch``; handed over one at a time
+    the batcher host-routes them as they come (its queue never reaches
+    ``host_crossover``) or cuts whatever its linger gathered from the first
+    row on. The worker knows what the batcher cannot: whether a request is
+    alone (its backlog, its in-flight groups, the frames its transport
+    holds, ``MessagingService.inbound_backlog``) and when the last one came.
+    So, with the default ``max_inflight_groups=None``:
+
+    - a LONE request (nothing parked before it, no group in flight, no
+      frame behind it in the transport) is admitted at once: today's short
+      path, no linger;
+    - a full bucket of parked signatures is admitted at once, whole;
+    - anything else is part of a stream and stays parked, stealable, while
+      requests keep coming: it is admitted, all of it, once the stream has
+      PAUSED: no request has come for ``PAUSE_SWITCHES`` switch intervals of
+      the interpreter (50 ms as Python ships; the batcher's
+      ``max_latency_s`` where that is longer), the transport holds none, and
+      every request admitted earlier has been answered (a requestor that
+      keeps a window outstanding sends its next requests when it has the
+      answers: while some are due the stream has not paused, and what is
+      parked goes with what they bring, one larger flush and not two small).
+      The pause is the worker's own measure and not the batcher's linger: a
+      stream of 6,000 signatures a second needs 32 ms to reach
+      ``host_crossover`` and 1.4 s to fill a bucket of 8,192, so rows that
+      keep coming ARE the company, however long ago the first one came; and
+      a peer written in Python sends once a turn of its loop thread, which
+      waits up to a switch interval for the interpreter lock, as this
+      process's reader does, so gaps of a few intervals are scheduling and
+      say nothing of the peer. A thread that lives while something is parked
+      watches for the pause; an observation it makes after it was itself
+      kept from running (a collection, a long burst) is void, because the
+      threads that read and decode were kept from running too.
+
+    Back-pressure: while the worker holds ``HELD_BUCKETS`` buckets' worth of
+    signatures (parked and admitted-and-unanswered together) it takes no
+    further request from its transport. One bucket being answered and one
+    filling is all a worker can use; what stands behind them waits where it
+    is cheapest, as frames in the transport (the reference's consumer takes
+    one message at a time and leaves the rest in the broker's queue). Without
+    the bound a worker that falls behind its requestor for a moment admits a
+    second and a third bucket while the first is still being answered; the
+    passes that answer them share one interpreter lock, so each takes as
+    many times longer as there are of them, everything outstanding stands
+    decoded in memory for that long, the collector walks it, and the worker
+    stays behind (measured: PERF.md section 6, PR 37).
+
+    A finite ``max_inflight_groups`` (fleet deployments, so that a
+    straggler keeps a stealable tail) admits head-first one group at a time
+    while the window has room, as before. Requests without signatures keep
+    the reference's synchronous host semantics (deterministic for the
+    manually-pumped test bus).
+
+    Observability (docs/OBSERVABILITY.md): meters ``Verifier.RequestsIn`` /
+    ``BytesIn`` / ``ResponsesOut`` on ``metrics`` (the batcher's registry
+    when one is passed), and with the process tracer on the spans
+    ``worker.decode`` (a request), ``worker.backlog_wait``,
+    ``worker.device_dispatch``, ``worker.host_verify`` and ``worker.reply``
+    (a burst each), recorded locally whether or not the requestor traces;
+    a request that arrives with a trace context still gets its own span
+    dicts shipped back on the reply."""
+
+    #: switch intervals of the interpreter without a request before the
+    #: stream counts as paused (the class docstring has why)
+    PAUSE_SWITCHES = 10
+    #: buckets' worth of signatures held (parked + admitted and unanswered)
+    #: at which the worker stops taking requests from its transport
+    HELD_BUCKETS = 2
 
     def __init__(self, network_service, queue_address: str,
                  batcher=None, use_device: bool = True, pool_workers: int = 4,
@@ -947,23 +1021,34 @@ class VerifierWorker:
                          else max(1, len(self.device_shard)))
         self.max_inflight_groups = max_inflight_groups
         self._backlog: "deque[VerificationRequest]" = deque()
-        self._backlog_lock = threading.Lock()
-        # trace stitching state (only populated for requests that ARRIVE
-        # carrying a trace context, i.e. node tracing is on): arrival wall
-        # time per vid feeds the backlog-wait span; the outbox holds
-        # finished spans with no reply to ride (worker.stolen), drained
-        # onto the next load report
+        self._backlog_sigs = 0          # signatures parked on the backlog
+        self._backlog_lock = threading.Condition()
+        # arrival wall time per parked vid (kept while the process tracer
+        # is on, or for a request that arrived carrying a trace context):
+        # feeds the backlog-wait spans; the outbox holds finished spans
+        # with no reply to ride (worker.stolen), drained onto the next
+        # load report
         self._arrival: dict[int, float] = {}
         self._span_outbox: "deque[dict]" = deque(maxlen=512)
+        self._last_arrival = 0.0        # monotonic, of the newest parked
+        self._linger_thread = None      # alive while something is parked
         self._inflight_groups = 0
         self._inflight_sigs = 0
         self._report_enabled = load_report_interval_s is not None
         self._batcher = batcher            # created lazily if None
-        self._pool = None
+        self.metrics = batcher.metrics if batcher is not None \
+            else MetricRegistry()
+        self._requests_in = self.metrics.meter("Verifier.RequestsIn")
+        self._bytes_in = self.metrics.meter("Verifier.BytesIn")
+        self._responses_out = self.metrics.meter("Verifier.ResponsesOut")
+        # completion tasks (one per admitted burst); threads start on the
+        # first submit
+        from concurrent.futures import ThreadPoolExecutor
+        self._pool = ThreadPoolExecutor(
+            max_workers=pool_workers, thread_name_prefix="verifier-worker")
         self._registration = network_service.add_message_handler(
             TopicSession(TOPIC_VERIFIER_REQUESTS), self._on_request)
         self._alive = True
-        self._pool_workers = pool_workers
         self._hello()
         if hello_interval_s is not None:
             # periodic re-attach (consumer keep-alive): a worker the queue
@@ -1052,35 +1137,64 @@ class VerifierWorker:
     def batcher(self):
         if self._batcher is None:
             from .batcher import SignatureBatcher
-            self._batcher = SignatureBatcher(use_device=self.use_device)
+            self._batcher = SignatureBatcher(use_device=self.use_device,
+                                             metrics=self.metrics)
         return self._batcher
 
     def _on_request(self, msg) -> None:
         if not self._alive:
             return
+        tracer = get_tracer()
+        t_wall, t0 = time.time(), time.perf_counter()
         payload = deserialize(msg.data)
         if isinstance(payload, StealRequest):
             self._on_steal(payload)
             return
         req: VerificationRequest = payload
+        self._requests_in.mark()
+        self._bytes_in.mark(len(msg.data))
+        if tracer.enabled:
+            tracer.record("worker.decode", parent=tuple(req.trace) or None,
+                          start_s=t_wall,
+                          duration_s=time.perf_counter() - t0,
+                          bytes=len(msg.data), n_sigs=len(req.signatures),
+                          **self._span_tags())
         if not req.signatures:
+            h_wall, h0 = time.time(), time.perf_counter()
+            error = self._verify_host(req)
+            took = time.perf_counter() - h0
+            spans: tuple = ()
             if req.trace:
-                t0_wall, t0 = time.time(), time.perf_counter()
-                error = self._verify_host(req)
-                span = make_span_dict(
-                    "worker.host_verify", tuple(req.trace), t0_wall,
-                    time.perf_counter() - t0, **self._span_tags())
-                self._reply(req, error, spans=(span,))
-            else:
-                self._reply(req, self._verify_host(req))
+                spans = (make_span_dict(
+                    "worker.host_verify", tuple(req.trace), h_wall, took,
+                    **self._span_tags()),)
+            if tracer.enabled:
+                tracer.record("worker.host_verify",
+                              parent=tuple(req.trace) or None,
+                              start_s=h_wall, duration_s=took, n_requests=1,
+                              **self._span_tags())
+            self._reply_all([(req, error, spans)])
             return
-        # device path: park on the stealable backlog; the feeder admits up
-        # to max_inflight_groups into the batcher (non-blocking)
+        # device path: park on the stealable backlog; the feeder admits
+        # bursts into the batcher (non-blocking)
         with self._backlog_lock:
             self._backlog.append(req)
-            if req.trace:
-                self._arrival[req.verification_id] = time.time()
+            self._backlog_sigs += len(req.signatures)
+            self._last_arrival = time.monotonic()
+            if req.trace or tracer.enabled:
+                self._arrival[req.verification_id] = t_wall
         self._feed()
+        if self.max_inflight_groups is None:
+            # back-pressure: the transport's thread stays here, and the
+            # frames behind this one in the transport, while HELD_BUCKETS
+            # are held. A full parked bucket was admitted just above, so
+            # what is held is mostly in flight and is answered without this
+            # thread; the timeout only re-reads ``_alive``
+            with self._backlog_lock:
+                while self._alive and (
+                        self._backlog_sigs + self._inflight_sigs
+                        >= self.HELD_BUCKETS * self.batcher.max_batch):
+                    self._backlog_lock.wait(timeout=1.0)
 
     def _span_tags(self) -> dict:
         """Identity tags every worker-side span carries."""
@@ -1089,31 +1203,111 @@ class VerifierWorker:
             tags["device_shard"] = list(self.device_shard)
         return tags
 
-    def _feed(self) -> None:
-        """Admit backlog head-first into the batcher while the in-flight
-        window has room. Everything still on the backlog is stealable.
+    def _next_burst_locked(self, stalled: bool = False) -> list:
+        """The requests to admit now, taken off the backlog's head, or []
+        (CALLER HOLDS THE BACKLOG LOCK). The class docstring has the rule;
+        ``stalled`` is the linger thread's call."""
+        if not self._backlog:
+            return []
+        bucket = None
+        if self.max_inflight_groups is not None:
+            # finite window: one group at a time while it has room
+            if self._inflight_groups >= self.max_inflight_groups:
+                return []
+            bucket = 1
+        elif self._backlog_sigs >= self.batcher.max_batch:
+            bucket = self.batcher.max_batch
+        elif not stalled and (len(self._backlog) > 1
+                              or self._inflight_groups
+                              or self.network_service.inbound_backlog()):
+            if self._linger_thread is None:
+                self._linger_thread = threading.Thread(
+                    target=self._linger, daemon=True,
+                    name="verifier-linger")
+                self._linger_thread.start()
+            return []
+        burst, n_sigs = [], 0
+        while self._backlog and (bucket is None or n_sigs < bucket):
+            req = self._backlog.popleft()
+            burst.append(req)
+            n_sigs += len(req.signatures)
+        self._backlog_sigs -= n_sigs
+        self._inflight_groups += len(burst)
+        self._inflight_sigs += n_sigs
+        return burst
 
-        Traced requests grow a per-request span accumulator here: the
-        backlog-wait span closes on admission, a device-dispatch span opens
-        (its context handed to the batcher so in-process batcher spans nest
-        under it), and _complete_device finishes + ships the lot."""
+    def _pause_s(self) -> float:
+        """How long no request must have come for the stream to count as
+        paused (the class docstring has the rule)."""
+        return max(self.batcher.max_latency_s,
+                   self.PAUSE_SWITCHES * sys.getswitchinterval())
+
+    def _linger(self) -> None:
+        """Admit what is parked once the stream has paused; ends when
+        nothing is parked."""
+        due = None      # when this thread meant to look next
+        while self._alive:
+            now = time.monotonic()
+            with self._backlog_lock:
+                if not self._backlog:
+                    self._linger_thread = None
+                    return
+                pause = self._pause_s()
+                wait = self._last_arrival + pause - now
+                answering = self._inflight_groups
+            if wait <= 0 and (
+                    answering
+                    or (due is not None and now - due > pause / 2)
+                    or self.network_service.inbound_backlog()):
+                # not a pause of the stream: requests admitted earlier are
+                # unanswered yet, and a requestor that keeps a window
+                # outstanding sends its next ones when it has the answers
+                # (what is parked meanwhile joins them: a partial bucket
+                # behind a partial bucket is two small flushes where one
+                # larger would do); or this thread woke late, so the
+                # threads that read and decode were kept from running as
+                # well (a collection, another thread's burst); or requests
+                # ARE coming, the transport holds them. Look again in a
+                # moment
+                wait = pause / 5
+            if wait > 0:
+                due = now + wait
+                time.sleep(wait)
+            else:
+                due = None
+                self._feed(stalled=True)
+
+    def _feed(self, stalled: bool = False) -> None:
+        """Admit bursts off the backlog's head while the rule allows one.
+        Everything still on the backlog is stealable."""
         while True:
             with self._backlog_lock:
-                if (not self._backlog
-                        or (self.max_inflight_groups is not None
-                            and self._inflight_groups
-                            >= self.max_inflight_groups)):
-                    return
-                req = self._backlog.popleft()
-                self._inflight_groups += 1
-                self._inflight_sigs += len(req.signatures)
-                arrived = self._arrival.pop(req.verification_id, None) \
-                    if req.trace else None
-            rt = None
-            ctx = None
-            if req.trace:
-                now_wall = time.time()
-                rt = {"spans": [], "t0": time.perf_counter()}
+                burst = self._next_burst_locked(stalled)
+                arrivals = [self._arrival.pop(r.verification_id, None)
+                            for r in burst] if self._arrival else None
+            if not burst:
+                return
+            self._admit(burst, arrivals)
+
+    def _admit(self, burst: list, arrivals) -> None:
+        """One burst into the batcher: one ``submit_groups`` call, one
+        completion task. A traced request grows its span accumulator here
+        (the backlog-wait span closes, a device-dispatch span opens whose
+        context the batcher's spans nest under); with the process tracer
+        on, the burst's own ``worker.backlog_wait`` is recorded from its
+        OLDEST request's arrival."""
+        tracer = get_tracer()
+        now_wall, t0 = time.time(), time.perf_counter()
+        n_sigs = sum(len(r.signatures) for r in burst)
+        rts: list = [None] * len(burst)
+        ctxs = None
+        if any(r.trace for r in burst):
+            ctxs = [None] * len(burst)
+            for i, req in enumerate(burst):
+                if not req.trace:
+                    continue
+                rt = rts[i] = {"spans": [], "t0": t0}
+                arrived = arrivals[i] if arrivals is not None else None
                 if arrived is not None:
                     rt["spans"].append(make_span_dict(
                         "worker.backlog_wait", tuple(req.trace), arrived,
@@ -1121,23 +1315,28 @@ class VerifierWorker:
                 rt["dispatch"] = make_span_dict(
                     "worker.device_dispatch", tuple(req.trace), now_wall,
                     0.0, n_sigs=len(req.signatures), **self._span_tags())
-                ctx = (rt["dispatch"]["trace_id"],
-                       rt["dispatch"]["span_id"])
-            try:
-                group_future = self.batcher.submit_group(req.signatures,
-                                                         ctx=ctx)
-            except Exception as e:
-                with self._backlog_lock:
-                    self._inflight_groups -= 1
-                    self._inflight_sigs -= len(req.signatures)
-                self._reply(req, str(e))
-                continue
-            if self._pool is None:
-                from concurrent.futures import ThreadPoolExecutor
-                self._pool = ThreadPoolExecutor(
-                    max_workers=self._pool_workers,
-                    thread_name_prefix="verifier-worker")
-            self._pool.submit(self._complete_device, req, group_future, rt)
+                ctxs[i] = (rt["dispatch"]["trace_id"],
+                           rt["dispatch"]["span_id"])
+        try:
+            futures = self.batcher.submit_groups(
+                [r.signatures for r in burst], ctxs)
+        except Exception as e:
+            with self._backlog_lock:
+                self._inflight_groups -= len(burst)
+                self._inflight_sigs -= n_sigs
+                self._backlog_lock.notify_all()
+            self._reply_all([(req, str(e), ()) for req in burst])
+            return
+        local = None
+        if tracer.enabled:
+            local = dict(self._span_tags(), n_requests=len(burst),
+                         n_sigs=n_sigs)
+            first = min((a for a in arrivals or () if a is not None),
+                        default=now_wall)
+            tracer.record("worker.backlog_wait", start_s=first,
+                          duration_s=now_wall - first, **local)
+        self._pool.submit(self._complete_burst, burst, futures, rts,
+                          (now_wall, t0, n_sigs, local))
 
     def _on_steal(self, steal: StealRequest) -> None:
         """Hand the backlog's TAIL back to the node (the feeder eats the
@@ -1150,6 +1349,7 @@ class VerifierWorker:
             limit = min(steal.max_items, (len(self._backlog) + 1) // 2)
             for _ in range(limit):
                 taken.append(self._backlog.pop())
+            self._backlog_sigs -= sum(len(r.signatures) for r in taken)
             arrivals = {r.verification_id:
                         self._arrival.pop(r.verification_id, now_wall)
                         for r in taken if r.trace}
@@ -1165,6 +1365,7 @@ class VerifierWorker:
             # still charged to us, so the node's detach path re-deals them
             with self._backlog_lock:
                 self._backlog.extendleft(reversed(taken))
+                self._backlog_sigs += sum(len(r.signatures) for r in taken)
                 for vid, t in arrivals.items():
                     self._arrival[vid] = t
             log.warning("returning stolen work to %s failed",
@@ -1192,40 +1393,62 @@ class VerifierWorker:
         except Exception as e:
             return str(e)
 
-    def _complete_device(self, req: VerificationRequest,
-                         group_future, rt=None) -> None:
-        error = None
-        try:
-            verdicts = group_future.result()
+    def _complete_burst(self, burst: list, futures: list, rts: list,
+                        admitted: tuple) -> None:
+        """One admitted burst, on a pool thread, in three passes: wait for
+        every group's verdicts, run the host rules of the requests whose
+        signatures all verified, reply to each. A pass is one contiguous
+        interval, so each is one span of the process tracer."""
+        tracer = get_tracer()
+        now_wall, t0, n_sigs, local = admitted
+        verdicts = []
+        for fut in futures:
+            try:
+                verdicts.append(fut.result())
+            except Exception as e:
+                verdicts.append(e)
+        t_back = time.perf_counter()
+        if local is not None:
+            tracer.record("worker.device_dispatch", start_s=now_wall,
+                          duration_s=t_back - t0, **local)
+        h_wall = time.time()
+        replies = []
+        for req, got, rt in zip(burst, verdicts, rts):
+            error = None
+            if isinstance(got, Exception):
+                error = str(got)
+            else:
+                for (key, _sig, _content), ok in zip(req.signatures, got):
+                    if not ok:
+                        error = (f"Signature by {key.to_string_short()} "
+                                 f"did not verify")
+                        break
             if rt is not None:
-                self._finish_dispatch_span(rt)
-            for (key, _sig, _content), ok in zip(req.signatures, verdicts):
-                if not ok:
-                    error = (f"Signature by {key.to_string_short()} did not "
-                             f"verify")
-                    break
+                self._finish_dispatch_span(
+                    rt, t_back, error if isinstance(got, Exception) else None)
             if error is None:
                 if rt is not None:
-                    h_wall, h0 = time.time(), time.perf_counter()
+                    r_wall, r0 = time.time(), time.perf_counter()
                     error = self._verify_host(req)
                     rt["spans"].append(make_span_dict(
-                        "worker.host_verify", tuple(req.trace), h_wall,
-                        time.perf_counter() - h0, **self._span_tags()))
+                        "worker.host_verify", tuple(req.trace), r_wall,
+                        time.perf_counter() - r0, **self._span_tags()))
                 else:
                     error = self._verify_host(req)
-        except Exception as e:
-            error = str(e)
-            if rt is not None:
-                self._finish_dispatch_span(rt, error=error)
-        self._reply(req, error,
-                    spans=tuple(rt["spans"]) if rt is not None else ())
+            replies.append((req, error,
+                            tuple(rt["spans"]) if rt is not None else ()))
+        if local is not None:
+            tracer.record("worker.host_verify", start_s=h_wall,
+                          duration_s=time.perf_counter() - t_back, **local)
+        self._reply_all(replies)
         with self._backlog_lock:
-            self._inflight_groups -= 1
-            self._inflight_sigs -= len(req.signatures)
-            self.processed_sig_count += len(req.signatures)
+            self._inflight_groups -= len(burst)
+            self._inflight_sigs -= n_sigs
+            self.processed_sig_count += n_sigs
             # busy-time marker: the fleet bench's scaling-efficiency metric
             # is mean(last_completion - t0) / makespan across workers
             self.last_completion_t = time.monotonic()
+            self._backlog_lock.notify_all()
         self._feed()
         with self._backlog_lock:
             idle = not self._backlog and self._inflight_groups == 0
@@ -1237,15 +1460,16 @@ class VerifierWorker:
             except Exception:
                 log.warning("idle load report failed", exc_info=True)
 
-    def _finish_dispatch_span(self, rt: dict, error: str | None = None
-                              ) -> None:
-        """Close the device-dispatch span (duration = submit→result) and
-        tag it with any breaker that was open when the group resolved — the
-        breaker-reroute marker for host-fallback diagnosis."""
+    def _finish_dispatch_span(self, rt: dict, t_back: float,
+                              error: str | None = None) -> None:
+        """Close a traced request's device-dispatch span (duration =
+        submit→verdicts back) and tag it with any breaker that was open
+        when the group resolved — the breaker-reroute marker for
+        host-fallback diagnosis."""
         disp = rt.pop("dispatch", None)
         if disp is None:
             return
-        disp["duration_s"] = time.perf_counter() - rt["t0"]
+        disp["duration_s"] = t_back - rt["t0"]
         if error is not None:
             disp["tags"]["error"] = error
         try:
@@ -1259,17 +1483,37 @@ class VerifierWorker:
             pass
         rt["spans"].append(disp)
 
+    def _reply_all(self, replies: list) -> None:
+        """Send ``(request, error, spans)`` replies, one frame each; one
+        failed send does not keep the rest from going."""
+        tracer = get_tracer()
+        r_wall, r0 = time.time(), time.perf_counter()
+        sent = 0
+        for req, error, spans in replies:
+            try:
+                sent += self._reply(req, error, spans)
+            except Exception:
+                log.warning("reply to %s failed", req.response_address,
+                            exc_info=True)
+        self._responses_out.mark(sent)
+        if tracer.enabled and sent:
+            tracer.record("worker.reply", start_s=r_wall,
+                          duration_s=time.perf_counter() - r0,
+                          n_requests=sent, **self._span_tags())
+
     def _reply(self, req: VerificationRequest, error: str | None,
-               spans: tuple = ()) -> None:
+               spans: tuple = ()) -> int:
+        """One reply; returns how many frames went out (0 for a worker
+        that was killed, or a reply a fault rule dropped)."""
         if not self._alive:
-            return   # killed mid-verify: the node requeues our outstanding work
+            return 0   # killed mid-verify: the node requeues our outstanding work
         # a "drop" rule here models a worker crashing BETWEEN finishing the
         # verify and sending the response — the node must redeliver
         if fault_point(
                 "oop.reply",
                 detail=f"{self.network_service.my_address}"
                        f"->{req.response_address}") == DROP:
-            return
+            return 0
         with self._count_lock:   # replies run on the completion pool's threads
             self.verified_count += 1
         self.network_service.send(
@@ -1277,18 +1521,20 @@ class VerifierWorker:
             serialize(VerificationResponse(req.verification_id, error,
                                            _pack_obs(list(spans)))),
             req.response_address)
+        return 1
 
     def stop(self, announce: bool = True) -> None:
         """Graceful stop announces Goodbye; a crash (announce=False) relies on
         the node detaching the worker when it notices (detach_worker)."""
         self._alive = False
+        with self._backlog_lock:
+            self._backlog_lock.notify_all()
         self.network_service.remove_message_handler(self._registration)
         if announce:
             self.network_service.send(
                 TopicSession(TOPIC_VERIFIER_REQUESTS),
                 serialize(WorkerGoodbye(self.network_service.my_address)),
                 self.queue_address)
-        if self._pool is not None:
-            self._pool.shutdown(wait=False)
+        self._pool.shutdown(wait=False)
         if self._batcher is not None:
             self._batcher.close()
