@@ -32,7 +32,8 @@ _CANDIDATES = [
 #: sm_r1p_mulfast (the secp256r1 half-gcd split ladder).  Version 4 changed
 #: the ECDSA preps' range check to Crypto.doVerify's (s in [1, n-1], no
 #: low-s bound): a version-3 library would refuse every high-s signature.
-SM_VERSION = 4
+#: Version 5 added sm_ecdsa_der_words (the strict-DER parse of a batch).
+SM_VERSION = 5
 
 _log = logging.getLogger(__name__)
 
@@ -40,6 +41,7 @@ _U64P = np.ctypeslib.ndpointer(dtype=np.uint64, flags="C_CONTIGUOUS")
 _U16P = np.ctypeslib.ndpointer(dtype=np.uint16, flags="C_CONTIGUOUS")
 _U8P = np.ctypeslib.ndpointer(dtype=np.uint8, flags="C_CONTIGUOUS")
 _I32P = np.ctypeslib.ndpointer(dtype=np.int32, flags="C_CONTIGUOUS")
+_I64P = np.ctypeslib.ndpointer(dtype=np.int64, flags="C_CONTIGUOUS")
 
 
 def _bind(lib) -> None:
@@ -54,6 +56,14 @@ def _bind(lib) -> None:
     lib.sm_r1_halfgcd.argtypes = [_U64P, _U8P, _U64P, _U64P]
     lib.sm_r1p_mulfast.restype = ctypes.c_int
     lib.sm_r1p_mulfast.argtypes = [_U64P, _U64P, _U64P]
+    # The one export called WITHOUT letting go of the interpreter lock
+    # (PyDLL over the same handle): the parse takes ~0.2 ms a batch of 8,192,
+    # and a thread that gave the lock up waits a switch interval (5 ms) to get
+    # it back while any other thread computes (measured: PERF.md, PR 38).
+    der_words = ctypes.PyDLL(lib._name, handle=lib._handle).sm_ecdsa_der_words
+    der_words.restype = ctypes.c_int
+    der_words.argtypes = [ctypes.c_int64, _U8P, _I64P, _U64P, _U64P, _U8P]
+    lib.sm_ecdsa_der_words = der_words
     lib.sm_k1_prep.restype = ctypes.c_int
     lib.sm_k1_prep.argtypes = [
         ctypes.c_int64, _U64P, _U64P, _U64P, _U64P,
@@ -151,7 +161,30 @@ def ecdsa_sigs_to_words(sigs) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     sign/trailing checks) plus the >= 2^256 clamp of the item-loop prep.
     Rejected encodings get ok=False and an all-zero row — r = 0 fails the
     preps' range precheck, so the member's verdict is False either way
-    (locked by the test_scalarprep differential)."""
+    (locked by the test_scalarprep differential).
+
+    One native call (sm_ecdsa_der_words) over the joined signatures; without
+    the library, :func:`ecdsa_sigs_to_words_py`, the same parse a row."""
+    if _LIB is None:
+        return ecdsa_sigs_to_words_py(sigs)
+    n = len(sigs)
+    offsets = np.zeros(n + 1, dtype=np.int64)
+    np.cumsum(np.fromiter(map(len, sigs), dtype=np.int64, count=n),
+              out=offsets[1:])
+    buf = np.frombuffer(b"".join(sigs), dtype=np.uint8)
+    r_words = np.empty((n, 4), dtype=np.uint64)
+    s_words = np.empty((n, 4), dtype=np.uint64)
+    ok = np.empty(n, dtype=np.uint8)
+    rc = _LIB.sm_ecdsa_der_words(n, buf, offsets, r_words, s_words, ok)
+    if rc != 0:
+        raise RuntimeError(f"sm_ecdsa_der_words failed: {rc}")
+    return r_words, s_words, ok.view(bool)
+
+
+def ecdsa_sigs_to_words_py(sigs) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """The strict-DER parse as a Python loop: the oracle that
+    sm_ecdsa_der_words is held to row for row (tests/test_scalarprep.py),
+    and :func:`ecdsa_sigs_to_words` itself where the library is absent."""
     n = len(sigs)
     r_rows = np.zeros((n, 32), dtype=np.uint8)
     s_rows = np.zeros((n, 32), dtype=np.uint8)

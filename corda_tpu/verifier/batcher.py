@@ -1264,8 +1264,9 @@ class SignatureBatcher:
         which the native range precheck rejects into a False verdict for
         that member alone. Returns the four word arrays and the rows whose
         DER or key was refused here. ``parent`` is the batch's
-        ``batcher.dispatch`` span: the DER parse and the digest loop are its
-        children ``ecdsa.prep.der`` / ``ecdsa.prep.digest``."""
+        ``batcher.dispatch`` span: the DER parse, the signers' key rows and
+        the digest loop are its children ``ecdsa.prep.der`` /
+        ``ecdsa.prep.keys`` / ``ecdsa.prep.digest``."""
         import hashlib
         from ..ops import scalarprep as sp
         tracer = get_tracer()
@@ -1273,13 +1274,20 @@ class SignatureBatcher:
         with tracer.span("ecdsa.prep.der", parent=parent, **tags):
             r_words, s_words, ok = sp.ecdsa_sigs_to_words(
                 [p.signature for p in items])
-        pub_words = np.zeros((len(items), 8), dtype=np.uint64)
-        for i, p in enumerate(items):
-            row = sec1_pub_row_cached(curve, p.key.encoded)
-            if row is None:
-                ok[i] = False
-            else:
-                pub_words[i] = row
+        with tracer.span("ecdsa.prep.keys", parent=parent, **tags):
+            # one cached row a DISTINCT signer, then one gather over the rows
+            encoded = [p.key.encoded for p in items]
+            slot = {k: j for j, k in enumerate(dict.fromkeys(encoded))}
+            table = np.zeros((len(slot), 8), dtype=np.uint64)
+            decodes = np.zeros(len(slot), dtype=bool)
+            for k, j in slot.items():
+                row = sec1_pub_row_cached(curve, k)
+                if row is not None:
+                    table[j], decodes[j] = row, True
+            which = np.fromiter(map(slot.__getitem__, encoded),
+                                dtype=np.intp, count=len(items))
+            pub_words = table[which]
+            ok &= decodes[which]
         r_words[~ok] = 0     # force the range precheck to reject
         with tracer.span("ecdsa.prep.digest", parent=parent, **tags):
             e_words = sp.digests_to_words(

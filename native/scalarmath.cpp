@@ -656,7 +656,7 @@ bool r1p_sqrt(const u64 z[4], u64 y[4]) {
 
 extern "C" {
 
-int sm_version() { return 4; }
+int sm_version() { return 5; }
 
 // Differential-test seam: r = a*b mod m for mod_id in
 // {0: k1 n, 1: k1 p, 2: r1 n, 3: r1 p, 4: ed L, 5: ed P}.
@@ -684,6 +684,51 @@ int sm_glv(const u64* k, u8* negs, u64* abs1, u64* abs2) {
     negs[0] = n1;
     negs[1] = n2;
     return fit ? 0 : -2;
+}
+
+// Strict-DER ECDSA signatures -> LE u64 word rows: the native body of
+// scalarprep.ecdsa_sigs_to_words, whose Python loop is the oracle.  Row i is
+// buf[offsets[i] .. offsets[i+1]).  The acceptance set is that loop's, byte
+// for byte: 0x30, ONE plain length byte equal to len - 2 (no long form), two
+// 0x02 integers with a length of 1..(what is left), no set high bit, a
+// leading zero only before a byte whose high bit is set, at most 32 bytes
+// after that sign byte (>= 2^256: clamp-to-reject), nothing trailing.  A
+// refused row is all zeros with ok = 0.
+int sm_ecdsa_der_words(int64_t n, const u8* buf, const int64_t* offsets,
+                       u64* r_words, u64* s_words, u8* ok)
+{
+    std::memset(r_words, 0, 32 * (size_t)n);
+    std::memset(s_words, 0, 32 * (size_t)n);
+    for (int64_t i = 0; i < n; ++i) {
+        const u8* der = buf + offsets[i];
+        const int64_t len = offsets[i + 1] - offsets[i];
+        u8* rows[2] = {reinterpret_cast<u8*>(r_words + 4 * i),
+                       reinterpret_cast<u8*>(s_words + 4 * i)};
+        bool good = len >= 8 && der[0] == 0x30 && (int64_t)der[1] == len - 2;
+        int64_t idx = 2;
+        for (int k = 0; good && k < 2; ++k) {
+            if (idx + 2 > len || der[idx] != 0x02) { good = false; break; }
+            const int64_t ln = der[idx + 1];
+            const u8* body = der + idx + 2;
+            if (ln == 0 || idx + 2 + ln > len || (body[0] & 0x80)
+                    || (ln > 1 && body[0] == 0 && !(body[1] & 0x80))) {
+                good = false;
+                break;
+            }
+            int64_t m = ln;
+            if (body[0] == 0) { ++body; --m; }   // the sign byte
+            if (m > 32) { good = false; break; }
+            for (int64_t j = 0; j < m; ++j) rows[k][j] = body[m - 1 - j];
+            idx += 2 + ln;
+        }
+        good = good && idx == len;
+        if (!good) {
+            std::memset(rows[0], 0, 32);
+            std::memset(rows[1], 0, 32);
+        }
+        ok[i] = good ? 1 : 0;
+    }
+    return 0;
 }
 
 // secp256k1 hybrid-GLV prep (mirrors weierstrass.prepare_batch_hybrid_wide

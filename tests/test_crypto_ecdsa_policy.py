@@ -343,11 +343,71 @@ def test_the_meters_say_which_prep_ran_and_what_it_refused(prep, curve_name):
         assert meters["EcdsaRefusedRange"] == why.count("range")
 
 
+def _ecdsa_words_a_row(curve, items):
+    """``SignatureBatcher._ecdsa_words`` as it was before it took a batch in
+    bulk: the Python DER loop, and one cached key row assigned a ROW."""
+    import hashlib
+    r_words, s_words, ok = sp.ecdsa_sigs_to_words_py(
+        [p.signature for p in items])
+    pub_words = np.zeros((len(items), 8), dtype=np.uint64)
+    for i, p in enumerate(items):
+        row = keys.sec1_pub_row_cached(curve, p.key.encoded)
+        if row is None:
+            ok[i] = False
+        else:
+            pub_words[i] = row
+    r_words[~ok] = 0
+    e_words = sp.digests_to_words(
+        [hashlib.sha256(p.content).digest() for p in items], 4)
+    return (e_words, r_words, s_words, pub_words), int((~ok).sum())
+
+
+@both_curves
+@pytest.mark.parametrize("library", [
+    pytest.param("native", marks=needs_native), "absent"])
+def test_the_bulk_word_prep_hands_over_what_the_per_row_form_did(
+        curve_name, library, monkeypatch):
+    """Several signers in no order, a key that does not decode (twice), a key
+    of the wrong length, a refused DER under a good key and under a bad one:
+    the same four word arrays, rows in the same order, the same refused
+    count. An empty batch is a batch."""
+    if library == "absent":
+        monkeypatch.setattr(sp, "_LIB", None)
+    scheme, curve, _oc, _bucket = CURVES[curve_name]
+    corpus = _corpus(curve_name)
+    good = [corpus[f"valid_low_s_{i}"] for i in range(4)] \
+        + [corpus[f"valid_high_s_twin_{i}"] for i in range(4)]
+    key, sig, msg = good[0][:3]
+    no_point = b"\x02" + b"\xff" * 32
+    padded = corpus["der_padded_r"][1]
+    triples = [row[:3] for row in good[4:] + good[:4]] + [
+        (no_point, sig, msg), (key[:32], sig, msg), (key, padded, msg),
+        good[2][:3], (no_point, padded, msg), (no_point, sig, b"another"),
+        corpus["key_uncompressed"][:3], good[7][:3]]
+    items = [_Pending(PublicKey(scheme, k), sg, m) for k, sg, m in triples]
+    for batch in (items, items[:1], items[8:9], []):
+        got, got_refused = SignatureBatcher._ecdsa_words(curve, batch)
+        want, want_refused = _ecdsa_words_a_row(curve, batch)
+        assert got_refused == want_refused
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(g, w)
+    (_e, r_words, _s, pub_words), refused = SignatureBatcher._ecdsa_words(
+        curve, items)
+    assert refused == 5
+    assert [i for i in range(len(items)) if not r_words[i].any()] \
+        == [8, 9, 10, 12, 13]
+    assert [i for i in range(len(items)) if not pub_words[i].any()] \
+        == [8, 9, 12, 13]
+    np.testing.assert_array_equal(pub_words[14], pub_words[4])  # 04 | x | y
+
+
 @needs_native
-def test_an_ecdsa_batch_names_its_three_prep_parts_under_the_dispatch():
+def test_an_ecdsa_batch_names_its_four_prep_parts_under_the_dispatch():
     """With tracing on, one ECDSA batch through the batcher's own front door
-    leaves ``ecdsa.prep.der``, ``.digest`` and ``.scalars`` as children of
-    its ``batcher.dispatch`` span, tagged with the bucket and the rows."""
+    leaves ``ecdsa.prep.der``, ``.keys``, ``.digest`` and ``.scalars`` as
+    children of its ``batcher.dispatch`` span, tagged with the bucket and
+    the rows."""
     from corda_tpu.observability import disable_tracing, enable_tracing
     corpus = _corpus("secp256k1")
     names = [f"valid_low_s_{i}" for i in range(4)] \
@@ -367,7 +427,8 @@ def test_an_ecdsa_batch_names_its_three_prep_parts_under_the_dispatch():
     assert dispatch["tags"]["bucket"] == "secp256k1"
     parts = [s for s in spans if s["name"].startswith("ecdsa.prep.")]
     assert sorted(s["name"] for s in parts) == [
-        "ecdsa.prep.der", "ecdsa.prep.digest", "ecdsa.prep.scalars"]
+        "ecdsa.prep.der", "ecdsa.prep.digest", "ecdsa.prep.keys",
+        "ecdsa.prep.scalars"]
     for part in parts:
         assert part["parent_id"] == dispatch["span_id"]
         assert part["trace_id"] == dispatch["trace_id"]
@@ -420,4 +481,4 @@ def test_no_verify_route_compares_s_with_half_the_order():
     assert "mp_cmp(s4, N->half" not in native
     assert native.count("mp_cmp(s4, N->m, 4) < 0") == 3
     assert "N->half" in native                      # the GLV split's bias
-    assert sp.SM_VERSION == 4
+    assert sp.SM_VERSION == 5   # 4->5: the strict-DER parse is an export

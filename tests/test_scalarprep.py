@@ -246,6 +246,94 @@ def test_ecdsa_sigs_to_words_matches_der_parser():
     assert ok[:24].all() and not ok[24:].any()
 
 
+def _der_int(rng) -> bytes:
+    """One INTEGER's body: 1-33 random bytes, the first with its high bit
+    set or clear as drawn, the sign byte in front of a set one. A first byte
+    that comes out 0 is a non-minimal body; 33 bytes after the sign byte are
+    an integer the words cannot hold."""
+    raw = bytearray(rng.randbytes(rng.randrange(1, 34)))
+    raw[0] = raw[0] | 0x80 if rng.random() < 0.5 else raw[0] & 0x7F
+    return (b"\x00" if raw[0] & 0x80 else b"") + bytes(raw)
+
+
+def _der_seq(r_body: bytes, s_body: bytes) -> bytes:
+    body = (bytes([0x02, len(r_body)]) + r_body
+            + bytes([0x02, len(s_body)]) + s_body)
+    return bytes([0x30, len(body)]) + body
+
+
+def _der_random(count: int = 10_000):
+    """``count`` seeded random signatures, each followed by its mutations,
+    and the strings no signature is; shuffled, so that what lies beside a
+    row in the joined buffer is no help to it."""
+    rng = random.Random(4805)
+    sigs = []
+    for _ in range(count):
+        r_body, s_body = _der_int(rng), _der_int(rng)
+        der = _der_seq(r_body, s_body)
+        s_tag = 2 + 2 + len(r_body)          # where the second INTEGER starts
+        at = rng.choice((0, 2, s_tag))       # a tag ...
+        ln = rng.choice((1, 3, s_tag + 1))   # ... and a length byte
+        cut = rng.randrange(1, 4)
+        sigs += [
+            der,
+            der[:at] + bytes([der[at] ^ (1 << rng.randrange(8))])
+            + der[at + 1:],                              # a flipped tag
+            der[:ln] + bytes([der[ln] + 1]) + der[ln + 1:],  # a length + 1
+            der[:ln] + bytes([der[ln] - 1]) + der[ln + 1:],  # a length - 1
+            der[:-cut],                                  # a truncated tail
+            der + rng.randbytes(cut),                    # an extended tail
+            _der_seq(b"\x00" + r_body, s_body),          # a leading zero more
+            _der_seq(r_body, b"\x00" + s_body),
+        ]
+    sigs += [b""] + [rng.randbytes(k) for k in range(1, 8)]
+    sigs += [b"\x30" + bytes([k - 2]) + rng.randbytes(k - 2)
+             for k in range(2, 8)]
+    # 300 bytes: a length that no one byte holds, also where the byte holds
+    # the length's low eight bits
+    sigs += [rng.randbytes(300), b"\x30" + bytes([298 & 0xFF])
+             + _der_seq(_der_int(rng), _der_int(rng)).ljust(298, b"\x00")]
+    rng.shuffle(sigs)
+    return sigs
+
+
+_DER_FAMILIES = {
+    "corpus": lambda: [_der_corpus()],
+    "random_and_mutated": lambda: [_der_random()],
+    "empty_batch": lambda: [[]],
+    "batches_of_one": lambda: [[der] for der in _der_corpus()],
+}
+
+
+@pytest.mark.parametrize(
+    "family", [*_DER_FAMILIES, "fallback_without_the_library"])
+def test_native_der_parse_is_the_python_parse_row_for_row(family, monkeypatch):
+    """sm_ecdsa_der_words against ``ecdsa_sigs_to_words_py``, the oracle:
+    the same ``ok``, ``r_words`` and ``s_words`` as arrays. Without the
+    library ``ecdsa_sigs_to_words`` hands back what the native parse did."""
+    fallback = family == "fallback_without_the_library"
+    batches = _DER_FAMILIES["corpus" if fallback else family]()
+    if fallback:
+        wants = [sp.ecdsa_sigs_to_words(sigs) for sigs in batches]
+        monkeypatch.setattr(sp, "_LIB", None)
+    else:
+        wants = [sp.ecdsa_sigs_to_words_py(sigs) for sigs in batches]
+    accepted = refused = 0
+    for sigs, want in zip(batches, wants):
+        got = sp.ecdsa_sigs_to_words(sigs)
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(g, w)
+        ok = got[2]
+        assert not got[0][~ok].any() and not got[1][~ok].any()
+        accepted += int(ok.sum())
+        refused += int((~ok).sum())
+    if family == "random_and_mutated":
+        assert accepted > 5_000 and refused > 50_000
+    elif family != "empty_batch":
+        assert (accepted, refused) == (24, 11)
+
+
 def test_pub_row_cache_matches_decompress():
     """keys.sec1_pub_row_cached vs the bigint decompress: same affine point
     as LE u64 words, None for undecodable encodings, and cache hits return
@@ -280,7 +368,7 @@ def test_stale_so_falls_back_loudly(caplog):
     # the matching version loads fine (the gate, not the loader, refused)
     assert sp._load(candidates=[real]) is not None
     # and a refused library means available() gates every native seam
-    assert sp.SM_VERSION == 4  # 3→4: the ECDSA preps' s bound is n, not n/2
+    assert sp.SM_VERSION == 5  # 4→5: the strict-DER parse is an export
 
 
 def test_k1_verify_through_native_prep():
