@@ -163,7 +163,7 @@ class KernelProfiler:
     # -- kernel dispatch ----------------------------------------------------
     def call(self, name: str, fn, *args, live: int | None = None,
              capacity: int | None = None, scheme: str | None = None,
-             field_products_per_row=None, **kwargs):
+             field_products_per_row=None, trace_span=None, **kwargs):
         """Invoke ``fn(*args, **kwargs)`` under the recorder.
 
         Books the call's wall time as compile time when the jitted
@@ -186,6 +186,8 @@ class KernelProfiler:
             compiled = cache_size() > before
         else:
             compiled = self._novel_signature(name, args)
+        if trace_span is not None:
+            trace_span.set_tag("compiled", compiled)
         with self._lock:
             st = self._kernels.get(name)
             if st is None:

@@ -72,7 +72,9 @@ class SpanContext:
 def _parent_ids(parent) -> tuple[str | None, str | None]:
     """Accept a SpanContext, a Span, a (trace_id, span_id) tuple (the
     messaging wire form), or None."""
-    if parent is None:
+    if parent is None or parent is NOOP_SPAN:
+        # the no-op span as a parent: tracing came on between the caller's
+        # span and this one; the child starts a trace of its own
         return None, None
     if isinstance(parent, SpanContext):
         return parent.trace_id, parent.span_id
@@ -87,13 +89,21 @@ class Span:
     """One timed operation. Use as a context manager, or call ``finish()``
     explicitly for spans that outlive a lexical scope (a flow's run span,
     a raft submission awaiting commit). Recording happens at finish time —
-    an unfinished span is never visible in the ring."""
+    an unfinished span is never visible in the ring.
+
+    ``cpu=True`` also reads the opening thread's CPU clock
+    (``time.thread_time``) at open and at ``finish``, and records the
+    difference as ``cpu_s`` beside ``duration_s``: how long the thread RAN.
+    Both reads have to be the same thread's, so it is for ``with`` spans
+    (and spans finished where they were opened). ``cpu_s`` stays None where
+    it was not asked for."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "start_s",
-                 "duration_s", "tags", "thread", "_ring", "_t0", "_done")
+                 "duration_s", "cpu_s", "tags", "thread", "_ring", "_t0",
+                 "_c0", "_done")
 
     def __init__(self, ring: SpanRing, name: str, trace_id: str,
-                 parent_id: str | None, tags: dict):
+                 parent_id: str | None, tags: dict, cpu: bool = False):
         self._ring = ring
         self.name = name
         self.trace_id = trace_id
@@ -107,6 +117,8 @@ class Span:
         self.start_s = time.time()
         self._t0 = time.perf_counter()
         self.duration_s = 0.0
+        self.cpu_s = None
+        self._c0 = time.thread_time() if cpu else None
         self._done = False
 
     def context(self) -> SpanContext:
@@ -121,13 +133,16 @@ class Span:
             return
         self._done = True
         self.duration_s = time.perf_counter() - self._t0
+        if self._c0 is not None:
+            self.cpu_s = time.thread_time() - self._c0
         self._ring.record(self.to_dict())
 
     def to_dict(self) -> dict:
         return {"name": self.name, "trace_id": self.trace_id,
                 "span_id": self.span_id, "parent_id": self.parent_id,
                 "start_s": self.start_s, "duration_s": self.duration_s,
-                "thread": self.thread, "tags": self.tags}
+                "cpu_s": self.cpu_s, "thread": self.thread,
+                "tags": self.tags}
 
     def __enter__(self) -> "Span":
         return self
@@ -171,7 +186,7 @@ class NoopTracer:
     enabled = False
     ring = None
 
-    def span(self, name, parent=None, **tags):
+    def span(self, name, parent=None, *, cpu=False, **tags):
         return NOOP_SPAN
 
     def record(self, name, parent=None, start_s=None, duration_s=0.0, **tags):
@@ -201,13 +216,16 @@ class Tracer:
     def __init__(self, capacity: int = 8192):
         self.ring = SpanRing(capacity)
 
-    def span(self, name: str, parent=None, **tags) -> Span:
+    def span(self, name: str, parent=None, *, cpu: bool = False,
+             **tags) -> Span:
         """Open a live span. ``parent`` is a SpanContext / Span /
-        (trace_id, span_id) tuple, or None to start a fresh trace."""
+        (trace_id, span_id) tuple, or None to start a fresh trace.
+        ``cpu=True`` records ``cpu_s`` too (see :class:`Span`): two more
+        clock reads a span, so off unless a site asks."""
         trace_id, parent_id = _parent_ids(parent)
         if trace_id is None:
             trace_id = _new_id()
-        return Span(self.ring, name, trace_id, parent_id, tags)
+        return Span(self.ring, name, trace_id, parent_id, tags, cpu)
 
     def record(self, name: str, parent=None, start_s: float | None = None,
                duration_s: float = 0.0, **tags) -> SpanContext:
@@ -222,8 +240,8 @@ class Tracer:
             "name": name, "trace_id": trace_id, "span_id": span_id,
             "parent_id": parent_id,
             "start_s": time.time() if start_s is None else start_s,
-            "duration_s": duration_s, "thread": current_thread().name,
-            "tags": tags})
+            "duration_s": duration_s, "cpu_s": None,
+            "thread": current_thread().name, "tags": tags})
         return SpanContext(trace_id, span_id)
 
     def ingest(self, span_dict) -> None:
@@ -240,6 +258,7 @@ class Tracer:
         d.setdefault("parent_id", None)
         d.setdefault("start_s", 0.0)
         d.setdefault("duration_s", 0.0)
+        d.setdefault("cpu_s", None)     # only a span asked for it carries one
         d.setdefault("thread", None)    # an older worker's span has none
         if not isinstance(d.get("tags"), dict):
             d["tags"] = {}

@@ -664,73 +664,101 @@ def prepare_batch_windowed(items: list[tuple[bytes, bytes, bytes]],
 
 def prepare_batch_split(items: list[tuple[bytes, bytes, bytes]],
                         w: int = SPLIT_B_WINDOW, device_tables: bool = True,
-                        staging=None):
+                        staging=None, trace_parent=None):
     """Host prep for the split-k kernel: signatures parsed by numpy (the
     wire bytes ARE little-endian u16 limbs), per-signer (−A, −A') rows from
     the _signer_row cache, SHA-512 challenges via hashlib, and the scalar
     windows from native scalarmath (Python-bigint fallback below).
 
     Returns (bb_idx, a_packed, rows, r_packed, [tables...], precheck) —
-    the consolidated 4-array wire form of verify_core_split."""
+    the consolidated 4-array wire form of verify_core_split.
+
+    Under ``trace_parent`` (the batcher's ``batcher.dispatch`` span) the
+    five phases are its children ``ed25519.prep.sig`` / ``.keys`` /
+    ``.digest`` / ``.scalars`` / ``.handover``, each tagged ``bucket`` and
+    ``rows`` and carrying ``cpu_s``; without one (the mesh route, the
+    tools) no span is opened."""
+    from ..observability.tracing import NOOP_TRACER, get_tracer
     from . import scalarprep as sp
     assert w == 16, "split prep emits 16-bit constant-base windows"
     n = len(items)
-    # ``staging`` (ops.staging.StagingLease) reuses the largest per-batch
-    # host buffer across flushes of the same bucket size — every row is
-    # overwritten below, so carried-over data never leaks into a verdict
-    rows = (staging.take("ed.rows", (n, 6, F.NLIMB), np.uint16)
-            if staging is not None
-            else np.empty((n, 6, F.NLIMB), dtype=np.uint16))
-    precheck = np.ones(n, dtype=bool)
-    digests: list[bytes] = []
-    sub = _substitute_row()
-    # signature bytes land in ONE joined frombuffer when every sig is the
-    # wire-format 64 bytes (the overwhelmingly common case) — n per-row
-    # frombuffer copies otherwise. Items whose KEY fails decompression keep
-    # their sig bytes here; their verdict is masked by precheck anyway.
-    sig_ok = np.fromiter((len(sig) == 64 for _, sig, _ in items),
-                         dtype=bool, count=n)
-    if sig_ok.all():
-        sig_mat = np.frombuffer(b"".join(sig for _, sig, _ in items),
-                                dtype=np.uint8).reshape(n, 64)
-    else:
-        sig_mat = np.zeros((n, 64), dtype=np.uint8)
-        for i, (_, sig, _) in enumerate(items):
-            if sig_ok[i]:
-                sig_mat[i] = np.frombuffer(sig, dtype=np.uint8)
-    for i, (pub, sig, msg) in enumerate(items):
-        row = _signer_row(bytes(pub)) if sig_ok[i] else None
-        if row is None:
-            precheck[i] = False
-            rows[i] = sub
-            digests.append(bytes(64))   # k := 0 (verdict is masked anyway)
+    tracer = get_tracer() if trace_parent is not None else NOOP_TRACER
+    tags = {"bucket": "ed25519", "rows": n}
+    with tracer.span("ed25519.prep.sig", parent=trace_parent, cpu=True,
+                     **tags):
+        # signature bytes land in ONE joined frombuffer when every sig is
+        # the wire-format 64 bytes (the overwhelmingly common case) — n
+        # per-row frombuffer copies otherwise. Items whose KEY fails
+        # decompression keep their sig bytes here; their verdict is masked
+        # by precheck anyway.
+        sig_ok = np.fromiter((len(sig) == 64 for _, sig, _ in items),
+                             dtype=bool, count=n)
+        if sig_ok.all():
+            sig_mat = np.frombuffer(b"".join(sig for _, sig, _ in items),
+                                    dtype=np.uint8).reshape(n, 64)
         else:
-            rows[i] = row
-            digests.append(hashlib.sha512(sig[:32] + pub + msg).digest())
-    r_packed = sig_mat[:, :32].copy().view("<u2")       # (n, 16) wire y
-    # the wire sign bit stays IN limb 15 bit 15 (the kernel unpacks it);
-    # range checks use the masked view
-    y15 = r_packed[:, 15] & 0x7FFF
-    # non-canonical y (>= p = 2^255-19) rejects like a failed decompression
-    ge_p = ((r_packed[:, 0] >= 0xFFED) & (y15 == 0x7FFF)
-            & (r_packed[:, 1:15] == 0xFFFF).all(axis=1))
-    precheck &= ~ge_p
-    s_words = sig_mat[:, 32:].copy().view("<u8")        # (n, 4)
-    if sp.available():
-        h_words = sp.le_digests_to_words(digests, 8)
-        b_idx, b2_idx, a_packed, s_ok = sp.ed_prep(h_words, s_words)
-    else:
-        b_idx, b2_idx, a_packed, s_ok = _split_windows_python(
-            digests, s_words)
-    precheck &= s_ok
-    a_digits = a_packed.reshape(128 // w, w // 2, n)
-    head = (jnp.asarray(np.concatenate([b_idx, b2_idx])),
-            jnp.asarray(a_digits), jnp.asarray(rows),
-            jnp.asarray(r_packed))
-    if device_tables:
-        return (*head, *b_table_device(w, 0), *b_table_device(w, 128),
-                precheck)
-    return (*head, precheck)
+            sig_mat = np.zeros((n, 64), dtype=np.uint8)
+            for i, (_, sig, _) in enumerate(items):
+                if sig_ok[i]:
+                    sig_mat[i] = np.frombuffer(sig, dtype=np.uint8)
+        r_packed = sig_mat[:, :32].copy().view("<u2")       # (n, 16) wire y
+        # the wire sign bit stays IN limb 15 bit 15 (the kernel unpacks it);
+        # range checks use the masked view
+        y15 = r_packed[:, 15] & 0x7FFF
+        # non-canonical y (>= p = 2^255-19) rejects like a failed
+        # decompression
+        ge_p = ((r_packed[:, 0] >= 0xFFED) & (y15 == 0x7FFF)
+                & (r_packed[:, 1:15] == 0xFFFF).all(axis=1))
+        s_words = sig_mat[:, 32:].copy().view("<u8")        # (n, 4)
+    with tracer.span("ed25519.prep.keys", parent=trace_parent, cpu=True,
+                     **tags):
+        # ``staging`` (ops.staging.StagingLease) reuses the largest
+        # per-batch host buffer across flushes of the same bucket size —
+        # every row is overwritten below, so carried-over data never leaks
+        # into a verdict
+        rows = (staging.take("ed.rows", (n, 6, F.NLIMB), np.uint16)
+                if staging is not None
+                else np.empty((n, 6, F.NLIMB), dtype=np.uint16))
+        keyed = np.ones(n, dtype=bool)     # length and key both passed
+        sub = _substitute_row()
+        for i, (pub, _, _) in enumerate(items):
+            row = _signer_row(bytes(pub)) if sig_ok[i] else None
+            if row is None:
+                keyed[i] = False
+                rows[i] = sub
+            else:
+                rows[i] = row
+        precheck = keyed & ~ge_p
+    with tracer.span("ed25519.prep.digest", parent=trace_parent, cpu=True,
+                     **tags):
+        # a second pass over the items (the keys' pass decided which rows
+        # hash): k := 0 where the key or the length was refused (the
+        # verdict is masked anyway)
+        zero = bytes(64)
+        digests = [hashlib.sha512(sig[:32] + pub + msg).digest() if ok
+                   else zero
+                   for ok, (pub, sig, msg) in zip(keyed.tolist(), items)]
+        native = sp.available()
+        if native:
+            h_words = sp.le_digests_to_words(digests, 8)
+    with tracer.span("ed25519.prep.scalars", parent=trace_parent, cpu=True,
+                     **tags):
+        if native:
+            b_idx, b2_idx, a_packed, s_ok = sp.ed_prep(h_words, s_words)
+        else:
+            b_idx, b2_idx, a_packed, s_ok = _split_windows_python(
+                digests, s_words)
+        precheck &= s_ok
+    with tracer.span("ed25519.prep.handover", parent=trace_parent, cpu=True,
+                     **tags):
+        a_digits = a_packed.reshape(128 // w, w // 2, n)
+        head = (jnp.asarray(np.concatenate([b_idx, b2_idx])),
+                jnp.asarray(a_digits), jnp.asarray(rows),
+                jnp.asarray(r_packed))
+        if device_tables:
+            return (*head, *b_table_device(w, 0), *b_table_device(w, 128),
+                    precheck)
+        return (*head, precheck)
 
 
 def _split_windows_python(digests: list[bytes], s_words: np.ndarray):
@@ -779,15 +807,20 @@ def _service_kernel_split():
                           (0, 1, 2, 3), static_argnames=("w",))
 
 
-def verify_batch_async(items: list[tuple[bytes, bytes, bytes]]):
+def verify_batch_async(items: list[tuple[bytes, bytes, bytes]],
+                       trace_parent=None):
     """Dispatch without forcing (see weierstrass.verify_batch_async): the
     device computes while the caller preps the next batch. Rides the
     split-k half-length ladder — the fastest measured path (PERF.md
     section 5) — with donated per-batch device buffers and leased host
     staging arrays (ops.staging) on the service path. Dispatches go
     through the kernel flight recorder (observability.profiling):
-    compile-cache accounting + batch occupancy."""
+    compile-cache accounting + batch occupancy. ``trace_parent`` is the
+    batcher's ``batcher.dispatch`` span: the prep's phases
+    (:func:`prepare_batch_split`) and ``batcher.launch``, the jitted call
+    alone until it returns, are its children."""
     from ..observability.profiling import get_profiler
+    from ..observability.tracing import get_tracer
     from .staging import get_staging_pool
     n = len(items)
     if n == 0:
@@ -796,12 +829,18 @@ def verify_batch_async(items: list[tuple[bytes, bytes, bytes]]):
     pool = get_staging_pool()
     lease = pool.lease()
     *args, precheck = prepare_batch_split(padded, SPLIT_B_WINDOW,
-                                          staging=lease)
-    dev = get_profiler().call(
-        "ed25519.split", _service_kernel_split(), *args, w=SPLIT_B_WINDOW,
-        live=n, capacity=len(padded), scheme="ed25519",
-        field_products_per_row=functools.partial(
-            split_field_products, len(padded), SPLIT_B_WINDOW))
+                                          staging=lease,
+                                          trace_parent=trace_parent)
+    with get_tracer().span("batcher.launch", parent=trace_parent, cpu=True,
+                           bucket="ed25519", rows=n,
+                           capacity=len(padded)) as lspan:
+        dev = get_profiler().call(
+            "ed25519.split", _service_kernel_split(), *args,
+            w=SPLIT_B_WINDOW, live=n, capacity=len(padded),
+            scheme="ed25519",
+            field_products_per_row=functools.partial(
+                split_field_products, len(padded), SPLIT_B_WINDOW),
+            trace_span=lspan)
     pending = (dev, precheck, n)
     # the lease rides the pending handle: finish_batch releases it after
     # the force, the earliest point the device provably no longer reads

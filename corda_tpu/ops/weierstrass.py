@@ -1637,7 +1637,9 @@ def verify_batch_async_words(curve: WeierstrassCurve, e_words, r_words,
     the donated twin, so steady-state flushes neither allocate fresh host
     rows nor leave stale device input buffers behind. The native scalar
     prep (range check, s^-1, the split, window digits) is the span
-    ``ecdsa.prep.scalars`` under ``trace_parent``, the caller's span."""
+    ``ecdsa.prep.scalars`` under ``trace_parent``, the caller's span; the
+    padding before it is ``ecdsa.prep.pad`` and the jitted call alone,
+    until it returns, ``batcher.launch``. All three carry ``cpu_s``."""
     from ..observability import get_tracer
     from ..observability.profiling import get_profiler
     from .staging import get_staging_pool
@@ -1651,30 +1653,40 @@ def verify_batch_async_words(curve: WeierstrassCurve, e_words, r_words,
     # a partial dispatch may still alias the buffers, so they must not
     # re-enter the free pool.
     lease = pool.lease()
-    tags = tuple(f"{curve.name}.{t}" for t in ("e", "r", "s", "pub"))
-    e_words, r_words, s_words, pub_words = pad_word_rows(
-        (e_words, r_words, s_words, pub_words), capacity,
-        staging=lease, tags=tags)
-    prep_span = get_tracer().span("ecdsa.prep.scalars", parent=trace_parent,
-                                  bucket=curve.name, rows=n)
-    if curve.name == "secp256k1":
-        with prep_span:
+    tracer = get_tracer()
+    span_tags = {"bucket": curve.name, "rows": n}
+    with tracer.span("ecdsa.prep.pad", parent=trace_parent, cpu=True,
+                     **span_tags):
+        tags = tuple(f"{curve.name}.{t}" for t in ("e", "r", "s", "pub"))
+        e_words, r_words, s_words, pub_words = pad_word_rows(
+            (e_words, r_words, s_words, pub_words), capacity,
+            staging=lease, tags=tags)
+    k1 = curve.name == "secp256k1"
+    with tracer.span("ecdsa.prep.scalars", parent=trace_parent, cpu=True,
+                     **span_tags):
+        if k1:
             *args, precheck = _prepare_hybrid_native_words(
                 e_words, r_words, s_words, pub_words, HYBRID_G_WINDOW)
-        pending = (prof.call("weierstrass.hybrid_k1",
-                             _service_kernel_hybrid_wide(),
-                             *args, g_w=HYBRID_G_WINDOW, live=n,
-                             capacity=capacity, scheme=curve.name),
-                   precheck, n)
-    else:
-        with prep_span:
-            *args, precheck, forced = _prepare_r1_split_native_words(
+            forced = ()
+        else:
+            *args, precheck, oracle = _prepare_r1_split_native_words(
                 e_words, r_words, s_words, pub_words, R1_G_WINDOW)
-        pending = (prof.call("weierstrass.r1_split",
-                             _service_kernel_r1_split(),
-                             *args, curve_name=curve.name, w=R1_G_WINDOW,
-                             live=n, capacity=capacity, scheme=curve.name),
-                   precheck, n, forced)
+            forced = (oracle,)
+    with tracer.span("batcher.launch", parent=trace_parent, cpu=True,
+                     capacity=capacity, **span_tags) as launch_span:
+        if k1:
+            dev = prof.call("weierstrass.hybrid_k1",
+                            _service_kernel_hybrid_wide(),
+                            *args, g_w=HYBRID_G_WINDOW, live=n,
+                            capacity=capacity, scheme=curve.name,
+                            trace_span=launch_span)
+        else:
+            dev = prof.call("weierstrass.r1_split",
+                            _service_kernel_r1_split(),
+                            *args, curve_name=curve.name, w=R1_G_WINDOW,
+                            live=n, capacity=capacity, scheme=curve.name,
+                            trace_span=launch_span)
+    pending = (dev, precheck, n, *forced)
     pool.attach(pending, lease)
     return pending
 

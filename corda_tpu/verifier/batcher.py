@@ -143,8 +143,10 @@ class _Pending:
     index: int = 0
     # tracing (observability.tracing): the submitter's SpanContext, carried
     # across the dispatcher/prep/finish threads; t_enq is the wall-clock
-    # enqueue time for the retroactive enqueue-wait span. Both stay at
-    # their defaults when tracing is off — zero cost.
+    # time the row joined its queue (ONE clock read a submission, written
+    # to its rows in _enqueue) for the retroactive enqueue-wait and
+    # queue-wait spans. Both stay at their defaults when tracing is off —
+    # zero cost.
     ctx: object = None
     t_enq: float = 0.0
 
@@ -524,11 +526,17 @@ class SignatureBatcher:
         """Bulk submission: one lock round for a whole transaction's (or
         ledger's) signature set — the per-item lock churn matters at the
         32k-batch scale the service path runs. ``ctx`` is the submitter's
-        SpanContext: the flushed batch's spans join that trace."""
-        pendings = [_Pending(key, sig, content, future=Future())
-                    for key, sig, content in checks]
-        self._stamp_trace(pendings, ctx)
-        self._enqueue(pendings, latency_class)
+        SpanContext: the flushed batch's spans join that trace, and so does
+        ``batcher.submit`` (this call on the caller's thread: the rows
+        built, an admission block waited out, the rows on their queues)."""
+        with get_tracer().span("batcher.submit", parent=ctx,
+                               cpu=True) as sspan:
+            pendings = [_Pending(key, sig, content, future=Future())
+                        for key, sig, content in checks]
+            self._stamp_trace(pendings, ctx)
+            sspan.set_tag("rows", len(pendings))
+            sspan.set_tag("groups", 0)
+            self._enqueue(pendings, latency_class)
         return [p.future for p in pendings]
 
     def submit_group(self, checks, ctx=None,
@@ -551,29 +559,34 @@ class SignatureBatcher:
         them over whole, so the queue goes from empty to ``max_batch`` in
         one step and the planner cuts a full bucket, where one enqueue a
         group would wake it once a group. ``ctxs`` is a SpanContext per
-        group (None where a group is untraced)."""
-        futures, pendings = [], []
-        for g, checks in enumerate(groups):
-            group = _Group(len(checks))
-            mine = [_Pending(key, sig, content, group=group, index=i)
-                    for i, (key, sig, content) in enumerate(checks)]
-            if ctxs is not None:
-                self._stamp_trace(mine, ctxs[g])
-            if not mine:
-                group.future.set_result([])
-            pendings.extend(mine)
-            futures.append(group.future)
-        self._enqueue(pendings, latency_class)
+        group (None where a group is untraced); ``batcher.submit`` (see
+        ``submit_many``) joins the first traced group's trace."""
+        first_ctx = None if ctxs is None else next(
+            (c for c in ctxs if c is not None), None)
+        with get_tracer().span("batcher.submit", parent=first_ctx,
+                               cpu=True) as sspan:
+            futures, pendings = [], []
+            for g, checks in enumerate(groups):
+                group = _Group(len(checks))
+                mine = [_Pending(key, sig, content, group=group, index=i)
+                        for i, (key, sig, content) in enumerate(checks)]
+                if ctxs is not None:
+                    self._stamp_trace(mine, ctxs[g])
+                if not mine:
+                    group.future.set_result([])
+                pendings.extend(mine)
+                futures.append(group.future)
+            sspan.set_tag("rows", len(pendings))
+            sspan.set_tag("groups", len(futures))
+            self._enqueue(pendings, latency_class)
         return futures
 
     @staticmethod
     def _stamp_trace(pendings, ctx) -> None:
         if ctx is None:     # tracing off, or an untraced caller
             return
-        now = _time.time()
         for p in pendings:
             p.ctx = ctx
-            p.t_enq = now
 
     def hold_group(self, checks, ctx=None,
                    wave_rows: int | None = None) -> "HeldGroup":
@@ -701,6 +714,13 @@ class SignatureBatcher:
                             start_s=blocked_t0, duration_s=now - blocked_t0,
                             wait_kind="verifier.admission",
                             n_sigs=len(pendings))
+            if get_tracer().enabled:
+                # ONE wall stamp a submission, on every row of it: a batch
+                # may begin anywhere in a submission, and its queue wait
+                # runs from its oldest row's
+                t_enq = _time.time()
+                for p in pendings:
+                    p.t_enq = t_enq
             now = _time.monotonic()
             for bucket, ps in routed.items():
                 self._queues[bucket].add(latency_class, ps, now)
@@ -748,13 +768,13 @@ class SignatureBatcher:
                     timeout = None if wake is None else max(0.0, wake - now)
                     self._lock.wait(timeout=timeout)
                     continue
-            for bucket, items, reason, release in plans:
-                self._submit_flush(bucket, items, reason, release)
+            for plan in plans:
+                self._submit_flush(*plan)
 
     def _plan_locked(self, now: float):
         """Cut every dispatchable plan from the queues (CALLER HOLDS THE
         LOCK). Returns (plans, wake): plans are (bucket, items, reason,
-        release) tuples ready for the prep pool; wake is the earliest
+        release, t_cut) tuples ready for the prep pool; wake is the earliest
         future deadline among the classes that are not ready yet (None
         when nothing is waiting on time)."""
         plans = []
@@ -831,8 +851,12 @@ class SignatureBatcher:
         LOCK) and build its idempotent release — the continuous-batching
         seam: the slot frees (and the planner re-wakes) the moment the
         batch RESOLVES, from whichever pool thread got there, never from a
-        planner-side blocking wait."""
+        planner-side blocking wait. With tracing on the plan also carries
+        the wall clock at the cut (``t_cut``, else None): the end of the
+        rows' ``batcher.queue_wait`` and the start of the batch's
+        ``batcher.pool_wait``."""
         self._inflight_n[bucket] += 1
+        t_cut = _time.time() if get_tracer().enabled else None
         released = [False]
 
         def release(_f=None):
@@ -843,10 +867,10 @@ class SignatureBatcher:
                 self._inflight_n[bucket] -= 1
                 self._lock.notify_all()
 
-        return bucket, items, reason, release
+        return bucket, items, reason, release, t_cut
 
     def _submit_flush(self, bucket: str, items: list[_Pending],
-                      reason: str, release) -> None:
+                      reason: str, release, t_cut=None) -> None:
         """Hand one planned batch to the prep pool. Never blocks: window
         accounting already happened in the planner, so the only wait left
         anywhere is pool scheduling."""
@@ -856,23 +880,23 @@ class SignatureBatcher:
                 thread_name_prefix="sig-batcher-prep")
         try:
             self._prep_pool.submit(
-                self._flush_slot, bucket, items, reason, release)
+                self._flush_slot, bucket, items, reason, release, t_cut)
         except RuntimeError:
             # pool already shut down (close() raced a long drain): flush
             # inline so no queued caller's future is dropped
-            inner = self._flush_slot(bucket, items, reason, release)
+            inner = self._flush_slot(bucket, items, reason, release, t_cut)
             if inner is not None:
                 inner.result()
 
     def _flush_slot(self, bucket: str, items: list[_Pending], reason: str,
-                    release):
+                    release, t_cut=None):
         """_flush under slot accounting: the in-flight slot releases when
         the batch fully resolves (inline for host routes, at the finish
         future for pipelined device batches), and a prep/finish crash
         fails the batch's futures instead of leaking them — zero lost
         futures even through a breaker trip mid-pipeline."""
         try:
-            inner = self._flush(bucket, items, reason)
+            inner = self._flush(bucket, items, reason, t_cut)
         except BaseException as exc:
             _log.exception("signature batch prep/finish failed")
             self.metrics.meter("SigBatcher.BatchFailure").mark()
@@ -904,12 +928,14 @@ class SignatureBatcher:
                 pass
         self.metrics.counter("SigBatcher.InFlight").dec(len(items))
 
-    def _flush(self, bucket: str, items: list[_Pending], reason: str):
+    def _flush(self, bucket: str, items: list[_Pending], reason: str,
+               t_cut=None):
         """Route one drained bucket: host loop below the crossover, device
         kernels above. RUNS ON A PREP-POOL WORKER, so a mixed drain's
         buckets prep and dispatch concurrently. Returns the finish-stage
         Future for pipelined device batches (None when the batch resolved
-        inline). Records the per-flush histogram + trace spans."""
+        inline). Records the per-flush histogram + trace spans; ``t_cut``
+        is the plan's cut stamp (None with tracing off)."""
         gauge = self.metrics.settable_gauge("SigBatcher.PrepActive")
         with self._pool_lock:
             self._prep_active += 1
@@ -919,7 +945,8 @@ class SignatureBatcher:
             tracer = get_tracer()
             host_route = bucket == "host" or len(items) < self.host_crossover
             bctx = self._trace_flush(tracer, bucket, items, reason,
-                                     "host" if host_route else "device") \
+                                     "host" if host_route else "device",
+                                     t_cut=t_cut) \
                 if tracer.enabled else None
             jlog(_log, "batcher.flush", ctx=bctx, bucket=bucket,
                  batch_size=len(items), flush_reason=reason)
@@ -979,13 +1006,19 @@ class SignatureBatcher:
     MAX_WAIT_SPANS = 64
 
     def _trace_flush(self, tracer, bucket, items, reason, route,
-                     **tags):
+                     t_cut=None, **tags):
         """Record the flush span (+ capped per-item enqueue-wait spans) and
         return its context — the parent for dispatch/wait/resolve spans.
         ``route`` is the one the flush is about to take (an open breaker can
         still turn a device flush to the host: batcher.dispatch says so).
         A mixed batch carries many traces; the flush span joins the FIRST
-        traced submitter's trace and tags how many others rode along."""
+        traced submitter's trace and tags how many others rode along.
+
+        A batch the planner cut (``t_cut``, the plan's stamp) gets two more
+        children of the flush span: ``batcher.queue_wait``, from the enqueue
+        of its OLDEST row (rows leave a queue in the order they joined it, so
+        its first) to the cut, and ``batcher.pool_wait``, from the cut to
+        now, the flush beginning on a prep worker."""
         now = _time.time()
         first_ctx = None
         traced = 0
@@ -1000,10 +1033,22 @@ class SignatureBatcher:
                               start_s=p.t_enq,
                               duration_s=max(0.0, now - p.t_enq),
                               bucket=bucket)
-        return tracer.record("batcher.flush", parent=first_ctx, start_s=now,
+        bctx = tracer.record("batcher.flush", parent=first_ctx, start_s=now,
                              bucket=bucket, batch_size=len(items),
                              flush_reason=reason, n_traced=traced,
                              route=route, **tags)
+        if t_cut is not None and items:
+            oldest = items[0].t_enq
+            if oldest:      # 0.0: the row was queued with tracing still off
+                tracer.record("batcher.queue_wait", parent=bctx,
+                              start_s=oldest,
+                              duration_s=max(0.0, t_cut - oldest),
+                              bucket=bucket, rows=len(items),
+                              flush_reason=reason)
+            tracer.record("batcher.pool_wait", parent=bctx, start_s=t_cut,
+                          duration_s=max(0.0, now - t_cut), bucket=bucket,
+                          rows=len(items))
+        return bctx
 
     #: Max device batches in flight PER SCHEME: the one just launched plus
     #: two awaiting their results. A/B on v5e (3 runs each, 32k batches):
@@ -1038,9 +1083,9 @@ class SignatureBatcher:
         (remaining underflow, double set_result)."""
         profile_ctx = self._profile_step(bucket)
         tracer = get_tracer()
-        dspan = tracer.span("batcher.dispatch", parent=bctx, bucket=bucket,
-                            batch_size=len(items), route="device",
-                            flush_reason=reason)
+        dspan = tracer.span("batcher.dispatch", parent=bctx, cpu=True,
+                            bucket=bucket, batch_size=len(items),
+                            route="device", flush_reason=reason)
         t_prep = _time.perf_counter()
         mesh_verdicts = None
         breaker = self._breakers[bucket]
@@ -1054,8 +1099,7 @@ class SignatureBatcher:
         else:
             pin_ctx = _null_ctx()
         try:
-            with self.metrics.timer(f"SigBatcher.{bucket}.Prep"), \
-                    (profile_ctx or _null_ctx()), pin_ctx:
+            with (profile_ctx or _null_ctx()), pin_ctx:
                 # chaos seam: a "raise" rule here exercises exactly the
                 # fallback + breaker path a real kernel failure would
                 fault_point("batcher.device_dispatch", detail=bucket)
@@ -1069,7 +1113,7 @@ class SignatureBatcher:
                     # host prep HERE — overlaps other schemes' preps and
                     # the finish pool's device waits
                     if bucket == "ed25519":
-                        pending, finish = self._start_ed25519(items)
+                        pending, finish = self._start_ed25519(items, dspan)
                     else:
                         pending, finish = self._start_ecdsa(bucket, items,
                                                             dspan)
@@ -1128,7 +1172,7 @@ class SignatureBatcher:
                                   bucket=bucket, batch_size=len(items))
         t0 = _time.perf_counter()
         try:
-            with wspan, self.metrics.timer(f"SigBatcher.{bucket}.Duration"):
+            with wspan:
                 verdicts = finish(pending)
             t_end = _time.perf_counter()
             self._breakers[bucket].record_success()
@@ -1227,10 +1271,18 @@ class SignatureBatcher:
         return ed_ops.verify_batch(triples)
 
     @staticmethod
-    def _start_ed25519(items: list[_Pending]):
+    def _start_ed25519(items: list[_Pending], dspan=None):
+        """Prep + async launch of one Ed25519 batch; under ``dspan`` (the
+        batch's ``batcher.dispatch``) the rows' triples built here
+        (``ed25519.prep.items``), the prep's five phases
+        (``ed25519.prep.sig`` ... ``.handover``) and ``batcher.launch`` are
+        its children."""
         from ..ops import ed25519 as ed_ops
-        pending = ed_ops.verify_batch_async(
-            [(p.key.encoded, p.signature, p.content) for p in items])
+        with get_tracer().span("ed25519.prep.items", parent=dspan, cpu=True,
+                               bucket="ed25519", rows=len(items)):
+            triples = [(p.key.encoded, p.signature, p.content)
+                       for p in items]
+        pending = ed_ops.verify_batch_async(triples, trace_parent=dspan)
         return pending, ed_ops.finish_batch
 
     @staticmethod
@@ -1266,15 +1318,16 @@ class SignatureBatcher:
         DER or key was refused here. ``parent`` is the batch's
         ``batcher.dispatch`` span: the DER parse, the signers' key rows and
         the digest loop are its children ``ecdsa.prep.der`` /
-        ``ecdsa.prep.keys`` / ``ecdsa.prep.digest``."""
+        ``ecdsa.prep.keys`` / ``ecdsa.prep.digest``, each with ``cpu_s``."""
         import hashlib
         from ..ops import scalarprep as sp
         tracer = get_tracer()
         tags = {"bucket": curve.name, "rows": len(items)}
-        with tracer.span("ecdsa.prep.der", parent=parent, **tags):
+        with tracer.span("ecdsa.prep.der", parent=parent, cpu=True, **tags):
             r_words, s_words, ok = sp.ecdsa_sigs_to_words(
                 [p.signature for p in items])
-        with tracer.span("ecdsa.prep.keys", parent=parent, **tags):
+        with tracer.span("ecdsa.prep.keys", parent=parent, cpu=True,
+                         **tags):
             # one cached row a DISTINCT signer, then one gather over the rows
             encoded = [p.key.encoded for p in items]
             slot = {k: j for j, k in enumerate(dict.fromkeys(encoded))}
@@ -1289,7 +1342,8 @@ class SignatureBatcher:
             pub_words = table[which]
             ok &= decodes[which]
         r_words[~ok] = 0     # force the range precheck to reject
-        with tracer.span("ecdsa.prep.digest", parent=parent, **tags):
+        with tracer.span("ecdsa.prep.digest", parent=parent, cpu=True,
+                         **tags):
             e_words = sp.digests_to_words(
                 [hashlib.sha256(p.content).digest() for p in items], 4)
         return (e_words, r_words, s_words, pub_words), int((~ok).sum())

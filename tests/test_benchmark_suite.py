@@ -24,12 +24,30 @@ EXCLUDED = {("test_latejoin", "test_the_cell_has_its_files"),
             ("test_ecdsawaves",
              "test_the_cell_has_its_files_and_the_spec_gained_entries_only")}
 
+#: per-layer metrics a later PR gave a cell whose test file pins the cell's
+#: list as ``METRICS`` (a PR may add metrics, and may not edit the
+#: benchmark's files): appended to the module's list here, so that its tests
+#: hold the cell to the longer list. A `benchmark` issue moves the names into
+#: the files and takes these lines out. PR 39: the batch's life in spans.
+_BATCH = ["batch_submit_ms_p50", "batch_queue_wait_ms_p50",
+          "batch_pool_wait_ms_p50", "batch_launch_ms_p50",
+          "batch_device_wait_ms_p50", "batch_resolve_ms_p50",
+          "dispatch_offcpu_share", "dispatch_unnamed_ms_p50"]
+_ED_PREP = [f"ed25519_{_ph}_ms_p50"
+            for _ph in ("items", "sig", "keys", "digest", "scalars",
+                        "handover")]
+ADDED = {"test_ecdsawaves": _BATCH + ["ecdsa_keys_ms_p50",
+                                      "ecdsa_pad_ms_p50"],
+         "test_oopstream": [f"{_n}.stream" for _n in _BATCH + _ED_PREP]}
+
 for _path in sorted((BENCH / "tests").glob("test_*.py")):
     _spec = importlib.util.spec_from_file_location(
         f"benchmarks_tests_{_path.stem}", _path)
     _module = importlib.util.module_from_spec(_spec)
     sys.modules[_spec.name] = _module
     _spec.loader.exec_module(_module)
+    if _path.stem in ADDED:
+        _module.METRICS = _module.METRICS + ADDED[_path.stem]
     for _name, _fn in vars(_module).items():
         if _name.startswith("test_") and callable(_fn) \
                 and (_path.stem, _name) not in EXCLUDED:
@@ -48,14 +66,18 @@ def test_ecdsawaves__the_cell_has_its_files_wherever_its_entries_stand():
     assert sorted(lm["name"] for lm in cell.layer_metric_files()) \
         == sorted(k1.METRICS)
     for lm in cell.layer_metric_files():
-        assert lm["workloads"] == [k1.CELL] and lm["moves"] == "sigs_per_s"
+        # a metric of both wave cells lists both (PR 39); the k1 cell's own
+        # list it alone
+        assert k1.CELL in lm["workloads"] and lm["moves"] == "sigs_per_s"
+        assert (lm["workloads"] == [k1.CELL]) \
+            == (lm["name"] not in _BATCH), lm["name"]
     (row,) = [c for c in k1.SPEC["configs"]
               if c["name"] == "genledger-secp256k1"]
     assert row["reduced"] == ["schemes"]
     assert [w["name"] for w in k1.SPEC["workloads"]].count(k1.CELL) == 1
     names = [m["name"] for m in k1.SPEC["per_layer"]]
     at = names.index(k1.METRICS[0])
-    assert names[at:at + 10] == k1.METRICS
+    assert names[at:at + 10] == k1.METRICS[:10]
     config = cell.config
     assert config["schemes"] == ["secp256k1"]
     assert config["batcher_args"] == {"max_batch": 8192}

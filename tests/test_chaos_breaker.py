@@ -33,7 +33,8 @@ def make_batcher(clock):
 def stub_device(b):
     """Replace the ed25519 device-start seam with an instant all-valid
     kernel: recovery-probe tests must not pay an XLA compile."""
-    b._start_ed25519 = lambda items: (None, lambda pending: [True] * len(items))
+    b._start_ed25519 = lambda items, dspan=None: (
+        None, lambda pending: [True] * len(items))
 
 
 @pytest.mark.parametrize("seed", SEEDS)

@@ -91,7 +91,7 @@ def test_steady_state_varying_batches_zero_new_compiles_after_warmup():
                          max_latency_s=0.01, interactive_latency_s=0.01,
                          bucket_ladder=(8, 16, 32, 64), max_batch=64)
 
-    def stub_start(items):
+    def stub_start(items, dspan=None):
         n = len(items)
         cap = F.bucket_size(n, floor=8)      # pad exactly like the kernels
         rows = np.zeros((cap,), dtype=np.uint8)
@@ -131,7 +131,7 @@ def test_interactive_submit_meets_deadline_under_bulk_pressure():
                          max_latency_s=0.05, interactive_latency_s=0.001,
                          bucket_ladder=(8,), max_batch=8)
 
-    def slow_start(items):
+    def slow_start(items, dspan=None):
         n = len(items)
 
         def finish(pending):

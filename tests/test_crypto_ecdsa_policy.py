@@ -403,11 +403,12 @@ def test_the_bulk_word_prep_hands_over_what_the_per_row_form_did(
 
 
 @needs_native
-def test_an_ecdsa_batch_names_its_four_prep_parts_under_the_dispatch():
+def test_an_ecdsa_batch_names_its_prep_parts_and_launch_under_the_dispatch():
     """With tracing on, one ECDSA batch through the batcher's own front door
-    leaves ``ecdsa.prep.der``, ``.keys``, ``.digest`` and ``.scalars`` as
-    children of its ``batcher.dispatch`` span, tagged with the bucket and
-    the rows."""
+    leaves ``ecdsa.prep.der``, ``.keys``, ``.digest``, ``.pad`` and
+    ``.scalars`` as children of its ``batcher.dispatch`` span, tagged with
+    the bucket and the rows, then ``batcher.launch`` (the jitted call alone);
+    each says how long its thread ran (``cpu_s``), and the dispatch too."""
     from corda_tpu.observability import disable_tracing, enable_tracing
     corpus = _corpus("secp256k1")
     names = [f"valid_low_s_{i}" for i in range(4)] \
@@ -426,15 +427,25 @@ def test_an_ecdsa_batch_names_its_four_prep_parts_under_the_dispatch():
     (dispatch,) = [s for s in spans if s["name"] == "batcher.dispatch"]
     assert dispatch["tags"]["bucket"] == "secp256k1"
     parts = [s for s in spans if s["name"].startswith("ecdsa.prep.")]
-    assert sorted(s["name"] for s in parts) == [
-        "ecdsa.prep.der", "ecdsa.prep.digest", "ecdsa.prep.keys",
-        "ecdsa.prep.scalars"]
-    for part in parts:
+    assert [s["name"] for s in parts] == [
+        "ecdsa.prep.der", "ecdsa.prep.keys", "ecdsa.prep.digest",
+        "ecdsa.prep.pad", "ecdsa.prep.scalars"]
+    (launch,) = [s for s in spans if s["name"] == "batcher.launch"]
+    assert launch["tags"] == {"bucket": "secp256k1", "rows": 8,
+                              "capacity": 8,
+                              "compiled": launch["tags"]["compiled"]}
+    assert launch["tags"]["compiled"] in (True, False)
+    assert dispatch["cpu_s"] is not None
+    for part in parts + [launch]:
         assert part["parent_id"] == dispatch["span_id"]
         assert part["trace_id"] == dispatch["trace_id"]
-        assert part["tags"] == {"bucket": "secp256k1", "rows": 8}
         assert part["start_s"] >= dispatch["start_s"]
         assert part["duration_s"] <= dispatch["duration_s"]
+        assert part["cpu_s"] is not None
+    for part in parts:
+        assert part["tags"] == {"bucket": "secp256k1", "rows": 8}
+    assert launch["start_s"] >= parts[-1]["start_s"] + parts[-1]["duration_s"] \
+        - 1e-4
 
 
 @needs_native
