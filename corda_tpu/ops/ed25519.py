@@ -662,103 +662,146 @@ def prepare_batch_windowed(items: list[tuple[bytes, bytes, bytes]],
 
 
 
-def prepare_batch_split(items: list[tuple[bytes, bytes, bytes]],
+def _columns(items):
+    """(pub32, sig64, msg) triples → the word prep's three lists."""
+    return ([bytes(pub) for pub, _, _ in items],
+            [sig for _, sig, _ in items], [msg for _, _, msg in items])
+
+
+def _signer_slots(keys):
+    """The batch's DISTINCT signers as the word prep's slot table, one
+    ``_signer_row`` lookup each: (which (n,) i32 — each row's slot —,
+    slot_keys (S, 32) u8, slot_rows (S, 6, 16) u16, slot_ok (S,) u8; a key
+    that is no point keeps zeros and ok = 0)."""
+    slot = {k: j for j, k in enumerate(dict.fromkeys(keys))}
+    slot_keys = np.zeros((len(slot), 32), dtype=np.uint8)
+    slot_rows = np.zeros((len(slot), 6, F.NLIMB), dtype=np.uint16)
+    slot_ok = np.zeros(len(slot), dtype=np.uint8)
+    for k, j in slot.items():
+        row = _signer_row(k)
+        if row is not None:
+            slot_keys[j] = np.frombuffer(k, dtype=np.uint8)
+            slot_rows[j], slot_ok[j] = row, 1
+    which = np.fromiter(map(slot.__getitem__, keys), dtype=np.int32,
+                        count=len(keys))
+    return which, slot_keys, slot_rows, slot_ok
+
+
+def prepare_words_split(keys, sigs, msgs, capacity: int | None = None,
                         w: int = SPLIT_B_WINDOW, device_tables: bool = True,
                         staging=None, trace_parent=None):
-    """Host prep for the split-k kernel: signatures parsed by numpy (the
-    wire bytes ARE little-endian u16 limbs), per-signer (−A, −A') rows from
-    the _signer_row cache, SHA-512 challenges via hashlib, and the scalar
-    windows from native scalarmath (Python-bigint fallback below).
+    """Host prep for the split-k kernel in WORD form: the rows' keys,
+    signatures and messages as three lists, taken in bulk. Python builds
+    only the inputs of ONE native call (scalarprep.ed_prep_words: parse,
+    SHA-512 challenges, scalars, windows, the signers' rows gathered, the
+    padding up to ``capacity``), one ``_signer_row`` lookup a DISTINCT
+    signer, and hands the four wire arrays over in one ``device_put``.
+    Without libscalarmath.so the same inputs go through
+    :func:`_prep_words_python`, bit-identical and slow.
 
     Returns (bb_idx, a_packed, rows, r_packed, [tables...], precheck) —
     the consolidated 4-array wire form of verify_core_split.
 
     Under ``trace_parent`` (the batcher's ``batcher.dispatch`` span) the
-    five phases are its children ``ed25519.prep.sig`` / ``.keys`` /
-    ``.digest`` / ``.scalars`` / ``.handover``, each tagged ``bucket`` and
-    ``rows`` and carrying ``cpu_s``; without one (the mesh route, the
-    tools) no span is opened."""
+    five phases are its children ``ed25519.prep.sig`` (the signatures'
+    join and lengths) / ``.keys`` (the distinct signers' slot table and
+    the rows' index into it) / ``.digest`` (the messages' join and
+    lengths: the hash's input) / ``.scalars`` (the one native call) /
+    ``.handover`` (the transfer), each tagged ``bucket`` and ``rows`` and
+    carrying ``cpu_s``; without one (the mesh route, the tools) no span
+    is opened."""
     from ..observability.tracing import NOOP_TRACER, get_tracer
     from . import scalarprep as sp
     assert w == 16, "split prep emits 16-bit constant-base windows"
-    n = len(items)
+    if capacity is None:
+        capacity = len(keys)
     tracer = get_tracer() if trace_parent is not None else NOOP_TRACER
-    tags = {"bucket": "ed25519", "rows": n}
+    tags = {"bucket": "ed25519", "rows": capacity}
     with tracer.span("ed25519.prep.sig", parent=trace_parent, cpu=True,
                      **tags):
-        # signature bytes land in ONE joined frombuffer when every sig is
-        # the wire-format 64 bytes (the overwhelmingly common case) — n
-        # per-row frombuffer copies otherwise. Items whose KEY fails
-        # decompression keep their sig bytes here; their verdict is masked
-        # by precheck anyway.
-        sig_ok = np.fromiter((len(sig) == 64 for _, sig, _ in items),
-                             dtype=bool, count=n)
-        if sig_ok.all():
-            sig_mat = np.frombuffer(b"".join(sig for _, sig, _ in items),
-                                    dtype=np.uint8).reshape(n, 64)
-        else:
-            sig_mat = np.zeros((n, 64), dtype=np.uint8)
-            for i, (_, sig, _) in enumerate(items):
-                if sig_ok[i]:
-                    sig_mat[i] = np.frombuffer(sig, dtype=np.uint8)
-        r_packed = sig_mat[:, :32].copy().view("<u2")       # (n, 16) wire y
-        # the wire sign bit stays IN limb 15 bit 15 (the kernel unpacks it);
-        # range checks use the masked view
-        y15 = r_packed[:, 15] & 0x7FFF
-        # non-canonical y (>= p = 2^255-19) rejects like a failed
-        # decompression
-        ge_p = ((r_packed[:, 0] >= 0xFFED) & (y15 == 0x7FFF)
-                & (r_packed[:, 1:15] == 0xFFFF).all(axis=1))
-        s_words = sig_mat[:, 32:].copy().view("<u8")        # (n, 4)
+        sig_buf, sig_len = sp.join_rows(sigs)
     with tracer.span("ed25519.prep.keys", parent=trace_parent, cpu=True,
+                     **tags):
+        which, slot_keys, slot_rows, slot_ok = _signer_slots(keys)
+    with tracer.span("ed25519.prep.digest", parent=trace_parent, cpu=True,
+                     **tags):
+        msg_buf, msg_len = sp.join_rows(msgs)
+    with tracer.span("ed25519.prep.scalars", parent=trace_parent, cpu=True,
                      **tags):
         # ``staging`` (ops.staging.StagingLease) reuses the largest
         # per-batch host buffer across flushes of the same bucket size —
-        # every row is overwritten below, so carried-over data never leaks
-        # into a verdict
-        rows = (staging.take("ed.rows", (n, 6, F.NLIMB), np.uint16)
-                if staging is not None
-                else np.empty((n, 6, F.NLIMB), dtype=np.uint16))
-        keyed = np.ones(n, dtype=bool)     # length and key both passed
-        sub = _substitute_row()
-        for i, (pub, _, _) in enumerate(items):
-            row = _signer_row(bytes(pub)) if sig_ok[i] else None
-            if row is None:
-                keyed[i] = False
-                rows[i] = sub
-            else:
-                rows[i] = row
-        precheck = keyed & ~ge_p
-    with tracer.span("ed25519.prep.digest", parent=trace_parent, cpu=True,
-                     **tags):
-        # a second pass over the items (the keys' pass decided which rows
-        # hash): k := 0 where the key or the length was refused (the
-        # verdict is masked anyway)
-        zero = bytes(64)
-        digests = [hashlib.sha512(sig[:32] + pub + msg).digest() if ok
-                   else zero
-                   for ok, (pub, sig, msg) in zip(keyed.tolist(), items)]
-        native = sp.available()
-        if native:
-            h_words = sp.le_digests_to_words(digests, 8)
-    with tracer.span("ed25519.prep.scalars", parent=trace_parent, cpu=True,
-                     **tags):
-        if native:
-            b_idx, b2_idx, a_packed, s_ok = sp.ed_prep(h_words, s_words)
-        else:
-            b_idx, b2_idx, a_packed, s_ok = _split_windows_python(
-                digests, s_words)
-        precheck &= s_ok
+        # every row is overwritten, so carried-over data never leaks into
+        # a verdict
+        rows_out = (staging.take("ed.rows", (capacity, 6, F.NLIMB),
+                                 np.uint16)
+                    if staging is not None else None)
+        prep = sp.ed_prep_words if sp.available() else _prep_words_python
+        bb_idx, a_packed, rows, r_packed, precheck = prep(
+            sig_buf, sig_len, msg_buf, msg_len, which, slot_keys, slot_rows,
+            slot_ok, _substitute_row(), capacity, rows_out)
     with tracer.span("ed25519.prep.handover", parent=trace_parent, cpu=True,
                      **tags):
-        a_digits = a_packed.reshape(128 // w, w // 2, n)
-        head = (jnp.asarray(np.concatenate([b_idx, b2_idx])),
-                jnp.asarray(a_digits), jnp.asarray(rows),
-                jnp.asarray(r_packed))
+        head = jax.device_put(
+            (bb_idx, a_packed.reshape(128 // w, w // 2, capacity), rows,
+             r_packed))
         if device_tables:
             return (*head, *b_table_device(w, 0), *b_table_device(w, 128),
                     precheck)
         return (*head, precheck)
+
+
+def prepare_batch_split(items: list[tuple[bytes, bytes, bytes]],
+                        w: int = SPLIT_B_WINDOW, device_tables: bool = True,
+                        staging=None, trace_parent=None):
+    """:func:`prepare_words_split` for (public_key32, signature64, message)
+    triples (the mesh route, the tools, the tests): ONE prep behind both
+    forms."""
+    return prepare_words_split(*_columns(items), None, w, device_tables,
+                               staging, trace_parent)
+
+
+def _prep_words_python(sig_buf, sig_len, msg_buf, msg_len, which, slot_keys,
+                       slot_rows, slot_ok, sub_row, capacity, rows_out=None):
+    """scalarprep.ed_prep_words as numpy and Python loops: what runs where
+    libscalarmath.so is absent or stale, and the oracle the native call is
+    held to row for row (tests/test_scalarprep.py). Same arguments, same
+    five arrays, bit for bit."""
+    n = len(which)
+    sig_at = np.concatenate(([0], np.cumsum(sig_len)))
+    msg_at = np.concatenate(([0], np.cumsum(msg_len)))
+    sig_ok = sig_len == 64
+    if sig_ok.all():
+        sig_mat = sig_buf.reshape(n, 64)
+    else:
+        sig_mat = np.zeros((n, 64), dtype=np.uint8)
+        for i in np.flatnonzero(sig_ok):
+            sig_mat[i] = sig_buf[sig_at[i]:sig_at[i] + 64]
+    r_packed = sig_mat[:, :32].copy().view("<u2")       # (n, 16) wire y
+    # the wire sign bit stays IN limb 15 bit 15 (the kernel unpacks it);
+    # range checks use the masked view
+    y15 = r_packed[:, 15] & 0x7FFF
+    # non-canonical y (>= p = 2^255-19) rejects like a failed decompression
+    ge_p = ((r_packed[:, 0] >= 0xFFED) & (y15 == 0x7FFF)
+            & (r_packed[:, 1:15] == 0xFFFF).all(axis=1))
+    s_words = sig_mat[:, 32:].copy().view("<u8")        # (n, 4)
+    keyed = sig_ok & slot_ok[which].astype(bool)   # length and key passed
+    # k := 0 where the key or the length was refused: such a row does not
+    # hash (the verdict is masked anyway)
+    zero = bytes(64)
+    digests = [hashlib.sha512(sig_mat[i, :32].tobytes()
+                              + slot_keys[which[i]].tobytes()
+                              + msg_buf[msg_at[i]:msg_at[i + 1]].tobytes()
+                              ).digest() if keyed[i] else zero
+               for i in range(n)]
+    b_idx, b2_idx, a_packed, s_ok = _split_windows_python(digests, s_words)
+    precheck = keyed & ~ge_p & s_ok
+    # rows n .. capacity-1 repeat row n-1 (the kernels' padding)
+    fill = np.minimum(np.arange(capacity), n - 1)
+    rows = (np.empty((capacity, 6, F.NLIMB), dtype=np.uint16)
+            if rows_out is None else rows_out)
+    rows[:] = np.where(keyed[:, None, None], slot_rows[which], sub_row)[fill]
+    return (np.concatenate([b_idx, b2_idx])[:, fill], a_packed[:, fill],
+            rows, r_packed[fill], precheck[fill])
 
 
 def _split_windows_python(digests: list[bytes], s_words: np.ndarray):
@@ -809,42 +852,48 @@ def _service_kernel_split():
 
 def verify_batch_async(items: list[tuple[bytes, bytes, bytes]],
                        trace_parent=None):
+    """:func:`verify_batch_async_words` for (pub32, sig64, msg) triples."""
+    return verify_batch_async_words(*_columns(items), trace_parent)
+
+
+def verify_batch_async_words(keys, sigs, msgs, trace_parent=None):
     """Dispatch without forcing (see weierstrass.verify_batch_async): the
     device computes while the caller preps the next batch. Rides the
     split-k half-length ladder — the fastest measured path (PERF.md
     section 5) — with donated per-batch device buffers and leased host
-    staging arrays (ops.staging) on the service path. Dispatches go
-    through the kernel flight recorder (observability.profiling):
-    compile-cache accounting + batch occupancy. ``trace_parent`` is the
-    batcher's ``batcher.dispatch`` span: the prep's phases
-    (:func:`prepare_batch_split`) and ``batcher.launch``, the jitted call
-    alone until it returns, are its children."""
+    staging arrays (ops.staging) on the service path. The rows arrive as
+    three lists (:func:`prepare_words_split`, which also pads them to the
+    bucket). Dispatches go through the kernel flight recorder
+    (observability.profiling): compile-cache accounting + batch occupancy.
+    ``trace_parent`` is the batcher's ``batcher.dispatch`` span: the prep's
+    phases and ``batcher.launch``, the jitted call alone until it returns,
+    are its children."""
     from ..observability.profiling import get_profiler
     from ..observability.tracing import get_tracer
     from .staging import get_staging_pool
-    n = len(items)
+    n = len(keys)
     if n == 0:
         return (None, np.zeros(0, dtype=bool), 0)
-    padded = items + [items[-1]] * (F.bucket_size(n) - n)
+    capacity = F.bucket_size(n)
     pool = get_staging_pool()
     lease = pool.lease()
-    *args, precheck = prepare_batch_split(padded, SPLIT_B_WINDOW,
-                                          staging=lease,
-                                          trace_parent=trace_parent)
+    *args, precheck = prepare_words_split(
+        keys, sigs, msgs, capacity, SPLIT_B_WINDOW, staging=lease,
+        trace_parent=trace_parent)
     with get_tracer().span("batcher.launch", parent=trace_parent, cpu=True,
                            bucket="ed25519", rows=n,
-                           capacity=len(padded)) as lspan:
+                           capacity=capacity) as lspan:
         dev = get_profiler().call(
             "ed25519.split", _service_kernel_split(), *args,
-            w=SPLIT_B_WINDOW, live=n, capacity=len(padded),
+            w=SPLIT_B_WINDOW, live=n, capacity=capacity,
             scheme="ed25519",
             field_products_per_row=functools.partial(
-                split_field_products, len(padded), SPLIT_B_WINDOW),
+                split_field_products, capacity, SPLIT_B_WINDOW),
             trace_span=lspan)
     pending = (dev, precheck, n)
     # the lease rides the pending handle: finish_batch releases it after
     # the force, the earliest point the device provably no longer reads
-    # the staged host memory (CPU jnp.asarray zero-copies; TPU H2D is
+    # the staged host memory (CPU device_put zero-copies; TPU H2D is
     # async)
     pool.attach(pending, lease)
     return pending

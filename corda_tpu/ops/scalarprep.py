@@ -33,7 +33,23 @@ _CANDIDATES = [
 #: the ECDSA preps' range check to Crypto.doVerify's (s in [1, n-1], no
 #: low-s bound): a version-3 library would refuse every high-s signature.
 #: Version 5 added sm_ecdsa_der_words (the strict-DER parse of a batch).
-SM_VERSION = 5
+#: Version 6 added sm_ed_prep_words (the whole Ed25519 split prep of a batch:
+#: parse, SHA-512 challenges, scalars, windows, the signers' rows, padding)
+#: and sm_sha512, the seam its hash is held to hashlib through.
+SM_VERSION = 6
+
+#: Live rows up to which sm_ed_prep_words is called with the interpreter lock
+#: HELD (PyDLL), and over which it is let go (CDLL): one algorithm, the one
+#: parameter read off the input. A thread that gives the lock up waits a
+#: switch interval (5 ms) to get it back while any other thread computes, so
+#: a short call is cheaper held; a long one holds every other thread up.
+#: Measured on a TPU v5e's host (PERF.md section 6, PR 40), both handles:
+#: at 8,192 rows (genledger-ed25519.wave8k; the call runs 7.5-8.3 ms) let go
+#: 316.8-330.5k sigs/s, held 243.0-249.9k; at 256 rows (genledger-oop.stream's
+#: flushes; 0.23 ms) ``ed25519.prep.scalars`` p50 0.30-0.34 ms held, 1.93 let
+#: go (mean 3.09). The rungs between were not measured: the constant sits
+#: where the call is ~1 ms, a fifth of a switch interval.
+ED_WORDS_HOLD_LOCK_ROWS = 1024
 
 _log = logging.getLogger(__name__)
 
@@ -79,6 +95,20 @@ def _bind(lib) -> None:
         ctypes.c_int64, _U64P, _U64P, _U64P, _U64P,
         _I32P, _U8P, _U16P, _U16P, _U16P,
         _U8P, _U8P, _U64P]
+    lib.sm_sha512.restype = ctypes.c_int
+    lib.sm_sha512.argtypes = [_U8P, ctypes.c_int64, _U8P]
+    # both handles of the one export: ed_prep_words chooses by row count
+    for name, dll in (("sm_ed_prep_words", lib),
+                      ("sm_ed_prep_words_held",
+                       ctypes.PyDLL(lib._name, handle=lib._handle))):
+        fn = dll.sm_ed_prep_words
+        fn.restype = ctypes.c_int
+        fn.argtypes = [
+            ctypes.c_int64, ctypes.c_int64,
+            _U8P, ctypes.c_int64, _I64P, _U8P, ctypes.c_int64, _I64P,
+            _I32P, ctypes.c_int64, _U8P, _U16P, _U8P, _U16P,
+            _I32P, _U8P, _U16P, _U16P, _U8P]
+        setattr(lib, name, fn)
     lib.sm_ed_prep.restype = ctypes.c_int
     lib.sm_ed_prep.argtypes = [
         ctypes.c_int64, _U64P, _U64P, _I32P, _I32P, _U8P, _U8P]
@@ -150,6 +180,14 @@ def le_digests_to_words(digests: list[bytes], nwords: int) -> np.ndarray:
         len(digests), nwords).copy()
 
 
+def join_rows(chunks) -> tuple[np.ndarray, np.ndarray]:
+    """Byte strings of any lengths → (their join as a u8 array, their
+    lengths as int64): the form a native batch export takes rows in. Two
+    calls that hold the interpreter lock and no numpy pass over the rows."""
+    lengths = np.fromiter(map(len, chunks), dtype=np.int64, count=len(chunks))
+    return np.frombuffer(b"".join(chunks), dtype=np.uint8), lengths
+
+
 def ecdsa_sigs_to_words(sigs) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Strict-DER ECDSA signatures → (r_words (B,4), s_words (B,4),
     ok (B,) bool), the preps' LE u64 wire format — a batched
@@ -168,10 +206,9 @@ def ecdsa_sigs_to_words(sigs) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     if _LIB is None:
         return ecdsa_sigs_to_words_py(sigs)
     n = len(sigs)
+    buf, lengths = join_rows(sigs)
     offsets = np.zeros(n + 1, dtype=np.int64)
-    np.cumsum(np.fromiter(map(len, sigs), dtype=np.int64, count=n),
-              out=offsets[1:])
-    buf = np.frombuffer(b"".join(sigs), dtype=np.uint8)
+    np.cumsum(lengths, out=offsets[1:])
     r_words = np.empty((n, 4), dtype=np.uint64)
     s_words = np.empty((n, 4), dtype=np.uint64)
     ok = np.empty(n, dtype=np.uint8)
@@ -249,6 +286,15 @@ def glv(k: int) -> tuple[int, int]:
     k1 = int.from_bytes(a1.tobytes(), "little")
     k2 = int.from_bytes(a2.tobytes(), "little")
     return (-k1 if negs[0] else k1), (-k2 if negs[1] else k2)
+
+
+def sha512(data: bytes) -> bytes:
+    """Native seam: the library's own SHA-512 (sm_ed_prep_words hashes with
+    it), held to hashlib.sha512 by tests/test_scalarprep.py."""
+    out = np.empty(64, dtype=np.uint8)
+    rc = _LIB.sm_sha512(np.frombuffer(data, dtype=np.uint8), len(data), out)
+    assert rc == 0, rc
+    return out.tobytes()
 
 
 def r1p_mulfast(a: int, b: int) -> int:
@@ -409,3 +455,42 @@ def ed_prep_plain(h_words, s_words):
     if rc != 0:
         raise RuntimeError(f"sm_ed_prep_plain failed: {rc}")
     return b_idx, a_digits, s_ok.astype(bool)
+
+
+def ed_prep_words(sig_buf, sig_len, msg_buf, msg_len, which, slot_keys,
+                  slot_rows, slot_ok, sub_row, capacity: int, rows_out=None):
+    """The whole Ed25519 split-k prep of a batch in ONE native call
+    (sm_ed_prep_words; its wire form is described there): the rows' joined
+    signatures and messages with their lengths (:func:`join_rows`), each
+    row's slot ``which`` (n,) i32 in the table of the batch's distinct
+    signers (``slot_keys`` (S, 32) u8, ``slot_rows`` (S, 6, 16) u16,
+    ``slot_ok`` (S,) u8), the substitute row, and the padded ``capacity``.
+    Returns (bb_idx (16, cap) i32, a_packed (64, cap) u8, rows (cap, 6, 16)
+    u16 — ``rows_out`` where given, a staging lease's buffer —, r_packed
+    (cap, 16) u16, precheck (cap,) bool).
+
+    Made with the interpreter lock held up to ED_WORDS_HOLD_LOCK_ROWS live
+    rows and let go over that."""
+    n = len(which)
+    if not (len(sig_len) == len(msg_len) == n and 0 <= n <= capacity
+            and len(slot_keys) == len(slot_rows) == len(slot_ok)
+            and slot_keys.shape[1:] == (32,)
+            and slot_rows.shape[1:] == sub_row.shape == (6, 16)):
+        raise ValueError("ed_prep_words: inconsistent input shapes")
+    rows = (np.empty((capacity, 6, 16), dtype=np.uint16)
+            if rows_out is None else rows_out)
+    if rows.shape != (capacity, 6, 16):
+        raise ValueError("ed_prep_words: rows_out is not (capacity, 6, 16)")
+    bb_idx = np.empty((16, capacity), dtype=np.int32)
+    a_packed = np.empty((64, capacity), dtype=np.uint8)
+    r_packed = np.empty((capacity, 16), dtype=np.uint16)
+    precheck = np.empty(capacity, dtype=np.uint8)
+    call = (_LIB.sm_ed_prep_words_held if n <= ED_WORDS_HOLD_LOCK_ROWS
+            else _LIB.sm_ed_prep_words)
+    rc = call(n, capacity, sig_buf, len(sig_buf), sig_len,
+              msg_buf, len(msg_buf), msg_len, which, len(slot_ok),
+              slot_keys, slot_rows, slot_ok, sub_row,
+              bb_idx, a_packed, rows, r_packed, precheck)
+    if rc != 0:
+        raise RuntimeError(f"sm_ed_prep_words failed: {rc}")
+    return bb_idx, a_packed, rows, r_packed, precheck.view(bool)

@@ -1270,19 +1270,28 @@ class SignatureBatcher:
         from ..ops import ed25519 as ed_ops
         return ed_ops.verify_batch(triples)
 
-    @staticmethod
-    def _start_ed25519(items: list[_Pending], dspan=None):
-        """Prep + async launch of one Ed25519 batch; under ``dspan`` (the
-        batch's ``batcher.dispatch``) the rows' triples built here
-        (``ed25519.prep.items``), the prep's five phases
-        (``ed25519.prep.sig`` ... ``.handover``) and ``batcher.launch`` are
-        its children."""
+    def _start_ed25519(self, items: list[_Pending], dspan=None):
+        """Prep + async launch of one Ed25519 batch, taken in bulk as
+        ``_ecdsa_words`` takes an ECDSA one: three lists out of the rows
+        (``ed25519.prep.items``), then the word prep's five phases
+        (``ed25519.prep.sig`` ... ``.handover``) and ``batcher.launch``, all
+        children of ``dspan`` (the batch's ``batcher.dispatch``). Which form
+        of the prep ran is metered by rows: ``Ed25519WordsPrep`` is the one
+        native call, ``Ed25519ItemsPrep`` the pure-Python form taken in
+        silence when libscalarmath.so is missing or stale."""
         from ..ops import ed25519 as ed_ops
+        from ..ops import scalarprep as sp
         with get_tracer().span("ed25519.prep.items", parent=dspan, cpu=True,
                                bucket="ed25519", rows=len(items)):
-            triples = [(p.key.encoded, p.signature, p.content)
-                       for p in items]
-        pending = ed_ops.verify_batch_async(triples, trace_parent=dspan)
+            keys = [p.key.encoded for p in items]
+            sigs = [p.signature for p in items]
+            msgs = [p.content for p in items]
+        native = sp.available()
+        pending = ed_ops.verify_batch_async_words(keys, sigs, msgs,
+                                                  trace_parent=dspan)
+        self.metrics.meter("SigBatcher.Ed25519WordsPrep" if native
+                           else "SigBatcher.Ed25519ItemsPrep").mark(
+                               len(items))
         return pending, ed_ops.finish_batch
 
     @staticmethod

@@ -648,6 +648,138 @@ bool r1p_sqrt(const u64 z[4], u64 y[4]) {
     return true;
 }
 
+// ---------------------------------------------------------------------------
+// SHA-512 (FIPS 180-4), portable: the library links nothing.  Held to
+// hashlib.sha512 through sm_sha512 (tests/test_scalarprep.py).
+// ---------------------------------------------------------------------------
+
+const u64 SHA512_K[80] = {
+    0x428a2f98d728ae22ull, 0x7137449123ef65cdull, 0xb5c0fbcfec4d3b2full, 0xe9b5dba58189dbbcull,
+    0x3956c25bf348b538ull, 0x59f111f1b605d019ull, 0x923f82a4af194f9bull, 0xab1c5ed5da6d8118ull,
+    0xd807aa98a3030242ull, 0x12835b0145706fbeull, 0x243185be4ee4b28cull, 0x550c7dc3d5ffb4e2ull,
+    0x72be5d74f27b896full, 0x80deb1fe3b1696b1ull, 0x9bdc06a725c71235ull, 0xc19bf174cf692694ull,
+    0xe49b69c19ef14ad2ull, 0xefbe4786384f25e3ull, 0x0fc19dc68b8cd5b5ull, 0x240ca1cc77ac9c65ull,
+    0x2de92c6f592b0275ull, 0x4a7484aa6ea6e483ull, 0x5cb0a9dcbd41fbd4ull, 0x76f988da831153b5ull,
+    0x983e5152ee66dfabull, 0xa831c66d2db43210ull, 0xb00327c898fb213full, 0xbf597fc7beef0ee4ull,
+    0xc6e00bf33da88fc2ull, 0xd5a79147930aa725ull, 0x06ca6351e003826full, 0x142929670a0e6e70ull,
+    0x27b70a8546d22ffcull, 0x2e1b21385c26c926ull, 0x4d2c6dfc5ac42aedull, 0x53380d139d95b3dfull,
+    0x650a73548baf63deull, 0x766a0abb3c77b2a8ull, 0x81c2c92e47edaee6ull, 0x92722c851482353bull,
+    0xa2bfe8a14cf10364ull, 0xa81a664bbc423001ull, 0xc24b8b70d0f89791ull, 0xc76c51a30654be30ull,
+    0xd192e819d6ef5218ull, 0xd69906245565a910ull, 0xf40e35855771202aull, 0x106aa07032bbd1b8ull,
+    0x19a4c116b8d2d0c8ull, 0x1e376c085141ab53ull, 0x2748774cdf8eeb99ull, 0x34b0bcb5e19b48a8ull,
+    0x391c0cb3c5c95a63ull, 0x4ed8aa4ae3418acbull, 0x5b9cca4f7763e373ull, 0x682e6ff3d6b2b8a3ull,
+    0x748f82ee5defb2fcull, 0x78a5636f43172f60ull, 0x84c87814a1f0ab72ull, 0x8cc702081a6439ecull,
+    0x90befffa23631e28ull, 0xa4506cebde82bde9ull, 0xbef9a3f7b2c67915ull, 0xc67178f2e372532bull,
+    0xca273eceea26619cull, 0xd186b8c721c0c207ull, 0xeada7dd6cde0eb1eull, 0xf57d4f7fee6ed178ull,
+    0x06f067aa72176fbaull, 0x0a637dc5a2c898a6ull, 0x113f9804bef90daeull, 0x1b710b35131c471bull,
+    0x28db77f523047d84ull, 0x32caab7b40c72493ull, 0x3c9ebe0a15c9bebcull, 0x431d67c49c100d4cull,
+    0x4cc5d4becb3e42b6ull, 0x597f299cfc657e2aull, 0x5fcb6fab3ad6faecull, 0x6c44198c4a475817ull,
+};
+
+inline u64 rotr64(u64 x, int n) { return (x >> n) | (x << (64 - n)); }
+
+// One 128-byte block into the state (big-endian message words).
+void sha512_block(u64 h[8], const u8* p) {
+    u64 w[80];
+    for (int t = 0; t < 16; ++t) {
+        u64 v = 0;
+        for (int j = 0; j < 8; ++j) v = (v << 8) | p[8 * t + j];
+        w[t] = v;
+    }
+    for (int t = 16; t < 80; ++t) {
+        u64 s0 = rotr64(w[t - 15], 1) ^ rotr64(w[t - 15], 8) ^ (w[t - 15] >> 7);
+        u64 s1 = rotr64(w[t - 2], 19) ^ rotr64(w[t - 2], 61) ^ (w[t - 2] >> 6);
+        w[t] = w[t - 16] + s0 + w[t - 7] + s1;
+    }
+    u64 a = h[0], b = h[1], c = h[2], d = h[3];
+    u64 e = h[4], f = h[5], g = h[6], hh = h[7];
+    for (int t = 0; t < 80; ++t) {
+        u64 S1 = rotr64(e, 14) ^ rotr64(e, 18) ^ rotr64(e, 41);
+        u64 t1 = hh + S1 + ((e & f) ^ (~e & g)) + SHA512_K[t] + w[t];
+        u64 S0 = rotr64(a, 28) ^ rotr64(a, 34) ^ rotr64(a, 39);
+        u64 t2 = S0 + ((a & b) ^ (a & c) ^ (b & c));
+        hh = g; g = f; f = e; e = d + t1;
+        d = c; c = b; b = a; a = t1 + t2;
+    }
+    h[0] += a; h[1] += b; h[2] += c; h[3] += d;
+    h[4] += e; h[5] += f; h[6] += g; h[7] += hh;
+}
+
+struct Sha512 {
+    u64 h[8];
+    u8 buf[128];
+    size_t fill;
+    u64 total;      // bytes so far (a message here is far under 2^61 bytes)
+};
+
+void sha512_init(Sha512* S) {
+    static const u64 H0[8] = {
+        0x6a09e667f3bcc908ull, 0xbb67ae8584caa73bull, 0x3c6ef372fe94f82bull,
+        0xa54ff53a5f1d36f1ull, 0x510e527fade682d1ull, 0x9b05688c2b3e6c1full,
+        0x1f83d9abfb41bd6bull, 0x5be0cd19137e2179ull};
+    std::memcpy(S->h, H0, sizeof H0);
+    S->fill = 0;
+    S->total = 0;
+}
+
+void sha512_update(Sha512* S, const u8* p, size_t n) {
+    S->total += n;
+    if (S->fill) {
+        size_t take = 128 - S->fill < n ? 128 - S->fill : n;
+        std::memcpy(S->buf + S->fill, p, take);
+        S->fill += take;
+        p += take;
+        n -= take;
+        if (S->fill < 128) return;
+        sha512_block(S->h, S->buf);
+        S->fill = 0;
+    }
+    for (; n >= 128; p += 128, n -= 128) sha512_block(S->h, p);
+    if (n) {
+        std::memcpy(S->buf, p, n);
+        S->fill = n;
+    }
+}
+
+// The digest as its 64 big-endian bytes (what hashlib's digest() gives).
+void sha512_final(Sha512* S, u8 out[64]) {
+    S->buf[S->fill++] = 0x80;
+    if (S->fill > 112) {
+        std::memset(S->buf + S->fill, 0, 128 - S->fill);
+        sha512_block(S->h, S->buf);
+        S->fill = 0;
+    }
+    std::memset(S->buf + S->fill, 0, 120 - S->fill);   // high length word too
+    u64 bits = S->total << 3;
+    for (int j = 0; j < 8; ++j) S->buf[120 + j] = (u8)(bits >> (8 * (7 - j)));
+    S->buf[119] = (u8)(S->total >> 61);
+    sha512_block(S->h, S->buf);
+    for (int i = 0; i < 8; ++i)
+        for (int j = 0; j < 8; ++j)
+            out[8 * i + j] = (u8)(S->h[i] >> (8 * (7 - j)));
+}
+
+// The split ladder's scalar windows of one row: s = s_lo + 2^128 s_hi as
+// w=16 windows MSB-first over each 128-bit half (b_lo / b_hi, (8, stride)),
+// k as joint 2-bit digits klo | khi<<2 (a_packed, (64, stride)).
+inline void ed_split_windows(const u64 s[4], const u64 k[4], int64_t stride,
+                             int64_t i, int32_t* b_lo, int32_t* b_hi,
+                             u8* a_packed) {
+    for (int t = 0; t < 8; ++t) {
+        int shift = 16 * (7 - t);        // within the 128-bit half
+        b_lo[(int64_t)t * stride + i] =
+            (int32_t)((s[shift / 64] >> (shift % 64)) & 0xFFFF);
+        b_hi[(int64_t)t * stride + i] =
+            (int32_t)((s[2 + shift / 64] >> (shift % 64)) & 0xFFFF);
+    }
+    for (int t = 0; t < 64; ++t) {
+        int shift = 2 * (63 - t);
+        u32 klo = (u32)((k[shift / 64] >> (shift % 64)) & 3);
+        u32 khi = (u32)((k[2 + shift / 64] >> (shift % 64)) & 3);
+        a_packed[(int64_t)t * stride + i] = (u8)(klo | (khi << 2));
+    }
+}
+
 }  // namespace
 
 // ---------------------------------------------------------------------------
@@ -656,7 +788,7 @@ bool r1p_sqrt(const u64 z[4], u64 y[4]) {
 
 extern "C" {
 
-int sm_version() { return 5; }
+int sm_version() { return 6; }
 
 // Differential-test seam: r = a*b mod m for mod_id in
 // {0: k1 n, 1: k1 p, 2: r1 n, 3: r1 p, 4: ed L, 5: ed P}.
@@ -684,6 +816,16 @@ int sm_glv(const u64* k, u8* negs, u64* abs1, u64* abs2) {
     negs[0] = n1;
     negs[1] = n2;
     return fit ? 0 : -2;
+}
+
+// Differential-test seam: out = SHA-512(buf[0 .. len)).
+int sm_sha512(const u8* buf, int64_t len, u8* out) {
+    if (len < 0) return -1;
+    Sha512 S;
+    sha512_init(&S);
+    sha512_update(&S, buf, (size_t)len);
+    sha512_final(&S, out);
+    return 0;
 }
 
 // Strict-DER ECDSA signatures -> LE u64 word rows: the native body of
@@ -1115,19 +1257,101 @@ int sm_ed_prep(int64_t n,
             mp_zero(s, 4);
             mp_zero(k, 4);
         }
-        // s = s_lo + 2^128 s_hi; windows of 16 bits, MSB-first over 128 bits
-        for (int t = 0; t < 8; ++t) {
-            int shift = 16 * (7 - t);        // within the 128-bit half
-            b_idx[(int64_t)t * n + i] =
-                (int32_t)((s[shift / 64] >> (shift % 64)) & 0xFFFF);
-            b2_idx[(int64_t)t * n + i] =
-                (int32_t)((s[2 + shift / 64] >> (shift % 64)) & 0xFFFF);
+        ed_split_windows(s, k, n, i, b_idx, b2_idx, a_packed);
+    }
+    return 0;
+}
+
+// The whole Ed25519 split-k prep of a batch in one call: what
+// ops/ed25519.py prepare_batch_split returns, for all `cap` rows, from the
+// rows' wire bytes.  Row i < n: signature sigs[sum(sig_len[..i]) ..) of
+// sig_len[i] bytes (anything but 64: refused for that row alone, its wire
+// words all zero), message likewise of ANY length, and the slot which[i]
+// of its signer in a table of the batch's DISTINCT signers: the key's 32
+// bytes, its cached (-A, -A') limb rows, and whether it decoded.  Written:
+//   r_packed  the wire y, sign bit left in limb 15
+//   rows      the signer's rows (sub_row where the length or the key was
+//             refused: such a row does not hash, k := 0)
+//   bb_idx    w=16 windows of s_lo (rows 0..7) and s_hi (8..15), MSB-first
+//   a_packed  klo | khi<<2 2-bit digits of k = SHA-512(R || A || M) mod L
+//   precheck  length, key, y < p and s < L all passed (s, k := 0 where
+//             s >= L, as sm_ed_prep)
+// Rows n .. cap-1 repeat row n-1 (the kernels' padding).  Returns -1 on bad
+// sizes, -2 on a slot out of range, -3 on lengths that overrun a buffer.
+int sm_ed_prep_words(int64_t n, int64_t cap,
+                     const u8* sigs, int64_t sigs_len, const int64_t* sig_len,
+                     const u8* msgs, int64_t msgs_len, const int64_t* msg_len,
+                     const int32_t* which,      // (n,)
+                     int64_t n_slots,
+                     const u8* slot_keys,       // (n_slots, 32)
+                     const u16* slot_rows,      // (n_slots, 6, 16)
+                     const u8* slot_ok,         // (n_slots,)
+                     const u16* sub_row,        // (6, 16)
+                     int32_t* bb_idx,           // (16, cap)
+                     u8* a_packed,              // (64, cap)
+                     u16* rows,                 // (cap, 6, 16)
+                     u16* r_packed,             // (cap, 16)
+                     u8* precheck)              // (cap,)
+{
+    if (n < 0 || cap < n || (n == 0 && cap > 0) || n_slots < 0) return -1;
+    const Mod* L = &ctx().edl;
+    int64_t sig_at = 0, msg_at = 0;
+    for (int64_t i = 0; i < n; ++i) {
+        const int64_t sl = sig_len[i], ml = msg_len[i];
+        if (sl < 0 || ml < 0 || sl > sigs_len - sig_at
+                || ml > msgs_len - msg_at) return -3;
+        if (which[i] < 0 || which[i] >= n_slots) return -2;
+        const u8* sig = sigs + sig_at;
+        const u8* msg = msgs + msg_at;
+        sig_at += sl;
+        msg_at += ml;
+        const int64_t slot = which[i];
+        const bool sig_ok = sl == 64;
+        const bool keyed = sig_ok && slot_ok[slot];
+        u16* rp = r_packed + 16 * i;
+        u64 s[4] = {0, 0, 0, 0}, k[4] = {0, 0, 0, 0};
+        bool ge_p = false, s_ok = true;    // a refused length reads s = 0
+        if (sig_ok) {
+            std::memcpy(rp, sig, 32);
+            std::memcpy(s, sig + 32, 32);
+            // non-canonical y (>= p = 2^255-19), the sign bit masked off
+            ge_p = rp[0] >= 0xFFED && (rp[15] & 0x7FFF) == 0x7FFF;
+            for (int j = 1; ge_p && j < 15; ++j) ge_p = rp[j] == 0xFFFF;
+            s_ok = mp_cmp(s, L->m, 4) < 0;
+            if (!s_ok) mp_zero(s, 4);
+        } else {
+            std::memset(rp, 0, 32);
+        }
+        std::memcpy(rows + 96 * i, keyed ? slot_rows + 96 * slot : sub_row,
+                    192);
+        if (keyed && s_ok) {
+            Sha512 S;
+            u8 dig[64];
+            u64 h[8];
+            sha512_init(&S);
+            sha512_update(&S, sig, 32);
+            sha512_update(&S, slot_keys + 32 * slot, 32);
+            sha512_update(&S, msg, (size_t)ml);
+            sha512_final(&S, dig);
+            std::memcpy(h, dig, 64);        // RFC 8032: the digest as LE
+            mod_red(L, h, k);
+        }
+        precheck[i] = (keyed && !ge_p && s_ok) ? 1 : 0;
+        ed_split_windows(s, k, cap, i, bb_idx, bb_idx + 8 * cap, a_packed);
+    }
+    for (int64_t i = n; i < cap; ++i) {
+        std::memcpy(r_packed + 16 * i, r_packed + 16 * (n - 1), 32);
+        std::memcpy(rows + 96 * i, rows + 96 * (n - 1), 192);
+        precheck[i] = precheck[n - 1];
+    }
+    if (cap > n) {
+        for (int t = 0; t < 16; ++t) {
+            int32_t* row = bb_idx + (int64_t)t * cap;
+            for (int64_t i = n; i < cap; ++i) row[i] = row[n - 1];
         }
         for (int t = 0; t < 64; ++t) {
-            int shift = 2 * (63 - t);
-            u32 klo = (u32)((k[shift / 64] >> (shift % 64)) & 3);
-            u32 khi = (u32)((k[2 + shift / 64] >> (shift % 64)) & 3);
-            a_packed[(int64_t)t * n + i] = (u8)(klo | (khi << 2));
+            u8* row = a_packed + (int64_t)t * cap;
+            std::memset(row + n, row[n - 1], (size_t)(cap - n));
         }
     }
     return 0;

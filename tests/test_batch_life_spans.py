@@ -6,6 +6,7 @@ tracing off, no stamp and no clock that was not written or read before.
 No EC kernel is compiled: the device seam is a host stand-in
 (``_start_ed25519``), or the jitted kernel alone is (``_service_kernel_split``
 behind the real prep and the real ``KernelProfiler.call``)."""
+import os
 import threading
 import time
 
@@ -19,6 +20,7 @@ from corda_tpu.observability import tracing
 from corda_tpu.observability.tracing import (
     NOOP_SPAN, Tracer, disable_tracing, set_tracer)
 from corda_tpu.ops import ed25519 as ed_ops
+from corda_tpu.ops import scalarprep as sp
 from corda_tpu.utils.metrics import MetricRegistry
 from corda_tpu.verifier import batcher as batcher_mod
 from corda_tpu.verifier.batcher import SignatureBatcher
@@ -146,8 +148,7 @@ def test_a_host_routed_plan_has_its_waits_and_an_inline_flush_has_none(
     assert len(_named(spans, "batcher.submit")) == 1
 
 
-def test_the_prep_phases_and_the_launch_are_the_dispatchs_children(
-        tracer, monkeypatch):
+def _kernel_stand_in(monkeypatch):
     """The real prep and the real ``KernelProfiler.call`` around a host
     function where the jitted kernel would be."""
     def kernel(bb_idx, a_digits, rows, r_packed, w):
@@ -158,6 +159,26 @@ def test_the_prep_phases_and_the_launch_are_the_dispatchs_children(
     monkeypatch.setattr(ed_ops, "_service_kernel_split", lambda: kernel)
     monkeypatch.setattr(ed_ops, "b_table_device", lambda w, shift=0: ())
     monkeypatch.setattr(ed_ops, "split_field_products", lambda rows, w: 0)
+
+
+@pytest.mark.parametrize("library", ["loaded", "absent", "stale"])
+def test_the_prep_phases_and_the_launch_are_the_dispatchs_children(
+        library, tracer, monkeypatch):
+    """With libscalarmath.so the word prep's one native call runs and the
+    rows are marked on ``Ed25519WordsPrep``; with none, or one of another
+    ``sm_version`` offered to the loader, the pure-Python form runs in
+    silence and they are marked on ``Ed25519ItemsPrep``
+    (``ed25519_words_prep_share`` then reads under 100): the spans and the
+    verdicts are the same."""
+    if library == "stale":
+        real = next(p for p in sp._CANDIDATES if os.path.exists(p))
+        assert sp._load(candidates=[real],
+                        expected=sp.SM_VERSION - 1) is None
+    if library != "loaded":
+        monkeypatch.setattr(sp, "_LIB", None)
+    marked, unmarked = ("Ed25519WordsPrep", "Ed25519ItemsPrep") \
+        if library == "loaded" else ("Ed25519ItemsPrep", "Ed25519WordsPrep")
+    _kernel_stand_in(monkeypatch)
     b = SignatureBatcher(metrics=MetricRegistry(), host_crossover=0,
                          max_batch=8, bucket_ladder=(8,))
     try:
@@ -167,6 +188,10 @@ def test_the_prep_phases_and_the_launch_are_the_dispatchs_children(
         b.close()
     # s >= L is refused by the prep itself; the stand-in accepts the rest
     assert got == [True] * 5 + [False]
+    snap = b.metrics.snapshot()
+    assert snap[f"SigBatcher.{marked}"]["count"] == 6        # by rows
+    assert f"SigBatcher.{unmarked}" not in snap
+    assert "SigBatcher.BatchFailure" not in snap
     spans = tracer.spans()
     (dispatch,) = _named(spans, "batcher.dispatch")
     children = [s for s in spans if s["parent_id"] == dispatch["span_id"]]
@@ -194,6 +219,30 @@ def test_the_prep_phases_and_the_launch_are_the_dispatchs_children(
     (resolve,) = _named(spans, "batcher.resolve")
     assert wait["parent_id"] == resolve["parent_id"] == dispatch["parent_id"]
     assert wait["start_s"] >= at - 1e-4
+
+
+def test_the_spans_name_all_but_a_twentieth_of_a_dispatch(tracer,
+                                                          monkeypatch):
+    """``dispatch_unnamed_ms_p50``'s bound, at a batch large enough to have
+    a duration: the seven children cover a device dispatch but for under 5%
+    of it (the best of five batches: a thread descheduled between two spans
+    is the machine's, not the code's)."""
+    _kernel_stand_in(monkeypatch)
+    b = SignatureBatcher(metrics=MetricRegistry(), host_crossover=0,
+                         max_batch=4096, bucket_ladder=(4096,))
+    try:
+        for _ in range(5):
+            assert all(b.submit_group([ROW] * 4096).result(timeout=60))
+    finally:
+        b.close()
+    spans = tracer.spans()
+    shares = []
+    for dispatch in _named(spans, "batcher.dispatch"):
+        named = sum(s["duration_s"] for s in spans
+                    if s["parent_id"] == dispatch["span_id"])
+        shares.append(1.0 - named / dispatch["duration_s"])
+    assert len(shares) == 5
+    assert min(shares) < 0.05, shares
 
 
 def test_with_tracing_off_no_stamp_is_written_and_no_wall_clock_read(

@@ -22,7 +22,12 @@ EXCLUDED = {("test_latejoin", "test_the_cell_has_its_files"),
             # genledger-oop after them (the same repair). Everything else it
             # asserts is held below, so only "is last" goes dark.
             ("test_ecdsawaves",
-             "test_the_cell_has_its_files_and_the_spec_gained_entries_only")}
+             "test_the_cell_has_its_files_and_the_spec_gained_entries_only"),
+            # the same repair once more: it pins PR 39's 30 metrics as the
+            # LAST of ``per_layer``, and PR 40 appended two after them. Its
+            # other asserts are held below.
+            ("test_batch_readers",
+             "test_the_new_metrics_are_listed_in_their_cells_and_appended")}
 
 #: per-layer metrics a later PR gave a cell whose test file pins the cell's
 #: list as ``METRICS`` (a PR may add metrics, and may not edit the
@@ -38,7 +43,8 @@ _ED_PREP = [f"ed25519_{_ph}_ms_p50"
                         "handover")]
 ADDED = {"test_ecdsawaves": _BATCH + ["ecdsa_keys_ms_p50",
                                       "ecdsa_pad_ms_p50"],
-         "test_oopstream": [f"{_n}.stream" for _n in _BATCH + _ED_PREP]}
+         "test_oopstream": [f"{_n}.stream" for _n in _BATCH + _ED_PREP]
+         + ["ed25519_words_prep_share.stream"]}     # PR 40
 
 for _path in sorted((BENCH / "tests").glob("test_*.py")):
     _spec = importlib.util.spec_from_file_location(
@@ -85,3 +91,48 @@ def test_ecdsawaves__the_cell_has_its_files_wherever_its_entries_stand():
     assert set(config["reduced"]) == {"schemes"}
     assert {"max_batch", "party_keys", "signer", "strict_der"} \
         <= set(config["assumed"])
+
+
+def test_batch_readers__the_new_metrics_are_listed_wherever_they_stand():
+    """``test_batch_readers``'s excluded test, assert for assert, but for
+    "PR 39's metrics are the LAST of ``per_layer``": here they are one
+    unbroken run, and what stands after it is PR 40's two shares of the
+    Ed25519 word prep, each listed as its file says."""
+    import json
+    br = sys.modules["benchmarks_tests_test_batch_readers"]
+    listed = {m["name"]: m for m in br.SPEC["per_layer"]}
+    files = {p.stem: json.loads(p.read_text())
+             for p in (BENCH / "layer_metrics").glob("*.json")}
+    order = [m["name"] for m in br.SPEC["per_layer"]]
+    at = min(order.index(name) for name in br.NEW)
+    assert sorted(order[at:at + len(br.NEW)]) == sorted(br.NEW)
+    for name, (cells, moves) in br.NEW.items():
+        row, lm = listed[name], files[name]
+        assert row["workloads"] == lm["workloads"] == cells
+        assert row["moves"] == lm["moves"] == moves
+        assert row["source"] == "program_span" and row["better"] == "lower"
+        assert "bound" not in row
+        assert row["layer"] == lm["layer"] == "batcher (verifier/batcher.py)"
+        assert row["unit"] == lm["unit"] \
+            == ("%" if name.startswith("dispatch_offcpu") else "ms")
+    for name in br.BATCH + br.ED_PREP:
+        wave, stream = files[name], files[f"{name}.stream"]
+        assert (wave["reader"], wave["args"]) \
+            == (stream["reader"], stream["args"])
+    after = {"ed25519_words_prep_share":
+             (["genledger-ed25519.wave8k"], "sigs_per_s"),
+             "ed25519_words_prep_share.stream":
+             (["genledger-oop.stream"], "tx_per_s")}
+    assert order[at + len(br.NEW):] == list(after)
+    for name, (cells, moves) in after.items():
+        row, lm = listed[name], files[name]
+        assert row["workloads"] == lm["workloads"] == cells
+        assert row["moves"] == lm["moves"] == moves
+        assert row["source"] == "program_counter"
+        assert row["better"] == "higher" and "bound" not in row
+        assert row["layer"] == lm["layer"] == "batcher (verifier/batcher.py)"
+        assert row["unit"] == lm["unit"] == "%"
+        assert lm["reader"] == "registry_ratio"
+        assert lm["args"]["numerator"] == ["SigBatcher.Ed25519WordsPrep"]
+        assert lm["args"]["denominator"] == [
+            "SigBatcher.Ed25519WordsPrep", "SigBatcher.Ed25519ItemsPrep"]

@@ -1,8 +1,9 @@
 """``prepare_batch_split``'s five phases as spans under the batcher's
 ``batcher.dispatch``, and its output held byte for byte to what the one-loop
-version (PR 38's) gave: the keys' pass and the digests' pass are two passes
-over the items now, with tracing on and off (one code path). No kernel is
-compiled here: the prep is host code."""
+version (PR 38's) gave: the prep is ONE native call over the rows' joined
+bytes now (``prepare_words_split``; PR 40), pure Python where the library
+is absent, with tracing on and off (one code path). No kernel is compiled
+here: the prep is host code."""
 import hashlib
 
 import numpy as np
@@ -95,12 +96,16 @@ def test_the_five_phases_in_order_disjoint_inside_the_parent(tracer, items):
     assert sum(s["duration_s"] for s in phases) <= top["duration_s"] + 1e-4
 
 
-@pytest.mark.parametrize("mode", ["off", "on", "on_python_scalars"])
+@pytest.mark.parametrize(
+    "mode", ["off", "on", "on_python_scalars", "off_no_library"])
 def test_the_arrays_are_the_one_loop_versions_byte_for_byte(
         mode, items, monkeypatch):
     if mode == "on_python_scalars":
         monkeypatch.setattr(sp, "available", lambda: False)
-    if mode == "off":
+    if mode == "off_no_library":
+        monkeypatch.setattr(sp, "_LIB", None)
+        assert not sp.available()
+    if mode.startswith("off"):
         out = ed.prepare_batch_split(items, device_tables=False)
     else:
         t = Tracer()
@@ -123,3 +128,22 @@ def test_without_a_parent_no_span_is_opened(tracer, items):
     out = ed.prepare_batch_split(items, device_tables=False)
     assert tracer.spans() == []
     assert digest(out) == ONE_LOOP_DIGEST
+
+
+@pytest.mark.parametrize("library", [True, False])
+@pytest.mark.parametrize("capacity", [24, 32, 64])
+def test_the_word_form_pads_as_the_list_of_items_did(capacity, library,
+                                                     items, monkeypatch):
+    """The rows as three lists and a capacity (what the batcher hands over)
+    against the triples with the last one repeated (what it handed over
+    before, and what the mesh route still does)."""
+    if not library:
+        monkeypatch.setattr(sp, "_LIB", None)
+    padded = items + [items[-1]] * (capacity - len(items))
+    want = ed.prepare_batch_split(padded, device_tables=False)
+    keys, sigs, msgs = (list(col) for col in zip(*items))
+    got = ed.prepare_words_split(keys, sigs, msgs, capacity,
+                                 device_tables=False)
+    assert digest(got) == digest(want)
+    if capacity == len(items):
+        assert digest(got) == ONE_LOOP_DIGEST

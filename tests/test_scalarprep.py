@@ -140,6 +140,137 @@ def test_ed_split_windows_native_matches_python():
         np.testing.assert_array_equal(a, b, err_msg=name)
 
 
+@pytest.mark.parametrize(
+    "msg_len", [0, 1, 47, 48, 111, 112, 127, 128, 239, 240, 1000])
+def test_native_sha512_matches_hashlib(msg_len):
+    """The library's own SHA-512 (sm_ed_prep_words hashes R || A || M with
+    it) against hashlib, at message lengths that cross the padding's edges:
+    64 bytes of R || A, then a message that ends 1 or 17 bytes short of a
+    block, on it, and past it."""
+    import hashlib
+    data = np.random.default_rng(msg_len).bytes(64 + msg_len)
+    assert sp.sha512(data) == hashlib.sha512(data).digest()
+    assert sp.sha512(data[64:]) == hashlib.sha512(data[64:]).digest()
+
+
+@pytest.fixture(scope="module")
+def ed_mixed_rows():
+    """(keys, sigs, msgs) of 257 rows by 66 signers and a key that is no
+    point (the first 66 rows: 65 distinct keys): messages of 32 bytes, an empty and a 5 kB one; a short, a long
+    and an empty signature; R's y >= p; s >= L; s = L - 1; y >= p under the
+    key that is no point."""
+    from corda_tpu.ops import ed25519 as ed
+    rng = np.random.default_rng(40)
+    seeds = [rng.bytes(32) for _ in range(66)]
+    pubs = [ecmath.ed25519_public_key(sd) for sd in seeds]
+    keys, sigs, msgs = [], [], []
+    for i in range(257):
+        k = i % 66
+        msg = {6: b"", 9: rng.bytes(5000)}.get(i, rng.bytes(32))
+        # one real signature a signer (pure-Python signing is ms a call);
+        # the prep does not verify, so any 64 bytes do for the rest
+        sig = (ecmath.ed25519_sign(seeds[k], msg, pubs[k]) if i < 66
+               else rng.bytes(32) + rng.bytes(31) + b"\x01")
+        keys.append(pubs[k]); sigs.append(sig); msgs.append(msg)
+    bad_key = next(bytes([b]) + bytes(31) for b in range(2, 255)
+                   if ecmath.ed_point_decompress(bytes([b]) + bytes(31))
+                   is None)
+    y_ge_p = b"\xee" + b"\xff" * 30 + b"\x7f"
+    keys[3] = bad_key
+    sigs[5] = sigs[5][:63]
+    sigs[7] = y_ge_p + sigs[7][32:]
+    sigs[8] = sigs[8] + b"\x00"
+    sigs[10] = b""
+    sigs[11] = sigs[11][:32] + b"\xff" * 32
+    sigs[12] = sigs[12][:32] + (ecmath.ED_L - 1).to_bytes(32, "little")
+    keys[13], sigs[13] = bad_key, y_ge_p + sigs[13][32:]
+    sigs[14] = sigs[14][:32] + ecmath.ED_L.to_bytes(32, "little")
+    assert ed._signer_row(bad_key) is None
+    return keys, sigs, msgs
+
+
+def _ed_words_both_ways(keys, sigs, msgs, capacity, hold_rows, monkeypatch):
+    from corda_tpu.ops import ed25519 as ed
+    monkeypatch.setattr(sp, "ED_WORDS_HOLD_LOCK_ROWS", hold_rows)
+    args = (*sp.join_rows(sigs), *sp.join_rows(msgs),
+            *ed._signer_slots(keys), ed._substitute_row(), capacity)
+    return sp.ed_prep_words(*args), ed._prep_words_python(*args)
+
+
+@pytest.mark.parametrize("handle", ["lock_let_go", "lock_held"])
+@pytest.mark.parametrize("live", [1, 255, 256, 257])
+def test_ed_prep_words_native_matches_python_row_for_row(
+        live, handle, ed_mixed_rows, monkeypatch):
+    """sm_ed_prep_words against the pure-Python form on a mixed batch, the
+    live rows padded to their bucket, through both handles of the export
+    (CDLL lets the interpreter lock go, PyDLL holds it)."""
+    from corda_tpu.ops import field as F
+    keys, sigs, msgs = (col[:live] for col in ed_mixed_rows)
+    capacity = F.bucket_size(live)
+    native, python = _ed_words_both_ways(
+        keys, sigs, msgs, capacity,
+        0 if handle == "lock_let_go" else 1 << 30, monkeypatch)
+    names = ["bb_idx", "a_packed", "rows", "r_packed", "precheck"]
+    for name, a, b in zip(names, native, python):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    precheck = native[4]
+    assert precheck.shape == (capacity,)
+    refused = {3, 5, 7, 8, 10, 11, 13, 14} & set(range(live))
+    assert set(np.flatnonzero(~precheck[:live]).tolist()) == refused
+    # the padding repeats the last live row in every array
+    for a, axis in zip(native, (1, 1, 0, 0, 0)):
+        a = np.moveaxis(a, axis, 0)
+        assert (a[live:] == a[live - 1]).all()
+
+
+@pytest.mark.parametrize("signers", [1, 65])
+def test_ed_prep_words_one_signer_and_sixty_five(signers, ed_mixed_rows,
+                                                 monkeypatch):
+    """The slot table at its smallest and over 64 (one slot a DISTINCT
+    signer, whatever their number), and k held to the definition: k =
+    SHA-512(R || A || M) mod L, read back from the packed joint digits."""
+    import hashlib
+    keys, sigs, msgs = (col[:66] for col in ed_mixed_rows)
+    if signers == 1:
+        keys = keys[:1] * 66
+    native, python = _ed_words_both_ways(keys, sigs, msgs, 128,
+                                         sp.ED_WORDS_HOLD_LOCK_ROWS,
+                                         monkeypatch)
+    for a, b in zip(native, python):
+        np.testing.assert_array_equal(a, b)
+    from corda_tpu.ops import ed25519 as ed
+    assert len(ed._signer_slots(keys)[3]) == signers
+    a_packed = native[1]
+    for i in (0, 6, 9, 65):        # 32 bytes, empty, 5 kB, the last row
+        klo = khi = 0
+        for d in a_packed[:, i]:   # MSB-first 2-bit digits of each half
+            klo, khi = klo << 2 | int(d) & 3, khi << 2 | int(d) >> 2
+        want = int.from_bytes(hashlib.sha512(
+            sigs[i][:32] + keys[i] + msgs[i]).digest(), "little") % ecmath.ED_L
+        assert klo | khi << 128 == want
+
+
+def test_ed_prep_words_refuses_inconsistent_input(ed_mixed_rows):
+    """Sizes are checked before a pointer is passed: lengths that overrun
+    the joined buffer and a slot outside the table are refused, not read."""
+    from corda_tpu.ops import ed25519 as ed
+    keys, sigs, msgs = (col[:8] for col in ed_mixed_rows)
+    sig_buf, sig_len = sp.join_rows(sigs)
+    msg_buf, msg_len = sp.join_rows(msgs)
+    which, *slots = ed._signer_slots(keys)
+    sub = ed._substitute_row()
+    with pytest.raises(RuntimeError, match="-3"):
+        sp.ed_prep_words(sig_buf[:-1], sig_len, msg_buf, msg_len, which,
+                         *slots, sub, 8)
+    with pytest.raises(RuntimeError, match="-2"):
+        sp.ed_prep_words(sig_buf, sig_len, msg_buf, msg_len, which + 8,
+                         *slots, sub, 8)
+    with pytest.raises(ValueError):
+        sp.ed_prep_words(sig_buf, sig_len, msg_buf, msg_len, which,
+                         *slots, sub, 7)
+
+
 def test_ed_plain_windows_native_matches_python():
     """ed_prep_plain (the legacy windowed kernel's window extraction) vs
     the pure-numpy bit path, over already-reduced scalars as
@@ -368,7 +499,7 @@ def test_stale_so_falls_back_loudly(caplog):
     # the matching version loads fine (the gate, not the loader, refused)
     assert sp._load(candidates=[real]) is not None
     # and a refused library means available() gates every native seam
-    assert sp.SM_VERSION == 5  # 4→5: the strict-DER parse is an export
+    assert sp.SM_VERSION == 6  # 5→6: sm_ed_prep_words and its SHA-512
 
 
 def test_k1_verify_through_native_prep():
