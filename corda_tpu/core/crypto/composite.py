@@ -129,14 +129,21 @@ class CompositeKey(PublicKey):
             out |= c.node.keys
         return frozenset(out)
 
-    def is_fulfilled_by(self, keys) -> bool:
+    def is_fulfilled_by(self, keys, tally: list | None = None) -> bool:
+        """``tally[2]``, where a tally is given
+        (``SignedTransaction.get_missing_signatures``), counts the leaf keys
+        this walk looked up in ``keys``."""
         if isinstance(keys, PublicKey):
             keys = (keys,)
         key_set = set(keys)
         total = 0
         for c in self.children:
-            ok = (c.node.is_fulfilled_by(key_set) if isinstance(c.node, CompositeKey)
-                  else c.node in key_set)
+            if isinstance(c.node, CompositeKey):
+                ok = c.node.is_fulfilled_by(key_set, tally)
+            else:
+                ok = c.node in key_set
+                if tally is not None:
+                    tally[2] += 1
             if ok:
                 total += c.weight
                 if total >= self.threshold:

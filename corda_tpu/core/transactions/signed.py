@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from functools import cached_property
 
+from ..crypto.composite import CompositeKey
 from ..crypto.keys import PublicKey
 from ..crypto.secure_hash import SecureHash
 from ..crypto.signatures import DigitalSignatureWithKey, SignatureException
@@ -79,9 +80,28 @@ class SignedTransaction:
                     needed, [k.to_string_short() for k in needed], self.id)
         return missing
 
-    def get_missing_signatures(self) -> set[PublicKey]:
+    def get_missing_signatures(self, tally: list | None = None
+                               ) -> set[PublicKey]:
+        """The required keys the signers' set does not fulfil (a
+        CompositeKey: by its weighted thresholds). ``tally``, where given,
+        is ``[required keys, of them CompositeKeys, leaf keys looked up in
+        the composite walks]`` and is added to (the verifier service's
+        coverage pass meters it)."""
         sig_keys = {s.by for s in self.sigs}
-        return {k for k in self.tx.must_sign if not k.is_fulfilled_by(sig_keys)}
+        if tally is None:
+            return {k for k in self.tx.must_sign
+                    if not k.is_fulfilled_by(sig_keys)}
+        missing = set()
+        for k in self.tx.must_sign:
+            tally[0] += 1
+            if isinstance(k, CompositeKey):
+                tally[1] += 1
+                fulfilled = k.is_fulfilled_by(sig_keys, tally)
+            else:
+                fulfilled = k.is_fulfilled_by(sig_keys)
+            if not fulfilled:
+                missing.add(k)
+        return missing
 
     # -- combination --------------------------------------------------------
     def plus(self, *sigs: DigitalSignatureWithKey) -> "SignedTransaction":

@@ -616,14 +616,18 @@ class StateMachineManager:
         kwargs = {}
         if getattr(svc, "supports_trace_ctx", False) and fsm.trace_ctx is not None:
             kwargs["trace_ctx"] = fsm.trace_ctx
-        if getattr(svc, "supports_wave_rows", False):
-            # the wave's members reach the verifier together and are routed
-            # as one depth: the service is told the size it can observe
-            kwargs["wave_rows"] = sum(len(stx.sigs) for stx in stxs)
-        futs = [svc.verify_signed(
-                    stx, self.hub, check_sufficient_signatures=
-                    request.check_sufficient_signatures, **kwargs)
-                for stx in stxs]
+        if hasattr(svc, "verify_wave"):
+            # the wave reaches the verifier as a wave: the service admits
+            # it by the size it can observe (one bulk burst at or over the
+            # batcher's crossover, member by member under it)
+            futs = svc.verify_wave(
+                stxs, self.hub, check_sufficient_signatures=
+                request.check_sufficient_signatures, **kwargs)
+        else:
+            futs = [svc.verify_signed(
+                        stx, self.hub, check_sufficient_signatures=
+                        request.check_sufficient_signatures, **kwargs)
+                    for stx in stxs]
         # ONE external-wait slot for the whole wave: the flow resumes once,
         # when the slowest member resolves
         self._awaiting_external += 1

@@ -856,7 +856,8 @@ def verify_batch_async(items: list[tuple[bytes, bytes, bytes]],
     return verify_batch_async_words(*_columns(items), trace_parent)
 
 
-def verify_batch_async_words(keys, sigs, msgs, trace_parent=None):
+def verify_batch_async_words(keys, sigs, msgs, trace_parent=None,
+                             capacity: int | None = None):
     """Dispatch without forcing (see weierstrass.verify_batch_async): the
     device computes while the caller preps the next batch. Rides the
     split-k half-length ladder — the fastest measured path (PERF.md
@@ -867,14 +868,16 @@ def verify_batch_async_words(keys, sigs, msgs, trace_parent=None):
     (observability.profiling): compile-cache accounting + batch occupancy.
     ``trace_parent`` is the batcher's ``batcher.dispatch`` span: the prep's
     phases and ``batcher.launch``, the jitted call alone until it returns,
-    are its children."""
+    are its children. ``capacity`` is the row count the batch is padded
+    to (the compiled shape): the next power of two unless the caller names
+    a larger one (the batcher: a rung of its ladder)."""
     from ..observability.profiling import get_profiler
     from ..observability.tracing import get_tracer
     from .staging import get_staging_pool
     n = len(keys)
     if n == 0:
         return (None, np.zeros(0, dtype=bool), 0)
-    capacity = F.bucket_size(n)
+    capacity = max(capacity or 0, F.bucket_size(n))
     pool = get_staging_pool()
     lease = pool.lease()
     *args, precheck = prepare_words_split(

@@ -1626,7 +1626,8 @@ def pad_word_rows(arrays, m: int, staging=None, tags=None):
 
 
 def verify_batch_async_words(curve: WeierstrassCurve, e_words, r_words,
-                             s_words, pub_words, trace_parent=None):
+                             s_words, pub_words, trace_parent=None,
+                             capacity: int | None = None):
     """Word-form async dispatch — the batcher's cached/vectorized ECDSA
     prep path: items arrive as the native preps' LE u64 rows (per-signer
     pub rows from keys.sec1_pub_row_cached, r/s from the batched DER
@@ -1647,7 +1648,9 @@ def verify_batch_async_words(curve: WeierstrassCurve, e_words, r_words,
     n = len(e_words)
     if n == 0:
         return (None, np.zeros(0, dtype=bool), 0)
-    capacity = F.bucket_size(n)
+    # the compiled shape: the next power of two unless the caller names a
+    # larger one (the batcher: a rung of its ladder)
+    capacity = max(capacity or 0, F.bucket_size(n))
     pool = get_staging_pool()
     # On any exception below the lease is simply dropped (never released):
     # a partial dispatch may still alias the buffers, so they must not

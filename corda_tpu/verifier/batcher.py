@@ -5,7 +5,7 @@ The TPU answer to the reference's per-signature JCA calls inside
 Crypto.doVerify, Crypto.kt:473-496): many flows/transactions submit
 (key, signature, content) checks concurrently; a dispatcher thread drains
 them, buckets by scheme (mixed-scheme batches would diverge on device —
-BASELINE.md config 2), and runs ONE batched kernel per scheme bucket.
+BASELINE.json configs[1]), and runs ONE batched kernel per scheme bucket.
 
 Pipeline shape (PR 6, continuous batching): a planner thread cuts every
 dispatchable batch the per-scheme in-flight windows allow and never blocks
@@ -99,6 +99,11 @@ class _SchemeQueue:
 
     def __len__(self) -> int:
         return len(self.interactive) + len(self.bulk)
+
+
+def _bucket_of(items) -> str:
+    """The scheme bucket of one batch's rows (a batch is one scheme's)."""
+    return _BUCKETS.get(items[0].key.scheme.scheme_number_id, "host")
 
 
 def _tid(bctx) -> str | None:
@@ -427,14 +432,37 @@ class SignatureBatcher:
         rung that fits, so steady-state flushes recur on a fixed shape set
         and the jit cache stays hot. Sub-floor tails dispatch at raw depth
         — the kernels pad those to power-of-two buckets, so the compiled
-        shape set stays bounded either way."""
+        shape set stays bounded either way.
+
+        Where that rung would take at most HALF of what is queued and a
+        higher rung holds all of it (a sparse ladder: 256 of 5,000 rows
+        under ``[256, 8192]``), the cut is the whole depth: ONE flush,
+        padded to that higher rung (``_padded_rows``), and not twenty of
+        the lower one, each paying a prep's lock waits and holding an
+        in-flight slot while the rest waits. On a power-of-two ladder a
+        fitting rung always takes over half: nothing changes there."""
+        ladder = self._ladder_for(bucket)
         cut = 0
-        for rung in self._ladder_for(bucket):
+        for rung in ladder:
             if rung <= depth:
                 cut = rung
-        if cut == 0:
+        if cut == 0 or (2 * cut <= depth <= min(ladder[-1],
+                                                self.max_batch)):
             cut = depth
         return min(cut, self.max_batch, depth)
+
+    def _padded_rows(self, bucket: str, rows: int) -> int:
+        """The row count a device flush of ``rows`` live rows is padded to:
+        the next power of two (the kernels' own rule) or, where that is no
+        rung of the bucket's ladder and the flush is at or over the
+        ladder's floor, the smallest rung that holds it: every such flush
+        runs a shape the ladder names."""
+        from ..ops.field import bucket_size
+        padded = bucket_size(rows)
+        ladder = self._ladder_for(bucket)
+        if rows >= ladder[0] and padded not in ladder:
+            padded = next((r for r in ladder if r >= padded), padded)
+        return padded
 
     # -- degradation ladder hooks (verifier/controller.py) -------------------
     def shed_bulk(self, on: bool, cap: int | None = None) -> None:
@@ -657,6 +685,28 @@ class SignatureBatcher:
             self._flush_host(tracer, bucket, ps, bctx)
             self.metrics.meter("SigBatcher.HostInline").mark(len(ps))
         return held.future.result()
+
+    def wave_is_the_planners(self, signers, wave_rows: int) -> bool:
+        """Whether a wave of ``wave_rows`` signature rows by ``signers``
+        (their keys, any order, repeats allowed) is the planner's as a
+        whole: some queue it touches is one ``_host_at_once`` would NOT
+        host-route at once, judged by the wave's size beside the depth, as
+        ``hold_group`` judges a member. The verifier service admits such a
+        wave as one bulk ``submit_groups`` burst; any other wave goes
+        member by member through ``hold_group`` / ``collect_group``. Rows
+        the interactive class would send to the host queue (device off, or
+        ``route_interactive_host`` on) leave the wave with its members."""
+        if not self.use_device or self._force_host_interactive:
+            return False
+        judged = set()
+        with self._lock:    # a large wave is settled by its first device row
+            for key in signers:
+                bucket = _BUCKETS.get(key.scheme.scheme_number_id, "host")
+                if bucket not in judged:
+                    if not self._host_at_once(bucket, wave_rows):
+                        return True
+                    judged.add(bucket)
+        return False
 
     def _host_at_once(self, name: str, wave_rows: int = 0) -> bool:
         """THE routing rule (CALLER HOLDS THE LOCK): a queue's rows go to
@@ -1128,7 +1178,7 @@ class SignatureBatcher:
             dspan.finish()
             self._resolve(bucket, items, self._run_host(items), bctx)
             return None
-        self._mark_device_flush(len(items), reason)
+        self._mark_device_flush(items, reason)
         if self.mesh is not None:
             breaker.record_success()
             self._mark_device(items)
@@ -1186,25 +1236,32 @@ class SignatureBatcher:
             verdicts = self._run_host(items)
         self._resolve(bucket, items, verdicts, bctx)
 
-    def _mark_device_flush(self, rows: int, reason: str) -> None:
+    def _mark_device_flush(self, items: list[_Pending], reason: str) -> None:
         """One flush launched on the device route: its live rows
         (``verifier_device_batch_rows``; ``verifier_batch_size`` holds the
         host flushes too), why the planner cut it
-        (``SigBatcher.DeviceFlush.<reason>``) and the row count the kernels
-        pad it to (``SigBatcher.DevicePadded.<rows>``: each is a compiled
-        shape, so a name that first counts after warm-up is a compile in
-        the steady state; the mesh route pads by its own rule and is not
-        counted)."""
-        self.metrics.histogram("verifier_device_batch_rows").update(rows)
+        (``SigBatcher.DeviceFlush.<reason>``) and the row count it is padded
+        to (``SigBatcher.DevicePadded.<rows>``, ``_padded_rows``: each is a
+        compiled shape, so a name that first counts after warm-up is a
+        compile in the steady state; the mesh route pads by its own rule
+        and is not counted)."""
+        self.metrics.histogram("verifier_device_batch_rows").update(
+            len(items))
         self.metrics.meter(f"SigBatcher.DeviceFlush.{reason}").mark()
         if self.mesh is None:
-            from ..ops.field import bucket_size
-            self.metrics.meter(
-                f"SigBatcher.DevicePadded.{bucket_size(rows)}").mark()
+            padded = self._padded_rows(_bucket_of(items), len(items))
+            self.metrics.meter(f"SigBatcher.DevicePadded.{padded}").mark()
 
     def _mark_device(self, items) -> None:
+        """One batch verified on the device: ``SigBatcher.DeviceChecked``
+        by rows, in all and by bucket (``SigBatcher.DeviceChecked.<bucket>``:
+        a batch is one scheme's)."""
         self.metrics.meter("SigBatcher.DeviceBatches").mark()
         self.metrics.meter("SigBatcher.DeviceChecked").mark(len(items))
+        if items:
+            self.metrics.meter(
+                f"SigBatcher.DeviceChecked.{_bucket_of(items)}").mark(
+                    len(items))
 
     def _resolve(self, bucket: str, items: list[_Pending], verdicts,
                  bctx=None) -> None:
@@ -1287,8 +1344,9 @@ class SignatureBatcher:
             sigs = [p.signature for p in items]
             msgs = [p.content for p in items]
         native = sp.available()
-        pending = ed_ops.verify_batch_async_words(keys, sigs, msgs,
-                                                  trace_parent=dspan)
+        pending = ed_ops.verify_batch_async_words(
+            keys, sigs, msgs, trace_parent=dspan,
+            capacity=self._padded_rows("ed25519", len(items)))
         self.metrics.meter("SigBatcher.Ed25519WordsPrep" if native
                            else "SigBatcher.Ed25519ItemsPrep").mark(
                                len(items))
@@ -1394,8 +1452,9 @@ class SignatureBatcher:
         n = len(items)
         if wc_ops.words_prep_available(curve):
             words, bad_encoding = self._ecdsa_words(curve, items, dspan)
-            pending = wc_ops.verify_batch_async_words(curve, *words,
-                                                      trace_parent=dspan)
+            pending = wc_ops.verify_batch_async_words(
+                curve, *words, trace_parent=dspan,
+                capacity=self._padded_rows(bucket, n))
             self.metrics.meter("SigBatcher.EcdsaWordsPrep").mark(n)
         else:
             kitems, bad_encoding = self._ecdsa_kernel_items(curve, items)

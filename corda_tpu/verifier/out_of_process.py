@@ -45,7 +45,8 @@ from ..observability.slog import jlog
 from ..utils import retry
 from ..utils.faults import DROP, fault_point
 from ..utils.metrics import MetricRegistry
-from .service import TransactionVerifierService
+from .service import (TransactionVerifierService, burst_verdicts,
+                      first_unverified)
 
 log = logging.getLogger(__name__)
 
@@ -1401,12 +1402,7 @@ class VerifierWorker:
         interval, so each is one span of the process tracer."""
         tracer = get_tracer()
         now_wall, t0, n_sigs, local = admitted
-        verdicts = []
-        for fut in futures:
-            try:
-                verdicts.append(fut.result())
-            except Exception as e:
-                verdicts.append(e)
+        verdicts = burst_verdicts(futures)
         t_back = time.perf_counter()
         if local is not None:
             tracer.record("worker.device_dispatch", start_s=now_wall,
@@ -1418,11 +1414,10 @@ class VerifierWorker:
             if isinstance(got, Exception):
                 error = str(got)
             else:
-                for (key, _sig, _content), ok in zip(req.signatures, got):
-                    if not ok:
-                        error = (f"Signature by {key.to_string_short()} "
-                                 f"did not verify")
-                        break
+                bad = first_unverified((c[0] for c in req.signatures), got)
+                if bad is not None:
+                    error = (f"Signature by {bad.to_string_short()} "
+                             f"did not verify")
             if rt is not None:
                 self._finish_dispatch_span(
                     rt, t_back, error if isinstance(got, Exception) else None)

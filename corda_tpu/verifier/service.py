@@ -15,6 +15,7 @@ seam (NodeConfiguration.kt:91-94) is `make_verifier_service`.
 """
 from __future__ import annotations
 
+import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
 
@@ -22,6 +23,47 @@ from ..core.crypto.signatures import SignatureException
 from ..observability import get_tracer
 from ..utils.metrics import MetricRegistry
 from .batcher import SignatureBatcher
+
+
+def burst_verdicts(futures) -> list:
+    """Pass one of a burst's completion (``submit_groups``' futures, in
+    submission order): each group's verdict list, or the exception its
+    future failed with. The in-process service's wave and the out-of-process
+    worker's burst both wait here."""
+    got = []
+    for fut in futures:
+        try:
+            got.append(fut.result())
+        except Exception as e:
+            got.append(e)
+    return got
+
+
+def first_unverified(signers, verdicts):
+    """The signer of the first signature, in order, that did not verify;
+    None where every one did. ``signers`` yields one key per verdict."""
+    if all(verdicts):
+        return None
+    return next(key for key, ok in zip(signers, verdicts) if not ok)
+
+
+def _finish_with_the_last(span, futures) -> None:
+    """Finish ``span`` when the last of ``futures`` resolves (at once where
+    there is none)."""
+    left = [len(futures)]
+    lock = threading.Lock()
+
+    def one_done(_f):
+        with lock:
+            left[0] -= 1
+            last = left[0] == 0
+        if last:
+            span.finish()
+
+    if not futures:
+        span.finish()
+    for fut in futures:
+        fut.add_done_callback(one_done)
 
 
 class TransactionVerifierService:
@@ -106,10 +148,6 @@ class TpuTransactionVerifierService(TransactionVerifierService):
     #: only submits and parks.
     resolves_off_node_thread = True
 
-    #: verify_signed takes ``wave_rows`` (the SMM's VerifyMany passes the
-    #: wave's signature count to a service that says so)
-    supports_wave_rows = True
-
     def __init__(self, workers: int = 4, batcher: SignatureBatcher | None = None,
                  metrics: MetricRegistry | None = None, mesh=None):
         self.metrics = metrics if metrics is not None else MetricRegistry()
@@ -123,8 +161,9 @@ class TpuTransactionVerifierService(TransactionVerifierService):
     # -- full TPU path (verify(ltx) is inherited) ----------------------------
     def verify_signed(self, stx, services,
                       check_sufficient_signatures: bool = True,
-                      trace_ctx=None, wave_rows: int | None = None) -> Future:
-        """Async full verify of a SignedTransaction; the per-signature EC math
+                      trace_ctx=None) -> Future:
+        """Async full verify of ONE SignedTransaction (a wave of them goes
+        to ``verify_wave``); the per-signature EC math
         rides the shared device batcher (cross-transaction batching). With
         tracing enabled the whole pipeline — submit, batch flush, device
         dispatch, resolve — lands in one trace rooted here (or in the
@@ -140,8 +179,15 @@ class TpuTransactionVerifierService(TransactionVerifierService):
         Otherwise the rows stay queued for the planner (prep pool, device)
         and the worker blocks on them; the groups of flows suspended
         together share the queue, so they still coalesce into one device
-        batch. ``wave_rows`` is the signature count of the ``VerifyMany``
-        wave ``stx`` belongs to: the batcher judges a member by it."""
+        batch."""
+        return self._verify_held(stx, services, check_sufficient_signatures,
+                                 trace_ctx, None)
+
+    def _verify_held(self, stx, services, check_sufficient_signatures,
+                     trace_ctx, wave_rows: int | None) -> Future:
+        """``verify_signed``'s body. ``wave_rows`` is the signature count of
+        the wave ``stx`` belongs to (``verify_wave`` under the crossover):
+        the batcher judges a member by it."""
         tracer = get_tracer()
         root = tracer.span("tx.verify", parent=trace_ctx,
                            tx_id=stx.id.bytes.hex()[:16],
@@ -192,6 +238,151 @@ class TpuTransactionVerifierService(TransactionVerifierService):
             failed.set_exception(exc)
             return failed
 
+    # -- a wave of transactions ------------------------------------------------
+    def verify_wave(self, stxs, services,
+                    check_sufficient_signatures: bool = True,
+                    trace_ctx=None) -> list[Future]:
+        """Async full verify of a WAVE of SignedTransactions (the SMM's
+        ``VerifyMany``: a dependency-resolution frontier, a back-fill's
+        ledger): one future a member, in order, each resolved with that
+        member's own outcome (None, ``SignatureException``,
+        ``SignaturesMissingException``, a resolution or contract failure);
+        no member fails because another did.
+
+        How the wave is admitted follows from its size, as its route does
+        (``SignatureBatcher.wave_is_the_planners``, the batcher's one
+        routing rule). At or over ``host_crossover`` the wave is a BULK
+        burst: ONE ``submit_groups`` call on the caller's thread and ONE
+        completion task on the pool (``_complete_wave``). Under it every
+        member takes ``verify_signed``'s path, judged by the wave's size:
+        held on the queue, collected and verified on the worker that
+        serves it, one hand-off a member. A closed batcher or a shut-down
+        pool yields FAILED FUTURES, never an exception.
+
+        Tracing: span ``verifier.wave`` (entry -> last member resolved;
+        tags ``n_tx``, ``n_sigs``, ``admitted`` = ``bulk`` | ``held``); a
+        bulk wave's children are ``verifier.wave.submit`` / ``.verdicts`` /
+        ``.coverage`` / ``.rules``. Meters ``Verifier.WaveTx.bulk`` /
+        ``.held`` count members by how their wave was admitted."""
+        stxs = list(stxs)
+        n_sigs = sum(len(stx.sigs) for stx in stxs)
+        tracer = get_tracer()
+        bulk = self.batcher.wave_is_the_planners(
+            (sig.by for stx in stxs for sig in stx.sigs), n_sigs)
+        wave = tracer.span("verifier.wave", parent=trace_ctx,
+                           n_tx=len(stxs), n_sigs=n_sigs,
+                           admitted="bulk" if bulk else "held")
+        self.metrics.meter("Verifier.WaveTx.bulk" if bulk
+                           else "Verifier.WaveTx.held").mark(len(stxs))
+        if not bulk:
+            futures = [self._verify_held(stx, services,
+                                         check_sufficient_signatures,
+                                         trace_ctx, n_sigs) for stx in stxs]
+            if tracer.enabled:
+                _finish_with_the_last(wave, futures)
+            return futures
+        ctx = wave.context()
+        members = [Future() for _ in stxs]
+        t0 = time.perf_counter()
+        try:
+            with tracer.span("verifier.wave.submit", parent=ctx, cpu=True):
+                groups = self.batcher.submit_groups(
+                    [[(sig.by, sig.bytes, stx.id.bytes) for sig in stx.sigs]
+                     for stx in stxs],
+                    None if ctx is None else [ctx] * len(stxs))
+            self.metrics.counter("Verification.InFlight").inc(len(stxs))
+            self._pool.submit(self._complete_wave, stxs, services,
+                              check_sufficient_signatures, groups, members,
+                              wave, t0)
+        except Exception as exc:
+            wave.set_tag("error", f"{type(exc).__name__}: {exc}")
+            wave.finish()
+            for fut in members:
+                if not fut.done():
+                    fut.set_exception(exc)
+        return members
+
+    def _complete_wave(self, stxs, services, check_sufficient_signatures,
+                       groups, members, wave, t0) -> None:
+        """One bulk wave, on a pool thread, in three passes, each one
+        contiguous interval and one span: the groups' verdicts (the wait);
+        coverage of the members whose signatures all verified (every
+        required key, CompositeKey thresholds included); resolution and the
+        contract rules of those. Then every member's future, in order. The
+        out-of-process worker's ``_complete_burst`` runs the same passes
+        over its requests (``burst_verdicts`` / ``first_unverified`` are
+        shared)."""
+        from ..core.transactions.signed import SignaturesMissingException
+        tracer = get_tracer()
+        ctx = wave.context()
+        outcomes: list = [None] * len(stxs)
+        try:
+            with tracer.span("verifier.wave.verdicts", parent=ctx):
+                verdicts = burst_verdicts(groups)
+            alive = []
+            for i, (stx, got) in enumerate(zip(stxs, verdicts)):
+                if isinstance(got, Exception):
+                    outcomes[i] = got
+                    continue
+                bad = first_unverified((sig.by for sig in stx.sigs), got)
+                if bad is None:
+                    alive.append(i)
+                else:
+                    outcomes[i] = SignatureException(
+                        f"Signature by {bad.to_string_short()} did not "
+                        f"verify on transaction {stx.id.prefix_chars()}")
+            if check_sufficient_signatures:
+                tally = [0, 0, 0]   # required, composite required, visits
+                with tracer.span("verifier.wave.coverage", parent=ctx,
+                                 cpu=True, n_tx=len(alive)):
+                    covered = []
+                    for i in alive:
+                        missing = stxs[i].get_missing_signatures(tally)
+                        if missing:
+                            outcomes[i] = SignaturesMissingException(
+                                missing,
+                                [k.to_string_short() for k in missing],
+                                stxs[i].id)
+                        else:
+                            covered.append(i)
+                    alive = covered
+                self.metrics.meter("Verifier.RequiredKeys").mark(tally[0])
+                self.metrics.meter("Verifier.CompositeRequired").mark(
+                    tally[1])
+                self.metrics.meter("Verifier.CompositeLeafVisits").mark(
+                    tally[2])
+            with tracer.span("verifier.wave.rules", parent=ctx, cpu=True,
+                             n_tx=len(alive)):
+                for i in alive:
+                    try:
+                        stxs[i].to_ledger_transaction(services).verify()
+                    except Exception as e:
+                        outcomes[i] = e
+        except BaseException as exc:    # never a member left unresolved
+            outcomes = [exc if o is None else o for o in outcomes]
+            raise
+        finally:
+            self._resolve_wave(members, outcomes, wave, t0)
+
+    def _resolve_wave(self, members, outcomes, wave, t0) -> None:
+        failed = sum(o is not None for o in outcomes)
+        self.metrics.meter("Verification.Success").mark(
+            len(outcomes) - failed)
+        self.metrics.meter("Verification.Failure").mark(failed)
+        self.metrics.counter("Verification.InFlight").dec(len(outcomes))
+        took = time.perf_counter() - t0
+        hist = self.metrics.histogram("tx_verify_seconds")
+        for fut, outcome in zip(members, outcomes):
+            hist.update(took)
+            if fut.done():
+                continue
+            if outcome is None:
+                fut.set_result(None)
+            else:
+                fut.set_exception(outcome)
+        wave.set_tag("failed", failed)
+        wave.finish()
+
     def shutdown(self) -> None:
         super().shutdown()
         self.batcher.close()
@@ -202,13 +393,15 @@ def make_verifier_service(verifier_type: str = "InMemory", **kwargs
     """The VerifierType config seam (NodeConfiguration.kt:91-94):
     "InMemory" | "Tpu" | "OutOfProcess".
 
-    NOTE on the Tpu backend: only ``verify_signed(stx, ...)`` pays off on
-    device — the reference-shaped ``verify(ltx)`` SPI verifies contract and
-    platform rules only (an ltx's signatures are already checked by the time
-    it exists), so callers holding a SignedTransaction should use
-    ``verify_signed``. The node's flow path does (the SMM's Verify
-    suspension point routes through verify_signed; locked by
-    tests/test_verify_suspension.py's device-batch assertion).
+    NOTE on the Tpu backend: ``verify_signed(stx, ...)`` and
+    ``verify_wave(stxs, ...)`` are the calls that pay off on device — the
+    reference-shaped ``verify(ltx)`` SPI verifies contract and platform
+    rules only (an ltx's signatures are already checked by the time it
+    exists), so callers holding SignedTransactions should use them. The
+    node's flow path does: the SMM's Verify suspension point routes through
+    ``verify_signed`` and its VerifyMany through ``verify_wave``, which
+    admits a wave at or over the batcher's crossover as ONE bulk burst
+    (locked by tests/test_verify_suspension.py's device-batch assertions).
 
     "OutOfProcess" needs ``network_service=`` (the node's messaging — the
     queue the worker fleet attaches to); ``expected_workers=`` sizes the

@@ -56,6 +56,68 @@ def test_ladder_cut_prefers_largest_fitting_rung():
         b.close()
 
 
+@pytest.mark.parametrize("depth,cut,padded", [
+    (100, 100, 128),        # a sub-floor tail: raw depth, the kernels' pad
+    (300, 256, 256),        # the rung takes over half: as before
+    (511, 256, 256),
+    (512, 512, 8192),       # the rung would take half or less: the WHOLE
+    (2600, 2600, 8192),     # depth, one flush padded to the next rung up
+    (5400, 5400, 8192),
+    (8192, 8192, 8192),
+    (9000, 8192, 8192),     # over the top rung: a full bucket first
+])
+def test_a_sparse_ladder_does_not_mince_a_partial_bucket(depth, cut, padded):
+    """Under ``[256, 8192]`` (the deployments' ladder) a partial bucket of
+    5,400 rows left as 21 flushes of 256, each a prep's lock waits and an
+    in-flight slot: it leaves as ONE, padded to 8,192, a shape the ladder
+    names. A power-of-two ladder cuts as it did."""
+    b = SignatureBatcher(metrics=MetricRegistry(), use_device=False,
+                         bucket_ladder=(256, 8192), max_batch=8192)
+    dense = SignatureBatcher(metrics=MetricRegistry(), use_device=False,
+                             max_batch=8192)
+    try:
+        assert b._ladder_cut("ed25519", depth) == cut
+        assert b._padded_rows("ed25519", cut) == padded
+        fits = max([r for r in dense._default_ladder if r <= depth] or [0])
+        assert dense._ladder_cut("ed25519", depth) == (fits or depth)
+        assert dense._padded_rows("ed25519", fits or depth) \
+            == max(128, fits)
+    finally:
+        b.close()
+        dense.close()
+
+
+def test_a_whole_partial_bucket_reaches_the_kernels_at_its_rung(monkeypatch):
+    """The planner cuts 600 queued rows whole, the prep is told the rung
+    (8,192) as its capacity, and the flush is metered at that shape."""
+    from corda_tpu.core.crypto import generate_keypair
+    from corda_tpu.core.crypto.signatures import Crypto
+    from corda_tpu.ops import ed25519 as ed_ops
+    kp = generate_keypair(entropy=b"\x51" * 32)
+    row = (kp.public, Crypto.sign_with_key(kp, b"m" * 32).bytes, b"m" * 32)
+    seen = []
+
+    def words(keys, sigs, msgs, trace_parent=None, capacity=None):
+        seen.append((len(keys), capacity))
+        return ("stub", len(keys))
+
+    monkeypatch.setattr(ed_ops, "verify_batch_async_words", words)
+    monkeypatch.setattr(ed_ops, "finish_batch",
+                        lambda pending: [True] * pending[1])
+    metrics = MetricRegistry()
+    b = SignatureBatcher(metrics=metrics, bucket_ladder=(256, 8192),
+                         max_batch=8192)
+    try:
+        assert b.submit_group([row] * 600).result(timeout=30) == [True] * 600
+    finally:
+        b.close()
+    assert seen == [(600, 8192)]
+    snap = metrics.snapshot()
+    assert snap["SigBatcher.DevicePadded.8192"]["count"] == 1
+    assert snap["SigBatcher.DeviceBatches"]["count"] == 1
+    assert snap["SigBatcher.DeviceChecked.ed25519"]["count"] == 600
+
+
 def test_per_scheme_ladder_overrides_default():
     b = SignatureBatcher(metrics=MetricRegistry(), use_device=False,
                          bucket_ladder={"ed25519": (512, 1024)})

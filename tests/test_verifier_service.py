@@ -208,32 +208,239 @@ def test_worker_waits_for_the_queue_when_the_depth_is_the_devices(services):
         svc.shutdown()
 
 
-@pytest.mark.parametrize("rows_kw", [{}, {"wave_rows": 191},
-                                     {"wave_rows": 192}],
-                         ids=["lone", "wave_under", "wave_at_crossover"])
+@pytest.mark.parametrize("entry", ["lone", "wave_under", "wave_at_crossover"])
 def test_verify_signed_on_closed_batcher_returns_failed_future(services,
-                                                               rows_kw):
+                                                               entry):
     """Span-leak fix: if the batcher rejects the group (closed), the
-    caller must get a FAILED FUTURE — verify_signed's contract is async —
-    and the root tx.verify span must still be finished, not leaked. The
-    rows are refused on the caller's thread, whatever their wave's size:
-    the future comes back already failed."""
+    caller must get a FAILED FUTURE (verify_signed's contract is async, and
+    verify_wave's: one failed future a member) and the root span must still
+    be finished, not leaked. The rows are refused on the caller's thread,
+    whatever their wave's size: the futures come back already failed."""
     from corda_tpu.observability import disable_tracing, enable_tracing
     tracer = enable_tracing()
-    svc = TpuTransactionVerifierService()
+    svc = TpuTransactionVerifierService(
+        batcher=SignatureBatcher(host_crossover=3))
     try:
         stx = make_issue_stx(services)
         svc.batcher.close()
-        fut = svc.verify_signed(stx, services, **rows_kw)
-        assert fut.done()
-        with pytest.raises(RuntimeError, match="closed"):
-            fut.result(timeout=5)
+        if entry == "lone":
+            futs, root = [svc.verify_signed(stx, services)], "tx.verify"
+        else:
+            n = 2 if entry == "wave_under" else 3
+            futs, root = svc.verify_wave([stx] * n, services), "verifier.wave"
+            assert len(futs) == n
+        for fut in futs:
+            assert fut.done()
+            with pytest.raises(RuntimeError, match="closed"):
+                fut.result(timeout=5)
         # an unfinished span never reaches the ring: its presence IS the
-        # proof that root.finish() ran on the failure path
-        assert "tx.verify" in {s["name"] for s in tracer.spans()}
+        # proof that the root's finish() ran on the failure path
+        names = [s["name"] for s in tracer.spans()]
+        assert root in names
+        if entry != "lone":
+            (wave,) = [s for s in tracer.spans()
+                       if s["name"] == "verifier.wave"]
+            assert wave["tags"]["admitted"] == (
+                "held" if entry == "wave_under" else "bulk")
     finally:
         disable_tracing()
         svc.shutdown()
+
+
+def _stub_device(b):
+    """Host verdicts behind the device route: no kernel is compiled."""
+    batches = []
+
+    def device(bucket, items, reason="full", bctx=None):
+        batches.append((bucket, len(items)))
+        b._mark_device(items)
+        b._resolve(bucket, items, b._run_host(items), bctx)
+
+    b._dispatch_device = device
+    return batches
+
+
+def _wave_of(services, n=4):
+    """``n`` distinct one-signature issues, every other one secp256k1."""
+    stxs = []
+    for i in range(n):
+        kp = ALICE_K1_KP if i % 2 else ALICE_KP
+        wtx = WireTransaction(
+            outputs=(TransactionState(DummyState(100 + i, (kp.public,)),
+                                      NOTARY),),
+            commands=(Command(DummyContract.Create(), (kp.public,)),),
+            notary=NOTARY, must_sign=(kp.public,))
+        stxs.append(services.sign_transaction(wtx, kp.public))
+    return stxs
+
+
+def test_a_wave_over_the_crossover_is_one_burst_and_members_fail_alone(
+        services):
+    """ONE ``submit_groups`` call in the bulk class and ONE completion task
+    a wave, on the caller's thread; a bad signature in member 3 and a
+    missing signer in member 2 leave the other four valid, each member answered
+    with its own outcome and type; the spans and meters appear."""
+    from corda_tpu.observability import disable_tracing, enable_tracing
+    b = SignatureBatcher(host_crossover=2)
+    batches = _stub_device(b)
+    svc = TpuTransactionVerifierService(batcher=b)
+    bursts, tasks = [], []
+    submit_groups, pool_submit = b.submit_groups, svc._pool.submit
+
+    def spy_groups(groups, ctxs=None, latency_class="bulk"):
+        bursts.append((threading.current_thread().name, len(groups),
+                       latency_class))
+        return submit_groups(groups, ctxs, latency_class)
+
+    def spy_pool(fn, *a, **k):
+        tasks.append(fn.__name__)
+        return pool_submit(fn, *a, **k)
+
+    b.submit_groups, svc._pool.submit = spy_groups, spy_pool
+    b.hold_group = lambda *a, **k: pytest.fail("a member was held")
+    stxs = _wave_of(services, 6)
+    stxs[2] = _corrupted(stxs[2])
+    stxs[1] = SignedTransaction.of(
+        stxs[1].tx, [services.sign(stxs[1].id.bytes, ALICE_KP.public)])
+    tracer = enable_tracing()
+    try:
+        futs = svc.verify_wave(stxs, services)
+        got = [f.exception(timeout=30) for f in futs]
+    finally:
+        disable_tracing()
+        svc.shutdown()
+    assert bursts == [(threading.current_thread().name, 6, "bulk")]
+    assert tasks == ["_complete_wave"]
+    assert [got[i] for i in (0, 3, 4, 5)] == [None] * 4
+    assert type(got[1]) is SignaturesMissingException
+    assert type(got[2]) is SignatureException
+    assert stxs[2].id.prefix_chars() in str(got[2])
+    # member 2's stand-in signature is Ed25519: four rows to two
+    assert sorted(batches) == [("ed25519", 4), ("secp256k1", 2)]
+    snap = svc.metrics.snapshot()
+    assert snap["Verifier.WaveTx.bulk"]["count"] == 6
+    assert "Verifier.WaveTx.held" not in snap
+    assert snap["Verification.Success"]["count"] == 4
+    assert snap["Verification.Failure"]["count"] == 2
+    assert snap["Verification.InFlight"]["value"] == 0
+    assert snap["tx_verify_seconds"]["count"] == 6
+    # coverage ran for the five members whose signatures all verified
+    assert snap["Verifier.RequiredKeys"]["count"] == 5
+    assert snap["Verifier.CompositeRequired"]["count"] == 0
+    device = b.metrics.snapshot()
+    assert device["SigBatcher.DeviceChecked"]["count"] == 6
+    assert device["SigBatcher.DeviceChecked.ed25519"]["count"] == 4
+    assert device["SigBatcher.DeviceChecked.secp256k1"]["count"] == 2
+    spans = {s["name"]: s for s in tracer.spans()}
+    wave = spans["verifier.wave"]
+    assert wave["tags"] == {"n_tx": 6, "n_sigs": 6, "admitted": "bulk",
+                            "failed": 2}
+    for child in ("submit", "verdicts", "coverage", "rules"):
+        span = spans[f"verifier.wave.{child}"]
+        assert span["parent_id"] == wave["span_id"], child
+        assert (span["cpu_s"] is not None) == (child != "verdicts"), child
+    assert spans["verifier.wave.coverage"]["tags"]["n_tx"] == 5
+    assert spans["verifier.wave.rules"]["tags"]["n_tx"] == 4
+    # the batcher's own spans hang under the wave, beside its passes
+    assert spans["batcher.submit"]["parent_id"] == wave["span_id"]
+    assert spans["batcher.flush"]["trace_id"] == wave["trace_id"]
+    assert "tx.verify" not in spans
+
+
+def test_a_wave_under_the_crossover_takes_the_path_a_member_took(services):
+    """Under the crossover every member is held and collected on the worker
+    that serves it, as before: ``SigBatcher.HostInline`` moves, the planner
+    cuts nothing, and the wave's span closes with its last member."""
+    from corda_tpu.observability import disable_tracing, enable_tracing
+    b = SignatureBatcher(host_crossover=5)
+    b._submit_flush = lambda *a, **k: pytest.fail("planner cut a plan")
+    b.submit_groups = lambda *a, **k: pytest.fail("a burst under the "
+                                                  "crossover")
+    svc = TpuTransactionVerifierService(batcher=b)
+    held = _spy_threads(b, "hold_group")
+    stxs = _wave_of(services)
+    stxs[2] = _corrupted(stxs[2])
+    tracer = enable_tracing()
+    try:
+        futs = svc.verify_wave(stxs, services)
+        got = [f.exception(timeout=30) for f in futs]
+    finally:
+        disable_tracing()
+        svc.shutdown()
+    assert [type(e) for e in got] == [type(None), type(None),
+                                      SignatureException, type(None)]
+    assert held == [threading.current_thread().name] * 4
+    snap = svc.metrics.snapshot()
+    assert snap["Verifier.WaveTx.held"]["count"] == 4
+    assert "Verifier.WaveTx.bulk" not in snap
+    for meter in ("HostInline", "HostRouted", "Checked"):
+        assert b.metrics.snapshot()[f"SigBatcher.{meter}"]["count"] == 4
+    spans = tracer.spans()
+    (wave,) = [s for s in spans if s["name"] == "verifier.wave"]
+    assert wave["tags"] == {"n_tx": 4, "n_sigs": 4, "admitted": "held"}
+    roots = [s for s in spans if s["name"] == "tx.verify"]
+    assert len(roots) == 4
+    assert wave["start_s"] + wave["duration_s"] >= max(
+        r["start_s"] + r["duration_s"] for r in roots) - 1e-3
+
+
+def test_a_host_only_batcher_keeps_every_wave_with_its_members(services):
+    """``use_device=False`` (and ``route_interactive_host``): the rows of a
+    held member go to the host queue, so a wave of any size stays held."""
+    b = SignatureBatcher(use_device=False, host_crossover=1)
+    assert not b.wave_is_the_planners([ALICE_KP.public] * 500, 500)
+    forced = SignatureBatcher(host_crossover=1)
+    try:
+        assert forced.wave_is_the_planners([ALICE_KP.public], 1)
+        forced.route_interactive_host(True)
+        assert not forced.wave_is_the_planners([ALICE_KP.public], 1)
+    finally:
+        forced.close()
+    svc = TpuTransactionVerifierService(batcher=b)
+    try:
+        futs = svc.verify_wave(_wave_of(services), services)
+        assert [f.exception(timeout=30) for f in futs] == [None] * 4
+        assert svc.metrics.snapshot()["Verifier.WaveTx.held"]["count"] == 4
+    finally:
+        svc.shutdown()
+
+
+def test_the_coverage_pass_meters_composite_keys_and_their_leaves(services):
+    """A 2-of-3 owner signed by two leaves is covered, by one it is not;
+    ``Verifier.CompositeRequired`` counts the composite required keys and
+    ``Verifier.CompositeLeafVisits`` the leaves the walks looked up."""
+    from corda_tpu.core.crypto.composite import CompositeKey
+    leaves = [generate_keypair(entropy=bytes([0x30 + i]) * 32)
+              for i in range(3)]
+    owner = CompositeKey.Builder().add_keys(
+        *(kp.public for kp in leaves)).build(2)
+    wtx = WireTransaction(
+        outputs=(TransactionState(DummyState(9, (owner,)), NOTARY),),
+        commands=(Command(DummyContract.Create(), (owner,)),),
+        notary=NOTARY, must_sign=(owner,))
+
+    def signed_by(*kps):
+        return SignedTransaction.of(
+            wtx, [Crypto.sign_with_key(kp, wtx.id.bytes) for kp in kps])
+
+    order = [c.node for c in owner.children]     # the walk's own order
+    first, second = (next(kp for kp in leaves if kp.public == k)
+                     for k in order[:2])
+    b = SignatureBatcher(host_crossover=2, max_batch=4)
+    _stub_device(b)
+    svc = TpuTransactionVerifierService(batcher=b)
+    try:
+        futs = svc.verify_wave([signed_by(first, second), signed_by(first)],
+                               services)
+        got = [f.exception(timeout=30) for f in futs]
+    finally:
+        svc.shutdown()
+    assert got[0] is None and type(got[1]) is SignaturesMissingException
+    snap = svc.metrics.snapshot()
+    assert snap["Verifier.RequiredKeys"]["count"] == 2
+    assert snap["Verifier.CompositeRequired"]["count"] == 2
+    # covered: the walk stops at the second leaf; not covered: all three
+    assert snap["Verifier.CompositeLeafVisits"]["count"] == 2 + 3
 
 
 def test_inline_flush_spans_hang_under_the_callers_tx_verify(services):

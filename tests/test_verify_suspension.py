@@ -341,23 +341,44 @@ def _spy_routes(batcher):
 
 @pytest.mark.parametrize("bad,outcome", [((), "verified"), ((3, 1), 1)],
                          ids=["all_valid", "first_failure_in_order"])
-def test_wave_at_the_crossover_is_enqueued_whole_from_the_node_thread(
+def test_wave_at_the_crossover_is_one_bulk_burst_from_the_node_thread(
         bad, outcome):
+    """At or over the crossover ``VerifyMany`` hands the wave to
+    ``verify_wave``, which admits it as ONE ``submit_groups`` call in the
+    bulk class on the node's thread and ONE completion task: no member is
+    held, no member is a pool task of its own."""
     import threading
     network, node = make_network_node()
     svcs = seed_services(node)
-    # 5 one-signature members against a crossover of 5; interactive_batch 5
-    # makes the whole wave ready at its cap: no deadline is waited for
-    batcher = SignatureBatcher(host_crossover=5, interactive_batch=5)
-    node.services.verifier_service = TpuTransactionVerifierService(
-        batcher=batcher)
+    # 5 one-signature members against a crossover of 5; max_batch 5 makes
+    # the whole wave ready at its cap: no deadline is waited for
+    batcher = SignatureBatcher(host_crossover=5, max_batch=5)
+    svc = TpuTransactionVerifierService(batcher=batcher)
+    node.services.verifier_service = svc
     seen = _spy_routes(batcher)
+    bursts, tasks = [], []
+    submit_groups, pool_submit = batcher.submit_groups, svc._pool.submit
+
+    def spy_groups(groups, ctxs=None, latency_class="bulk"):
+        bursts.append((threading.current_thread().name, len(groups),
+                       latency_class))
+        return submit_groups(groups, ctxs, latency_class)
+
+    def spy_pool(fn, *a, **k):
+        tasks.append(fn.__name__)
+        return pool_submit(fn, *a, **k)
+
+    batcher.submit_groups, svc._pool.submit = spy_groups, spy_pool
     stxs = _wave(svcs, bad)
     try:
         with batcher._lock:     # re-entrant: the planner waits for the wave
             fsm = node.start_flow(WaveFlow(stxs))
-            assert len(batcher._queues["ed25519"]) == 5
-        assert seen["hold"] == [threading.current_thread().name] * 5
+            assert len(batcher._queues["ed25519"].bulk) == 5
+            assert not batcher._queues["ed25519"].interactive
+        assert bursts == [(threading.current_thread().name, 5, "bulk")]
+        assert tasks == ["_complete_wave"]
+        assert seen["hold"] == [] and seen["collect"] == []
+        assert node.smm.awaiting_external == 1
         network.run_network()
         got = fsm.result_future.result(timeout=60)
         assert got == "verified" if outcome == "verified" else \
@@ -368,8 +389,12 @@ def test_wave_at_the_crossover_is_enqueued_whole_from_the_node_thread(
         assert not seen["host_loop"][0].startswith("tpu-verifier")
         snap = batcher.metrics.snapshot()
         assert snap["SigBatcher.DeviceChecked"]["count"] == 5
+        assert snap["SigBatcher.DeviceChecked.ed25519"]["count"] == 5
         assert "SigBatcher.HostInline" not in snap
         assert "SigBatcher.HostRouted" not in snap
+        waves = svc.metrics.snapshot()
+        assert waves["Verifier.WaveTx.bulk"]["count"] == 5
+        assert "Verifier.WaveTx.held" not in waves
     finally:
         node.services.verifier_service.shutdown()
 
@@ -399,13 +424,16 @@ def test_wave_under_the_crossover_goes_inline_member_by_member(bad, outcome):
         for meter in ("HostInline", "HostRouted", "Checked"):
             assert snap[f"SigBatcher.{meter}"]["count"] == 5
         assert snap["SigBatcher.InFlight"]["value"] == 0
+        waves = node.services.verifier_service.metrics.snapshot()
+        assert waves["Verifier.WaveTx.held"]["count"] == 5
+        assert "Verifier.WaveTx.bulk" not in waves
     finally:
         node.services.verifier_service.shutdown()
 
 
-def test_wave_rows_reach_only_a_service_that_takes_them():
-    """ManualVerifierService's verify_signed has no wave_rows: VerifyMany
-    must keep calling such a service as it did."""
+def test_a_wave_reaches_only_a_service_that_takes_one():
+    """ManualVerifierService has verify_signed and no verify_wave:
+    VerifyMany must keep calling such a service member by member."""
     network, node = make_network_node()
     svcs = seed_services(node)
     manual = ManualVerifierService()
