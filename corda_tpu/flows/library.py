@@ -37,6 +37,8 @@ class NotarisationRequest:
 @dataclass(frozen=True)
 class FetchTransactionsRequest:
     tx_ids: tuple             # SecureHash...
+    ancestors: int = 0        # besides those, at most this many of the
+    #                           transactions they descend from, as held
 
 
 @dataclass(frozen=True)
@@ -54,9 +56,16 @@ class SignTransactionRequest:
     stx: Any
 
 
-for _cls in (NotarisationRequest, FetchTransactionsRequest,
-             FetchAttachmentsRequest, NotifyTxRequest, SignTransactionRequest):
+for _cls in (NotarisationRequest, FetchAttachmentsRequest, NotifyTxRequest,
+             SignTransactionRequest):
     register_type(f"flows.{_cls.__name__}", _cls)
+# a request for no ancestors is on the wire what it was before there was a
+# budget: the field travels only where it says something
+register_type(
+    "flows.FetchTransactionsRequest", FetchTransactionsRequest,
+    to_fields=lambda r: [r.tx_ids, r.ancestors] if r.ancestors
+    else [r.tx_ids],
+    from_fields=lambda f: FetchTransactionsRequest(tuple(f[0]), *f[1:]))
 
 
 class NotaryException(FlowException):
@@ -151,11 +160,22 @@ def _reject(msg: str):
 @initiating_flow
 class FetchTransactionsFlow(FlowLogic):
     """Download transactions by id from a peer, verifying each returned blob
-    hashes to its requested id (FetchDataFlow's maybeCheckHash)."""
+    hashes to its requested id (FetchDataFlow's maybeCheckHash).
 
-    def __init__(self, peer, tx_ids):
+    ``ancestors`` is a budget: besides the ids, the peer may send up to that
+    many of the transactions they descend from and that it holds (nearest
+    first; see ``FetchTransactionsHandler``). They come back after the
+    requested ones. Each has to be an ancestor: its id, recomputed from its
+    bytes, is an input of a transaction of the same reply or, for a caller
+    that is walking a graph, of ``descends_from`` (the input ids its walk
+    has met). At 0, the default, request and reply are FetchDataFlow's:
+    exactly the items asked for."""
+
+    def __init__(self, peer, tx_ids, ancestors: int = 0, descends_from=()):
         self.peer = peer
         self.tx_ids = tuple(tx_ids)
+        self.ancestors = ancestors
+        self.descends_from = descends_from
 
     def call(self):
         from_disk, to_fetch = [], []
@@ -164,41 +184,99 @@ class FetchTransactionsFlow(FlowLogic):
             (from_disk if stx is not None else to_fetch).append(stx or tx_id)
         if not to_fetch:
             return from_disk
-        resp = yield SendAndReceive(self.peer,
-                                    FetchTransactionsRequest(tuple(to_fetch)),
-                                    list)
+        resp = yield SendAndReceive(
+            self.peer,
+            FetchTransactionsRequest(tuple(to_fetch), self.ancestors), list)
 
         def validate(stxs):
-            if len(stxs) != len(to_fetch):
+            unasked = len(stxs) - len(to_fetch)
+            if not 0 <= unasked <= self.ancestors:
                 raise FlowException("Peer returned wrong number of transactions")
             for tx_id, stx in zip(to_fetch, stxs):
                 if not isinstance(stx, SignedTransaction) or stx.id != tx_id:
                     raise FlowException(
                         f"Peer returned a transaction that hashes to {stx.id} "
                         f"instead of the requested {tx_id}")
+            if unasked:
+                _check_ancestors(stxs, len(to_fetch), self.descends_from)
             return list(stxs)
 
         return from_disk + resp.unwrap(validate)
 
 
+def _check_ancestors(stxs, n_asked: int, descends_from) -> None:
+    """The transactions of a reply after its first ``n_asked`` came unasked:
+    each is a SignedTransaction whose id (recomputed from its bytes) is an
+    input of a transaction of the reply or of the walk, and none comes
+    twice. Ids are hashes, so no cycle can vouch for itself: every chain of
+    such links ends in something that was asked for."""
+    for stx in stxs[n_asked:]:
+        if not isinstance(stx, SignedTransaction):
+            raise FlowException(
+                f"Peer returned {type(stx).__name__} among the ancestors")
+    spent = {ref.txhash for stx in stxs for ref in stx.inputs}
+    sent = {stx.id for stx in stxs[:n_asked]}
+    for stx in stxs[n_asked:]:
+        if stx.id in sent:
+            raise FlowException(
+                f"Peer returned transaction {stx.id} twice")
+        if stx.id not in spent and stx.id not in descends_from:
+            raise FlowException(
+                f"Peer returned transaction {stx.id}, which was not asked "
+                "for and is no ancestor of what was")
+        sent.add(stx.id)
+
+
 class FetchTransactionsHandler(FlowLogic):
     """Serves FetchTransactionsFlow requests from local storage — installed on
-    every node (installCoreFlows, AbstractNode.kt:285)."""
+    every node (installCoreFlows, AbstractNode.kt:285). The requested
+    transactions come first, in the order asked, and one that is missing
+    fails the request. Where the request has a budget of ``ancestors``, the
+    holder then walks its own store breadth first from their inputs and
+    appends the ancestors it holds, nearest first and none twice, until the
+    budget (a page at most) is spent; one it does not hold is left out."""
 
     def __init__(self, peer):
         self.peer = peer
 
     def call(self):
         req = yield Receive(self.peer, FetchTransactionsRequest)
-        tx_ids = req.unwrap(lambda r: r.tx_ids)
+        tx_ids, budget = req.unwrap(lambda r: (r.tx_ids, r.ancestors))
+        storage = self.service_hub.storage
         out = []
         for tx_id in tx_ids:
-            stx = self.service_hub.storage.get_transaction(tx_id)
+            stx = storage.get_transaction(tx_id)
             if stx is None:
                 raise FlowException(f"Transaction {tx_id} not found")
             out.append(stx)
+        if isinstance(budget, int) and budget > 0:
+            out.extend(_held_ancestors(storage, out, min(budget, FETCH_PAGE)))
         yield Send(self.peer, out)
         return None
+
+
+def _held_ancestors(storage, stxs, budget: int) -> list:
+    """Breadth first from ``stxs``' inputs through ``storage``: the ancestors
+    held there, nearest first, none twice and none of ``stxs``, at most
+    ``budget`` of them."""
+    out = []
+    seen = {stx.id for stx in stxs}
+    level = stxs
+    while level:
+        following = []
+        for stx in level:
+            for ref in stx.inputs:
+                if ref.txhash in seen:
+                    continue
+                seen.add(ref.txhash)
+                held = storage.get_transaction(ref.txhash)
+                if held is not None:
+                    following.append(held)
+        out.extend(following[:budget - len(out)])
+        if len(out) >= budget:
+            break
+        level = following
+    return out
 
 
 @initiating_flow
@@ -256,34 +334,61 @@ FETCH_PAGE = 500  # tx ids per FetchTransactionsFlow request within a wave
 @initiating_flow
 class ResolveTransactionsFlow(FlowLogic):
     """Wave-based dependency download + verify+record
-    (ResolveTransactionsFlow.kt:31-134, vectorized): instead of walking the
-    graph link-by-link, each round fetches the ENTIRE unseen frontier as
-    one batched request (paged at FETCH_PAGE ids), so a depth-D graph costs
-    D round trips, not D x (graph width). Verification then runs in
-    topological WAVES: every member of a wave has its dependencies already
-    recorded, so the whole wave is submitted to the verifier service at
-    once (VerifyMany). Hard cap of 5000 transactions per walk.
+    (ResolveTransactionsFlow.kt:31-134, vectorized): each round fetches the
+    ENTIRE unseen frontier as one batched request (paged at FETCH_PAGE
+    ids), and a request carries a BUDGET of ancestors: besides the ids, the
+    holder sends up to that many of the transactions they descend from and
+    that it holds (``FetchTransactionsFlow``). The budget is what the walk
+    can see of itself, its length so far:
+    ``min(len(fetched), FETCH_PAGE - len(page), cap - all that is wanted)``.
+    A walk's first request therefore asks for none, and a walk that ends
+    after one level (the validating notary's, a payment whose receiver
+    lacks only the last move) sends and receives FetchDataFlow's protocol
+    to the byte. A walk that goes on holds 1, 3, 7, 15, ... transactions
+    after each round: a chain D deep is down in about log2(D) round trips
+    where it was D, and a walk never receives more transactions it did not
+    ask for than it has already had to fetch, so what is wasted where the
+    requester turns out to hold the deeper history is bounded by what was
+    of use. Budgets are a function of counts the response log replays: a
+    restored flow sends the same requests.
 
-    What that buys depends on the graph's WIDTH. On a wide graph a wave's
-    signatures reach the batcher together. On a CHAIN (one coin paid on and
-    on, its change spent by the next payment) the frontier is one id and
-    every level is one transaction: D round trips, D fetch sessions, D
-    ``VerifyMany`` waves of one, each a lone host-routed verify under
-    ``host_crossover`` and a park of its own, and not one row for the
-    device however deep the chain (measured: PERF.md,
-    ``crosscash-deepchain.latejoin``). The walk's cost is linear in D.
+    This departs from v0.14: ``FetchDataFlow.kt``'s reply holds exactly the
+    items asked for, and ``ResolveTransactionsFlow.kt`` learns a level's ids
+    only from the level above. Here the holder, who has the chain,
+    volunteers it. Nothing that arrives is trusted more for it: every id is
+    recomputed from the bytes, a transaction nobody asked for has to be an
+    ancestor of what was (``_check_ancestors``) or the walk is refused, one
+    the requester already holds is dropped, and everything kept, asked for
+    or not, takes the same order, verification and recording.
 
-    Traced, a walk that fetched anything leaves ``resolve.walk`` (tags
-    ``fetched``, ``hops``, ``waves``, ``peer``) with the children
+    Verification runs in topological WAVES: every member of a wave has its
+    dependencies already recorded, so the whole wave is submitted to the
+    verifier service at once (VerifyMany). Hard cap of 5000 transactions
+    per walk; the budget never asks past it. What the waves buy depends on
+    the graph's WIDTH. On a wide graph a wave's signatures reach the
+    batcher together. On a CHAIN (one coin paid on and on, its change spent
+    by the next payment) every level is one transaction: D ``VerifyMany``
+    waves of one, each a lone host-routed verify under ``host_crossover``
+    and a park of its own, and not one row for the device however deep the
+    chain (measured: PERF.md, ``crosscash-deepchain.latejoin``). The
+    verify half of a walk is linear in D.
+
+    A HOP is one level of ancestry (it was also a round trip while a round
+    fetched one level): a walk's ``hops`` is the breadth-first distance of
+    the deepest transaction it fetched, plus one. Traced, a walk that sent
+    a request leaves ``resolve.walk`` (tags ``fetched``, ``hops``,
+    ``round_trips``, ``waves``, ``peer``) with the children
     ``resolve.fetch`` (the download loop), ``resolve.order``,
     ``resolve.verify`` and ``resolve.record`` (each the SUM over the waves,
     laid from its first wave's start, each tagged ``hops`` too). They join
     the flow's trace without a parent span: the walk spans many of the
     flow's steps and waits, and a critical-path walk that charges every
     millisecond of a ``flow.run`` to one span has to go on charging those.
-    Counted always:
-    ``Resolve.Walks`` / ``Hops`` / ``Fetched`` / ``Recorded`` / ``Refused``
-    and the ``resolve_depth`` histogram (hops per walk)."""
+    Counted always: ``Resolve.Walks`` / ``Hops`` / ``RoundTrips`` (fetch
+    requests sent) / ``Fetched`` (transactions kept) / ``Prefetched``
+    (transactions that arrived unasked) / ``PrefetchUnused`` (of those, the
+    ones the requester already held) / ``Recorded`` / ``Refused`` and the
+    ``resolve_depth`` histogram (hops per walk)."""
 
     def __init__(self, peer, tx_ids=None, stx: SignedTransaction | None = None):
         self.peer = peer
@@ -296,10 +401,10 @@ class ResolveTransactionsFlow(FlowLogic):
         if self.stx is not None:
             frontier.extend(ref.txhash for ref in self.stx.inputs)
         fetched: dict = {}
-        seen = set(frontier)
-        queue = [tx_id for tx_id in frontier
-                 if hub.storage.get_transaction(tx_id) is None]
-        walk = _WalkRecord(self)
+        seen = set(frontier)    # the ids asked for and every input id met
+        held = hub.storage.get_transaction
+        queue = [tx_id for tx_id in frontier if held(tx_id) is None]
+        walk = _WalkRecord(self, queue, fetched)
         try:
             while queue:
                 if len(fetched) + len(queue) > MAX_RESOLVE_TRANSACTIONS:
@@ -309,20 +414,43 @@ class ResolveTransactionsFlow(FlowLogic):
                 # one wave = the whole current frontier; page only to bound
                 # the size of a single wire message
                 wave, queue = queue, []
-                walk.hops += 1
-                stxs = []
                 for i in range(0, len(wave), FETCH_PAGE):
-                    page = yield from self.sub_flow(
-                        FetchTransactionsFlow(self.peer, wave[i:i + FETCH_PAGE]))
-                    stxs.extend(page)
-                for stx in stxs:
-                    fetched[stx.id] = stx
-                    for ref in stx.inputs:
-                        dep = ref.txhash
-                        if dep not in seen:
-                            seen.add(dep)
-                            if hub.storage.get_transaction(dep) is None:
-                                queue.append(dep)
+                    page = wave[i:i + FETCH_PAGE]
+                    # the budget for ancestors nobody asked for by id: as
+                    # many as the walk has had to fetch so far (none on its
+                    # first request), within the page and within the cap
+                    # less all that is known to be wanted
+                    budget = max(0, min(
+                        len(fetched), FETCH_PAGE - len(page),
+                        MAX_RESOLVE_TRANSACTIONS - len(fetched)
+                        - (len(wave) - i) - len(queue)))
+                    walk.asking = page
+                    walk.round_trips += 1
+                    stxs = yield from self.sub_flow(FetchTransactionsFlow(
+                        self.peer, page, ancestors=budget,
+                        descends_from=seen))
+                    walk.asking = ()
+                    asked = set(page)
+                    kept = []
+                    for stx in stxs:
+                        if stx.id not in asked:
+                            walk.prefetched += 1
+                            if stx.id in fetched or held(stx.id) is not None:
+                                walk.prefetch_unused += 1
+                                continue
+                            seen.add(stx.id)
+                        fetched[stx.id] = stx
+                        kept.append(stx)
+                    for stx in kept:
+                        for ref in stx.inputs:
+                            dep = ref.txhash
+                            if dep not in seen:
+                                seen.add(dep)
+                                if held(dep) is None:
+                                    queue.append(dep)
+                # a wave's last page (the one with room for a budget) may
+                # have brought what an earlier page's reply had queued
+                queue = [tx_id for tx_id in queue if tx_id not in fetched]
             walk.fetched = len(fetched)
             # attachments referenced anywhere in the resolved set must be
             # local before verification can open them (FetchAttachmentsFlow
@@ -363,9 +491,13 @@ class _WalkRecord:
     """One resolution walk's counts and, traced, its phases' times: the
     meters and spans named in ResolveTransactionsFlow's docstring."""
 
-    def __init__(self, flow):
+    def __init__(self, flow, first, fetched):
         self.flow = flow
-        self.hops = self.fetched = self.waves = self.recorded = 0
+        self.first = tuple(first)      # what the walk set out to fetch
+        self.got = fetched             # the walk's own dict, as it fills
+        self.asking = ()               # the page of the request in flight
+        self.round_trips = self.prefetched = self.prefetch_unused = 0
+        self.fetched = self.waves = self.recorded = 0
         self.tracer = get_tracer()
         self.phases: dict = {}         # name -> [first start, summed seconds]
         self.t0 = self.mark = _time.time() if self.tracer.enabled else None
@@ -379,18 +511,46 @@ class _WalkRecord:
         first[1] += now - self.mark
         self.mark = now
 
+    def levels(self) -> int:
+        """The walk's hops: the levels of ancestry it went down, which is
+        the breadth-first distance of the deepest transaction it fetched
+        (or was asking for when it failed), plus one. It is the number of
+        requests a walk that learned each level from the one above would
+        have sent."""
+        wanted = set(self.got).union(self.asking)
+        level = [tx_id for tx_id in self.first if tx_id in wanted]
+        seen = set(level)
+        hops = 0
+        while level:
+            hops += 1
+            following = []
+            for tx_id in level:
+                if tx_id not in self.got:       # asked for, never received
+                    continue
+                for ref in self.got[tx_id].inputs:
+                    if ref.txhash in wanted and ref.txhash not in seen:
+                        seen.add(ref.txhash)
+                        following.append(ref.txhash)
+            level = following
+        return hops
+
     def close(self, refused: bool) -> None:
-        if not self.hops:       # nothing to fetch: no walk to count
+        if not self.round_trips:        # nothing to fetch: no walk to count
             return
+        hops = self.levels()
         monitoring = getattr(self.flow.service_hub, "monitoring", None)
         if monitoring is not None:
             monitoring.meter("Resolve.Walks").mark()
-            monitoring.meter("Resolve.Hops").mark(self.hops)
+            monitoring.meter("Resolve.Hops").mark(hops)
+            monitoring.meter("Resolve.RoundTrips").mark(self.round_trips)
             monitoring.meter("Resolve.Fetched").mark(self.fetched)
+            monitoring.meter("Resolve.Prefetched").mark(self.prefetched)
+            monitoring.meter("Resolve.PrefetchUnused").mark(
+                self.prefetch_unused)
             monitoring.meter("Resolve.Recorded").mark(self.recorded)
             if refused:
                 monitoring.meter("Resolve.Refused").mark()
-            monitoring.histogram("resolve_depth").update(self.hops)
+            monitoring.histogram("resolve_depth").update(hops)
         ctx = getattr(self.flow.state_machine, "trace_ctx", None)
         if self.t0 is None or ctx is None:
             return
@@ -398,12 +558,12 @@ class _WalkRecord:
         walk = self.tracer.record(
             "resolve.walk", parent=(trace_id, None), start_s=self.t0,
             duration_s=_time.time() - self.t0, fetched=self.fetched,
-            hops=self.hops, waves=self.waves, recorded=self.recorded,
-            refused=refused, peer=str(self.flow.peer.name))
+            hops=hops, round_trips=self.round_trips, waves=self.waves,
+            recorded=self.recorded, refused=refused,
+            peer=str(self.flow.peer.name))
         for name, (start, seconds) in self.phases.items():
             self.tracer.record(f"resolve.{name}", parent=walk,
-                               start_s=start, duration_s=seconds,
-                               hops=self.hops)
+                               start_s=start, duration_s=seconds, hops=hops)
 
 
 def _topological_waves(txs: dict) -> list:

@@ -27,7 +27,11 @@ EXCLUDED = {("test_latejoin", "test_the_cell_has_its_files"),
             # LAST of ``per_layer``, and PR 40 appended two after them. Its
             # other asserts are held below.
             ("test_batch_readers",
-             "test_the_new_metrics_are_listed_in_their_cells_and_appended")}
+             "test_the_new_metrics_are_listed_in_their_cells_and_appended"),
+            # and once more: it pins PR 42's 19 metrics as the LAST of
+            # ``per_layer``, and PR 43 appended two. Its other asserts are
+            # held below.
+            ("test_mixedbackfill", "test_the_cell_has_its_files")}
 
 #: per-layer metrics a later PR gave a cell whose test file pins the cell's
 #: list as ``METRICS`` (a PR may add metrics, and may not edit the
@@ -45,6 +49,16 @@ ADDED = {"test_ecdsawaves": _BATCH + ["ecdsa_keys_ms_p50",
                                       "ecdsa_pad_ms_p50"],
          "test_oopstream": [f"{_n}.stream" for _n in _BATCH + _ED_PREP]
          + ["ed25519_words_prep_share.stream"]}     # PR 40
+
+#: PR 43: what a walk's round trips carry, read in both cells whose walks
+#: fetch anything (one file a metric, both cells in its ``workloads``).
+WALK_COUNTERS = {
+    "resolve_levels_per_round_trip": (
+        "levels/trip", "higher", ["Resolve.Hops"], ["Resolve.RoundTrips"]),
+    "resolve_prefetch_unused_share": (
+        "%", "lower", ["Resolve.PrefetchUnused"],
+        ["Resolve.Fetched", "Resolve.PrefetchUnused"])}
+WALK_CELLS = ["crosscash-deepchain.latejoin", "crosscash-raft.steady"]
 
 for _path in sorted((BENCH / "tests").glob("test_*.py")):
     _spec = importlib.util.spec_from_file_location(
@@ -125,9 +139,10 @@ def test_batch_readers__the_new_metrics_are_listed_wherever_they_stand():
              "ed25519_words_prep_share.stream":
              (["genledger-oop.stream"], "tx_per_s")}
     # PR 42 appended the genledger-mixed cell's metrics after these two
-    # (benchmarks/tests/test_mixedbackfill.py holds those to their own list)
+    # (benchmarks/tests/test_mixedbackfill.py holds those to their own
+    # list), and PR 43 the walk's two counters (held by name below)
     assert order[at + len(br.NEW):at + len(br.NEW) + 2] == list(after)
-    assert all(name.endswith(".backfill")
+    assert all(name.endswith(".backfill") or name in WALK_COUNTERS
                for name in order[at + len(br.NEW) + 2:])
     for name, (cells, moves) in after.items():
         row, lm = listed[name], files[name]
@@ -141,3 +156,111 @@ def test_batch_readers__the_new_metrics_are_listed_wherever_they_stand():
         assert lm["args"]["numerator"] == ["SigBatcher.Ed25519WordsPrep"]
         assert lm["args"]["denominator"] == [
             "SigBatcher.Ed25519WordsPrep", "SigBatcher.Ed25519ItemsPrep"]
+
+
+def test_the_walk_counters_are_listed_in_both_cells_wherever_they_stand():
+    """PR 43's two metrics by MEMBERSHIP: each is in ``per_layer`` once,
+    as its file says, in the two cells whose walks fetch anything, and no
+    entry that stood before them changed place (the files above hold
+    that)."""
+    import json
+    lj = sys.modules["benchmarks_tests_test_latejoin"]
+    listed = [m for m in lj.SPEC["per_layer"] if m["name"] in WALK_COUNTERS]
+    assert sorted(m["name"] for m in listed) == sorted(WALK_COUNTERS)
+    for row in listed:
+        unit, better, numerator, denominator = WALK_COUNTERS[row["name"]]
+        lm = json.loads((BENCH / "layer_metrics" / f"{row['name']}.json")
+                        .read_text())
+        assert row["workloads"] == lm["workloads"] == WALK_CELLS
+        assert row["moves"] == lm["moves"] == "commit_ms_p50"
+        assert row["source"] == "program_counter" and "bound" not in row
+        assert row["unit"] == lm["unit"] == unit and row["better"] == better
+        assert row["layer"] == lm["layer"] \
+            == "flows / scheduler (flows/, node/statemachine.py)"
+        assert lm["reader"] == "registry_ratio"
+        assert lm["args"]["numerator"] == numerator
+        assert lm["args"]["denominator"] == denominator
+    for name in WALK_CELLS:
+        cell = lj.bench_run.Cell(name, lj.SPEC)
+        assert set(WALK_COUNTERS) <= {lm["name"]
+                                      for lm in cell.layer_metric_files()}
+
+
+def test_latejoin_traced_rehearsal_reads_the_walk_counters(capsys):
+    """A joiner's walk goes down many levels a round trip and, its store
+    being empty, nothing it is sent is of no use; the readers that select
+    walks by ``hops`` still find the joiners' (a hop is a level)."""
+    lj = sys.modules["benchmarks_tests_test_latejoin"]
+    result = lj.rehearse(capsys, trace=True)
+    assert result["correct"]
+    m = {k: v["value"] for k, v in result["metrics"].items()}
+    # 25-30 levels a joiner's walk in 5 round trips, the notary's walks of
+    # one level in one: well over 2 however many of each the window held
+    assert m["resolve_levels_per_round_trip"] > 2.0
+    assert m["resolve_prefetch_unused_share"] == 0.0
+    assert m["resolve_fetch_ms_p50.latejoin"] > 0.0     # hops >= 16 still
+
+
+def test_steady_traced_rehearsal_reads_the_walk_counters(capsys):
+    sr = sys.modules["benchmarks_tests_test_span_readers"]
+    result = sr.bench_run.run_cell(
+        sr.bench_run.Cell("crosscash-raft.steady", sr.SPEC), 3_000_000_029,
+        3.0, True, sr.CPU, scale=sr.LEDGER_TINY, quiet=True, notes=[])
+    assert capsys.readouterr().out == ""
+    assert result["correct"]
+    m = {k: v["value"] for k, v in result["metrics"].items()}
+    assert m["resolve_levels_per_round_trip"] >= 1.0
+    assert 0.0 <= m["resolve_prefetch_unused_share"] <= 50.0
+
+
+def test_mixedbackfill__the_cell_has_its_files_wherever_its_metrics_stand():
+    """``test_mixedbackfill``'s excluded test, assert for assert, but for
+    "PR 42's metrics are the LAST of ``per_layer``": here they are one
+    unbroken run in their order, wherever later metrics were appended."""
+    mb = sys.modules["benchmarks_tests_test_mixedbackfill"]
+    cell = mb.bench_run.Cell(mb.CELL, mb.SPEC)
+    assert cell.driver_name == "mixedbackfill" and cell.chips == 1
+    assert cell.end_to_end_names() == ["tx_per_s", "setup_s"]
+    assert sorted(lm["name"] for lm in cell.layer_metric_files()) \
+        == sorted(mb.METRICS)
+    for lm in cell.layer_metric_files():
+        assert lm["workloads"] == [mb.CELL] and lm["moves"] == "tx_per_s"
+        assert (BENCH / "readers" / f"{lm['reader']}.py").is_file()
+    listed = {m["name"]: m for m in mb.SPEC["per_layer"]}
+    for name in mb.METRICS:
+        assert listed[name]["workloads"] == [mb.CELL]
+        assert listed[name]["moves"] == "tx_per_s"
+        assert "bound" not in listed[name]
+    order = [m["name"] for m in mb.SPEC["per_layer"]]
+    at = order.index(mb.METRICS[0])
+    assert order[at:at + len(mb.METRICS)] == mb.METRICS
+    assert mb.SPEC["workloads"][-1]["name"] == mb.CELL
+    assert mb.SPEC["configs"][-1]["name"] == "genledger-mixed"
+    (tx,) = [m for m in mb.SPEC["end_to_end"] if m["name"] == "tx_per_s"]
+    assert tx["workloads"][-1] == mb.CELL and tx["bound"] == 0.05
+    config, traffic = cell.config, cell.traffic
+    assert config["batcher_args"] == {"max_batch": 8192,
+                                      "bucket_ladder": [256, 8192]}
+    assert config["schemes"] == ["ed25519", "secp256k1"]
+    assert (config["party_keys"], config["composite_parties"],
+            config["nested_composites"], config["notary_replicas"]) \
+        == (64, 16, 4, 3)
+    assert config["ledgers"] * config["ledger_transactions"] == 32768 \
+        == 2 * traffic["clients"] * traffic["wave_transactions"]
+    assert (traffic["pool_waves"], traffic["wave_transactions"]) \
+        == (config["ledgers"], config["ledger_transactions"])
+    assert traffic["wave_transactions"] < 5000      # one walk's cap
+    assert traffic["warm_verdicts"] == 49152 and traffic["loop"] == "closed"
+    assert traffic["bucket_rows"] == config["batcher_args"]["max_batch"]
+    assert 2 <= traffic["trace_seconds"] <= 8
+    assert len(config["invalid_kinds"]) == mb.mixed_ledgers.N_INVALID == 8
+    assert len(config["valid_shapes"]) == 2
+    assert set(config["reduced"]) == {"schemes"}
+    assert {"parties", "composite_owners", "notary", "transactions",
+            "generator", "invalid", "contract", "signer", "max_batch"} \
+        <= set(config["assumed"])
+    assert config["collector_thresholds"] == [1000000, 10, 1000000]
+    assert "collector" in config["assumed"]
+    (row,) = [c for c in mb.SPEC["configs"] if c["name"] == "genledger-mixed"]
+    assert row["reduced"] == ["schemes"] and row["source"] == config["source"]
+    assert len(row["source"]) <= 200 and "CompositeKey.kt:35" in row["source"]

@@ -1,13 +1,17 @@
 """A late joiner resolves a deep back chain (ResolveTransactionsFlowTest.kt's
 cases at depth): the joiner's store and record order against the plain
 reference, hostile holders refused, the cap, a joiner killed in mid-walk,
-and the walk's growth pinned by COUNTS (no wall-clock deadline anywhere)."""
+the holder's pages of ancestors (round trips, budgets, what a reply may
+hold), and the walk's growth pinned by COUNTS (no wall-clock deadline
+anywhere)."""
+import hashlib
 import pathlib
 import sys
 
 import pytest
 
 from corda_tpu.core.contracts.amount import USD, Amount
+from corda_tpu.core.crypto.secure_hash import SecureHash
 from corda_tpu.core.crypto.signatures import TransactionSignature
 from corda_tpu.core.serialization import serialize
 from corda_tpu.core.transactions.signed import SignedTransaction
@@ -17,6 +21,8 @@ from corda_tpu.flows import library
 from corda_tpu.node.checkpoints import (CheckpointStorage,
                                         FileCheckpointStorage,
                                         KvCheckpointStorage)
+from corda_tpu.node.statemachine import (SessionData, SessionInit,
+                                         StateMachineManager)
 from corda_tpu.testing import MockNetwork
 from corda_tpu.utils.metrics import MetricRegistry
 
@@ -100,18 +106,44 @@ CLEAN = {"missing": 0, "extra": 0, "recorded_twice": 0, "order_violations": 0,
 
 # -- the joiner's store and record order against the plain reference --------------
 
+def count(registry, name):
+    return registry.meter(f"Resolve.{name}").count
+
+
+def alone(node):
+    """A registry of this node's own, from here on: its walks and no other's
+    (the notary and the counterparty walk one level at every payment)."""
+    node.services.monitoring = MetricRegistry()
+    return node.services.monitoring
+
+
 def test_chain_of_64_is_resolved_whole_and_in_order():
     led = Ledger()
     led.chain(64)
+    mine = alone(led.joiners[0])
     final = led.pay(led.wallet, led.joiners[0])
     got = recorded(led.joiners[0])
     assert len(got) == 66            # the issue, 64 moves, the payment
     assert ref.judge_join(led.history(), final.id.bytes, got) == CLEAN
-    walks = led.registry.meter("Resolve.Walks").count
-    assert led.registry.meter("Resolve.Hops").count >= 65
-    assert led.registry.meter("Resolve.Recorded").count \
-        == led.registry.meter("Resolve.Fetched").count
-    assert led.registry.meter("Resolve.Refused").count == 0
+    # the joiner's one walk: 65 levels of ancestry, as ever, in the 7 round
+    # trips of 1, 3, 7, 15, 31, 63, 65 transactions held
+    assert count(mine, "Walks") == 1
+    assert count(mine, "Hops") >= 65
+    assert count(mine, "RoundTrips") <= 8
+    assert count(mine, "Recorded") == count(mine, "Fetched") == 65
+    assert count(mine, "Prefetched") \
+        == count(mine, "Fetched") - count(mine, "RoundTrips")
+    assert count(mine, "PrefetchUnused") == 0       # its store was empty
+    assert count(mine, "Refused") == 0
+    depth = mine.histogram("resolve_depth")
+    assert depth.count == 1 and depth.snapshot_fields()["max"] == 65
+    # every other walk of this ledger went down one level, in one request
+    walks = count(led.registry, "Walks")
+    assert walks >= 64
+    assert count(led.registry, "Hops") == walks \
+        == count(led.registry, "RoundTrips")
+    assert count(led.registry, "Prefetched") == 0
+    assert count(led.registry, "Recorded") == count(led.registry, "Fetched")
     assert led.registry.histogram("resolve_depth").count == walks
 
 
@@ -140,7 +172,224 @@ def test_many_input_merge_is_resolved_whole_and_in_order():
     assert ref.judge_join(led.history(), final.id.bytes, got) == CLEAN
 
 
+# -- pages of ancestors: what goes over the wire ------------------------------------------
+
+@pytest.fixture
+def wire(monkeypatch):
+    """Every session message any node posts, as the object it serialises."""
+    sent = []
+    post = StateMachineManager._post
+
+    def spy(self, party, message, fsm=None):
+        sent.append(message)
+        return post(self, party, message, fsm)
+
+    monkeypatch.setattr(StateMachineManager, "_post", spy)
+    return sent
+
+
+def fetches(wire, by=None):
+    """(request, reply) of every fetch on the wire, in order; ``by``, where
+    given, is the one node whose requests are wanted."""
+    replies = {m.recipient_session_id: m.payload for m in wire
+               if isinstance(m, SessionData) and isinstance(m.payload, list)}
+    return [(m.first_payload, replies[m.initiator_session_id])
+            for m in wire if isinstance(m, SessionInit)
+            and isinstance(m.first_payload, library.FetchTransactionsRequest)
+            and (by is None or m.initiator_party == str(by.party.name))]
+
+
+def test_a_request_for_no_ancestors_is_on_the_wire_what_it_always_was():
+    ids = (SecureHash.sha256(b"a"), SecureHash.sha256(b"b"))
+    plain = library.FetchTransactionsRequest(ids)
+    assert plain.ancestors == 0
+    blob = serialize(plain)
+    # the bytes the parent of this protocol wrote for the same request
+    assert hashlib.sha256(blob).hexdigest() == \
+        "a98c5e9dc365e1cb0bdc94117d0f42a0fac14980a722fb1926d1c2e7c5a956dc"
+    from corda_tpu.core.serialization import deserialize
+    assert deserialize(blob) == plain
+    paged = library.FetchTransactionsRequest(ids, 7)
+    assert deserialize(serialize(paged)) == paged != plain
+
+
+def test_one_level_walk_asks_for_no_ancestors_and_gets_what_it_asked(wire):
+    led = Ledger()
+    led.chain(3)
+    del wire[:]
+    # the validating notary holds all but this payment's input (the payee
+    # was paid by it, and walks nowhere)
+    paid = led.pay(led.wallet, led.other)
+    (previous,) = {r.txhash for r in paid.inputs}
+    ((request, reply),) = fetches(wire)
+    assert request.tx_ids == (previous,) and request.ancestors == 0
+    assert [stx.id for stx in reply] == [previous]
+    assert count(led.registry, "Prefetched") == 0
+
+
+def test_walk_doubles_its_budget_and_the_holder_fills_it_nearest_first(wire):
+    led = Ledger()
+    led.chain(20)
+    chain = [stx.id for stx in led.wallet.services.storage.transactions]
+    assert len(chain) == 21
+    del wire[:]
+    mine = alone(led.joiners[0])
+    led.pay(led.wallet, led.joiners[0])
+    first, *seen = fetches(wire, by=led.joiners[0])
+    assert first[0] == library.FetchTransactionsRequest((chain[-1],))
+    assert [stx.id for stx in first[1]] == [chain[-1]]
+    # 1 held, then 3, 7, 15 and the last 6: each request one id, the budget
+    # what the walk held, the reply that id and then its ancestors in order
+    assert [request.ancestors for request, _reply in seen] == [1, 3, 7, 15]
+    down = chain[::-1]
+    at = 1
+    for request, reply in seen:
+        assert request.tx_ids == (down[at],)
+        assert [stx.id for stx in reply] == \
+            down[at:at + 1 + request.ancestors]
+        at += len(reply)
+    assert at == 21
+    assert count(mine, "RoundTrips") == 5 and count(mine, "Hops") == 21
+    assert count(mine, "Prefetched") == 16
+
+
+@pytest.mark.parametrize("ancestors", [0, 2, 50])
+def test_fetch_flow_alone_takes_a_budget_and_defaults_to_none(ancestors):
+    led = Ledger()
+    led.chain(6)
+    chain = [stx.id for stx in led.wallet.services.storage.transactions]
+    flow = library.FetchTransactionsFlow(
+        led.wallet.party, chain[-2:], **({"ancestors": ancestors}
+                                         if ancestors else {}))
+    got = [stx.id for stx in led.run(led.joiners[0], flow)]
+    # the two asked for, in the order asked, then what they descend from,
+    # nearest first, as far as the budget and the holder's store go
+    assert got == chain[-2:] + chain[:-2][::-1][:ancestors]
+    assert recorded(led.joiners[0]) == []       # a download records nothing
+
+
+@pytest.mark.parametrize("page", [2, 3, 4])
+def test_small_pages_of_a_wide_and_deep_graph_resolve_whole(page, monkeypatch):
+    """Several pages a round, budgets on the later ones, ancestors that a
+    page brings while an earlier page's reply has them queued."""
+    monkeypatch.setattr(library, "FETCH_PAGE", page)
+    led = Ledger()
+    for _ in range(3):
+        led.issue(led.wallet, 100)
+    for _ in range(4):
+        led.pay(led.wallet, led.other, 30)        # spends a coin, takes change
+        led.pay(led.other, led.wallet, 20)        # and a coin comes back
+    mine = alone(led.joiners[0])
+    final = led.pay(led.wallet, led.joiners[0], 250)
+    assert len(final.inputs) >= 4
+    got = recorded(led.joiners[0])
+    assert len(got) == 3 + 8 + 1
+    assert ref.judge_join(led.history(), final.id.bytes, got) == CLEAN
+    assert count(mine, "Recorded") == count(mine, "Fetched") == 11
+    assert count(mine, "RoundTrips") >= -(-11 // page)
+
+
+def test_ancestor_that_an_earlier_page_queued_crosses_the_wire_once(
+        wire, monkeypatch):
+    """Pages of two. The payment spends C (the change of a move out of P's
+    change), an issue, and A (what came back out of P's other output): the
+    first page's reply queues P, and the second page, the one id A with
+    room for one ancestor, brings P."""
+    monkeypatch.setattr(library, "FETCH_PAGE", 2)
+    led = Ledger()
+    issue = led.issue(led.wallet, 100)
+    p = led.pay(led.wallet, led.other, 30)
+    c = led.pay(led.wallet, led.other, 5)
+    b = led.issue(led.wallet, 100)
+    a = led.pay(led.other, led.wallet, 20)
+    del wire[:]
+    joiner = led.joiners[0]
+    mine = alone(joiner)
+    final = led.pay(led.wallet, joiner, 185)
+    assert [r.txhash for r in final.inputs] == [c.id, b.id, a.id]
+    got = recorded(joiner)
+    assert ref.judge_join(led.history(), final.id.bytes, got) == CLEAN
+    seen = fetches(wire, by=joiner)
+    assert [(request.tx_ids, request.ancestors) for request, _r in seen] == [
+        ((c.id, b.id), 0), ((a.id,), 1), ((issue.id,), 1)]
+    assert [[stx.id for stx in reply] for _r, reply in seen] == [
+        [c.id, b.id], [a.id, p.id], [issue.id]]
+    assert count(mine, "RoundTrips") == 3 and count(mine, "Hops") == 3
+    assert count(mine, "Fetched") == 5 and count(mine, "Prefetched") == 1
+    assert count(mine, "PrefetchUnused") == 0
+
+
+def test_requester_that_holds_the_deep_half_records_nothing_twice():
+    led = Ledger()
+    led.chain(32)
+    joiner = led.joiners[0]
+    led.pay(led.wallet, joiner)     # the chain goes on through this payment
+    assert len(recorded(joiner)) == 34
+    for _ in range(32):
+        led.pay(led.wallet, led.other)
+    mine = alone(joiner)
+    final = led.pay(led.wallet, joiner)
+    got = recorded(joiner)
+    assert len(got) == 34 + 32 + 1
+    assert ref.judge_join(led.history(), final.id.bytes, got) == CLEAN
+    # 32 to fetch, in rounds of 1, 2, 4, 8, 16 and then one id with a
+    # budget of 31: the one move still missing, and 31 the joiner held
+    assert count(mine, "Hops") == 32 == count(mine, "Fetched")
+    assert count(mine, "RoundTrips") == 6
+    assert count(mine, "PrefetchUnused") == 31
+    assert count(mine, "Prefetched") == 26 + 31
+    assert count(mine, "PrefetchUnused") <= count(mine, "Fetched")
+    assert count(mine, "Recorded") == 32
+
+
 # -- hostile holders ----------------------------------------------------------------
+
+def stranger(led):
+    """A genuine transaction that no walk of the wallet's chain descends
+    from, put where the wallet's handler finds it."""
+    stx = led.issue(led.other, 5)
+    led.wallet.services.storage._txs[stx.id] = stx
+    return stx
+
+
+REPLIES = {
+    # name -> what the holder appends in place of the ancestors it owes
+    "no_ancestor": lambda owed, asked, odd: owed[:-1] + [odd],
+    "over_the_budget": lambda owed, asked, odd: owed + [odd],
+    "ancestor_twice": lambda owed, asked, odd: owed[:-1] + owed[:1],
+    "requested_again": lambda owed, asked, odd: owed[:-1] + asked[:1],
+}
+
+
+@pytest.mark.parametrize("kind", sorted(REPLIES))
+def test_reply_with_anything_but_ancestors_within_the_budget_is_refused(
+        kind, monkeypatch):
+    led = Ledger()
+    led.chain(12)
+    odd = stranger(led)
+    honest = library._held_ancestors
+    sent = []
+
+    def hostile(storage, stxs, budget):
+        owed = honest(storage, stxs, budget)
+        if len(owed) < 3:           # the first rounds stay honest
+            return owed
+        sent.append(REPLIES[kind](owed, list(stxs), odd))
+        return sent[-1]
+
+    monkeypatch.setattr(library, "_held_ancestors", hostile)
+    joiner = led.joiners[0]
+    mine = alone(joiner)
+    with pytest.raises(FlowException, match={
+            "no_ancestor": "no ancestor of what was",
+            "over_the_budget": "wrong number of transactions",
+            "ancestor_twice": "twice", "requested_again": "twice"}[kind]):
+        led.pay(led.wallet, joiner)
+    assert len(sent) == 1           # the walk stopped at that reply
+    assert recorded(joiner) == []
+    assert count(mine, "Refused") == 1 and count(mine, "Recorded") == 0
+    assert joiner.services.vault.unconsumed_states() == []
+
 
 def tamper(holder, tx_id, kind, other_key):
     """The holder's stored copy of one back-chain transaction, made bad."""
@@ -209,18 +458,23 @@ def test_joiner_killed_in_mid_walk_finishes_with_the_same_store(kind, tmp_path):
     led.chain(depth)
     joiner = led.joiners[0]
     fsm = led.wallet.start_flow(CashPaymentFlow(dollars(10), joiner.party))
-    # pump until the joiner is half way down the chain
+    # pump until the joiner stands between two fetch rounds: the payment and
+    # three replies (1 + 2 + 4 transactions) are in its log, the fourth
+    # request is out, and 34 of the chain are still to come
     for _ in range(100_000):
         walking = [f for f in joiner.smm.flows.values()
-                   if len(f.response_log) >= depth // 2]
+                   if len(f.response_log) >= 4]
         if walking:
             break
         led.net.bus.run_network(rounds=1)
     else:
-        raise AssertionError("the joiner never got half way")
+        raise AssertionError("the joiner never got that far")
     assert recorded(joiner) == []                   # nothing verified yet
     held = joiner.smm.checkpoints.get_all_checkpoints()
-    assert len(held) == 1 and len(held[0].response_log) >= depth // 2
+    assert len(held) == 1
+    pages = [value for kind_, value in held[0].response_log[1:]
+             if kind_ == "data"]
+    assert [len(page) for page in pages] == [1, 2, 4]
     assert len(held[0].sessions) <= 3               # ended ones left
     if kind != "memory":        # the restart reads the disk, not the object
         if kind == "kv":
@@ -246,8 +500,11 @@ def test_joiner_killed_in_mid_walk_finishes_with_the_same_store(kind, tmp_path):
 # -- growth, pinned by counts ----------------------------------------------------------
 
 def test_checkpoint_entries_per_hop_are_bounded_by_a_constant():
-    """What a suspension writes (sessions + log entries) does not grow with
-    the number of hops behind it: the same small bound at 64, 128, 256."""
+    """What a suspension writes (sessions + log entries; a page of
+    transactions is one entry) does not grow with the number of hops behind
+    it: the same small bound at 64, 128, 256. And the suspensions
+    themselves grow as the round trips do, with the logarithm of the
+    depth."""
     led = Ledger()
     led.issue(led.wallet)
     depth, worst = 0, {}
@@ -260,7 +517,10 @@ def test_checkpoint_entries_per_hop_are_bounded_by_a_constant():
         led.pay(led.wallet, joiner)
         depth += 1
         written = fresh.histogram("checkpoint_entries")
-        assert written.count >= target          # one suspension a hop at least
+        trips = count(fresh, "RoundTrips")
+        assert count(fresh, "Hops") == depth
+        assert trips <= target.bit_length() + 2  # the walk doubles
+        assert written.count >= trips   # one suspension a round trip at least
         worst[target] = written.snapshot_fields()["max"]
         assert len(recorded(joiner)) == depth + 1
     assert worst[64] == worst[128] == worst[256] <= 6
