@@ -101,15 +101,37 @@ class Verify:
 
 @dataclass(frozen=True)
 class VerifyMany:
-    """Suspend until the verifier service resolves ALL of ``stxs`` — one
-    yield site submits the whole wave, so N transactions' signatures land
-    in the batcher concurrently instead of one service round-trip per
-    link (the wave-based ResolveTransactionsFlow discipline). Resumes with
-    None when every verification succeeds; the FIRST failure (submission
-    order) is thrown at the yield site with its original type."""
+    """Suspend ONCE until the verifier service has judged a whole set of
+    transactions. ``stxs`` is one LEVEL: members that do not spend one
+    another, submitted together, so their signatures reach the batcher
+    together. ``levels`` is the ORDERED form, a dependency walk's
+    topological levels, first level first (``ResolveTransactionsFlow``: what
+    ``_topological_waves`` returns): the levels are verified in order, each
+    level routed by its own size exactly as a ``VerifyMany`` of that level
+    alone, and a member's inputs resolve from the request's own
+    transactions before the node's store, so a level may spend what an
+    earlier level made without anything having been recorded in between.
+    The one-level form is the ordered form with one level (``stxs`` always
+    reads as the members in order, ``levels`` as the levels): nothing a
+    caller sets picks a path but the shape of what it hands over.
 
-    stxs: tuple
+    Resumes with None when every member passed. Otherwise the FIRST failure
+    in the order is thrown at the yield site with its original type, and
+    its ``verified`` attribute says how many members stand before it: each
+    of those passed, and nothing after it is vouched for (an ordered
+    request stops there). The count is logged beside the typed error, so a
+    flow that caught it replays identically after a restart."""
+
+    stxs: tuple = ()
     check_sufficient_signatures: bool = True
+    levels: tuple = ()
+
+    def __post_init__(self):
+        if self.levels:
+            object.__setattr__(self, "stxs", tuple(
+                stx for level in self.levels for stx in level))
+        else:
+            object.__setattr__(self, "levels", (tuple(self.stxs),))
 
 
 @dataclass(frozen=True)

@@ -361,17 +361,28 @@ class ResolveTransactionsFlow(FlowLogic):
     the requester already holds is dropped, and everything kept, asked for
     or not, takes the same order, verification and recording.
 
-    Verification runs in topological WAVES: every member of a wave has its
-    dependencies already recorded, so the whole wave is submitted to the
-    verifier service at once (VerifyMany). Hard cap of 5000 transactions
-    per walk; the budget never asks past it. What the waves buy depends on
-    the graph's WIDTH. On a wide graph a wave's signatures reach the
-    batcher together. On a CHAIN (one coin paid on and on, its change spent
-    by the next payment) every level is one transaction: D ``VerifyMany``
-    waves of one, each a lone host-routed verify under ``host_crossover``
-    and a park of its own, and not one row for the device however deep the
-    chain (measured: PERF.md, ``crosscash-deepchain.latejoin``). The
-    verify half of a walk is linear in D.
+    Verification runs in topological LEVELS, and the walk hands them to
+    the verifier WHOLE, in order, in ONE suspension (the ordered
+    ``VerifyMany``): every member of a level has its dependencies in the
+    levels before it, and resolves them from the walk's own transactions
+    (nothing is recorded between levels). What passed is then recorded in
+    one ``record_transactions`` call, in topological order; where member k
+    fails, the members before k are recorded, nothing at or after it is,
+    and k's exception leaves the flow with its type. Hard cap of 5000
+    transactions per walk; the budget never asks past it. What a level
+    costs depends on the graph's WIDTH, and each level is still routed by
+    its own size. On a wide graph a level's signatures reach the batcher
+    together, one bulk burst at or over ``host_crossover``. On a CHAIN (one
+    coin paid on and on, its change spent by the next payment) every level
+    is one transaction: host-routed, inline on the thread of the walk's one
+    verification task, and not one row for the device however deep the
+    chain (sending a walk's rows as ONE group is a change at
+    ``TpuTransactionVerifierService._verify_in_order``, once a first device
+    call is affordable there). The verify half of a walk is still linear in
+    D; the constant is what fell: a level costs its two signature checks,
+    coverage, resolution and the contract rules, where it also cost a
+    suspension, a checkpoint, two thread hand-offs and a recording
+    (measured: PERF.md, ``crosscash-deepchain.latejoin``).
 
     A HOP is one level of ancestry (it was also a round trip while a round
     fetched one level): a walk's ``hops`` is the breadth-first distance of
@@ -379,16 +390,18 @@ class ResolveTransactionsFlow(FlowLogic):
     a request leaves ``resolve.walk`` (tags ``fetched``, ``hops``,
     ``round_trips``, ``waves``, ``peer``) with the children
     ``resolve.fetch`` (the download loop), ``resolve.order``,
-    ``resolve.verify`` and ``resolve.record`` (each the SUM over the waves,
-    laid from its first wave's start, each tagged ``hops`` too). They join
+    ``resolve.verify`` (the one park) and ``resolve.record`` (the one
+    recording), each tagged ``hops`` too. They join
     the flow's trace without a parent span: the walk spans many of the
     flow's steps and waits, and a critical-path walk that charges every
     millisecond of a ``flow.run`` to one span has to go on charging those.
     Counted always: ``Resolve.Walks`` / ``Hops`` / ``RoundTrips`` (fetch
     requests sent) / ``Fetched`` (transactions kept) / ``Prefetched``
     (transactions that arrived unasked) / ``PrefetchUnused`` (of those, the
-    ones the requester already held) / ``Recorded`` / ``Refused`` and the
-    ``resolve_depth`` histogram (hops per walk)."""
+    ones the requester already held) / ``Recorded`` / ``Refused`` /
+    ``VerifyParks`` (the suspensions the walk spent in verification: one,
+    whatever its depth) and the ``resolve_depth`` histogram (hops per
+    walk)."""
 
     def __init__(self, peer, tx_ids=None, stx: SignedTransaction | None = None):
         self.peer = peer
@@ -468,18 +481,31 @@ class ResolveTransactionsFlow(FlowLogic):
             waves = _topological_waves(fetched)
             walk.waves = len(waves)
             walk.phase("order")
-            # verify in topological waves: all of wave N's dependencies were
-            # recorded by waves < N, and within a wave the transactions are
-            # independent, so the whole wave verifies concurrently
-            ordered = []
-            for wave in waves:
-                yield VerifyMany(tuple(wave),
+            # verify the levels in topological order in ONE suspension: the
+            # verifier takes them whole and resolves a member's inputs from
+            # the walk itself (an id was recomputed from the bytes before a
+            # transaction was kept, so what a StateRef names is settled;
+            # what a verdict is worth is not, past the first failure)
+            request = VerifyMany(levels=tuple(map(tuple, waves)),
                                  check_sufficient_signatures=False)
+            ordered = list(request.stxs)
+            refusal = None
+            if ordered:
+                walk.verify_parks += 1
+                try:
+                    yield request
+                except Exception as e:
+                    refusal = e
+                    del ordered[getattr(e, "verified", 0):]
                 walk.phase("verify")
-                hub.record_transactions(*wave)
-                ordered.extend(wave)
-                walk.recorded += len(wave)
+                # what passed, in topological order, in one call: a batch
+                # that consumes its own outputs is sound because storage
+                # and vault walk it in order
+                hub.record_transactions(*ordered)
+                walk.recorded = len(ordered)
                 walk.phase("record")
+            if refusal is not None:
+                raise refusal
         except Exception:
             walk.close(refused=True)
             raise
@@ -497,7 +523,7 @@ class _WalkRecord:
         self.got = fetched             # the walk's own dict, as it fills
         self.asking = ()               # the page of the request in flight
         self.round_trips = self.prefetched = self.prefetch_unused = 0
-        self.fetched = self.waves = self.recorded = 0
+        self.fetched = self.waves = self.recorded = self.verify_parks = 0
         self.tracer = get_tracer()
         self.phases: dict = {}         # name -> [first start, summed seconds]
         self.t0 = self.mark = _time.time() if self.tracer.enabled else None
@@ -543,6 +569,7 @@ class _WalkRecord:
             monitoring.meter("Resolve.Walks").mark()
             monitoring.meter("Resolve.Hops").mark(hops)
             monitoring.meter("Resolve.RoundTrips").mark(self.round_trips)
+            monitoring.meter("Resolve.VerifyParks").mark(self.verify_parks)
             monitoring.meter("Resolve.Fetched").mark(self.fetched)
             monitoring.meter("Resolve.Prefetched").mark(self.prefetched)
             monitoring.meter("Resolve.PrefetchUnused").mark(

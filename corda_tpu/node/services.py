@@ -282,6 +282,31 @@ class NetworkMapCache:
         return list(self._nodes.values())
 
 
+class ResolvedFromWalk:
+    """The services as the verification of a dependency walk sees them
+    (the ordered ``VerifyMany``): ``load_state`` answers from the walk's own
+    transactions first and from the hub's store after, everything else is
+    the hub's. Sound because a walk keeps a transaction only under the id
+    recomputed from its bytes (``FetchTransactionsFlow``), so the output a
+    ``StateRef`` names is that output whether or not its transaction has
+    been verified yet; what a descendant's verdict is worth is the
+    caller's rule (nothing counts past the first failure in the order)."""
+
+    def __init__(self, hub, stxs):
+        self._hub = hub
+        self._walk = {stx.id: stx for stx in stxs}
+
+    def load_state(self, ref):
+        stx = self._walk.get(ref.txhash)
+        if stx is None:
+            return self._hub.load_state(ref)
+        outputs = stx.tx.outputs
+        return outputs[ref.index] if ref.index < len(outputs) else None
+
+    def __getattr__(self, name):
+        return getattr(self._hub, name)
+
+
 class ServiceHub:
     """The hub handed to flows (`flow.service_hub`) and services."""
 

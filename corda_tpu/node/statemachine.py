@@ -33,6 +33,7 @@ from ..network.messaging import TOPIC_P2P, TopicSession
 from ..observability import get_tracer, jlog
 from ..utils.faults import DROP, fault_point
 from .checkpoints import Checkpoint, CheckpointStorage, SessionSnapshot
+from .services import ResolvedFromWalk
 
 _log = logging.getLogger(__name__)
 
@@ -595,76 +596,104 @@ class StateMachineManager:
             self._resume(fsm, error=err)
 
     def _do_verify_many(self, fsm: FlowStateMachine, request: VerifyMany):
-        """One yield site, N verifier submissions: the whole wave of a
-        dependency-resolution frontier lands in the batcher concurrently
-        (the group-commit analog on the verify side). Resumes with None
-        when every verification succeeds; the first failure in submission
-        order is thrown at the yield site. A node without an async
-        verifier service falls back to verifying the wave synchronously."""
-        stxs = list(request.stxs)
+        """One yield site, ONE suspension, however many levels the request
+        carries. A request of one level is a wave: its members land in the
+        batcher together (the group-commit analog on the verify side) and
+        resolve from the node's store. A request of several levels is a
+        dependency walk in order: its members resolve from the walk's own
+        transactions first (``ResolvedFromWalk``), and the verifier takes
+        the levels whole (``verify_levels``: one task for the walk, each
+        level routed by its own size), where the flow used to park once a
+        level. A service with ``verify_signed`` alone is handed the members
+        one by one with the same view, and a node without an async service
+        verifies them here, in order, stopping at the first failure.
+
+        Resumes with None when every member passed. Otherwise the first
+        failure in the order is thrown at the yield site, its ``verified``
+        the number of members before it (all of which passed); the count
+        is logged beside the typed error and replays with it."""
+        levels = request.levels
+        stxs = request.stxs
         if not stxs:
             return self._log(fsm, ("value", None))
+        services = self.hub if len(levels) == 1 \
+            else ResolvedFromWalk(self.hub, stxs)
+        check = request.check_sufficient_signatures
         svc = self.hub.verifier_service
         if svc is None or not hasattr(svc, "verify_signed"):
-            for stx in stxs:
+            for k, stx in enumerate(stxs):
                 try:
-                    stx.verify(self.hub, check_sufficient_signatures=
-                               request.check_sufficient_signatures)
+                    stx.verify(services, check_sufficient_signatures=check)
                 except Exception as e:
-                    return self._log(fsm, ("error", _error_payload(e)))
+                    return self._log(fsm, ("error", _error_payload(e, k)))
             return self._log(fsm, ("value", None))
         kwargs = {}
         if getattr(svc, "supports_trace_ctx", False) and fsm.trace_ctx is not None:
             kwargs["trace_ctx"] = fsm.trace_ctx
-        if hasattr(svc, "verify_wave"):
-            # the wave reaches the verifier as a wave: the service admits
-            # it by the size it can observe (one bulk burst at or over the
-            # batcher's crossover, member by member under it)
-            futs = svc.verify_wave(
-                stxs, self.hub, check_sufficient_signatures=
-                request.check_sufficient_signatures, **kwargs)
-        else:
-            futs = [svc.verify_signed(
-                        stx, self.hub, check_sufficient_signatures=
-                        request.check_sufficient_signatures, **kwargs)
-                    for stx in stxs]
-        # ONE external-wait slot for the whole wave: the flow resumes once,
-        # when the slowest member resolves
+        # ONE external-wait slot for the whole request: the flow resumes
+        # once, when the verifier is done with it
         self._awaiting_external += 1
-        state = {"remaining": len(futs), "errors": {},
-                 "n": len(futs), "t0": _time.time()}
+        t0 = _time.time()
+        if hasattr(svc, "verify_levels"):
+            # the request reaches the verifier in the shape it has: the
+            # service admits each level by the size it can observe (one
+            # bulk burst at or over the batcher's crossover, member by
+            # member under it)
+            fut = svc.verify_levels(levels, services,
+                                    check_sufficient_signatures=check,
+                                    **kwargs)
+            fut.add_done_callback(
+                lambda f: self._post_external(
+                    lambda: self._on_verify_many_done(fsm, request, t0,
+                                                      *f.result())))
+            return _PARK
+        futs = [svc.verify_signed(stx, services,
+                                  check_sufficient_signatures=check, **kwargs)
+                for stx in stxs]
+        state = {"remaining": len(futs), "errors": {}}
         for i, fut in enumerate(futs):
             fut.add_done_callback(
                 lambda f, i=i: self._post_external(
                     lambda: self._on_verify_many_one(fsm, f, i, state,
-                                                     request)))
+                                                     request, t0)))
         return _PARK
 
     def _on_verify_many_one(self, fsm: FlowStateMachine, fut: Future,
                             index: int, state: dict,
-                            request: VerifyMany) -> None:
-        """Node-thread continuation for ONE member of a VerifyMany wave;
-        the last arrival resumes the flow."""
+                            request: VerifyMany, t0: float) -> None:
+        """Node-thread continuation for ONE member of a VerifyMany handed
+        over member by member; the last arrival settles the request by
+        the prefix rule: the first failure in the order, and as many
+        passed as stand before it."""
         err = fut.exception()
         if err is not None:
             state["errors"][index] = err
         state["remaining"] -= 1
         if state["remaining"] > 0:
             return
+        first = min(state["errors"], default=len(request.stxs))
+        self._on_verify_many_done(fsm, request, t0, first,
+                                  state["errors"].get(first))
+
+    def _on_verify_many_done(self, fsm: FlowStateMachine,
+                             request: VerifyMany, t0: float, verified: int,
+                             err: Exception | None) -> None:
+        """Node-thread end of a VerifyMany park: ``verified`` members passed,
+        in order, before ``err`` (None: all of them)."""
         self._awaiting_external -= 1
         if fsm.done or fsm.run_id not in self.flows:
             return
         if fsm.parked_on is not request:
             return
-        self._record_park(fsm, "wait.verify_gather", "verify.gather",
-                          state["t0"], wave=state["n"])
-        if state["errors"]:
-            first = state["errors"][min(state["errors"])]
-            fsm.response_log.append(("error", _error_payload(first)))
-            self._resume(fsm, error=first)
-        else:
+        self._record_park(fsm, "wait.verify_gather", "verify.gather", t0,
+                          wave=len(request.stxs), levels=len(request.levels))
+        if err is None:
             fsm.response_log.append(("value", None))
             self._resume(fsm, value=None)
+        else:
+            err.verified = verified
+            fsm.response_log.append(("error", _error_payload(err, verified)))
+            self._resume(fsm, error=err)
 
     def _do_await_future(self, fsm: FlowStateMachine, request: AwaitFuture):
         """Generic park-on-a-future (the notary-wait suspension point for
@@ -1214,16 +1243,21 @@ class FlowScheduler:
 _PARK = object()
 
 
-def _error_payload(exc: Exception):
+def _error_payload(exc: Exception, verified: int | None = None):
     """Checkpointable encoding of a flow-visible error that preserves the
     TYPE across replay: flows legitimately catch specific exceptions
     (FlowTimeoutException, SignatureException from Verify) and continue —
     replaying them as bare FlowException would make a recovered flow
     diverge after a restart. Plain FlowExceptions stay strings (legacy
-    log-entry format, still accepted by _rebuild_error)."""
+    log-entry format, still accepted by _rebuild_error). ``verified`` is a
+    VerifyMany failure's count of members that passed before it: it rides
+    as a third field and comes back as the rebuilt error's ``verified``."""
+    path = f"{type(exc).__module__}:{type(exc).__qualname__}"
+    if verified is not None:
+        return [path, str(exc), verified]
     if type(exc) is FlowException:
         return str(exc)
-    return [f"{type(exc).__module__}:{type(exc).__qualname__}", str(exc)]
+    return [path, str(exc)]
 
 
 #: Modules whose Exception types may be reconstructed from a checkpoint
@@ -1270,14 +1304,15 @@ def _error_registry() -> dict[str, type]:
 def _rebuild_error(payload) -> Exception:
     if isinstance(payload, str):
         return FlowException(payload)
-    type_path, msg = payload
+    type_path, msg, *verified = payload
     cls = _error_registry().get(type_path)
-    if cls is None:
-        return FlowException(msg)
     try:
-        return cls(msg)
+        err = FlowException(msg) if cls is None else cls(msg)
     except Exception:
-        return FlowException(msg)
+        err = FlowException(msg)
+    if verified:
+        err.verified = verified[0]
+    return err
 
 
 def _import_flow_class(name: str) -> type:

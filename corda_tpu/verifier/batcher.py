@@ -629,8 +629,9 @@ class SignatureBatcher:
         queue by the same rule, so a row goes to the device exactly when it
         did; under the crossover the group crosses one thread, not three.
 
-        ``wave_rows`` is the signature count of the ``VerifyMany`` wave the
-        group belongs to: a member is judged by the wave's size, so a wave
+        ``wave_rows`` is the signature count of the LEVEL the group belongs
+        to (a ``VerifyMany`` of one level, or one level of a walk handed
+        over in order): a member is judged by its level's size, so a level
         at or over the crossover is the planner's as a whole, however the
         threads interleave while it is being submitted. A closed batcher
         raises as ``_enqueue`` does. Every held group MUST be collected

@@ -47,9 +47,9 @@ def first_unverified(signers, verdicts):
     return next(key for key, ok in zip(signers, verdicts) if not ok)
 
 
-def _finish_with_the_last(span, futures) -> None:
-    """Finish ``span`` when the last of ``futures`` resolves (at once where
-    there is none)."""
+def _after_the_last(futures, then) -> None:
+    """Call ``then()`` when the last of ``futures`` resolves (at once where
+    there is none, or all are done), on the thread that resolves it."""
     left = [len(futures)]
     lock = threading.Lock()
 
@@ -58,12 +58,36 @@ def _finish_with_the_last(span, futures) -> None:
             left[0] -= 1
             last = left[0] == 0
         if last:
-            span.finish()
+            then()
 
     if not futures:
-        span.finish()
+        then()
     for fut in futures:
         fut.add_done_callback(one_done)
+
+
+def _on_this_thread(fn, *args) -> Future:
+    """``ThreadPoolExecutor.submit``'s contract without the pool: ``fn`` runs
+    here and now, and the future handed back is already resolved. What a
+    level's admission takes in the pool's place when the thread it is
+    admitted on is the one that waits for it (``verify_levels``)."""
+    done: Future = Future()
+    try:
+        done.set_result(fn(*args))
+    except Exception as exc:
+        done.set_exception(exc)
+    return done
+
+
+def _passed_in_order(members) -> tuple:
+    """The prefix rule over resolved futures in order: ``(k, error)`` where
+    member ``k`` is the first that failed and ``error`` what it failed with,
+    or ``(len(members), None)``."""
+    for k, fut in enumerate(members):
+        err = fut.exception()
+        if err is not None:
+            return k, err
+    return len(members), None
 
 
 class TransactionVerifierService:
@@ -95,7 +119,11 @@ class TransactionVerifierService:
                 check_sufficient_signatures=check_sufficient_signatures),
             trace_ctx=trace_ctx)
 
-    def _submit_instrumented(self, work_fn, trace_ctx=None) -> Future:
+    def _submit_instrumented(self, work_fn, trace_ctx=None,
+                             run=None) -> Future:
+        """``work_fn`` under the service's metrics and its ``verifier.run``
+        span, handed to ``run`` (the pool's ``submit`` unless told
+        otherwise)."""
         self.metrics.counter("Verification.InFlight").inc()
         hist = self.metrics.histogram("tx_verify_seconds")
         tracer = get_tracer()
@@ -116,7 +144,7 @@ class TransactionVerifierService:
                     hist.update(time.perf_counter() - t0,
                                 trace_id=getattr(trace_ctx, "trace_id", None))
 
-        return self._pool.submit(work)
+        return (run or self._pool.submit)(work)
 
     def shutdown(self) -> None:
         self._pool.shutdown(wait=False)
@@ -181,13 +209,15 @@ class TpuTransactionVerifierService(TransactionVerifierService):
         together share the queue, so they still coalesce into one device
         batch."""
         return self._verify_held(stx, services, check_sufficient_signatures,
-                                 trace_ctx, None)
+                                 trace_ctx, None, self._pool.submit)
 
     def _verify_held(self, stx, services, check_sufficient_signatures,
-                     trace_ctx, wave_rows: int | None) -> Future:
+                     trace_ctx, wave_rows: int | None, run) -> Future:
         """``verify_signed``'s body. ``wave_rows`` is the signature count of
-        the wave ``stx`` belongs to (``verify_wave`` under the crossover):
-        the batcher judges a member by it."""
+        the LEVEL ``stx`` belongs to (``_admit_level`` under the crossover):
+        the batcher judges a member by it. ``run`` takes the member's work:
+        the pool's ``submit``, or ``_on_this_thread`` where the thread that
+        holds the rows is the one that collects them."""
         tracer = get_tracer()
         root = tracer.span("tx.verify", parent=trace_ctx,
                            tx_id=stx.id.bytes.hex()[:16],
@@ -228,7 +258,7 @@ class TpuTransactionVerifierService(TransactionVerifierService):
                 finally:
                     root.finish()
 
-            return self._submit_instrumented(work, trace_ctx=ctx)
+            return self._submit_instrumented(work, trace_ctx=ctx, run=run)
         except Exception as exc:
             # submission failed (e.g. closed batcher / shut-down pool): the
             # root span must still close and the caller must get a FAILED
@@ -238,33 +268,113 @@ class TpuTransactionVerifierService(TransactionVerifierService):
             failed.set_exception(exc)
             return failed
 
-    # -- a wave of transactions ------------------------------------------------
+    # -- a wave of transactions, and a walk's levels in order -------------------
     def verify_wave(self, stxs, services,
                     check_sufficient_signatures: bool = True,
                     trace_ctx=None) -> list[Future]:
-        """Async full verify of a WAVE of SignedTransactions (the SMM's
-        ``VerifyMany``: a dependency-resolution frontier, a back-fill's
-        ledger): one future a member, in order, each resolved with that
-        member's own outcome (None, ``SignatureException``,
-        ``SignaturesMissingException``, a resolution or contract failure);
-        no member fails because another did.
+        """Async full verify of a WAVE of SignedTransactions (one level: a
+        dependency-resolution frontier, a back-fill's ledger): one future a
+        member, in order, each resolved with that member's own outcome
+        (None, ``SignatureException``, ``SignaturesMissingException``, a
+        resolution or contract failure); no member fails because another
+        did. The wave is admitted on the caller's thread and completed on
+        the pool (``_admit_level``). A closed batcher or a shut-down pool
+        yields FAILED FUTURES, never an exception."""
+        return self._admit_level(list(stxs), services,
+                                 check_sufficient_signatures, trace_ctx,
+                                 self._pool.submit)
 
-        How the wave is admitted follows from its size, as its route does
+    def verify_levels(self, levels, services,
+                      check_sufficient_signatures: bool = True,
+                      trace_ctx=None) -> Future:
+        """The entry point the scheduler's ``VerifyMany`` calls: a dependency
+        walk's topological LEVELS, first level first, verified in order. ONE
+        future for the request, resolved (never failed) with
+        ``(verified, error)``: the number of members, counted through the
+        levels in order, that passed before the first that did not, and
+        what that one failed with (``(all of them, None)`` where none did).
+
+        Whatever the number of levels, a LEVEL is what the batcher judges,
+        by the rule and the constant it has (``_admit_level``). A request of
+        one level is a wave: admitted here, on the caller's thread, its
+        members completed on the pool side by side, as ``verify_wave``
+        does. A request of more runs as ONE task on the pool
+        (``_verify_in_order``), where each level is admitted and completed
+        on the task's own thread before the next begins, and the task stops
+        at the first level with a failure: a 400-deep chain is one hand-off
+        to the pool and one back, where it was 400 of each. ``services``
+        has to resolve a member's inputs from the earlier levels (the
+        scheduler hands ``ResolvedFromWalk``): nothing is recorded between
+        levels.
+
+        Tracing: a request of several levels leaves ``verifier.levels``
+        (tags ``levels``, ``n_tx``, ``verified``) over its task, and under
+        it a ``verifier.wave`` a level and a ``tx.verify`` a member, as a
+        wave leaves them."""
+        levels = list(levels)
+        outcome: Future = Future()
+        if len(levels) == 1:
+            members = self._admit_level(
+                levels[0], services, check_sufficient_signatures, trace_ctx,
+                self._pool.submit)
+            _after_the_last(members, lambda: outcome.set_result(
+                _passed_in_order(members)))
+            return outcome
+        try:
+            self._pool.submit(self._verify_in_order, levels, services,
+                              check_sufficient_signatures, trace_ctx, outcome)
+        except Exception as exc:        # a shut-down pool
+            outcome.set_result((0, exc))
+        return outcome
+
+    def _verify_in_order(self, levels, services, check_sufficient_signatures,
+                         trace_ctx, outcome: Future) -> None:
+        """``verify_levels``' task. It waits for nothing that needs another
+        thread of this pool (more walks than workers would stand still): a
+        level under the crossover is verified here, row by row; a level at
+        or over it waits for the batcher's verdicts alone."""
+        verified, error = 0, None
+        span = get_tracer().span(
+            "verifier.levels", parent=trace_ctx, levels=len(levels),
+            n_tx=sum(len(level) for level in levels))
+        try:
+            ctx = span.context() or trace_ctx
+            for level in levels:
+                passed, error = _passed_in_order(self._admit_level(
+                    level, services, check_sufficient_signatures, ctx,
+                    _on_this_thread))
+                verified += passed
+                if error is not None:
+                    break
+        except BaseException as exc:    # never a request left unresolved
+            error = exc
+            raise
+        finally:
+            span.set_tag("verified", verified)
+            span.finish()
+            outcome.set_result((verified, error))
+
+    def _admit_level(self, stxs, services, check_sufficient_signatures,
+                     trace_ctx, run) -> list[Future]:
+        """One level, admitted on the calling thread; ``run`` takes what
+        completes it (the pool's ``submit``: members complete side by side
+        on its workers; ``_on_this_thread``: here, one after another, and
+        every future handed back is resolved). One future a member, in
+        order.
+
+        How the level is admitted follows from its size, as its route does
         (``SignatureBatcher.wave_is_the_planners``, the batcher's one
-        routing rule). At or over ``host_crossover`` the wave is a BULK
-        burst: ONE ``submit_groups`` call on the caller's thread and ONE
-        completion task on the pool (``_complete_wave``). Under it every
-        member takes ``verify_signed``'s path, judged by the wave's size:
-        held on the queue, collected and verified on the worker that
-        serves it, one hand-off a member. A closed batcher or a shut-down
-        pool yields FAILED FUTURES, never an exception.
+        routing rule). At or over ``host_crossover`` the level is a BULK
+        burst: ONE ``submit_groups`` call and ONE completion
+        (``_complete_wave``). Under it every member takes
+        ``verify_signed``'s path, judged by the level's size: held on the
+        queue, collected and verified by the thread that completes it.
 
         Tracing: span ``verifier.wave`` (entry -> last member resolved;
         tags ``n_tx``, ``n_sigs``, ``admitted`` = ``bulk`` | ``held``); a
-        bulk wave's children are ``verifier.wave.submit`` / ``.verdicts`` /
+        bulk level's children are ``verifier.wave.submit`` / ``.verdicts`` /
         ``.coverage`` / ``.rules``. Meters ``Verifier.WaveTx.bulk`` /
-        ``.held`` count members by how their wave was admitted."""
-        stxs = list(stxs)
+        ``.held`` count members by how their level was admitted."""
         n_sigs = sum(len(stx.sigs) for stx in stxs)
         tracer = get_tracer()
         bulk = self.batcher.wave_is_the_planners(
@@ -277,9 +387,10 @@ class TpuTransactionVerifierService(TransactionVerifierService):
         if not bulk:
             futures = [self._verify_held(stx, services,
                                          check_sufficient_signatures,
-                                         trace_ctx, n_sigs) for stx in stxs]
+                                         trace_ctx, n_sigs, run)
+                       for stx in stxs]
             if tracer.enabled:
-                _finish_with_the_last(wave, futures)
+                _after_the_last(futures, wave.finish)
             return futures
         ctx = wave.context()
         members = [Future() for _ in stxs]
@@ -291,9 +402,8 @@ class TpuTransactionVerifierService(TransactionVerifierService):
                      for stx in stxs],
                     None if ctx is None else [ctx] * len(stxs))
             self.metrics.counter("Verification.InFlight").inc(len(stxs))
-            self._pool.submit(self._complete_wave, stxs, services,
-                              check_sufficient_signatures, groups, members,
-                              wave, t0)
+            run(self._complete_wave, stxs, services,
+                check_sufficient_signatures, groups, members, wave, t0)
         except Exception as exc:
             wave.set_tag("error", f"{type(exc).__name__}: {exc}")
             wave.finish()
@@ -393,15 +503,21 @@ def make_verifier_service(verifier_type: str = "InMemory", **kwargs
     """The VerifierType config seam (NodeConfiguration.kt:91-94):
     "InMemory" | "Tpu" | "OutOfProcess".
 
-    NOTE on the Tpu backend: ``verify_signed(stx, ...)`` and
-    ``verify_wave(stxs, ...)`` are the calls that pay off on device — the
-    reference-shaped ``verify(ltx)`` SPI verifies contract and platform
-    rules only (an ltx's signatures are already checked by the time it
-    exists), so callers holding SignedTransactions should use them. The
-    node's flow path does: the SMM's Verify suspension point routes through
-    ``verify_signed`` and its VerifyMany through ``verify_wave``, which
-    admits a wave at or over the batcher's crossover as ONE bulk burst
-    (locked by tests/test_verify_suspension.py's device-batch assertions).
+    NOTE on the Tpu backend: ``verify_signed(stx, ...)``,
+    ``verify_wave(stxs, ...)`` and ``verify_levels(levels, ...)`` are the
+    calls that pay off on device — the reference-shaped ``verify(ltx)`` SPI
+    verifies contract and platform rules only (an ltx's signatures are
+    already checked by the time it exists), so callers holding
+    SignedTransactions should use them. The node's flow path does: the SMM's
+    Verify suspension point routes through ``verify_signed`` and its
+    VerifyMany through ``verify_levels``, which takes a walk's levels whole
+    and in order and admits each LEVEL by its own size: at or over the
+    batcher's crossover ONE bulk burst, under it member by member (locked
+    by tests/test_verify_suspension.py's device-batch assertions). A
+    service without ``verify_levels`` (InMemory, OutOfProcess, a custom
+    one) is handed a VerifyMany's members through ``verify_signed`` one by
+    one, with the same view over the walk for resolution, and the SMM
+    applies the prefix rule to the outcomes.
 
     "OutOfProcess" needs ``network_service=`` (the node's messaging — the
     queue the worker fleet attaches to); ``expected_workers=`` sizes the

@@ -57,7 +57,10 @@ WALK_COUNTERS = {
         "levels/trip", "higher", ["Resolve.Hops"], ["Resolve.RoundTrips"]),
     "resolve_prefetch_unused_share": (
         "%", "lower", ["Resolve.PrefetchUnused"],
-        ["Resolve.Fetched", "Resolve.PrefetchUnused"])}
+        ["Resolve.Fetched", "Resolve.PrefetchUnused"]),
+    # PR 47: the levels a walk hands the verifier in one suspension
+    "resolve_levels_per_verify_park": (
+        "levels/park", "higher", ["Resolve.Hops"], ["Resolve.VerifyParks"])}
 WALK_CELLS = ["crosscash-deepchain.latejoin", "crosscash-raft.steady"]
 
 for _path in sorted((BENCH / "tests").glob("test_*.py")):
@@ -197,6 +200,10 @@ def test_latejoin_traced_rehearsal_reads_the_walk_counters(capsys):
     # 25-30 levels a joiner's walk in 5 round trips, the notary's walks of
     # one level in one: well over 2 however many of each the window held
     assert m["resolve_levels_per_round_trip"] > 2.0
+    # a joiner's 25-30 levels in ONE verification park; every other walk of
+    # the window one level in one
+    assert m["resolve_levels_per_verify_park"] \
+        > m["resolve_levels_per_round_trip"]
     assert m["resolve_prefetch_unused_share"] == 0.0
     assert m["resolve_fetch_ms_p50.latejoin"] > 0.0     # hops >= 16 still
 
@@ -210,6 +217,9 @@ def test_steady_traced_rehearsal_reads_the_walk_counters(capsys):
     assert result["correct"]
     m = {k: v["value"] for k, v in result["metrics"].items()}
     assert m["resolve_levels_per_round_trip"] >= 1.0
+    # a walk spends one park in verification and at least one round trip
+    assert m["resolve_levels_per_verify_park"] \
+        >= m["resolve_levels_per_round_trip"]
     assert 0.0 <= m["resolve_prefetch_unused_share"] <= 50.0
 
 

@@ -172,6 +172,150 @@ def test_many_input_merge_is_resolved_whole_and_in_order():
     assert ref.judge_join(led.history(), final.id.bytes, got) == CLEAN
 
 
+# -- the verification of a walk: one park, levels whole and in order -----------------
+
+def serve(led, node, backend):
+    """Put ``backend`` behind ``node``'s verifier seam; what it hands back
+    is to be shut down (None: the no-service fallback)."""
+    from corda_tpu.verifier import (InMemoryTransactionVerifierService,
+                                    SignatureBatcher,
+                                    TpuTransactionVerifierService)
+    from corda_tpu.verifier.out_of_process import (
+        OutOfProcessTransactionVerifierService, VerifierWorker)
+    if backend == "fallback":
+        return None
+    if backend == "in_memory":
+        svc = InMemoryTransactionVerifierService()
+    elif backend == "tpu":
+        svc = TpuTransactionVerifierService()
+    elif backend == "tpu_bulk":     # every level of two rows is a burst
+        batcher = SignatureBatcher(host_crossover=2, max_batch=2)
+
+        def device(bucket, items, reason="full", bctx=None):
+            """Host verdicts behind the device route: no kernel."""
+            batcher._mark_device(items)
+            batcher._resolve(bucket, items, batcher._run_host(items), bctx)
+
+        batcher._dispatch_device = device
+        svc = TpuTransactionVerifierService(batcher=batcher)
+    else:
+        svc = OutOfProcessTransactionVerifierService(node.messaging)
+        VerifierWorker(led.net.bus.create_node(
+            f"worker-of-{node.party.name.organisation}"),
+            str(node.info.address))
+        led.net.run_network()           # the worker's handshake
+    node.services.verifier_service = svc
+    return svc
+
+
+def shut(*services):
+    for svc in services:
+        if svc is not None:
+            svc.shutdown()
+
+
+@pytest.mark.parametrize("depths", [(64, 128)])
+def test_chain_is_verified_in_one_park_whatever_its_depth(depths):
+    """A walk spends ONE suspension in verification (it was one a level),
+    so what a walk checkpoints grows with its round trips alone; each level
+    still reaches the batcher as a level of two rows, inline on the one
+    task's thread; the store is whole and in order."""
+    led = Ledger()
+    led.issue(led.wallet)
+    depth, suspensions = 0, {}
+    for target, joiner in zip(depths, led.joiners):
+        while depth < target:
+            led.pay(led.wallet, led.other)
+            depth += 1
+        svc = serve(led, joiner, "tpu")
+        mine = alone(joiner)
+        try:
+            final = led.pay(led.wallet, joiner)
+        finally:
+            shut(svc)
+        depth += 1
+        got = recorded(joiner)
+        assert len(got) == depth + 1
+        assert ref.judge_join(led.history(), final.id.bytes, got) == CLEAN
+        assert count(mine, "Walks") == 1 == count(mine, "VerifyParks")
+        assert count(mine, "Hops") == count(mine, "Recorded") == depth
+        rows = svc.batcher.metrics.snapshot()
+        # the walk's levels (the issue has one signature, a move two) and
+        # the payment itself, a lone ``Verify``
+        assert rows["SigBatcher.HostInline"]["count"] == 2 * depth + 1
+        assert "SigBatcher.DeviceChecked" not in rows
+        waves = svc.metrics.snapshot()
+        assert waves["Verifier.WaveTx.held"]["count"] == depth
+        assert "Verifier.WaveTx.bulk" not in waves
+        written = mine.histogram("checkpoint_entries")
+        suspensions[target] = written.count - count(mine, "RoundTrips")
+        assert written.snapshot_fields()["max"] <= 6
+    # suspensions that are not fetch round trips: a constant, not the depth
+    assert suspensions[depths[0]] == suspensions[depths[1]] <= 4
+
+
+SHAPES = {
+    # name -> (build the history, dollars of the joiner's payment,
+    #          transactions the joiner ends with)
+    "chain": (lambda led: led.chain(20), 10, 22),
+    "diamond": (lambda led: (led.issue(led.wallet, 100),
+                             led.pay(led.wallet, led.other, 60),
+                             led.pay(led.other, led.wallet, 50)), 90, 4),
+    "merge": (lambda led: [led.issue(led.wallet, 10) for _ in range(8)],
+              80, 9)}
+
+
+@pytest.mark.parametrize("backend", ["in_memory", "tpu", "tpu_bulk",
+                                     "out_of_process"])
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_walk_resolves_whole_in_one_park_behind_every_backend(shape, backend):
+    """The chain, the diamond and the many-input merge through the ordered
+    form, behind each service of the seam (the no-service fallback is what
+    every other test of this file runs): the same store, the same order."""
+    build, dollars_, n_held = SHAPES[shape]
+    led = Ledger()
+    build(led)
+    joiner = led.joiners[0]
+    svc = serve(led, joiner, backend)
+    mine = alone(joiner)
+    try:
+        final = led.pay(led.wallet, joiner, dollars_)
+    finally:
+        shut(svc)
+    got = recorded(joiner)
+    assert len(got) == n_held
+    assert ref.judge_join(led.history(), final.id.bytes, got) == CLEAN
+    assert count(mine, "VerifyParks") == count(mine, "Walks") == 1
+    assert count(mine, "Recorded") == n_held - 1
+    assert sum(s.state.data.amount.quantity for s in
+               joiner.services.vault.unconsumed_states()) == dollars_ * 100
+
+
+def test_a_batch_that_consumes_its_own_outputs_is_recorded_in_order():
+    """``record_transactions`` of a whole chain in ONE call: storage keeps
+    the order it was handed and the vault walks the batch in that order, so
+    the change each move makes is there to be consumed by the next. The
+    same vault as one call a transaction."""
+    from corda_tpu.node.vault import NodeVaultService
+    led = Ledger()
+    led.chain(8)
+    chain = list(led.wallet.services.storage.transactions)
+    whole, one_by_one = (NodeVaultService(led.wallet.services)
+                         for _ in range(2))
+    updates = whole.notify_all(chain)
+    for stx in chain:
+        one_by_one.notify_all([stx])
+    assert len(updates) == 9
+    assert [len(u.consumed) for u in updates] == [0] + [1] * 8
+    assert set(whole._unconsumed) == set(one_by_one._unconsumed) \
+        == {r for r in led.wallet.services.vault._unconsumed}
+    assert list(whole._consumed) == list(one_by_one._consumed)
+    assert len(whole._unconsumed) == 1 and len(whole._consumed) == 8
+    joiner = led.joiners[0]
+    joiner.services.record_transactions(*chain)
+    assert recorded(joiner) == [stx.id.bytes for stx in chain]
+
+
 # -- pages of ancestors: what goes over the wire ------------------------------------------
 
 @pytest.fixture
@@ -429,6 +573,35 @@ def test_hostile_chain_is_refused_and_nothing_at_or_below_it_recorded(kind):
     assert joiner.services.vault.unconsumed_states() == []
 
 
+@pytest.mark.parametrize("backend", ["in_memory", "tpu", "out_of_process"])
+@pytest.mark.parametrize("kind", ["flipped_signature", "wrong_signer_key"])
+def test_hostile_chain_is_refused_alike_behind_every_backend(kind, backend):
+    """The test above behind each service of the seam: the six the bad
+    transaction descends from are recorded, in order, and nothing at or
+    below it, though every level went to the verifier in one request."""
+    led = Ledger()
+    led.chain(12)
+    chain = [stx for stx in led.wallet.services.storage.transactions]
+    bad_tx = chain[6].id
+    tamper(led.wallet, bad_tx, kind, led.other.party.owning_key)
+    joiner = led.joiners[0]
+    svc = serve(led, joiner, backend)
+    mine = alone(joiner)
+    try:
+        with pytest.raises(FlowException,
+                           match="FINAL but could not be delivered"):
+            led.pay(led.wallet, joiner)
+    finally:
+        shut(svc)
+    got = recorded(joiner)
+    assert ref.judge_refusal(led.history(), bad_tx.bytes, got) == 0
+    assert len(got) == 6
+    assert ref.judge_join(led.history(), chain[5].id.bytes, got) == CLEAN
+    assert count(mine, "Refused") == 1 == count(mine, "VerifyParks")
+    assert count(mine, "Recorded") == 6
+    assert joiner.services.vault.unconsumed_states() == []
+
+
 def test_walk_over_the_cap_is_refused_and_nothing_recorded(monkeypatch):
     monkeypatch.setattr(library, "MAX_RESOLVE_TRANSACTIONS", 20)
     led = Ledger()
@@ -495,6 +668,102 @@ def test_joiner_killed_in_mid_walk_finishes_with_the_same_store(kind, tmp_path):
     assert ref.judge_join(led.history(), final.id.bytes, got) == CLEAN
     assert restored.smm.checkpoints.get_all_checkpoints() == []
     assert len(restored.services.vault.unconsumed_states()) == 1
+
+
+class NeverAnswers:
+    """A verifier that takes every member and answers none."""
+
+    def __init__(self):
+        self.asked = []
+
+    def verify_signed(self, stx, services, check_sufficient_signatures=True):
+        from concurrent.futures import Future
+        self.asked.append(stx.id)
+        return Future()
+
+
+@pytest.mark.parametrize("kind", ["memory", "file", "kv"])
+def test_joiner_killed_in_its_one_verification_park_finishes_with_the_same_store(
+        kind, tmp_path):
+    """The whole chain is down and handed to the verifier, nothing is
+    recorded, and the joiner dies: the restored flow replays its fetches
+    from the log, hands the same levels over again and ends with the store
+    an undisturbed joiner has."""
+    from corda_tpu.flows.api import VerifyMany
+    depth = 24
+    led = Ledger(joiner_storage=storage_of(kind, tmp_path))
+    led.chain(depth)
+    joiner = led.joiners[0]
+    silent = joiner.services.verifier_service = NeverAnswers()
+    fsm = led.wallet.start_flow(CashPaymentFlow(dollars(10), joiner.party))
+    for _ in range(100_000):
+        if any(isinstance(f.parked_on, VerifyMany)
+               for f in joiner.smm.flows.values()):
+            break
+        led.net.bus.run_network(rounds=1)
+    else:
+        raise AssertionError("the joiner never got that far")
+    assert len(silent.asked) == depth + 1 and recorded(joiner) == []
+    assert joiner.smm.awaiting_external == 1
+    (held,) = joiner.smm.checkpoints.get_all_checkpoints()
+    assert sum(len(value) for what, value in held.response_log[1:]
+               if what == "data") == depth + 1
+    if kind != "memory":        # the restart reads the disk, not the object
+        if kind == "kv":
+            joiner.smm.checkpoints.close()
+        joiner.smm.checkpoints = storage_of(kind, tmp_path)
+    restored = joiner.restart()
+    restored.services.monitoring = led.registry
+    # the no-service fallback from here on: the restored flow replays its
+    # log and verifies the levels in the step that takes it up
+    restored.start()
+    led.net.run_network()
+    final = fsm.result_future.result(timeout=1)
+    got = recorded(restored)
+    assert len(got) == depth + 2
+    assert ref.judge_join(led.history(), final.id.bytes, got) == CLEAN
+    assert restored.smm.checkpoints.get_all_checkpoints() == []
+    assert len(restored.services.vault.unconsumed_states()) == 1
+
+
+@pytest.mark.parametrize("backend", ["tpu", "tpu_bulk"])
+def test_more_walks_at_once_than_the_pool_has_workers_all_finish(backend):
+    """Six walks of one service with four workers, every one of them a task
+    that holds its worker from its first level to its last: a task waits
+    for nothing that needs another worker, so the six finish."""
+    import time
+    led = Ledger()
+    led.chain(16)
+    tip = led.wallet.services.storage.transactions[-1]
+    walkers = led.joiners + [led.net.create_node(
+        f"O=Walker {i}, L=Oslo, C=NO") for i in range(3)]
+    for node in walkers[3:]:
+        node.start()
+    svc = serve(led, walkers[0], backend)
+    assert svc._pool._max_workers == 4
+    for node in walkers:
+        node.services.verifier_service = svc
+    try:
+        with svc.batcher._lock:     # a task's first level waits for this
+            fsms = [node.start_flow(library.ResolveTransactionsFlow(
+                led.wallet.party, tx_ids=[tip.id])) for node in walkers]
+            deadline = time.monotonic() + 60
+            while sum(n.smm.awaiting_external for n in walkers) < 6:
+                led.net.bus.run_network(rounds=1)
+                assert time.monotonic() < deadline
+            assert svc._pool._work_queue.qsize() == 2   # six tasks, four taken
+        deadline = time.monotonic() + 60    # the test's own limit
+        while not all(f.result_future.done() for f in fsms):
+            for node in walkers:
+                node.smm.drain_external()
+            led.net.bus.run_network(rounds=1)
+            assert time.monotonic() < deadline, "the walks stand still"
+    finally:
+        shut(svc)
+    for node, fsm in zip(walkers, fsms):
+        assert len(fsm.result_future.result(timeout=1)) == 17
+        assert ref.judge_join(led.history(), tip.id.bytes,
+                              recorded(node)) == CLEAN
 
 
 # -- growth, pinned by counts ----------------------------------------------------------

@@ -545,3 +545,176 @@ def test_flows_route_verification_through_the_service_seam():
     finally:
         for b in batchers.values():
             b.close()
+
+
+# ---------------------------------------------------------------------------
+# verify_levels: a walk's levels, whole and in order, in ONE task
+# ---------------------------------------------------------------------------
+
+def _issue(services, magic, kp=ALICE_KP):
+    wtx = WireTransaction(
+        outputs=(TransactionState(DummyState(magic, (kp.public,)), NOTARY),),
+        commands=(Command(DummyContract.Create(), (kp.public,)),),
+        notary=NOTARY, must_sign=(kp.public,))
+    return services.sign_transaction(wtx, kp.public)
+
+
+def _spend(services, parents, magic, kp=ALICE_KP):
+    """A move of every parent's output into one new state, signed by the
+    owner and the notary: two signature rows."""
+    wtx = WireTransaction(
+        inputs=tuple(StateRef(p.id, 0) for p in parents),
+        outputs=(TransactionState(DummyState(magic, (kp.public,)), NOTARY),),
+        commands=(Command(DummyContract.Move(), (kp.public,)),),
+        notary=NOTARY, must_sign=(kp.public, NOTARY_KP.public))
+    return services.sign_transaction(wtx, kp.public, NOTARY_KP.public)
+
+
+def _graph(services, width):
+    """Three levels, none of them recorded anywhere: ``width`` issues, two
+    moves that spend them between them, one move that spends both."""
+    issues = [_issue(services, 200 + i) for i in range(width)]
+    half = width // 2
+    moves = [_spend(services, issues[:half], 300),
+             _spend(services, issues[half:], 301)]
+    return [issues, moves, [_spend(services, moves, 400)]]
+
+
+def _spy_levels(svc):
+    """Where a request's bursts, holds and pool tasks happen."""
+    b = svc.batcher
+    seen = {"bursts": [], "tasks": [], "holds": _spy_threads(b, "hold_group"),
+            "collects": _spy_threads(b, "collect_group")}
+    submit_groups, pool_submit = b.submit_groups, svc._pool.submit
+
+    def spy_groups(groups, ctxs=None, latency_class="bulk"):
+        seen["bursts"].append((threading.current_thread().name, len(groups),
+                               latency_class))
+        return submit_groups(groups, ctxs, latency_class)
+
+    def spy_pool(fn, *a, **k):
+        seen["tasks"].append(fn.__name__)
+        return pool_submit(fn, *a, **k)
+
+    b.submit_groups, svc._pool.submit = spy_groups, spy_pool
+    return seen
+
+
+def test_levels_run_as_one_task_and_each_level_keeps_its_own_route(services):
+    """Three levels in one request: ONE pool task; the level of five rows at
+    the crossover is one bulk burst from that task's thread, the levels of
+    four rows and two under it are held and collected on it, member after
+    member; a member's inputs resolve from the request's own transactions."""
+    from corda_tpu.node.services import ResolvedFromWalk
+    from corda_tpu.observability import disable_tracing, enable_tracing
+    b = SignatureBatcher(host_crossover=5, max_batch=5)
+    batches = _stub_device(b)
+    svc = TpuTransactionVerifierService(batcher=b)
+    seen = _spy_levels(svc)
+    levels = _graph(services, 5)
+    walk = [stx for level in levels for stx in level]
+    tracer = enable_tracing()
+    try:
+        got = svc.verify_levels(
+            levels, ResolvedFromWalk(services, walk)).result(timeout=30)
+    finally:
+        disable_tracing()
+        svc.shutdown()
+    assert got == (8, None)
+    assert seen["tasks"] == ["_verify_in_order"]
+    (task,) = {name for name, _n, _c in seen["bursts"]}
+    assert task.startswith("tpu-verifier")
+    assert seen["bursts"] == [(task, 5, "bulk")]
+    assert seen["holds"] == seen["collects"] == [task] * 3
+    assert batches == [("ed25519", 5)]
+    snap = svc.metrics.snapshot()
+    assert snap["Verifier.WaveTx.bulk"]["count"] == 5
+    assert snap["Verifier.WaveTx.held"]["count"] == 3
+    assert snap["Verification.Success"]["count"] == 8
+    assert snap["Verification.InFlight"]["value"] == 0
+    rows = b.metrics.snapshot()
+    assert rows["SigBatcher.DeviceChecked"]["count"] == 5
+    assert rows["SigBatcher.HostInline"]["count"] == 6
+    assert rows["SigBatcher.HostRouted"]["count"] == 6
+    spans = tracer.spans()
+    (whole,) = [s for s in spans if s["name"] == "verifier.levels"]
+    assert whole["tags"] == {"levels": 3, "n_tx": 8, "verified": 8}
+    assert whole["thread"] == task
+    waves = [s for s in spans if s["name"] == "verifier.wave"]
+    assert [w["tags"]["admitted"] for w in sorted(
+        waves, key=lambda s: s["start_s"])] == ["bulk", "held", "held"]
+    members = [s for s in spans if s["name"] == "tx.verify"]
+    assert len(members) == 3        # a bulk level's members leave none
+    assert all(s["parent_id"] == whole["span_id"] for s in waves + members)
+
+
+def test_members_of_a_walk_do_not_resolve_from_a_store_that_lacks_them(
+        services):
+    """The view is what lets a level spend what an earlier level made: the
+    same request against the bare services fails at the first move, with
+    the issues before it counted as passed."""
+    from corda_tpu.core.contracts.exceptions import (
+        TransactionResolutionException)
+    svc = TpuTransactionVerifierService()
+    try:
+        verified, error = svc.verify_levels(
+            _graph(services, 2), services).result(timeout=30)
+    finally:
+        svc.shutdown()
+    assert verified == 2 and type(error) is TransactionResolutionException
+
+
+@pytest.mark.parametrize("bad,verified,holds", [
+    ("held_level", 5, 2), ("bulk_level", 1, 0)])
+def test_levels_stop_at_the_first_member_that_fails_in_order(
+        services, bad, verified, holds):
+    """Nothing after the first failure is vouched for: the levels behind it
+    are never admitted, and the count is of the members before it. A level
+    judges all its members (they do not depend on one another), held or
+    bulk; the first of them to fail in order is the one."""
+    from corda_tpu.node.services import ResolvedFromWalk
+    b = SignatureBatcher(host_crossover=5, max_batch=5)
+    _stub_device(b)
+    svc = TpuTransactionVerifierService(batcher=b)
+    levels = _graph(services, 5)
+    if bad == "held_level":
+        levels[1][0] = _corrupted(levels[1][0])
+    else:
+        levels[0][1] = _corrupted(levels[0][1])
+        levels[0][3] = _corrupted(levels[0][3])
+    failing = levels[1][0] if bad == "held_level" else levels[0][1]
+    seen = _spy_levels(svc)
+    walk = [stx for level in levels for stx in level]
+    try:
+        got, error = svc.verify_levels(
+            levels, ResolvedFromWalk(services, walk)).result(timeout=30)
+    finally:
+        svc.shutdown()
+    assert got == verified and type(error) is SignatureException
+    assert failing.id.prefix_chars() in str(error)
+    assert len(seen["bursts"]) == 1 and len(seen["holds"]) == holds
+    assert seen["tasks"] == ["_verify_in_order"]
+
+
+@pytest.mark.parametrize("entry", ["closed_batcher_one_level",
+                                   "closed_batcher_levels",
+                                   "shut_down_pool_levels"])
+def test_verify_levels_resolves_its_future_and_never_fails_it(services,
+                                                              entry):
+    """The scheduler reads ``(verified, error)`` off the future on its own
+    thread: a closed batcher or a shut-down pool comes back as a count of 0
+    and the error, not as a raise."""
+    svc = TpuTransactionVerifierService()
+    levels = _graph(services, 2)
+    try:
+        if entry == "shut_down_pool_levels":
+            svc._pool.shutdown(wait=True)
+        else:
+            svc.batcher.close()
+        fut = svc.verify_levels(
+            levels[:1] if entry == "closed_batcher_one_level" else levels,
+            services)
+        verified, error = fut.result(timeout=30)
+    finally:
+        svc.shutdown()
+    assert verified == 0 and type(error) is RuntimeError

@@ -481,3 +481,201 @@ def test_lone_verifies_suspended_together_are_judged_as_one_depth(
         assert snap["SigBatcher.InFlight"]["value"] == 0
     finally:
         node.services.verifier_service.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# The ORDERED VerifyMany: a walk's levels in one suspension
+# ---------------------------------------------------------------------------
+
+def make_move_stx(services, parents, i):
+    """Spends every parent's output; owner and notary sign: two rows."""
+    from corda_tpu.core.contracts import StateRef
+    wtx = WireTransaction(
+        inputs=tuple(StateRef(p.id, 0) for p in parents),
+        outputs=(TransactionState(DummyState(i, (ALICE_KP.public,)), NOTARY),),
+        commands=(Command(DummyContract.Move(), (ALICE_KP.public,)),),
+        notary=NOTARY, must_sign=(ALICE_KP.public, NOTARY_KP.public))
+    return services.sign_transaction(wtx, ALICE_KP.public, NOTARY_KP.public)
+
+
+def _levels(svcs, width=5, bad=False):
+    """``width`` issues, two moves that spend them between them, a move that
+    spends both, and one more on top; nothing of it is in any store.
+    ``bad``: the first move's owner signature is corrupted (member
+    ``width`` of the order)."""
+    issues = [make_issue_stx(svcs, 40 + i) for i in range(width)]
+    moves = [make_move_stx(svcs, issues[:2], 50),
+             make_move_stx(svcs, issues[2:], 51)]
+    if bad:
+        moves[0] = moves[0].__class__(moves[0].tx_bits, (
+            _corrupt(moves[0]).sigs[0], moves[0].sigs[1]))
+    join = make_move_stx(svcs, moves, 60)
+    return (tuple(issues), tuple(moves), (join,),
+            (make_move_stx(svcs, [join], 70),))
+
+
+class LevelsFlow(FlowLogic):
+    """Yields a walk's levels whole; what it caught, and then (``pause``)
+    one more suspension, so that a restart has something to replay up to."""
+
+    def __init__(self, levels, pause=False):
+        self.levels = levels
+        self.pause = pause
+
+    def call(self):
+        from corda_tpu.flows.api import Sleep, VerifyMany
+        got = "verified"
+        try:
+            yield VerifyMany(levels=self.levels)
+        except Exception as e:
+            got = (type(e).__name__, e.verified)
+        if self.pause:
+            yield Sleep(3600)
+        return got
+
+
+def test_one_level_form_is_the_ordered_form_with_one_level():
+    from corda_tpu.flows.api import VerifyMany
+    a, b, c = object(), object(), object()
+    wave = VerifyMany((a, b))
+    assert wave.levels == ((a, b),) and wave.stxs == (a, b)
+    walk = VerifyMany(levels=((a,), (b, c)), check_sufficient_signatures=False)
+    assert walk.stxs == (a, b, c) and walk.levels == ((a,), (b, c))
+    assert VerifyMany(()).stxs == () and not walk.check_sufficient_signatures
+
+
+def test_levels_are_one_park_and_each_level_keeps_its_route():
+    """Four levels in one ``VerifyMany``: ONE suspension and one pool task;
+    the level at the crossover is one bulk burst (from the task's thread:
+    the node's only parks), the levels under it are held and collected on
+    that same thread, and each member's inputs resolve from the request."""
+    import threading
+    network, node = make_network_node()
+    svcs = seed_services(node)
+    batcher = SignatureBatcher(host_crossover=5, max_batch=5)
+    svc = TpuTransactionVerifierService(batcher=batcher)
+    node.services.verifier_service = svc
+    seen = _spy_routes(batcher)
+    bursts, tasks = [], []
+    submit_groups, pool_submit = batcher.submit_groups, svc._pool.submit
+    batcher.submit_groups = lambda groups, ctxs=None, latency_class="bulk": (
+        bursts.append((threading.current_thread().name, len(groups),
+                       latency_class)),
+        submit_groups(groups, ctxs, latency_class))[1]
+    svc._pool.submit = lambda fn, *a, **k: (
+        tasks.append(fn.__name__), pool_submit(fn, *a, **k))[1]
+    try:
+        fsm = node.start_flow(LevelsFlow(_levels(svcs)))
+        assert node.smm.awaiting_external == 1
+        assert len(node.smm.checkpoints.get_all_checkpoints()) == 1
+        network.run_network()
+        assert fsm.result_future.result(timeout=60) == "verified"
+        assert tasks == ["_verify_in_order"]
+        ((task, n, klass),) = bursts
+        assert task.startswith("tpu-verifier") and (n, klass) == (5, "bulk")
+        assert seen["hold"] == seen["collect"] == [task] * 4
+        assert seen["device_batches"] == [5]
+        snap = batcher.metrics.snapshot()
+        assert snap["SigBatcher.DeviceChecked"]["count"] == 5
+        assert snap["SigBatcher.HostInline"]["count"] == 8
+        assert snap["SigBatcher.HostRouted"]["count"] == 8
+        assert snap["SigBatcher.InFlight"]["value"] == 0
+        waves = svc.metrics.snapshot()
+        assert waves["Verifier.WaveTx.bulk"]["count"] == 5
+        assert waves["Verifier.WaveTx.held"]["count"] == 4
+        assert node.services.storage.transactions == []   # nothing recorded
+    finally:
+        svc.shutdown()
+
+
+class PrefixOnlyService(ManualVerifierService):
+    """``verify_signed`` alone, each future resolved at once on the caller's
+    thread with the transaction's own verdict."""
+
+    def verify_signed(self, stx, services, check_sufficient_signatures=True):
+        fut = super().verify_signed(stx, services)
+        try:
+            stx.verify(services,
+                       check_sufficient_signatures=check_sufficient_signatures)
+            fut.set_result(None)
+        except Exception as e:
+            fut.set_exception(e)
+        return fut
+
+
+def _backend(kind, network, node):
+    from corda_tpu.verifier import InMemoryTransactionVerifierService
+    if kind == "fallback":
+        return None
+    if kind == "member_by_member":
+        return PrefixOnlyService()
+    if kind == "in_memory":
+        return InMemoryTransactionVerifierService()
+    if kind == "tpu":
+        return TpuTransactionVerifierService()
+    svc = OutOfProcessTransactionVerifierService(node.messaging)
+    VerifierWorker(network.bus.create_node("verifier-worker-1"),
+                   str(node.info.address))
+    return svc
+
+
+BACKENDS = ["fallback", "member_by_member", "in_memory", "tpu",
+            "out_of_process"]
+
+
+@pytest.mark.parametrize("kind", BACKENDS)
+@pytest.mark.parametrize("bad", [False, True], ids=["all_valid", "one_bad"])
+def test_ordered_request_has_one_outcome_behind_every_backend(kind, bad):
+    """Every service behind the seam is handed the members with the view
+    over the walk, so each resolves what the store does not hold; a failure
+    is the first in the order, and ``verified`` the members before it."""
+    network, node = make_network_node()
+    svcs = seed_services(node)
+    svc = node.services.verifier_service = _backend(kind, network, node)
+    network.run_network()       # a worker's handshake
+    try:
+        fsm = node.start_flow(LevelsFlow(_levels(svcs, bad=bad)))
+        assert node.smm.awaiting_external == (0 if kind == "fallback" else 1)
+        network.run_network()
+        got = fsm.result_future.result(timeout=60)
+    finally:
+        if hasattr(svc, "shutdown"):
+            svc.shutdown()
+    if not bad:
+        assert got == "verified"
+    elif kind == "out_of_process":  # the worker's answer is a message
+        assert got[1] == 5
+    else:
+        assert got == ("SignatureException", 5)
+
+
+@pytest.mark.parametrize("kind", ["fallback", "by_hand"])
+def test_failure_in_an_ordered_request_replays_with_its_type_and_count(kind):
+    """The count rides in the response log beside the typed error: a flow
+    that caught the failure and parked again is restored to the same
+    ``(type, verified)``, whichever path logged it."""
+    network, node = make_network_node()
+    svcs = seed_services(node)
+    levels = _levels(svcs, width=2, bad=True)
+    if kind == "fallback":      # verified here, in the flow's own step
+        fsm = node.start_flow(LevelsFlow(levels, pause=True))
+    else:
+        manual = node.services.verifier_service = ManualVerifierService()
+        fsm = node.start_flow(LevelsFlow(levels, pause=True))
+        assert len(manual.futures) == 6         # every member, handed over
+        for i in (5, 3, 4, 0, 1):               # out of order, on purpose
+            manual.futures[i].set_result(None)
+        manual.futures[2].set_exception(SignatureException("flipped"))
+        network.run_network()
+    assert not fsm.result_future.done()         # asleep, past the failure
+    (held,) = node.smm.checkpoints.get_all_checkpoints()
+    (error,) = [value for what, value in held.response_log if what == "error"]
+    assert error[0].endswith(":SignatureException") and error[2] == 2
+    node2 = node.restart()
+    node2.services.verifier_service = ManualVerifierService()
+    node2.start()               # replays the failure, sleeps again
+    assert node2.services.verifier_service.futures == []    # none asked anew
+    (restored,) = node2.smm.flows.values()
+    assert node2.smm.wake_timers(now=node2.smm.clock() + 4000) == 1
+    assert restored.result_future.result(timeout=30) \
+        == ("SignatureException", 2)
