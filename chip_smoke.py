@@ -38,7 +38,7 @@ ROWS = 8192
 UNIQUE = 512
 #: Every CORRUPT_EVERY-th row is corrupted (128 of 8192 rows, 1.6%).
 CORRUPT_EVERY = 64
-#: secp256r1 is "not run": its donated 8192-bucket compile for a described
+#: secp256r1 is "not run": its 8192-bucket compile for a described
 #: v5e did not finish in the ten minutes ISSUE 22 allows (see CHANGES.md).
 SCHEMES = ("ed25519", "secp256k1")
 NOT_RUN = ("secp256r1",)

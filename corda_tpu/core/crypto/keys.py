@@ -171,7 +171,7 @@ def sec1_decompress_cached(curve: ecmath.WeierstrassCurve, data: bytes):
 def sec1_pub_row_cached(curve: ecmath.WeierstrassCurve, data: bytes):
     """``sec1_decompress_cached`` in the native preps' wire format: the (8,)
     little-endian u64 row (x ‖ y, 32 LE bytes each) that sm_k1_prep /
-    sm_r1_prep consume. Memoized per (curve, encoding) — the batcher's ECDSA
+    sm_r1_prep_hg consume. Memoized per (curve, encoding) — the batcher's ECDSA
     prep copies one cached row per item instead of paying decompress plus
     two ``to_bytes`` round trips (the Weierstrass analog of the Ed25519
     kernel's per-signer A′ row cache). Returns None for invalid encodings."""

@@ -152,15 +152,8 @@ def shamir_ladder(bits1, bits2, P1, P2):
 
 
 # ---------------------------------------------------------------------------
-# Windowed ladder: constant-B Niels table + 2-bit per-item A windows
-# (the ed25519 sibling of weierstrass.hybrid_ladder_wide — no endomorphism
-# on edwards25519, so the doubles stay at 256, but the adds collapse from
-# 256 to 128 A adds + 256/w mixed B adds)
+# Constant-B Niels tables (the split ladder's two bases, B and [2^128]B)
 # ---------------------------------------------------------------------------
-
-#: Constant-base window width: one mixed B add per w bits from a 2^w-entry
-#: Niels table. 256 = 16x16 divides exactly; the table is ~6MB of u16.
-B_WINDOW = 16
 
 #: Constant-base window width for the split-k ladder (128 = 8x16 divides
 #: exactly: 8 outer steps of 16 doubles + 8 joint A adds + 1 B + 1 B' add).
@@ -212,7 +205,7 @@ def _b_window_table(w: int, shift: int = 0):
     return tab
 
 
-def b_table_device(w: int = B_WINDOW, shift: int = 0):
+def b_table_device(w: int = SPLIT_B_WINDOW, shift: int = 0):
     """The Niels base table as committed device arrays (kernel ARGUMENTS,
     not baked constants — see weierstrass.g_window_table_device)."""
     return F.device_table_cache(("niels_b", w, shift),
@@ -236,74 +229,6 @@ def madd_niels(Pt, tab_p, tab_m, tab_td):
     return (F.mul(e, f, P), F.mul(g, h, P), F.mul(f, g, P), F.mul(e, h, P))
 
 
-def windowed_ladder(b_idx, a_digits, neg_a, btab, w: int):
-    """[s]B + [k](-A): per outer step, ``w`` bits — w doubles, w/2 A adds
-    (2-bit per-item windows over {0,-A,-2A,-3A}), ONE Niels mixed B add
-    gathered from the 2^w-entry constant table.
-
-    ``b_idx``: (256/w, B) table indices; ``a_digits``: (256/w, w/2, B)
-    2-bit digits of k; ``neg_a``: extended -A batch; ``btab``: the three
-    (2^w, NLIMB) table arrays."""
-    tab_p, tab_m, tab_td = btab
-    batch_shape = neg_a[0].shape[:-1]
-    Pid = identity(batch_shape)
-    a2 = double(neg_a)
-    a_tab = (Pid, neg_a, a2, add(a2, neg_a))   # {0,-A,-2A,-3A}
-
-    def a_addend(dig):
-        return _select4(dig, *a_tab)
-
-    def b_add(acc, bi):
-        return madd_niels(acc, tab_p[bi].astype(jnp.uint64),
-                          tab_m[bi].astype(jnp.uint64),
-                          tab_td[bi].astype(jnp.uint64))
-
-    def a_step(acc, dig):
-        acc = double(double(acc))
-        return add(acc, a_addend(dig)), None
-
-    def step(acc, ins):
-        bi, digs = ins
-        acc, _ = jax.lax.scan(a_step, acc, digs)
-        return b_add(acc, bi), None
-
-    # peel step 0: the accumulator is the identity, so the leading
-    # double-double-add collapses to selecting the first A addend
-    acc = a_addend(a_digits[0][0])
-    acc, _ = jax.lax.scan(a_step, acc, a_digits[0][1:])
-    acc = b_add(acc, b_idx[0])
-    acc, _ = jax.lax.scan(step, acc, (b_idx[1:], a_digits[1:]))
-    return acc
-
-
-def verify_core_windowed(b_idx, a_digits, neg_a, r_y, r_sign,
-                         tab_p, tab_m, tab_td, w: int):
-    """ok[i] = compress([s]B + [k](-A)) == wire R bytes — RFC 8032
-    re-encoding equivalence: the wire y (canonical, host-range-checked)
-    and sign bit are compared against the DEVICE-computed affine result,
-    so the host never pays the per-item modular sqrt of decompressing R.
-    One batched Fermat inversion (a lax.scan pow) lands the affine
-    coordinates; Z is never 0 for the complete extended formulas."""
-    b_idx = jnp.asarray(b_idx, jnp.int32)
-    a_digits = jnp.asarray(a_digits, jnp.uint64)
-    neg_a = tuple(jnp.asarray(c, jnp.uint64) for c in neg_a)
-    r_y = jnp.asarray(r_y, jnp.uint64)
-    r_sign = jnp.asarray(r_sign)
-    acc = windowed_ladder(b_idx, a_digits, neg_a,
-                          (tab_p, tab_m, tab_td), w)
-    x, y, z, _ = acc
-    zi = F.inv(z, P)
-    x_aff = F.canon(F.mul(x, zi, P), P)
-    y_aff = F.canon(F.mul(y, zi, P), P)
-    ok_y = jnp.all(y_aff == r_y, axis=-1)
-    ok_sign = (x_aff[..., 0] & 1) == r_sign
-    return ok_y & ok_sign
-
-
-_verify_kernel_windowed = jax.jit(verify_core_windowed,
-                                  static_argnames=("w",))
-
-
 # ---------------------------------------------------------------------------
 # Split-k windowed ladder: both scalars split at bit 128, HALVING the
 # doublings (the dominant ladder cost) — the ed25519 analog of secp256k1's
@@ -322,7 +247,7 @@ _verify_kernel_windowed = jax.jit(verify_core_windowed,
 #   16 Niels adds  7 M                                                   112 M
 #   tail           one inversion per INV_BATCH_STOP rows + 3 M a row,
 #                  2 M to land affine, 3 canonical forms                  ~5 M
-# against the plain windowed ladder's 256 doublings + 128 A adds + 16 B adds.
+# against the plain Shamir ladder's 256 doublings + 256 unified adds.
 # T IS computed by every joint add though only the last of a window is read
 # (by the Niels add after it): leaving those 55 products out, by flags and a
 # window peeled or reordered, was measured 5% SLOWER on v5e (PERF.md, PR 30).
@@ -425,7 +350,9 @@ def verify_core_split(bb_idx, a_packed, rows, r_packed,
                       tab_p, tab_m, tab_td, tab2_p, tab2_m, tab2_td,
                       w: int):
     """Split-k verify: RFC 8032 re-encoding acceptance (see
-    verify_core_windowed) over the half-length ladder.
+    reencode_verdict: the wire y and sign bit against the DEVICE-computed
+    affine point, so the host never pays the per-item modular sqrt of
+    decompressing R) over the half-length ladder.
 
     CONSOLIDATED wire form — 4 per-batch arrays instead of 12: every
     host→device transfer pays a per-array latency on top of bandwidth
@@ -565,46 +492,36 @@ def _substitute_row() -> np.ndarray:
     return _row_from_affine(ecmath.ED_B)
 
 
-def _precheck_items(items, decompress_r: bool):
-    """ONE host-side structural-check + scalar-derivation loop for both
-    kernel preps. ``decompress_r=True`` (plain ladder) additionally pays
-    the modular sqrt to materialize R as a point; the windowed kernel
-    verifies by RE-ENCODING the computed point (RFC 8032 equivalence), so
-    its prep only range-checks the raw y — the R sqrt was ~0.3ms of host
-    bigint per ITEM, the dominant service-path cost for the default
-    scheme. Returns (precheck, A points, R points|None, R y-ints,
-    R sign bits, s scalars, k scalars)."""
+def _precheck_items(items):
+    """The plain reference's host-side structural checks and scalars, one
+    loop: lengths, the key and R as points (R pays a modular sqrt here;
+    the split kernel verifies by RE-ENCODING the computed point and its
+    prep only range-checks the raw y), s < L, k = SHA-512(R ‖ A ‖ M) mod L.
+    Returns (precheck, A points, R points, s scalars, k scalars)."""
     n = len(items)
     precheck = np.ones(n, dtype=bool)
-    a_pts, r_pts, r_ys, r_signs, ss, ks = [], [], [], [], [], []
+    a_pts, r_pts, ss, ks = [], [], [], []
     for i, (pub, sig, msg) in enumerate(items):
         ok = len(sig) == 64
         R = None
         if ok:
-            r_enc = int.from_bytes(sig[:32], "little")
-            r_y = r_enc & ((1 << 255) - 1)
-            r_sign = r_enc >> 255
             s = int.from_bytes(sig[32:], "little")
             A = _decompress_a(bytes(pub))
-            # non-canonical y (>= p) rejects exactly like a failed
-            # decompression — the oracle's ed_point_decompress does
-            ok = A is not None and r_y < P and s < ecmath.ED_L
-            if ok and decompress_r:
-                R = ecmath.ed_point_decompress(sig[:32])
-                ok = R is not None
+            # a non-canonical y (>= p) fails the decompression, as the
+            # oracle's ed_point_decompress has it
+            R = ecmath.ed_point_decompress(sig[:32])
+            ok = A is not None and R is not None and s < ecmath.ED_L
         if not ok:
             precheck[i] = False
-            A, R, r_y, r_sign, s, k = ecmath.ED_B, ecmath.ED_B, 1, 0, 0, 0
+            A, R, s, k = ecmath.ED_B, ecmath.ED_B, 0, 0
         else:
             h = hashlib.sha512(sig[:32] + pub + msg).digest()
             k = int.from_bytes(h, "little") % ecmath.ED_L
         a_pts.append(A)
         r_pts.append(R)
-        r_ys.append(r_y)
-        r_signs.append(r_sign)
         ss.append(s)
         ks.append(k)
-    return precheck, a_pts, r_pts, r_ys, r_signs, ss, ks
+    return precheck, a_pts, r_pts, ss, ks
 
 
 def prepare_batch(items: list[tuple[bytes, bytes, bytes]]):
@@ -616,50 +533,13 @@ def prepare_batch(items: list[tuple[bytes, bytes, bytes]]):
     we map to verdict False and let the caller decide). Failed items are
     substituted with the base point so shapes stay static.
     """
-    precheck, a_pts, r_pts, _, _, ss, ks = _precheck_items(
-        items, decompress_r=True)
+    precheck, a_pts, r_pts, ss, ks = _precheck_items(items)
     neg_a = _pack_point_ext([(P - x, y) for x, y in a_pts])
     rx = jnp.asarray(F.to_limbs([p[0] for p in r_pts]).astype(np.uint16))
     ry = jnp.asarray(F.to_limbs([p[1] for p in r_pts]).astype(np.uint16))
     s_bits = jnp.asarray(F.scalars_to_bits(ss))
     k_bits = jnp.asarray(F.scalars_to_bits(ks))
     return s_bits, k_bits, neg_a, (rx, ry), precheck
-
-
-def prepare_batch_windowed(items: list[tuple[bytes, bytes, bytes]],
-                           w: int = B_WINDOW, device_tables: bool = True):
-    """Host prep for the windowed kernel: s → w-bit constant-B table
-    indices, k → 2-bit A-window digits grouped per outer step, -A extended,
-    R as its RAW canonical y + sign bit (no host decompression — the
-    kernel re-encodes), plus the device-committed Niels table (appended
-    before precheck so ``*args, precheck`` callers pass straight through).
-    Mesh callers pass ``device_tables=False`` and supply their own
-    replicated table copies instead (no stranded single-device upload)."""
-    from . import scalarprep as sp
-    from .weierstrass import _bits_to_w_windows, _bits_to_windows
-    precheck, a_pts, _, r_ys, r_signs, ss, ks = _precheck_items(
-        items, decompress_r=False)
-    neg_a = _pack_point_ext([(P - x, y) for x, y in a_pts])
-    r_y = jnp.asarray(F.to_limbs(r_ys).astype(np.uint16))
-    r_sign = jnp.asarray(np.asarray(r_signs, dtype=np.uint8))
-    if w == 16 and sp.available():
-        # native window extraction (h is not retained by _precheck_items,
-        # so feed the already-derived k scalars as 256-bit "digests")
-        h_words = np.zeros((len(items), 8), dtype=np.uint64)
-        h_words[:, :4] = sp.ints_to_words(ks)
-        b_idx, a_digits_flat, _ = sp.ed_prep_plain(
-            h_words, sp.ints_to_words(ss))
-        a_digits = a_digits_flat.reshape(256 // w, w // 2, len(items))
-    else:
-        b_idx = _bits_to_w_windows(F.scalars_to_bits(ss), w).astype(
-            np.int32)
-        digs = _bits_to_windows(F.scalars_to_bits(ks)).astype(np.uint8)
-        a_digits = digs.reshape(256 // w, w // 2, *digs.shape[1:])
-    head = (jnp.asarray(b_idx), jnp.asarray(a_digits), neg_a, r_y, r_sign)
-    if device_tables:
-        return (*head, *b_table_device(w), precheck)
-    return (*head, precheck)
-
 
 
 def _columns(items):
@@ -805,8 +685,8 @@ def _prep_words_python(sig_buf, sig_len, msg_buf, msg_len, which, slot_keys,
 
 
 def _split_windows_python(digests: list[bytes], s_words: np.ndarray):
-    """Pure-Python fallback of scalarprep.ed_prep (bit-identical; used when
-    libscalarmath.so is absent — locked by tests/test_scalarprep.py)."""
+    """The split ladder's windows of a batch, from the rows' SHA-512
+    digests and s words: the scalar half of :func:`_prep_words_python`."""
     from .weierstrass import _bits_to_w_windows, _bits_to_windows
     n = len(digests)
     mask128 = (1 << 128) - 1
@@ -838,18 +718,6 @@ def verify_batch(items: list[tuple[bytes, bytes, bytes]]) -> np.ndarray:
     return finish_batch(pending)
 
 
-def _service_kernel_split():
-    """Donated-jit twin of ``_verify_kernel_split`` for the async service
-    path: the four per-batch wire arrays (bb_idx, a_digits, rows,
-    r_packed) are donated so XLA reuses their device memory; the six
-    Niels table args are committed device_table_cache buffers and are
-    NEVER donated. Separate from the plain handle so synchronous callers
-    that re-invoke with the same prepared args (bench's _kernel_rate)
-    keep valid buffers."""
-    return F.donating_jit("ed25519.split.donated", verify_core_split,
-                          (0, 1, 2, 3), static_argnames=("w",))
-
-
 def verify_batch_async(items: list[tuple[bytes, bytes, bytes]],
                        trace_parent=None):
     """:func:`verify_batch_async_words` for (pub32, sig64, msg) triples."""
@@ -861,8 +729,8 @@ def verify_batch_async_words(keys, sigs, msgs, trace_parent=None,
     """Dispatch without forcing (see weierstrass.verify_batch_async): the
     device computes while the caller preps the next batch. Rides the
     split-k half-length ladder — the fastest measured path (PERF.md
-    section 5) — with donated per-batch device buffers and leased host
-    staging arrays (ops.staging) on the service path. The rows arrive as
+    section 5) — through the module's one jit handle, with leased host
+    staging arrays (ops.staging). The rows arrive as
     three lists (:func:`prepare_words_split`, which also pads them to the
     bucket). Dispatches go through the kernel flight recorder
     (observability.profiling): compile-cache accounting + batch occupancy.
@@ -887,7 +755,7 @@ def verify_batch_async_words(keys, sigs, msgs, trace_parent=None,
                            bucket="ed25519", rows=n,
                            capacity=capacity) as lspan:
         dev = get_profiler().call(
-            "ed25519.split", _service_kernel_split(), *args,
+            "ed25519.split", _verify_kernel_split, *args,
             w=SPLIT_B_WINDOW, live=n, capacity=capacity,
             scheme="ed25519",
             field_products_per_row=functools.partial(

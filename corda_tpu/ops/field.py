@@ -917,48 +917,6 @@ def device_table_cache(key, build):
     return tabs
 
 
-_DONATING_JIT_CACHE: dict = {}
-_DONATING_JIT_LOCK = threading.Lock()
-
-
-def donation_supported() -> bool:
-    """True when the active backend implements input-buffer donation.
-    CPU does not: jax warns and silently keeps the copy, so donation is
-    gated off there rather than paying a warning per dispatch."""
-    return jax.default_backend() != "cpu"
-
-
-def donating_jit(key, fn, donate_argnums, **jit_kwargs):
-    """Process-cached ``jax.jit(fn, donate_argnums=...)`` for the async
-    service path: per-batch input buffers are donated to the kernel so
-    XLA reuses their device memory for outputs/temporaries instead of
-    allocating fresh HBM per flush (guide: persistent per-request buffers
-    + donate, all_trn_tricks).
-
-    Two rules every caller must honor:
-
-    - donate ONLY per-batch arrays. The committed lookup tables from
-      :func:`device_table_cache` are reused across every dispatch —
-      donating one would invalidate the cache and crash the next batch.
-    - donated variants are SEPARATE jit handles from the plain kernels:
-      synchronous callers that re-invoke with the same prepared args
-      would find them deleted by donation.
-
-    Resolved lazily at first call (never at import) so pulling in an ops
-    module does not force backend initialization; on CPU this degrades
-    to a plain ``jax.jit``."""
-    cached = _DONATING_JIT_CACHE.get(key)
-    if cached is None:
-        with _DONATING_JIT_LOCK:
-            cached = _DONATING_JIT_CACHE.get(key)
-            if cached is None:
-                kw = dict(jit_kwargs)
-                if donation_supported():
-                    kw["donate_argnums"] = donate_argnums
-                cached = _DONATING_JIT_CACHE[key] = jax.jit(fn, **kw)
-    return cached
-
-
 def bucket_size(n: int, floor: int = 8) -> int:
     """Next power of two >= n (>= floor). Batch kernels pad to bucket sizes so
     XLA compiles once per bucket, not once per batch length (shared by the

@@ -4,8 +4,8 @@ The C library (native/scalarmath.cpp) performs the per-item scalar layer of
 signature verification (Barrett mulmod, Montgomery batch inversion, GLV
 decomposition, window/digit extraction, u16 limb packing) in one pass per
 batch; the Python bigint loops it replaces were the service path's ceiling
-(BASELINE.md round-4 close-out: ~0.9s per 32k secp256k1 batch, ~1.9s
-Ed25519).  Callers (ops/weierstrass.py, ops/ed25519.py) fall back to the
+(~0.9s per 32k secp256k1 batch, ~1.9s Ed25519, measured in an early
+round).  Callers (ops/weierstrass.py, ops/ed25519.py) fall back to the
 original Python prep when the library is absent — behavior is identical
 (locked by tests/test_scalarprep.py differential tests).
 
@@ -36,7 +36,11 @@ _CANDIDATES = [
 #: Version 6 added sm_ed_prep_words (the whole Ed25519 split prep of a batch:
 #: parse, SHA-512 challenges, scalars, windows, the signers' rows, padding)
 #: and sm_sha512, the seam its hash is held to hashlib through.
-SM_VERSION = 6
+#: Version 7 took out three exports, the preps of ladders that are gone
+#: (the Ed25519 scalar-only split and plain windowed preps, the secp256r1
+#: single-scalar windowed prep): a version-6 library still exports them and
+#: is refused, so what loads is what scalarmath.cpp says.
+SM_VERSION = 7
 
 #: Live rows up to which sm_ed_prep_words is called with the interpreter lock
 #: HELD (PyDLL), and over which it is let go (CDLL): one algorithm, the one
@@ -85,11 +89,6 @@ def _bind(lib) -> None:
         ctypes.c_int64, _U64P, _U64P, _U64P, _U64P,
         _I32P, _U8P, _U16P, _U16P, _U16P, _U16P, _U16P,
         _U8P, _U8P, _U64P]
-    lib.sm_r1_prep.restype = ctypes.c_int
-    lib.sm_r1_prep.argtypes = [
-        ctypes.c_int64, _U64P, _U64P, _U64P, _U64P,
-        _I32P, _U8P, _U16P, _U16P, _U16P,
-        _U8P, _U8P, _U64P]
     lib.sm_r1_prep_hg.restype = ctypes.c_int
     lib.sm_r1_prep_hg.argtypes = [
         ctypes.c_int64, _U64P, _U64P, _U64P, _U64P,
@@ -109,12 +108,6 @@ def _bind(lib) -> None:
             _I32P, ctypes.c_int64, _U8P, _U16P, _U8P, _U16P,
             _I32P, _U8P, _U16P, _U16P, _U8P]
         setattr(lib, name, fn)
-    lib.sm_ed_prep.restype = ctypes.c_int
-    lib.sm_ed_prep.argtypes = [
-        ctypes.c_int64, _U64P, _U64P, _I32P, _I32P, _U8P, _U8P]
-    lib.sm_ed_prep_plain.restype = ctypes.c_int
-    lib.sm_ed_prep_plain.argtypes = [
-        ctypes.c_int64, _U64P, _U64P, _I32P, _U8P, _U8P]
 
 
 def _load(candidates=None, expected: int = SM_VERSION):
@@ -381,26 +374,6 @@ def k1_prep(e_words, r_words, s_words, pub_words):
             rn_ok, precheck.astype(bool))
 
 
-def r1_prep(e_words, r_words, s_words, pub_words):
-    """secp256r1 single-scalar windowed prep (w = 16, 4-bit Q digits)."""
-    n = len(e_words)
-    g_idx = np.empty((16, n), dtype=np.int32)
-    q_digits = np.empty((64, n), dtype=np.uint8)
-    q_x = np.empty((n, 16), dtype=np.uint16)
-    q_y = np.empty((n, 16), dtype=np.uint16)
-    r_limbs = np.empty((n, 16), dtype=np.uint16)
-    rn_ok = np.empty(n, dtype=np.uint8)
-    precheck = np.empty(n, dtype=np.uint8)
-    work = np.empty((3 * n, 4), dtype=np.uint64)
-    rc = _LIB.sm_r1_prep(
-        n, np.ascontiguousarray(e_words), np.ascontiguousarray(r_words),
-        np.ascontiguousarray(s_words), np.ascontiguousarray(pub_words),
-        g_idx, q_digits, q_x, q_y, r_limbs, rn_ok, precheck, work)
-    if rc != 0:
-        raise RuntimeError(f"sm_r1_prep failed: {rc}")
-    return (g_idx, q_digits, q_x, q_y, r_limbs, rn_ok, precheck.astype(bool))
-
-
 def r1_prep_hg(e_words, r_words, s_words, pub_words):
     """secp256r1 half-gcd split prep (the PR-3 fast path; wire layout in
     scalarmath.cpp sm_r1_prep_hg).  Returns (g_idx(16,B) i32 — row 2j =
@@ -424,37 +397,6 @@ def r1_prep_hg(e_words, r_words, s_words, pub_words):
         raise RuntimeError(f"sm_r1_prep_hg failed: {rc}")
     return (g_idx, q_digits, q_x, q_y, xd_limbs, hg_ok,
             precheck.astype(bool))
-
-
-def ed_prep(h_words, s_words):
-    """Ed25519 split-k prep: returns (b_idx(8,B), b2_idx(8,B) i32,
-    a_packed(64,B) u8, s_ok(B) bool)."""
-    n = len(h_words)
-    b_idx = np.empty((8, n), dtype=np.int32)
-    b2_idx = np.empty((8, n), dtype=np.int32)
-    a_packed = np.empty((64, n), dtype=np.uint8)
-    s_ok = np.empty(n, dtype=np.uint8)
-    rc = _LIB.sm_ed_prep(
-        n, np.ascontiguousarray(h_words), np.ascontiguousarray(s_words),
-        b_idx, b2_idx, a_packed, s_ok)
-    if rc != 0:
-        raise RuntimeError(f"sm_ed_prep failed: {rc}")
-    return b_idx, b2_idx, a_packed, s_ok.astype(bool)
-
-
-def ed_prep_plain(h_words, s_words):
-    """Ed25519 plain windowed prep: (b_idx(16,B) i32, a_digits(128,B) u8,
-    s_ok(B) bool)."""
-    n = len(h_words)
-    b_idx = np.empty((16, n), dtype=np.int32)
-    a_digits = np.empty((128, n), dtype=np.uint8)
-    s_ok = np.empty(n, dtype=np.uint8)
-    rc = _LIB.sm_ed_prep_plain(
-        n, np.ascontiguousarray(h_words), np.ascontiguousarray(s_words),
-        b_idx, a_digits, s_ok)
-    if rc != 0:
-        raise RuntimeError(f"sm_ed_prep_plain failed: {rc}")
-    return b_idx, a_digits, s_ok.astype(bool)
 
 
 def ed_prep_words(sig_buf, sig_len, msg_buf, msg_len, which, slot_keys,

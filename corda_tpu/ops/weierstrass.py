@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import functools
 import hashlib
-import threading
 import time
 
 import jax
@@ -61,9 +60,9 @@ def select_tree(table, idx):
     """16-way batched point select over a 16-entry table of coordinate
     tuples: fold by index bit (LSB first) — a binary tree of 15 two-way
     selects per coordinate.  (A flat masked-sum over a stacked table is
-    HBM-bound and costs more — BASELINE r1 dead end; u32-downcasting the
-    tree was measured FLAT on v5e.)  Shared by the k1 hybrid ladder, the
-    r1 windowed ladder, and the ed25519 split ladder."""
+    HBM-bound and costs more, a dead end of an early round; u32-downcasting
+    the tree was measured FLAT on v5e.)  Shared by the k1 hybrid ladder,
+    the r1 split ladder, and the ed25519 split ladder."""
     level = table
     for j in range(4):
         b = ((idx >> j) & 1).astype(jnp.bool_)
@@ -361,7 +360,7 @@ def _madd_w(Pt, Qa, curve: WeierstrassCurve):
     products collapse host-side — t2 = Z1, t4 = X1 + Z1·X2,
     t5 = Y1 + Z1·Y2 — saving three of the twelve full products. Complete
     for every projective P1; NOT valid for an identity addend (the
-    windowed ladder's table carries a validity flag).  The a = -3 case
+    constant-G tables carry a validity flag).  The a = -3 case
     rides the column-fused tail (:func:`_m3_tail`)."""
     X1, Y1, Z1 = Pt
     X2, Y2 = Qa
@@ -437,47 +436,11 @@ def shamir_ladder(bits1, bits2, P1, P2, curve: WeierstrassCurve):
     return acc
 
 
-# ---------------------------------------------------------------------------
-# GLV path (secp256k1 only): 4-scalar joint ladder over 129 bits
-# ---------------------------------------------------------------------------
-
-GLV_BITS = 128  # Babai rounding bounds the decomposition halves by
-                # (|a1|+|a2|)/2 < 2^127.35 and (|b1|+|b2|)/2 < 2^127.12
-                # (ecmath constants), so 128 bits always suffice;
-                # scalars_to_bits asserts if a scalar ever exceeded this
-
-
-def glv_ladder(bits4, pts4, curve: WeierstrassCurve):
-    """[a]P0 + [b]P1 + [c]P2 + [d]P3 where bits4 (GLV_BITS, B, 4) holds the 4
-    scalars' bit-planes, MSB-first.
-
-    Builds the 16-entry subset-sum table (11 complete adds, one-time per
-    call), then runs GLV_BITS iterations of double + select + add — half the
-    iterations of the plain 2-scalar 256-bit ladder. The 16-way table select
-    is a binary tree of 15 two-way selects per coordinate on (B, NLIMB)
-    operands (a flat masked-sum over a (16, B, NLIMB) stack is HBM-bound and
-    costs more than the adds it saves)."""
-    batch_shape = pts4[0][0].shape[:-1]
-    Pid = identity(batch_shape)
-    table = [Pid] * 16
-    for t in range(1, 16):
-        low = t & -t                      # lowest set bit
-        rest = t ^ low
-        pt = pts4[low.bit_length() - 1]
-        table[t] = pt if rest == 0 else add(table[rest], pt, curve)
-
-    def step(acc, bits):
-        acc = dbl(acc, curve)
-        level = table
-        for j in range(4):                # fold by bit j (LSB first)
-            b = bits[..., j].astype(jnp.bool_)
-            level = [tuple(F.select(b, hi_c, lo_c)
-                           for lo_c, hi_c in zip(lo, hi))
-                     for lo, hi in zip(level[0::2], level[1::2])]
-        return add(acc, level[0], curve), None
-
-    acc, _ = jax.lax.scan(step, Pid, bits4)
-    return acc
+GLV_BITS = 128  # Babai rounding bounds the halves of secp256k1's lambda
+                # decomposition by (|a1|+|a2|)/2 < 2^127.35 and
+                # (|b1|+|b2|)/2 < 2^127.12 (ecmath constants), so 128 bits
+                # always suffice; scalars_to_bits asserts if a scalar ever
+                # exceeded this
 
 
 def _accept(X, Z, r_cands, p):
@@ -503,24 +466,6 @@ def _accept_rn(X, Z, r, rn_ok, p: int, n: int):
     ok_r = (jnp.all(cx == F.canon(F.mul(r, Z, p), p), axis=-1)
             | (rn_ok & jnp.all(cx == F.canon(F.mul(r1, Z, p), p), axis=-1)))
     return nonzero & ok_r
-
-
-def verify_core_glv(bits4, pts4, r_cands):
-    """secp256k1 ECDSA verify via the lambda endomorphism: the host splits
-    u1 = a + b*lambda, u2 = c + d*lambda (ecmath.glv_decompose) and sign-
-    adjusts the four base points; the device computes
-    [|a|](±G) + [|b|](±phi(G)) + [|c|](±Q) + [|d|](±phi(Q)) in GLV_BITS
-    iterations."""
-    bits4 = jnp.asarray(bits4, jnp.uint64)
-    pts4 = tuple(tuple(jnp.asarray(c, jnp.uint64) for c in pt)
-                 for pt in pts4)
-    r_cands = jnp.asarray(r_cands, jnp.uint64)
-    curve = CURVES["secp256k1"]
-    X, Y, Z = glv_ladder(bits4, pts4, curve)
-    return _accept(X, Z, r_cands, curve.p)
-
-
-_verify_kernel_glv = jax.jit(verify_core_glv)
 
 
 def _batch_modinv(values, n: int):
@@ -583,35 +528,6 @@ def _precheck_and_scalars(curve: WeierstrassCurve, items):
     r0 = rs
     r1 = [r + curve.n if r + curve.n < curve.p else r for r in rs]
     return precheck, pubs, u1s, u2s, r0, r1
-
-
-def prepare_batch_glv(items):
-    """Host prep for the GLV kernel: (pub, msg, r, s) → (bits4, pts4, r_cands,
-    precheck) where bits4 is the (GLV_BITS, B, 4) MSB-first bit-plane array of
-    the four decomposed scalars. Each scalar pair is GLV-decomposed; negative
-    halves flip the corresponding base point (cheap host affine negation)."""
-    curve = CURVES["secp256k1"]
-    p = curve.p
-    precheck, pubs, u1s, u2s, r0, r1 = _precheck_and_scalars(curve, items)
-    pts_cols = [[] for _ in range(4)]   # per-item affine points P0..P3
-    scalars = [[] for _ in range(4)]
-    for pub, u1, u2 in zip(pubs, u1s, u2s):
-        a, b = glv_decompose(u1)
-        c, d = glv_decompose(u2)
-        g, q = curve.g, pub
-        phi = lambda pt: (SECP256K1_BETA * pt[0] % p, pt[1])
-        for j, (k, pt) in enumerate(
-                ((a, g), (b, phi(g)), (c, q), (d, phi(q)))):
-            if k < 0:
-                k, pt = -k, (pt[0], (p - pt[1]) % p)
-            scalars[j].append(k)
-            pts_cols[j].append(pt)
-    bits4 = np.stack([F.scalars_to_bits(scalars[j], GLV_BITS)
-                      for j in range(4)], axis=-1)  # (GLV_BITS, B, 4)
-    pts4 = tuple(_points_to_limbs(col) for col in pts_cols)
-    r_cands = jnp.asarray(np.stack(
-        [F.to_limbs(r0), F.to_limbs(r1)]).astype(np.uint16))
-    return jnp.asarray(bits4), pts4, r_cands, precheck
 
 
 # ---------------------------------------------------------------------------
@@ -818,13 +734,13 @@ def g_window_table_single_device(curve: WeierstrassCurve, w: int,
         lambda: _g_window_table_single(curve, w, shift))
 
 
-#: Constant-G window width for the single-scalar windowed ladder (r1).
+#: Constant-G window width of the r1 split ladder (both tables).
 R1_G_WINDOW = 16
 
 
-#: Per-item Q window width for the single-scalar ladder: 4-bit windows
-#: over a 16-entry {0..15}·Q per-batch table (14-op build) — 64 table
-#: adds instead of the 2-bit windows' 128 (measured on v5e, BASELINE r5).
+#: Per-item Q window width of the r1 split ladder: 4-bit windows over a
+#: 16-entry {0..15}·Q per-batch table (14-op build), half the table adds
+#: of 2-bit windows (measured on v5e in an early round).
 R1_Q_WINDOW = 4
 
 
@@ -842,112 +758,6 @@ def _q_table_single(Q, curve: WeierstrassCurve):
     return T
 
 
-def windowed_ladder_single(g_idx, q_digits, Q, gtab,
-                           curve: WeierstrassCurve, w: int):
-    """[u1]G + [u2]Q for a curve without an endomorphism: per outer step,
-    ``w`` bits — w doublings, w/4 Q adds (4-bit per-item windows over the
-    16-entry {0..15}Q table) and ONE mixed G add gathered from the
-    2^w-entry affine table (flag-selected identity rows). The r1 sibling
-    of hybrid_ladder_wide; it replaces the 256-add plain Shamir ladder.
-
-    ``g_idx``: (256/w, B); ``q_digits``: (256/w, w/4, B) 4-bit digits;
-    ``Q``: affine (x, y) limb pair."""
-    tab_x, tab_y, tab_ok = gtab
-    # shape consistency against the static w (a mismatched caller would
-    # otherwise be silently governed by the array shapes alone)
-    assert g_idx.shape[0] * w == 256 and q_digits.shape[1] * 4 == w, \
-        (g_idx.shape, q_digits.shape, w)
-    assert tab_x.shape[0] == 1 << w, (tab_x.shape, w)
-    q_tab = _q_table_single(Q, curve)
-
-    def q_addend(dig):
-        return select_tree(q_tab, dig)
-
-    def g_add(acc, gi):
-        q2 = (tab_x[gi].astype(jnp.uint64), tab_y[gi].astype(jnp.uint64))
-        added = _madd_w(acc, q2, curve)
-        ok = tab_ok[gi].astype(jnp.bool_)
-        return tuple(F.select(ok, new_c, acc_c)
-                     for new_c, acc_c in zip(added, acc))
-
-    def q_step(acc, dig):
-        acc = dbl(dbl(dbl(dbl(acc, curve), curve), curve), curve)
-        return add(acc, q_addend(dig), curve), None
-
-    def step(acc, ins):
-        gi, digs = ins
-        acc, _ = jax.lax.scan(q_step, acc, digs)
-        return g_add(acc, gi), None
-
-    # peel step 0 (accumulator starts as the identity)
-    acc = q_addend(q_digits[0][0])
-    acc, _ = jax.lax.scan(q_step, acc, q_digits[0][1:])
-    acc = g_add(acc, g_idx[0])
-    acc, _ = jax.lax.scan(step, acc, (g_idx[1:], q_digits[1:]))
-    return acc
-
-
-def verify_core_windowed_single(g_idx, q_digits, Q, r_limbs, rn_ok,
-                                tab_x, tab_y, tab_ok, curve_name: str,
-                                w: int):
-    g_idx = jnp.asarray(g_idx, jnp.int32)
-    q_digits = jnp.asarray(q_digits, jnp.uint64)
-    Q = tuple(jnp.asarray(c, jnp.uint64) for c in Q)
-    r_limbs = jnp.asarray(r_limbs, jnp.uint64)
-    rn_ok = jnp.asarray(rn_ok).astype(jnp.bool_)
-    curve = CURVES[curve_name]
-    X, Y, Z = windowed_ladder_single(g_idx, q_digits, Q,
-                                     (tab_x, tab_y, tab_ok), curve, w)
-    return _accept_rn(X, Z, r_limbs, rn_ok, curve.p, curve.n)
-
-
-_verify_kernel_windowed_single = jax.jit(
-    verify_core_windowed_single, static_argnames=("curve_name", "w"))
-
-
-def prepare_batch_windowed_single(curve: WeierstrassCurve, items,
-                                  w: int = R1_G_WINDOW):
-    """Host prep for the single-scalar windowed kernel: u1 → w-bit G-table
-    indices, u2 → 4-bit Q digits (R1_Q_WINDOW) grouped per outer step, Q
-    affine, r + the r+n-valid flag, the device-committed G table (appended
-    before precheck so ``*args, precheck`` callers pass through)."""
-    from . import scalarprep as sp
-    if w == 16 and curve.name == "secp256r1" and sp.available():
-        return _prepare_windowed_single_native_words(
-            *_items_to_words(items), w)
-    return _prepare_windowed_single_python(curve, items, w)
-
-
-def _prepare_windowed_single_native_words(e_words, r_words, s_words,
-                                          pub_words, w: int):
-    """Word-form core of the native r1 prep (see
-    _prepare_hybrid_native_words)."""
-    from . import scalarprep as sp
-    curve = CURVES["secp256r1"]
-    (g_idx, q_digits, q_x, q_y, r_limbs, rn_ok,
-     precheck) = sp.r1_prep(e_words, r_words, s_words, pub_words)
-    return (jnp.asarray(g_idx),
-            jnp.asarray(q_digits.reshape(256 // w, w // 4, len(e_words))),
-            (jnp.asarray(q_x), jnp.asarray(q_y)),
-            jnp.asarray(r_limbs), jnp.asarray(rn_ok),
-            *g_window_table_single_device(curve, w), precheck)
-
-
-def _prepare_windowed_single_python(curve: WeierstrassCurve, items,
-                                    w: int = R1_G_WINDOW):
-    precheck, pubs, u1s, u2s, r0, _ = _precheck_and_scalars(curve, items)
-    g_idx = _bits_to_w_windows(F.scalars_to_bits(u1s), w).astype(np.int32)
-    digs = _bits_to_w_windows(F.scalars_to_bits(u2s),
-                              R1_Q_WINDOW).astype(np.uint8)
-    q_digits = digs.reshape(256 // w, w // 4, *digs.shape[1:])
-    r_limbs = jnp.asarray(F.to_limbs(r0).astype(np.uint16))
-    rn_ok = jnp.asarray(np.asarray(
-        [r + curve.n < curve.p for r in r0], dtype=np.uint8))
-    return (jnp.asarray(g_idx), jnp.asarray(q_digits),
-            _points_to_limbs_affine(pubs), r_limbs, rn_ok,
-            *g_window_table_single_device(curve, w), precheck)
-
-
 # ---------------------------------------------------------------------------
 # Half-gcd split path (secp256r1): [t_lo]G + [t_hi]G' + [|v1|](±Q) ?= [v2]R
 # ---------------------------------------------------------------------------
@@ -957,7 +767,7 @@ def _prepare_windowed_single_python(curve: WeierstrassCurve, items,
 # u2·v2 ≡ ±v1 (mod n). Multiplying the ECDSA equation X = [u1]G + [u2]Q by
 # v2 gives [t]G ± [v1]Q = [v2]X with t = v2·u1 mod n — t is full-width, but
 # splitting it at 2^128 against a second constant table G' = [2^128]G keeps
-# every DOUBLING run at 128 bits: 124 doublings instead of the windowed
+# every DOUBLING run at 128 bits: 124 doublings instead of a full-width
 # ladder's 252. The host decompresses R = (r, y) and computes
 # x_D = x([v2]R) (one Jacobian ladder + ONE batch inversion per batch);
 # the device accepts iff x(W2) == x_D projectively — parity-insensitive,
@@ -970,23 +780,6 @@ def _prepare_windowed_single_python(curve: WeierstrassCurve, items,
 # craftable), r not a quadratic-residue x-coordinate, or a defensive
 # half-gcd bound failure. Precheck failures keep hg_ok=1: their verdict is
 # already False and their zeroed windows make W2 = ∞ on device.
-
-_R1_HG_STATS = {"items": 0, "fallback": 0}
-_R1_HG_LOCK = threading.Lock()
-
-
-def _record_hg_stats(items: int, fallback: int) -> None:
-    with _R1_HG_LOCK:
-        _R1_HG_STATS["items"] += int(items)
-        _R1_HG_STATS["fallback"] += int(fallback)
-
-
-def r1_split_stats() -> dict:
-    """Process-cumulative half-gcd split counters: items prepped through
-    the split path and how many fell back to the host oracle (hg_ok=0)."""
-    with _R1_HG_LOCK:
-        return dict(_R1_HG_STATS)
-
 
 def _r1_host_verify_scalars(curve: WeierstrassCurve, pub, e_raw: int,
                             r: int, s: int) -> bool:
@@ -1095,11 +888,11 @@ def prepare_batch_r1_split(curve: WeierstrassCurve, items,
 
 def _r1_split_pack(curve, g_idx, q_digits, q_pts, xd_limbs, hg_ok,
                    precheck, forced, w: int):
-    """Shared tail of both split preps: fallback accounting, window
-    reshapes, and the two G tables (plain G and G' = [2^128]G)."""
+    """Shared tail of both split preps: window reshapes, the fallback
+    rows masked out of precheck, and the two G tables (plain G and
+    G' = [2^128]G)."""
     B = len(precheck)
     hg = np.asarray(hg_ok, dtype=bool)
-    _record_hg_stats(B, int((precheck & ~hg).sum()))
     return (jnp.asarray(g_idx.reshape(128 // w, 2, B)),
             jnp.asarray(q_digits.reshape(128 // w, w // 4, B)),
             q_pts, jnp.asarray(xd_limbs),
@@ -1467,90 +1260,32 @@ def prepare_batch(curve: WeierstrassCurve,
 
 
 
-def verify_batch(curve: WeierstrassCurve,
-                 items: list[tuple[tuple[int, int] | None, bytes, int, int]],
-                 mode: str = "auto") -> np.ndarray:
-    """Batched ECDSA verify: [(pub_affine, msg, r, s)] → bool verdicts (B,).
-
-    Pads to a power-of-two bucket (replicating the last item) so the device
-    kernel compiles once per bucket size. ``mode``:
-    - "auto": the fastest measured path — "hybrid" (GLV) for secp256k1,
-      "halfgcd" for secp256r1, "windowed" otherwise.
-    - "hybrid": GLV half-length ladder with the constant-G gather table.
-    - "halfgcd": the Antipa split ladder — 128-bit legs against the G and
-      [2^128]G tables, host [v2]R comparand, per-item host fallback
-      (r1_split_ladder — the r1 production path).
-    - "windowed": single-scalar constant-G windows + 4-bit Q windows
-      (windowed_ladder_single — kept as the r1 A/B reference path).
-    - "glv": the all-select GLV ladder (kept for differential testing —
-      measured at parity with plain: the 15-select tree eats the saved ops).
-    - "plain": the 256-bit two-scalar Shamir ladder.
-    """
+def verify_batch_plain(curve: WeierstrassCurve,
+                       items: list[tuple[tuple[int, int] | None, bytes, int, int]]
+                       ) -> np.ndarray:
+    """The plain reference: [(pub_affine, msg, r, s)] → bool verdicts (B,)
+    through the 256-bit two-scalar Shamir ladder (:func:`verify_core`), on
+    either curve. What the differential tests hold the production ladders
+    to on the device side; nothing ships it."""
     n = len(items)
     if n == 0:
         return np.zeros(0, dtype=bool)
     padded = items + [items[-1]] * (F.bucket_size(n) - n)
-    if mode == "auto":
-        mode = {"secp256k1": "hybrid",
-                "secp256r1": "halfgcd"}.get(curve.name, "windowed")
-    if mode not in ("plain", "glv", "hybrid", "windowed", "halfgcd"):
-        raise ValueError(f"unknown verify mode {mode!r}")
-    if mode in ("glv", "hybrid") and curve.name != "secp256k1":
-        raise ValueError(f"mode {mode!r} requires secp256k1")
-    if mode == "halfgcd" and curve.name != "secp256r1":
-        raise ValueError(f"mode {mode!r} requires secp256r1")
-    from ..observability.profiling import get_profiler
-    prof = get_profiler()
-    if mode == "halfgcd":
-        *args, precheck, forced = prepare_batch_r1_split(curve, padded)
-        ok = np.asarray(prof.call(
-            "weierstrass.r1_split", _verify_kernel_r1_split, *args,
-            curve_name=curve.name, w=R1_G_WINDOW,
-            live=n, capacity=len(padded), scheme=curve.name))
-        return ((ok & precheck) | forced)[:n]
-    if mode == "hybrid":
-        *args, precheck = prepare_batch_hybrid_wide(padded, HYBRID_G_WINDOW)
-        ok = np.asarray(prof.call(
-            "weierstrass.hybrid_k1", _verify_kernel_hybrid_wide, *args,
-            g_w=HYBRID_G_WINDOW,
-            live=n, capacity=len(padded), scheme=curve.name))
-    elif mode == "windowed":
-        *args, precheck = prepare_batch_windowed_single(curve, padded,
-                                                        R1_G_WINDOW)
-        ok = np.asarray(prof.call(
-            "weierstrass.windowed", _verify_kernel_windowed_single, *args,
-            curve_name=curve.name, w=R1_G_WINDOW,
-            live=n, capacity=len(padded), scheme=curve.name))
-    elif mode == "glv":
-        bits4, pts4, r_cands, precheck = prepare_batch_glv(padded)
-        ok = np.asarray(_verify_kernel_glv(bits4, pts4, r_cands))
-    else:
-        u1_bits, u2_bits, q_pts, r_cands, precheck = prepare_batch(curve, padded)
-        ok = np.asarray(_verify_kernel(u1_bits, u2_bits, q_pts, r_cands,
-                                       curve.name))
+    u1_bits, u2_bits, q_pts, r_cands, precheck = prepare_batch(curve, padded)
+    ok = np.asarray(_verify_kernel(u1_bits, u2_bits, q_pts, r_cands,
+                                   curve.name))
     return (ok & precheck)[:n]
 
 
-def _service_kernel_hybrid_wide():
-    """Donated-jit twin of ``_verify_kernel_hybrid_wide`` for the async
-    service path: the four per-batch wire arrays (g_idx, q_bits, pts,
-    r_limbs) are donated so XLA reuses their device memory for the
-    batch's temporaries; the G-table args are committed
-    device_table_cache buffers and are NEVER donated. Kept separate from
-    the plain handle so synchronous callers that re-invoke with the same
-    prepared args (bench's _kernel_rate) keep valid buffers."""
-    return F.donating_jit("weierstrass.hybrid_wide.donated",
-                          verify_core_hybrid_wide, (0, 1, 2, 3),
-                          static_argnames=("g_w",))
-
-
-def _service_kernel_r1_split():
-    """Donated-jit twin of ``_verify_kernel_r1_split`` (same rules as
-    :func:`_service_kernel_hybrid_wide`; argnum 2 donates the whole Q
-    2-tuple pytree)."""
-    return F.donating_jit("weierstrass.r1_split.donated",
-                          verify_core_r1_split, (0, 1, 2, 3),
-                          static_argnames=("curve_name", "w"))
+def verify_batch(curve: WeierstrassCurve,
+                 items: list[tuple[tuple[int, int] | None, bytes, int, int]]
+                 ) -> np.ndarray:
+    """Batched ECDSA verify: [(pub_affine, msg, r, s)] → bool verdicts (B,)
+    through the curve's production ladder (the hybrid GLV ladder for
+    secp256k1, the half-gcd split for secp256r1). Pads to a power-of-two
+    bucket (replicating the last item) so the device kernel compiles once
+    per bucket size."""
+    return finish_batch(verify_batch_async(curve, items))
 
 
 def verify_batch_async(curve: WeierstrassCurve,
@@ -1558,8 +1293,8 @@ def verify_batch_async(curve: WeierstrassCurve,
     """Dispatch a verify batch WITHOUT forcing the result: returns an opaque
     pending handle for :func:`finish_batch`. The device computes while the
     caller preps the next batch (the service batcher's one-deep pipeline —
-    host prep was ~2/3 of the unpipelined service-path cost). Per-batch
-    device buffers are donated (see :func:`_service_kernel_hybrid_wide`)."""
+    host prep was ~2/3 of the unpipelined service-path cost). The item
+    form: what the batcher takes on a host without libscalarmath.so."""
     from ..observability.profiling import get_profiler
     prof = get_profiler()
     n = len(items)
@@ -1568,38 +1303,24 @@ def verify_batch_async(curve: WeierstrassCurve,
     padded = items + [items[-1]] * (F.bucket_size(n) - n)
     if curve.name == "secp256k1":
         *args, precheck = prepare_batch_hybrid_wide(padded, HYBRID_G_WINDOW)
-        return (prof.call("weierstrass.hybrid_k1",
-                          _service_kernel_hybrid_wide(),
+        return (prof.call("weierstrass.hybrid_k1", _verify_kernel_hybrid_wide,
                           *args, g_w=HYBRID_G_WINDOW, live=n,
                           capacity=len(padded), scheme=curve.name),
                 precheck, n)
-    if curve.name == "secp256r1":
-        *args, precheck, forced = prepare_batch_r1_split(curve, padded)
-        return (prof.call("weierstrass.r1_split", _service_kernel_r1_split(),
-                          *args, curve_name=curve.name, w=R1_G_WINDOW,
-                          live=n, capacity=len(padded), scheme=curve.name),
-                precheck, n, forced)
-    *args, precheck = prepare_batch_windowed_single(curve, padded,
-                                                    R1_G_WINDOW)
-    return (prof.call("weierstrass.windowed", _verify_kernel_windowed_single,
+    *args, precheck, forced = prepare_batch_r1_split(curve, padded)
+    return (prof.call("weierstrass.r1_split", _verify_kernel_r1_split,
                       *args, curve_name=curve.name, w=R1_G_WINDOW,
                       live=n, capacity=len(padded), scheme=curve.name),
-            precheck, n)
+            precheck, n, forced)
 
 
 def words_prep_available(curve: WeierstrassCurve) -> bool:
     """True when the word-form fast path (:func:`verify_batch_async_words`)
-    covers ``curve``: native scalar prep present AND the production window
-    configs match the native kernels' fixed widths (k1 g_w = 8, r1 w = 16
-    — the only widths scalarmath.cpp implements)."""
+    covers ``curve``: one of the two curves scalarmath.cpp prepares (at the
+    production widths, k1 g_w = 8 and r1 w = 16: the only ones it
+    implements) and the library present."""
     from . import scalarprep as sp
-    if not sp.available():
-        return False
-    if curve.name == "secp256k1":
-        return HYBRID_G_WINDOW == 8
-    if curve.name == "secp256r1":
-        return R1_G_WINDOW == 16
-    return False
+    return curve.name in CURVES and sp.available()
 
 
 def pad_word_rows(arrays, m: int, staging=None, tags=None):
@@ -1634,9 +1355,9 @@ def verify_batch_async_words(curve: WeierstrassCurve, e_words, r_words,
     parse, e from digests_to_words), skipping the per-item decompress +
     DER + to_bytes loop entirely. Same pending/finish contract as
     :func:`verify_batch_async`; callers gate on words_prep_available.
-    Padding goes through reused staging buffers and the kernel call uses
-    the donated twin, so steady-state flushes neither allocate fresh host
-    rows nor leave stale device input buffers behind. The native scalar
+    Padding goes through reused staging buffers, so steady-state flushes
+    allocate no fresh host rows; the kernel call is the module's one jit
+    handle of the curve's ladder. The native scalar
     prep (range check, s^-1, the split, window digits) is the span
     ``ecdsa.prep.scalars`` under ``trace_parent``, the caller's span; the
     padding before it is ``ecdsa.prep.pad`` and the jitted call alone,
@@ -1679,13 +1400,13 @@ def verify_batch_async_words(curve: WeierstrassCurve, e_words, r_words,
                      capacity=capacity, **span_tags) as launch_span:
         if k1:
             dev = prof.call("weierstrass.hybrid_k1",
-                            _service_kernel_hybrid_wide(),
+                            _verify_kernel_hybrid_wide,
                             *args, g_w=HYBRID_G_WINDOW, live=n,
                             capacity=capacity, scheme=curve.name,
                             trace_span=launch_span)
         else:
             dev = prof.call("weierstrass.r1_split",
-                            _service_kernel_r1_split(),
+                            _verify_kernel_r1_split,
                             *args, curve_name=curve.name, w=R1_G_WINDOW,
                             live=n, capacity=capacity, scheme=curve.name,
                             trace_span=launch_span)

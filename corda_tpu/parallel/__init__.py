@@ -11,8 +11,7 @@ from .sharded import (  # noqa: F401
     make_mesh,
     make_shard_mesh,
     shard_devices,
-    sharded_ed25519_verify,
-    sharded_ecdsa_verify,
+    sharded_ed25519_verify_split,
     sharded_ecdsa_verify_hybrid,
     sharded_merkle_root,
     sharded_verify_batch_ed25519,

@@ -17,7 +17,6 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -28,17 +27,6 @@ from ..ops import weierstrass as wc_ops
 from ..ops.staging import get_staging_pool
 
 AXIS = "chips"
-
-
-def _jit_donating_batch(shmapped, donate_argnums=(0, 1, 2, 3)):
-    """jit a shard_mapped verify kernel with its per-batch leading args
-    donated (the wire-form arrays rebuilt every flush), so XLA reuses
-    their device memory for the batch's temporaries. The replicated
-    constant tables at higher argnums are cached per mesh and must NEVER
-    be donated. CPU backends don't support donation — gated off there."""
-    if F.donation_supported():
-        return jax.jit(shmapped, donate_argnums=donate_argnums)
-    return jax.jit(shmapped)
 
 
 def make_mesh(n_devices: int | None = None, devices=None) -> Mesh:
@@ -92,43 +80,6 @@ def _check_batch(b: int, mesh: Mesh, what: str) -> None:
                          "(pad to a bucket first)")
 
 
-def sharded_ed25519_verify(mesh: Mesh):
-    """Returns jitted fn over ed25519 kernel inputs, batch-sharded on `mesh`.
-
-    Input layout (from ops.ed25519.prepare_batch): s_bits/k_bits (256, B);
-    neg_a 4×(B, 16); r_affine 2×(B, 16). Output ok (B,), sharded.
-    """
-    bits_spec = P(None, AXIS)
-    pt_spec = P(AXIS, None)
-    shmapped = jax.shard_map(
-        ed_ops.verify_core, mesh=mesh,
-        in_specs=(bits_spec, bits_spec, (pt_spec,) * 4, (pt_spec,) * 2),
-        out_specs=P(AXIS),
-        # the ladder scan's carry starts as replicated constants but becomes
-        # device-varying after the first add; VMA can't express that promotion
-        check_vma=False)
-    return jax.jit(shmapped)
-
-
-def sharded_ed25519_verify_windowed(mesh: Mesh):
-    """Batch-sharded Ed25519 verify over the WINDOWED constant-B kernel —
-    the production service path (ops.ed25519.verify_core_windowed): Niels
-    base table replicated per chip, batch axis sharded.
-
-    Input layout (from ops.ed25519.prepare_batch_windowed): b_idx
-    (256/w, B); a_digits (256/w, w/2, B); neg_a 4×(B, 16); r_y (B, 16);
-    r_sign (B,); the three Niels table arrays replicated."""
-    core = functools.partial(ed_ops.verify_core_windowed, w=ed_ops.B_WINDOW)
-    shmapped = jax.shard_map(
-        core, mesh=mesh,
-        in_specs=(P(None, AXIS), P(None, None, AXIS),
-                  (P(AXIS, None),) * 4, P(AXIS, None), P(AXIS),
-                  P(None, None), P(None, None), P(None, None)),
-        out_specs=P(AXIS),
-        check_vma=False)  # see sharded_ed25519_verify
-    return jax.jit(shmapped)
-
-
 def sharded_ed25519_verify_split(mesh: Mesh):
     """Batch-sharded Ed25519 verify over the SPLIT-K half-length ladder —
     the fastest single-chip path (ops.ed25519.verify_core_split), scaled
@@ -146,24 +97,9 @@ def sharded_ed25519_verify_split(mesh: Mesh):
                   P(AXIS, None, None), P(AXIS, None),
                   *((P(None, None),) * 6)),
         out_specs=P(AXIS),
-        check_vma=False)  # see sharded_ed25519_verify
-    return _jit_donating_batch(shmapped)
-
-
-def sharded_ecdsa_verify(mesh: Mesh, curve_name: str):
-    """Same as sharded_ed25519_verify for the Weierstrass ECDSA kernel.
-
-    Input layout (from ops.weierstrass.prepare_batch): u1/u2 bits (256, B);
-    q_pts 3×(B, 16); r_cands (2, B, 16).
-    """
-    core = functools.partial(wc_ops.verify_core, curve_name=curve_name)
-    bits_spec = P(None, AXIS)
-    pt_spec = P(AXIS, None)
-    shmapped = jax.shard_map(
-        core, mesh=mesh,
-        in_specs=(bits_spec, bits_spec, (pt_spec,) * 3, P(None, AXIS, None)),
-        out_specs=P(AXIS),
-        check_vma=False)  # see sharded_ed25519_verify
+        # the ladder scan's carry starts as replicated constants but becomes
+        # device-varying after the first add; VMA can't express that promotion
+        check_vma=False)
     return jax.jit(shmapped)
 
 
@@ -185,8 +121,8 @@ def sharded_ecdsa_verify_hybrid(mesh: Mesh):
                   P(AXIS, None, None), P(AXIS, None),
                   P(None, None), P(None, None), P(None)),
         out_specs=P(AXIS),
-        check_vma=False)  # see sharded_ed25519_verify
-    return _jit_donating_batch(shmapped)
+        check_vma=False)  # see sharded_ed25519_verify_split
+    return jax.jit(shmapped)
 
 
 def sharded_merkle_root(mesh: Mesh):
@@ -219,7 +155,6 @@ def _pad_to_mesh_bucket(n: int, mesh: Mesh) -> int:
     """Bucket size that is mesh-divisible with a power-of-two PER-SHARD
     count (one compile per per-shard bucket). Computed as pow2(ceil(n/d))·d
     so it terminates for any device count, including non-powers-of-two."""
-    from ..ops import field as F
     d = mesh.devices.size
     return F.bucket_size(-(-n // d)) * d
 
@@ -245,8 +180,8 @@ def sharded_verify_batch_ed25519(mesh: Mesh, items, _cache={}):
     """[(pub32, sig64, msg)] → bool verdicts (B,), the batch dp-sharded over
     ``mesh`` — the drop-in mesh backend for the SignatureBatcher
     (ops.ed25519.verify_batch semantics, N chips instead of one). Rides
-    the windowed constant-B kernel with the Niels table replicated once
-    per mesh."""
+    the split-k kernel with both Niels tables (B and [2^128]B) replicated
+    once per mesh."""
     n = len(items)
     if n == 0:
         return np.zeros(0, dtype=bool)
@@ -347,8 +282,8 @@ def sharded_ecdsa_verify_r1_split(mesh: Mesh):
                   P(None, None), P(None, None), P(None),
                   P(None, None), P(None, None), P(None)),
         out_specs=P(AXIS),
-        check_vma=False)  # see sharded_ed25519_verify
-    return _jit_donating_batch(shmapped)
+        check_vma=False)  # see sharded_ed25519_verify_split
+    return jax.jit(shmapped)
 
 
 def _r1_mesh_fn(mesh: Mesh, _cache={}):

@@ -1321,12 +1321,11 @@ class SignatureBatcher:
         return verdicts
 
     def _run_ed25519(self, items: list[_Pending]):
+        """The mesh's Ed25519 batch, forced (``_dispatch_device`` comes
+        here only with a mesh)."""
+        from ..parallel import sharded_verify_batch_ed25519
         triples = [(p.key.encoded, p.signature, p.content) for p in items]
-        if self.mesh is not None:
-            from ..parallel import sharded_verify_batch_ed25519
-            return sharded_verify_batch_ed25519(self.mesh, triples)
-        from ..ops import ed25519 as ed_ops
-        return ed_ops.verify_batch(triples)
+        return sharded_verify_batch_ed25519(self.mesh, triples)
 
     def _start_ed25519(self, items: list[_Pending], dspan=None):
         """Prep + async launch of one Ed25519 batch, taken in bulk as

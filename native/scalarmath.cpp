@@ -3,7 +3,7 @@
 // The TPU device kernels (corda_tpu/ops/{weierstrass,ed25519}.py) consume
 // pre-derived scalars/window indices/limb arrays; deriving them per item in
 // Python bigints was the service path's ceiling (~0.9s per 32k secp256k1
-// batch, ~1.9s for Ed25519 — BASELINE.md round-4 close-out).  This module
+// batch, ~1.9s for Ed25519, measured in an early round).  This module
 // does the whole scalar layer in one C pass per batch:
 //   - Barrett modular arithmetic over the fixed curve moduli
 //   - Montgomery batch inversion (one Fermat modpow per BATCH)
@@ -788,7 +788,7 @@ inline void ed_split_windows(const u64 s[4], const u64 k[4], int64_t stride,
 
 extern "C" {
 
-int sm_version() { return 6; }
+int sm_version() { return 7; }
 
 // Differential-test seam: r = a*b mod m for mod_id in
 // {0: k1 n, 1: k1 p, 2: r1 n, 3: r1 p, 4: ed L, 5: ed P}.
@@ -985,91 +985,6 @@ int sm_k1_prep(int64_t n,
     return 0;
 }
 
-// secp256r1 single-scalar windowed prep (mirrors
-// weierstrass.prepare_batch_windowed_single for w = 16).
-int sm_r1_prep(int64_t n,
-               const u64* e, const u64* rr, const u64* ss, const u64* pub,
-               int32_t* g_idx,      // (16, n): w=16 windows of u1
-               u8* q_digits,        // (64, n): 4-bit digits of u2
-               u16* q_x, u16* q_y,  // (n,16)
-               u16* r_limbs, u8* rn_ok, u8* precheck,
-               u64* work)           // scratch: 3*n*4 words
-{
-    const Ctx& C = ctx();
-    const Mod* N = &C.r1n;
-    const Mod* P = &C.r1p;
-    u64* sw = work;
-    u64* scratch = work + 4 * n;
-    u64* em = work + 8 * n;
-    for (int64_t i = 0; i < n; ++i) {
-        const u64* r4 = rr + 4 * i;
-        const u64* s4 = ss + 4 * i;
-        const u64* x4 = pub + 8 * i;
-        const u64* y4 = pub + 8 * i + 4;
-        bool ok = !mp_is_zero(r4, 4) && mp_cmp(r4, N->m, 4) < 0
-               && !mp_is_zero(s4, 4) && mp_cmp(s4, N->m, 4) < 0
-               && on_curve(P, x4, y4, R1_B, true);
-        precheck[i] = ok ? 1 : 0;
-        if (ok) {
-            mp_copy(sw + 4 * i, s4, 4);
-            const u64* e4 = e + 4 * i;
-            if (mp_cmp(e4, N->m, 4) >= 0) mp_sub(em + 4 * i, e4, N->m, 4);
-            else mp_copy(em + 4 * i, e4, 4);
-        } else {
-            u64 one[4] = {1, 0, 0, 0};
-            mp_copy(sw + 4 * i, one, 4);
-            mp_zero(em + 4 * i, 4);
-        }
-    }
-    batch_inv(N, sw, n, scratch);
-    const u64 R1GX[4] = {0xF4A13945D898C296ull, 0x77037D812DEB33A0ull,
-                         0xF8BCE6E563A440F2ull, 0x6B17D1F2E12C4247ull};
-    const u64 R1GY[4] = {0xCBB6406837BF51F5ull, 0x2BCE33576B315ECEull,
-                         0x8EE7EB4A7C0F9E16ull, 0x4FE342E2FE1A7F9Bull};
-    for (int64_t i = 0; i < n; ++i) {
-        bool ok = precheck[i];
-        u64 u1[4], u2[4];
-        if (ok) {
-            mod_mul(N, em + 4 * i, sw + 4 * i, u1);
-            u64 rmod[4];
-            mp_copy(rmod, rr + 4 * i, 4);
-            mod_mul(N, rmod, sw + 4 * i, u2);
-        } else {
-            mp_zero(u1, 4);
-            mp_zero(u2, 4);
-        }
-        u64 qx[4], qy[4];
-        if (ok) {
-            mp_copy(qx, pub + 8 * i, 4);
-            mp_copy(qy, pub + 8 * i + 4, 4);
-        } else {
-            mp_copy(qx, R1GX, 4);
-            mp_copy(qy, R1GY, 4);
-        }
-        write_limbs(q_x + 16 * i, qx);
-        write_limbs(q_y + 16 * i, qy);
-        for (int t = 0; t < 16; ++t) {
-            int shift = 16 * (15 - t);
-            g_idx[(int64_t)t * n + i] =
-                (int32_t)((u1[shift / 64] >> (shift % 64)) & 0xFFFF);
-        }
-        for (int t = 0; t < 64; ++t) {
-            int shift = 4 * (63 - t);
-            q_digits[(int64_t)t * n + i] =
-                (u8)((u2[shift / 64] >> (shift % 64)) & 0xF);
-        }
-        const u64* r4 = rr + 4 * i;
-        u64 rw[4];
-        if (ok) mp_copy(rw, r4, 4);
-        else mp_zero(rw, 4);
-        write_limbs(r_limbs + 16 * i, rw);
-        u64 rn[4];
-        u64 carry = mp_add(rn, rw, N->m, 4);
-        rn_ok[i] = (!carry && mp_cmp(rn, P->m, 4) < 0) ? 1 : 0;
-    }
-    return 0;
-}
-
 // Differential-test seam for the half-gcd split: k (4 LE words, 0 < k < n)
 // → neg1, v1, v2 (2 words each) with k*v2 ≡ (neg1 ? -v1 : v1) (mod n) and
 // both legs < 2^128.  Returns -2 when the split degenerates.
@@ -1232,36 +1147,6 @@ int sm_r1_prep_hg(int64_t n,
     return 0;
 }
 
-// Ed25519 split-k scalar prep: s (wire LE), h (raw SHA-512 LE) →
-// k = h mod L; windows for the split ladder (s_lo/s_hi w=16 constant-base
-// windows, joint 2-bit (k_lo, k_hi) digits).  A-point handling (decompress,
-// [2^128]A) stays in Python (per-signer cached).
-int sm_ed_prep(int64_t n,
-               const u64* h,        // (n, 8)
-               const u64* ss,       // (n, 4)
-               int32_t* b_idx,      // (8, n): w=16 windows of s_lo, MSB-first
-               int32_t* b2_idx,     // (8, n): w=16 windows of s_hi
-               u8* a_packed,        // (64, n): klo | khi<<2 2-bit digits
-               u8* s_ok)            // (n,)
-{
-    const Mod* L = &ctx().edl;
-    for (int64_t i = 0; i < n; ++i) {
-        const u64* s4 = ss + 4 * i;
-        bool ok = mp_cmp(s4, L->m, 4) < 0;
-        s_ok[i] = ok ? 1 : 0;
-        u64 s[4], k[4];
-        if (ok) {
-            mp_copy(s, s4, 4);
-            mod_red(L, h + 8 * i, k);
-        } else {
-            mp_zero(s, 4);
-            mp_zero(k, 4);
-        }
-        ed_split_windows(s, k, n, i, b_idx, b2_idx, a_packed);
-    }
-    return 0;
-}
-
 // The whole Ed25519 split-k prep of a batch in one call: what
 // ops/ed25519.py prepare_batch_split returns, for all `cap` rows, from the
 // rows' wire bytes.  Row i < n: signature sigs[sum(sig_len[..i]) ..) of
@@ -1275,7 +1160,7 @@ int sm_ed_prep(int64_t n,
 //   bb_idx    w=16 windows of s_lo (rows 0..7) and s_hi (8..15), MSB-first
 //   a_packed  klo | khi<<2 2-bit digits of k = SHA-512(R || A || M) mod L
 //   precheck  length, key, y < p and s < L all passed (s, k := 0 where
-//             s >= L, as sm_ed_prep)
+//             s >= L)
 // Rows n .. cap-1 repeat row n-1 (the kernels' padding).  Returns -1 on bad
 // sizes, -2 on a slot out of range, -3 on lengths that overrun a buffer.
 int sm_ed_prep_words(int64_t n, int64_t cap,
@@ -1352,41 +1237,6 @@ int sm_ed_prep_words(int64_t n, int64_t cap,
         for (int t = 0; t < 64; ++t) {
             u8* row = a_packed + (int64_t)t * cap;
             std::memset(row + n, row[n - 1], (size_t)(cap - n));
-        }
-    }
-    return 0;
-}
-
-// Plain (non-split) Ed25519 prep for the legacy windowed kernel: w=16
-// windows of full s, 2-bit digits of full k.
-int sm_ed_prep_plain(int64_t n,
-                     const u64* h, const u64* ss,
-                     int32_t* b_idx,      // (16, n)
-                     u8* a_digits,        // (128, n)
-                     u8* s_ok)
-{
-    const Mod* L = &ctx().edl;
-    for (int64_t i = 0; i < n; ++i) {
-        const u64* s4 = ss + 4 * i;
-        bool ok = mp_cmp(s4, L->m, 4) < 0;
-        s_ok[i] = ok ? 1 : 0;
-        u64 s[4], k[4];
-        if (ok) {
-            mp_copy(s, s4, 4);
-            mod_red(L, h + 8 * i, k);
-        } else {
-            mp_zero(s, 4);
-            mp_zero(k, 4);
-        }
-        for (int t = 0; t < 16; ++t) {
-            int shift = 16 * (15 - t);
-            b_idx[(int64_t)t * n + i] =
-                (int32_t)((s[shift / 64] >> (shift % 64)) & 0xFFFF);
-        }
-        for (int t = 0; t < 128; ++t) {
-            int shift = 2 * (127 - t);
-            a_digits[(int64_t)t * n + i] =
-                (u8)((k[shift / 64] >> (shift % 64)) & 3);
         }
     }
     return 0;

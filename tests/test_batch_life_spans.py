@@ -4,7 +4,7 @@ the Ed25519 prep's phases and ``batcher.launch`` under the dispatch; and with
 tracing off, no stamp and no clock that was not written or read before.
 
 No EC kernel is compiled: the device seam is a host stand-in
-(``_start_ed25519``), or the jitted kernel alone is (``_service_kernel_split``
+(``_start_ed25519``), or the jitted kernel alone is (``_verify_kernel_split``
 behind the real prep and the real ``KernelProfiler.call``)."""
 import os
 import threading
@@ -156,7 +156,7 @@ def _kernel_stand_in(monkeypatch):
 
     from corda_tpu.observability import profiling
     monkeypatch.setattr(profiling, "_PROFILER", profiling.KernelProfiler())
-    monkeypatch.setattr(ed_ops, "_service_kernel_split", lambda: kernel)
+    monkeypatch.setattr(ed_ops, "_verify_kernel_split", kernel)
     monkeypatch.setattr(ed_ops, "b_table_device", lambda w, shift=0: ())
     monkeypatch.setattr(ed_ops, "split_field_products", lambda rows, w: 0)
 

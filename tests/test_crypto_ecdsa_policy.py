@@ -270,6 +270,36 @@ def test_the_strict_parse_refuses_what_openssl_refuses(curve_name):
         assert oracle(curve_name, key, sig, msg) is False, name
 
 
+@both_curves
+def test_the_plain_reference_agrees_with_cryptography(curve_name):
+    """``verify_batch_plain``, the 256-bit Shamir ladder every differential
+    test of a production ladder trusts, held to the corpus itself: every row
+    that reaches it (a key that decodes, a signature that parses), verdict
+    for verdict. In chunks of 8 rows, the bucket
+    ``tests/test_ops_curves.py::test_ecdsa_verify_batch[*-plain]`` compiles."""
+    from corda_tpu.ops import weierstrass as wc_ops
+    curve = CURVES[curve_name][1]
+    names, items = [], []
+    for name in ROWS:
+        key, sig, msg, _meant, _why = _corpus(curve_name)[name]
+        point = keys.sec1_decompress(curve, key)
+        try:
+            r, s = ecmath.ecdsa_sig_from_der(sig)
+        except (ValueError, IndexError):
+            continue
+        if point is not None:
+            names.append(name)
+            items.append((point, msg, r, s))
+    assert {"valid_high_s_twin_0", "r_eq_0", "s_eq_n", "altered_message",
+            "another_signers_key", "r_plus_n"} <= set(names)
+    verdicts = []
+    for at in range(0, len(items), 8):
+        verdicts.extend(wc_ops.verify_batch_plain(curve, items[at:at + 8]))
+    for name, verdict in zip(names, verdicts, strict=True):
+        key, sig, msg, _meant, _why = _corpus(curve_name)[name]
+        assert bool(verdict) is oracle(curve_name, key, sig, msg), name
+
+
 # -- the device path, as the batcher drives it ---------------------------------------
 
 def _device_verdicts(curve_name: str, prep: str):
@@ -490,6 +520,7 @@ def test_no_verify_route_compares_s_with_half_the_order():
     native = (pathlib.Path(__file__).resolve().parents[1] / "native"
               / "scalarmath.cpp").read_text()
     assert "mp_cmp(s4, N->half" not in native
-    assert native.count("mp_cmp(s4, N->m, 4) < 0") == 3
+    # one range check a native ECDSA prep: sm_k1_prep, sm_r1_prep_hg
+    assert native.count("mp_cmp(s4, N->m, 4) < 0") == 2
     assert "N->half" in native                      # the GLV split's bias
     assert sp.SM_VERSION >= 5   # 4->5: the strict-DER parse is an export

@@ -8,7 +8,10 @@ prep on every deployment.  Skips LOUDLY (with the rebuild recipe) when no
 compiler is available.
 """
 import ctypes
+import inspect
 import os
+import pathlib
+import re
 import shutil
 import subprocess
 
@@ -52,3 +55,21 @@ def test_committed_so_version_matches_python_gate():
         assert _version_of(path) == sp.SM_VERSION, (path, RECIPE)
     # and the loader actually accepted it (no silent Python fallback)
     assert sp.available(), RECIPE
+
+
+def test_every_export_is_bound_and_used():
+    """What scalarmath.cpp exports is what ``_bind`` binds, and what it
+    binds something in the package calls: an export whose ladder went, or a
+    binding nobody reads, fails here and not in a reviewer's ``grep``."""
+    source = pathlib.Path(SRC).read_text()
+    block = source[source.index('extern "C" {'):]
+    exports = set(re.findall(r"^int (sm_\w+)\(", block, flags=re.M))
+    bind = inspect.getsource(sp._bind)
+    bound = set(re.findall(r"\.(sm_\w+)", bind))
+    assert exports - {"sm_version"} == bound
+    package = pathlib.Path(REPO, "corda_tpu")
+    rest = "".join(p.read_text() for p in sorted(package.rglob("*.py")))
+    assert bind in rest
+    called = set(re.findall(r"\.(sm_\w+?)(?:_held)?\b",
+                            rest.replace(bind, "")))
+    assert bound <= called, sorted(bound - called)

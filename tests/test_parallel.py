@@ -14,7 +14,8 @@ from corda_tpu.core.crypto import ecmath
 from corda_tpu.ops import ed25519 as ed_ops
 from corda_tpu.ops import sha256 as sha_ops
 from corda_tpu.parallel import (make_mesh, sharded_ecdsa_verify_hybrid,
-                                sharded_ed25519_verify, sharded_merkle_root,
+                                sharded_ed25519_verify_split,
+                                sharded_merkle_root,
                                 tx_verify_step)
 
 RNG = np.random.default_rng(11)
@@ -41,10 +42,16 @@ def _ed_items(n):
 
 
 def test_sharded_ed25519_matches_host(mesh):
-    items, want = _ed_items(16)
-    s_bits, k_bits, neg_a, r_affine, precheck = ed_ops.prepare_batch(items)
-    fn = sharded_ed25519_verify(mesh)
-    ok = np.asarray(fn(s_bits, k_bits, neg_a, r_affine)) & precheck
+    # 64 rows: the 8-rows-a-chip shape the mesh-backed batcher below compiles
+    items, want = _ed_items(64)
+    *args, precheck = ed_ops.prepare_batch_split(items, device_tables=False)
+    w = ed_ops.SPLIT_B_WINDOW
+    replicated = jax.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    tabs = [jax.device_put(t, replicated)
+            for t in (*ed_ops._b_window_table(w, 0),
+                      *ed_ops._b_window_table(w, 128))]
+    fn = sharded_ed25519_verify_split(mesh)
+    ok = np.asarray(fn(*args, *tabs)) & precheck
     assert list(ok) == want
     assert True in ok and False in list(ok)
 

@@ -156,7 +156,7 @@ def test_fallback_items_marked_and_forced():
 @needs_native
 def test_split_verdicts_match_oracle_native():
     items = _mixed_items()
-    got = wc.verify_batch(CURVE, items, mode="halfgcd")
+    got = wc.verify_batch(CURVE, items)
     np.testing.assert_array_equal(got, _oracle(items))
 
 
@@ -164,7 +164,7 @@ def test_split_verdicts_match_oracle_python(monkeypatch):
     monkeypatch.setattr(sp, "_LIB", None)
     assert not sp.available()
     items = _mixed_items()
-    got = wc.verify_batch(CURVE, items, mode="halfgcd")
+    got = wc.verify_batch(CURVE, items)
     np.testing.assert_array_equal(got, _oracle(items))
     # Crypto.doVerify's rule: the six signed rows and the n - s twin
     assert list(np.nonzero(got)[0]) == [0, 1, 2, 3, 4, 5, 10]
@@ -176,7 +176,7 @@ def test_fallback_parity_end_to_end():
     valid and invalid members, plus the async words seam."""
     items = _mixed_items()[:6] + _fallback_items()[:3]
     want = _oracle(items)
-    got = wc.verify_batch(CURVE, items, mode="halfgcd")
+    got = wc.verify_batch(CURVE, items)
     np.testing.assert_array_equal(got, want)
     if sp.available():
         pend = wc.verify_batch_async_words(CURVE, *wc._items_to_words(items))

@@ -3,7 +3,7 @@
 The native layer (native/scalarmath.cpp via ops/scalarprep.py) must be
 BIT-IDENTICAL to the Python prep it replaces — these tests lock that for
 the low-level arithmetic seams (Barrett mulmod/mod512, GLV split) and the
-full batch preps (secp256k1 hybrid, secp256r1 windowed), over valid,
+full batch preps (secp256k1 hybrid, the Ed25519 word form), over valid,
 tampered, and structurally-malformed inputs.  Mirrors the reference's
 approach of differential-testing Crypto.doVerify against test vectors
 (core/src/test/kotlin/net/corda/core/crypto/CryptoUtilsTest.kt).
@@ -90,54 +90,6 @@ def test_k1_prep_native_matches_python():
         else:
             np.testing.assert_array_equal(
                 np.asarray(a), np.asarray(b), err_msg=name)
-
-
-def test_r1_prep_native_matches_python():
-    rng = np.random.default_rng(43)
-    curve = ecmath.SECP256R1
-    items = []
-    for _ in range(12):
-        priv = int.from_bytes(rng.bytes(32), "little") % (curve.n - 1) + 1
-        pub = curve.mul(priv, curve.g)
-        msg = rng.bytes(40)
-        r, s = ecmath.ecdsa_sign(curve, priv, msg)
-        items.append((pub, msg, r, s))
-    pub0 = items[0][0]
-    items += [(None, b"x", 5, 7), (pub0, b"m", 0, 7),
-              (pub0, b"m", curve.n + 5, 7),
-              ((pub0[0], (pub0[1] + 1) % curve.p), b"m", 5, 7)]
-    native = wc.prepare_batch_windowed_single(curve, items, 16)
-    python = wc._prepare_windowed_single_python(curve, items, 16)
-    names = ["g_idx", "q_digits", "Q", "r_limbs", "rn_ok",
-             "tab_x", "tab_y", "tab_ok", "precheck"]
-    for name, a, b in zip(names, native, python):
-        if isinstance(a, tuple):
-            for i, (ac, bc) in enumerate(zip(a, b)):
-                np.testing.assert_array_equal(
-                    np.asarray(ac), np.asarray(bc), err_msg=f"{name}[{i}]")
-        else:
-            np.testing.assert_array_equal(
-                np.asarray(a), np.asarray(b), err_msg=name)
-
-
-def test_ed_split_windows_native_matches_python():
-    import hashlib
-
-    from corda_tpu.ops import ed25519 as ed
-    rng = np.random.default_rng(44)
-    digests, s_ints = [], []
-    for _ in range(40):
-        digests.append(hashlib.sha512(rng.bytes(32)).digest())
-        s_ints.append(int.from_bytes(rng.bytes(32), "little"))
-    # boundary s values: 0, L-1, L (invalid), max
-    s_ints[:4] = [0, ecmath.ED_L - 1, ecmath.ED_L, (1 << 256) - 1]
-    s_words = sp.ints_to_words(s_ints)
-    h_words = sp.le_digests_to_words(digests, 8)
-    native = sp.ed_prep(h_words, s_words)
-    python = ed._split_windows_python(digests, s_words)
-    for name, a, b in zip(["b_idx", "b2_idx", "a_packed", "s_ok"],
-                          native, python):
-        np.testing.assert_array_equal(a, b, err_msg=name)
 
 
 @pytest.mark.parametrize(
@@ -271,33 +223,10 @@ def test_ed_prep_words_refuses_inconsistent_input(ed_mixed_rows):
                          *slots, sub, 7)
 
 
-def test_ed_plain_windows_native_matches_python():
-    """ed_prep_plain (the legacy windowed kernel's window extraction) vs
-    the pure-numpy bit path, over already-reduced scalars as
-    prepare_batch_windowed feeds it."""
-    import numpy as _np
-
-    from corda_tpu.ops import field as F
-    from corda_tpu.ops.weierstrass import (_bits_to_w_windows,
-                                           _bits_to_windows)
-    rng = np.random.default_rng(46)
-    ss = [int.from_bytes(rng.bytes(32), "little") % ecmath.ED_L
-          for _ in range(30)] + [0, ecmath.ED_L - 1]
-    ks = [int.from_bytes(rng.bytes(32), "little") % ecmath.ED_L
-          for _ in range(30)] + [ecmath.ED_L - 1, 0]
-    h_words = _np.zeros((len(ks), 8), dtype=_np.uint64)
-    h_words[:, :4] = sp.ints_to_words(ks)
-    b_idx, a_digits, s_ok = sp.ed_prep_plain(h_words, sp.ints_to_words(ss))
-    assert s_ok.all()
-    want_b = _bits_to_w_windows(F.scalars_to_bits(ss), 16).astype(np.int32)
-    want_a = _bits_to_windows(F.scalars_to_bits(ks)).astype(np.uint8)
-    np.testing.assert_array_equal(b_idx, want_b)
-    np.testing.assert_array_equal(a_digits, want_a)
-
-
-def test_ed_split_kernel_matches_plain_windowed():
-    """The split-k kernel and the plain windowed kernel must agree verdict-
-    for-verdict over valid + tampered + edge-encoded signatures."""
+def test_ed_split_kernel_matches_plain_reference():
+    """The split-k kernel and the plain reference (``verify_core``, the
+    256-bit Shamir ladder fed by ``prepare_batch``) must agree verdict for
+    verdict over valid + tampered + edge-encoded signatures."""
     from corda_tpu.ops import ed25519 as ed
     rng = np.random.default_rng(45)
     items = []
@@ -315,9 +244,8 @@ def test_ed_split_kernel_matches_plain_windowed():
         (pub0, b"short", msg0),
     ]
     split = ed.verify_batch(items)   # routes through the split kernel
-    plain_pending = ed.prepare_batch_windowed(items, ed.B_WINDOW)
-    *args, pre = plain_pending
-    plain = np.asarray(ed._verify_kernel_windowed(*args, w=ed.B_WINDOW)) & pre
+    *args, pre = ed.prepare_batch(items + [items[-1]] * (16 - len(items)))
+    plain = (np.asarray(ed._verify_kernel(*args)) & pre)[:len(items)]
     np.testing.assert_array_equal(split, plain)
     want = [ecmath.ed25519_verify(pub, msg, sig) for pub, sig, msg in items]
     np.testing.assert_array_equal(split, np.asarray(want))
@@ -499,7 +427,7 @@ def test_stale_so_falls_back_loudly(caplog):
     # the matching version loads fine (the gate, not the loader, refused)
     assert sp._load(candidates=[real]) is not None
     # and a refused library means available() gates every native seam
-    assert sp.SM_VERSION == 6  # 5→6: sm_ed_prep_words and its SHA-512
+    assert sp.SM_VERSION == 7  # 6→7: the exports of deleted ladders left
 
 
 def test_k1_verify_through_native_prep():

@@ -161,35 +161,29 @@ def _ecdsa_items(curve, n):
     return _tile(base, n)
 
 
-def _donated_service_kernel(scheme):
-    """(jit, real prep args, static kwargs) — the DONATED form the service
-    path runs on a chip, built with the argnums its field.donating_jit
-    caller passes (CPU tests only ever see the plain-jit degrade)."""
-    donate = (0, 1, 2, 3)
+def _service_kernel(scheme):
+    """(the scheme's one jit handle, real prep args, static kwargs): what
+    the service path, ``verify_batch`` and the tools all dispatch."""
     if scheme == "ed25519":
         *args, _ = ed_ops.prepare_batch_split(_ed_items(ROWS),
                                               ed_ops.SPLIT_B_WINDOW)
-        return (jax.jit(ed_ops.verify_core_split, donate_argnums=donate,
-                        static_argnames=("w",)),
-                args, {"w": ed_ops.SPLIT_B_WINDOW})
+        return (ed_ops._verify_kernel_split, args,
+                {"w": ed_ops.SPLIT_B_WINDOW})
     if scheme == "secp256k1":
         *args, _ = wc_ops.prepare_batch_hybrid_wide(
             _ecdsa_items(ecmath.SECP256K1, ROWS), wc_ops.HYBRID_G_WINDOW)
-        return (jax.jit(wc_ops.verify_core_hybrid_wide,
-                        donate_argnums=donate, static_argnames=("g_w",)),
-                args, {"g_w": wc_ops.HYBRID_G_WINDOW})
+        return (wc_ops._verify_kernel_hybrid_wide, args,
+                {"g_w": wc_ops.HYBRID_G_WINDOW})
     *args, _, _ = wc_ops.prepare_batch_r1_split(
         ecmath.SECP256R1, _ecdsa_items(ecmath.SECP256R1, ROWS))
-    return (jax.jit(wc_ops.verify_core_r1_split, donate_argnums=donate,
-                    static_argnames=("curve_name", "w")),
-            args, {"curve_name": "secp256r1", "w": wc_ops.R1_G_WINDOW})
+    return (wc_ops._verify_kernel_r1_split, args,
+            {"curve_name": "secp256r1", "w": wc_ops.R1_G_WINDOW})
 
 
 @pytest.mark.slow
-@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
 @pytest.mark.parametrize("scheme", ["ed25519", "secp256k1", "secp256r1"])
-def test_donated_ec_kernel_compiles_for_v5e(one_chip, scheme):
-    fn, args, static = _donated_service_kernel(scheme)
+def test_service_ec_kernel_compiles_for_v5e(one_chip, scheme):
+    fn, args, static = _service_kernel(scheme)
     compiled = _compile(fn, *_shapes(args, one_chip), **static)
     # fits one v5e chip's 16 GB with room for three batches in flight
     mem = compiled.memory_analysis()
@@ -197,11 +191,7 @@ def test_donated_ec_kernel_compiles_for_v5e(one_chip, scheme):
 
 
 @pytest.mark.slow
-@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
-def test_sharded_ed25519_split_compiles_for_v5e_mesh(mesh4, monkeypatch):
-    # the mesh twin donates only where the backend supports it; the CPU
-    # backend these tests run under does not, so steer it here
-    monkeypatch.setattr(F, "donation_supported", lambda: True)
+def test_sharded_ed25519_split_compiles_for_v5e_mesh(mesh4):
     w = ed_ops.SPLIT_B_WINDOW
     *head, _ = ed_ops.prepare_batch_split(_ed_items(ROWS), w,
                                           device_tables=False)
