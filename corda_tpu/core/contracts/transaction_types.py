@@ -6,6 +6,8 @@ verify dispatch (General) / unmodified-but-notary check (NotaryChange).
 """
 from __future__ import annotations
 
+import time
+
 from ..serialization import serializable
 from .exceptions import (
     ContractRejection, DuplicateInputStates, InvalidNotaryChange,
@@ -21,10 +23,12 @@ class TransactionType:
     General: "TransactionType"
     NotaryChange: "TransactionType"
 
-    def verify(self, tx) -> None:
+    def verify(self, tx, tally: dict | None = None) -> None:
         """Platform rules common to all types, then type-specific rules.
         Presence of *signatures* is NOT checked here — only required keys
-        (TransactionTypes.kt:21-28)."""
+        (TransactionTypes.kt:21-28). ``tally``, where given, is added to:
+        contract class name -> ``[runs, nanoseconds inside verify]`` (the
+        verifier service's rules pass meters it once a wave)."""
         if tx.notary is None and tx.time_window is not None:
             raise TransactionVerificationException(
                 tx.id, "Transactions with time-windows must be notarised")
@@ -34,7 +38,7 @@ class TransactionType:
         missing = self.verify_signers(tx)
         if missing:
             raise SignersMissing(tx.id, sorted(missing))
-        self.verify_transaction(tx)
+        self.verify_transaction(tx, tally)
 
     def verify_signers(self, tx) -> set:
         notary_keys = {inp.state.notary.owning_key for inp in tx.inputs}
@@ -55,7 +59,7 @@ class TransactionType:
     def get_required_signers(self, tx) -> set:
         raise NotImplementedError
 
-    def verify_transaction(self, tx) -> None:
+    def verify_transaction(self, tx, tally: dict | None = None) -> None:
         raise NotImplementedError
 
     def __eq__(self, other):
@@ -74,10 +78,10 @@ class _General(TransactionType):
     def get_required_signers(self, tx) -> set:
         return {k for cmd in tx.commands for k in cmd.signers}
 
-    def verify_transaction(self, tx) -> None:
+    def verify_transaction(self, tx, tally: dict | None = None) -> None:
         self._verify_no_notary_change(tx)
         self._verify_encumbrances(tx)
-        self._verify_contracts(tx)
+        self._verify_contracts(tx, tally)
 
     @staticmethod
     def _verify_no_notary_change(tx):
@@ -105,17 +109,23 @@ class _General(TransactionType):
                     tx.id, enc, TransactionMissingEncumbranceException.OUTPUT)
 
     @staticmethod
-    def _verify_contracts(tx):
+    def _verify_contracts(tx, tally: dict | None = None):
         ctx = tx.to_transaction_for_contract()
         contracts = []
         for st in list(ctx.inputs) + list(ctx.outputs):
             if st.contract not in contracts:
                 contracts.append(st.contract)
         for contract in contracts:
+            t0 = time.perf_counter_ns() if tally is not None else 0
             try:
                 contract.verify(ctx)
             except Exception as e:
                 raise ContractRejection(tx.id, contract, e) from e
+            finally:
+                if tally is not None:
+                    row = tally.setdefault(type(contract).__name__, [0, 0])
+                    row[0] += 1
+                    row[1] += time.perf_counter_ns() - t0
 
 
 @serializable("TransactionType.NotaryChange", to_fields=lambda t: [],
@@ -125,7 +135,7 @@ class _NotaryChange(TransactionType):
         return {k.owning_key if hasattr(k, "owning_key") else k
                 for inp in tx.inputs for k in inp.state.data.participants}
 
-    def verify_transaction(self, tx) -> None:
+    def verify_transaction(self, tx, tally: dict | None = None) -> None:
         ok = (len(tx.inputs) == len(tx.outputs) and not tx.commands and all(
             inp.state.data == out.data and inp.state.notary != out.notary
             for inp, out in zip(tx.inputs, tx.outputs)))

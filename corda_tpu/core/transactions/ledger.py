@@ -90,9 +90,11 @@ class LedgerTransaction:
         self.type = type if type is not None else TransactionType.General
         self.time_window = time_window
 
-    def verify(self) -> None:
-        """Host-side synchronous verification (LedgerTransaction.kt:62)."""
-        self.type.verify(self)
+    def verify(self, tally: dict | None = None) -> None:
+        """Host-side synchronous verification (LedgerTransaction.kt:62).
+        ``tally``: ``TransactionType.verify``'s, the time inside each
+        contract's ``verify`` by contract."""
+        self.type.verify(self, tally)
 
     def to_transaction_for_contract(self) -> TransactionForContract:
         return TransactionForContract(

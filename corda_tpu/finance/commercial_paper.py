@@ -193,6 +193,36 @@ class CommercialPaper(Contract):
             paper_ref.state.notary)
         builder.add_command(Move(), paper_ref.state.data.owner)
 
+    @staticmethod
+    def generate_redeem(builder, paper_ref, coins: list) -> list[PublicKey]:
+        """Redeem ``paper_ref`` at face value (CommercialPaper.kt
+        ``generateRedeem``): the paper is consumed, the face value moves
+        from ``coins`` (the issuer's cash, StateAndRefs) to the paper's
+        holder with the change back to the coins' first owner, ``Redeem`` is
+        signed by the holder and the cash's ``Move`` by the coins' owners.
+        Returns the keys the cash leg needs. The transaction still wants a
+        time-window at or after maturity, which the caller sets as the
+        Kotlin's caller does.
+
+        Departures from the Kotlin: it picks the cash itself
+        (``vault.generateSpend(tx, amount, paper.state.data.owner)``); here
+        the caller hands the coins, as ``finance/trade.py`` hands
+        ``Cash.generate_spend`` the buyer's, and the cash leg is that
+        helper's. The coins have to be of the face value's own (issuer,
+        currency) token: ``RedeemClause`` counts no other cash, where the
+        Kotlin's ``sumCashBy(owner)`` would fail on a mixed sum."""
+        from .cash import Cash
+        paper = paper_ref.state.data
+        token = paper.face_value.token
+        if any(c.state.data.amount.token != token for c in coins):
+            raise ValueError(f"a redemption pays in {token} and no other cash")
+        keys = Cash.generate_spend(
+            builder, Amount(paper.face_value.quantity, token.product),
+            paper.owner, coins, change_owner=coins[0].state.data.owner)
+        builder.add_input_state(paper_ref)
+        builder.add_command(Redeem(), paper.owner)
+        return keys
+
 
 CP_PROGRAM = CommercialPaper()
 

@@ -339,10 +339,10 @@ class TpuTransactionVerifierService(TransactionVerifierService):
             n_tx=sum(len(level) for level in levels))
         try:
             ctx = span.context() or trace_ctx
-            for level in levels:
+            for k, level in enumerate(levels):
                 passed, error = _passed_in_order(self._admit_level(
                     level, services, check_sufficient_signatures, ctx,
-                    _on_this_thread))
+                    _on_this_thread, level=k))
                 verified += passed
                 if error is not None:
                     break
@@ -355,7 +355,8 @@ class TpuTransactionVerifierService(TransactionVerifierService):
             outcome.set_result((verified, error))
 
     def _admit_level(self, stxs, services, check_sufficient_signatures,
-                     trace_ctx, run) -> list[Future]:
+                     trace_ctx, run, level: int | None = None
+                     ) -> list[Future]:
         """One level, admitted on the calling thread; ``run`` takes what
         completes it (the pool's ``submit``: members complete side by side
         on its workers; ``_on_this_thread``: here, one after another, and
@@ -371,10 +372,12 @@ class TpuTransactionVerifierService(TransactionVerifierService):
         queue, collected and verified by the thread that completes it.
 
         Tracing: span ``verifier.wave`` (entry -> last member resolved;
-        tags ``n_tx``, ``n_sigs``, ``admitted`` = ``bulk`` | ``held``); a
-        bulk level's children are ``verifier.wave.submit`` / ``.verdicts`` /
-        ``.coverage`` / ``.rules``. Meters ``Verifier.WaveTx.bulk`` /
-        ``.held`` count members by how their level was admitted."""
+        tags ``n_tx``, ``n_sigs``, ``admitted`` = ``bulk`` | ``held`` and,
+        under a ``verifier.levels``, ``level``: its place in the walk, 0
+        first); a bulk level's children are ``verifier.wave.submit`` /
+        ``.verdicts`` / ``.coverage`` / ``.rules``. Meters
+        ``Verifier.WaveTx.bulk`` / ``.held`` count members by how their
+        level was admitted."""
         n_sigs = sum(len(stx.sigs) for stx in stxs)
         tracer = get_tracer()
         bulk = self.batcher.wave_is_the_planners(
@@ -382,6 +385,8 @@ class TpuTransactionVerifierService(TransactionVerifierService):
         wave = tracer.span("verifier.wave", parent=trace_ctx,
                            n_tx=len(stxs), n_sigs=n_sigs,
                            admitted="bulk" if bulk else "held")
+        if level is not None:
+            wave.set_tag("level", level)
         self.metrics.meter("Verifier.WaveTx.bulk" if bulk
                            else "Verifier.WaveTx.held").mark(len(stxs))
         if not bulk:
@@ -421,7 +426,13 @@ class TpuTransactionVerifierService(TransactionVerifierService):
         contract rules of those. Then every member's future, in order. The
         out-of-process worker's ``_complete_burst`` runs the same passes
         over its requests (``burst_verdicts`` / ``first_unverified`` are
-        shared)."""
+        shared).
+
+        The time INSIDE the contracts is tallied apart from resolution (two
+        clock reads a contract a member, no span) and marked once a wave,
+        as coverage's tally is: ``Verifier.ContractRuns.<Contract>`` and
+        ``Verifier.ContractMicros.<Contract>``, by the contract's class
+        name."""
         from ..core.transactions.signed import SignaturesMissingException
         tracer = get_tracer()
         ctx = wave.context()
@@ -461,13 +472,19 @@ class TpuTransactionVerifierService(TransactionVerifierService):
                     tally[1])
                 self.metrics.meter("Verifier.CompositeLeafVisits").mark(
                     tally[2])
+            contracts: dict = {}    # class name -> [runs, nanoseconds]
             with tracer.span("verifier.wave.rules", parent=ctx, cpu=True,
                              n_tx=len(alive)):
                 for i in alive:
                     try:
-                        stxs[i].to_ledger_transaction(services).verify()
+                        stxs[i].to_ledger_transaction(services).verify(
+                            contracts)
                     except Exception as e:
                         outcomes[i] = e
+            for name, (runs, nanos) in contracts.items():
+                self.metrics.meter(f"Verifier.ContractRuns.{name}").mark(runs)
+                self.metrics.meter(f"Verifier.ContractMicros.{name}").mark(
+                    nanos // 1000)
         except BaseException as exc:    # never a member left unresolved
             outcomes = [exc if o is None else o for o in outcomes]
             raise

@@ -143,9 +143,11 @@ def test_batch_readers__the_new_metrics_are_listed_wherever_they_stand():
              (["genledger-oop.stream"], "tx_per_s")}
     # PR 42 appended the genledger-mixed cell's metrics after these two
     # (benchmarks/tests/test_mixedbackfill.py holds those to their own
-    # list), and PR 43 the walk's two counters (held by name below)
+    # list), PR 43 the walk's two counters (held by name below) and PR 49
+    # the traderdemo-replay cell's nine (test_bookwalk.py holds those)
     assert order[at + len(br.NEW):at + len(br.NEW) + 2] == list(after)
-    assert all(name.endswith(".backfill") or name in WALK_COUNTERS
+    assert all(name.endswith((".backfill", ".bookwalk"))
+               or name in WALK_COUNTERS
                for name in order[at + len(br.NEW) + 2:])
     for name, (cells, moves) in after.items():
         row, lm = listed[name], files[name]
@@ -225,8 +227,10 @@ def test_steady_traced_rehearsal_reads_the_walk_counters(capsys):
 
 def test_mixedbackfill__the_cell_has_its_files_wherever_its_metrics_stand():
     """``test_mixedbackfill``'s excluded test, assert for assert, but for
-    "PR 42's metrics are the LAST of ``per_layer``": here they are one
-    unbroken run in their order, wherever later metrics were appended."""
+    "PR 42's entries are the LAST of their lists": here its metrics are one
+    unbroken run in their order, and its cell, its configuration and its
+    place in ``tx_per_s`` are each there once, wherever later entries were
+    appended (PR 49 appended a deployment after it)."""
     mb = sys.modules["benchmarks_tests_test_mixedbackfill"]
     cell = mb.bench_run.Cell(mb.CELL, mb.SPEC)
     assert cell.driver_name == "mixedbackfill" and cell.chips == 1
@@ -244,10 +248,11 @@ def test_mixedbackfill__the_cell_has_its_files_wherever_its_metrics_stand():
     order = [m["name"] for m in mb.SPEC["per_layer"]]
     at = order.index(mb.METRICS[0])
     assert order[at:at + len(mb.METRICS)] == mb.METRICS
-    assert mb.SPEC["workloads"][-1]["name"] == mb.CELL
-    assert mb.SPEC["configs"][-1]["name"] == "genledger-mixed"
+    assert [w["name"] for w in mb.SPEC["workloads"]].count(mb.CELL) == 1
+    assert [c["name"] for c in mb.SPEC["configs"]].count(
+        "genledger-mixed") == 1
     (tx,) = [m for m in mb.SPEC["end_to_end"] if m["name"] == "tx_per_s"]
-    assert tx["workloads"][-1] == mb.CELL and tx["bound"] == 0.05
+    assert tx["workloads"].count(mb.CELL) == 1 and tx["bound"] == 0.05
     config, traffic = cell.config, cell.traffic
     assert config["batcher_args"] == {"max_batch": 8192,
                                       "bucket_ladder": [256, 8192]}
